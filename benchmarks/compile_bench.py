@@ -27,8 +27,8 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import os
 import sys
-import tempfile
 import time
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
@@ -45,8 +45,10 @@ def main(argv=None):
     ap.add_argument("--rounds-per-step", type=int, default=4,
                     help="chunk width of the benchmarked round program")
     ap.add_argument("--cache", default=None, metavar="DIR",
-                    help="cache dir (default: fresh temp dir, so the cold "
-                         "leg is genuinely cold)")
+                    help="cache dir (default: <checkout>/.jax_cache; "
+                         "JAX_COMPILATION_CACHE_DIR, when set, wins). This "
+                         "program's own entry is dropped first, so the cold "
+                         "leg is genuinely cold")
     ap.add_argument("--min-speedup", type=float, default=5.0,
                     help="required cold/warm time-to-first-round ratio")
     ap.add_argument("--out", default="BENCH_COMPILE.json",
@@ -55,7 +57,8 @@ def main(argv=None):
 
     import jax
 
-    from fedtpu.compilation import (ProgramCache, program_config_slice,
+    from fedtpu.compilation import (ProgramCache, program_cache_dir,
+                                    program_config_slice,
                                     program_fingerprint)
     from fedtpu.config import get_preset
     from fedtpu.orchestration.loop import build_experiment
@@ -73,19 +76,22 @@ def main(argv=None):
         args=(exp.state, exp.batch),
         extra={"rounds_per_step": int(args.rounds_per_step)})
 
-    cache_dir = args.cache or tempfile.mkdtemp(prefix="fedtpu-compile-bench-")
+    # The ProgramCache of the one cache directory every program of the
+    # repo uses (never a temp dir: a cache that moves never hits). The
+    # cold leg is made cold by dropping this program's own entry first.
+    cache_dir = program_cache_dir(args.cache)
+    cache = ProgramCache(cache_dir)
+    for stale in cache._paths(key):
+        if os.path.exists(stale):
+            os.remove(stale)
 
     # COLD leg: trace + XLA compile (+ store) + first chunk of rounds.
     # The state is cloned per call: the round step donates its state
     # buffer, and both legs must start from identical bits.
-    cache = ProgramCache(cache_dir)
     t0 = time.perf_counter()
     entry = cache.get_or_compile(key, step, exp.state, exp.batch,
                                  label="bench-round")
     cold_compile_s = time.perf_counter() - t0
-    if entry.warm:
-        raise SystemExit("compile_bench: cache dir already holds this "
-                         "program; point --cache at a fresh dir")
     out_cold = entry.compiled(clone(exp.state), exp.batch)
     jax.block_until_ready(out_cold)
     cold_total_s = time.perf_counter() - t0

@@ -76,11 +76,10 @@ def bench_mode(name: str, kw: dict, ds, reps: int, rps: int,
                           rounds_per_step=rps, server_opt=server, **kw)
 
     # Fetch-forced timing + flops floor — see fedtpu.utils.timing docstring
-    # for the methodology (round-1 postmortem). SEVERAL independent samples
-    # per mode (each itself min-of-3 windows): the tunneled transport's
-    # dispatch share jitters by ~±15%, and a single sample let added work
-    # appear cheaper than the baseline (review r2 weak #5) — the caller
-    # compares BANDS, not points.
+    # for the methodology. SEVERAL independent samples per mode (each
+    # itself min-of-3 windows): the dispatch share jitters with host load,
+    # and a single sample let added work appear cheaper than the baseline
+    # (review r2 weak #5) — the caller compares BANDS, not points.
     from fedtpu.utils.timing import compile_with_flops, timed_rounds
 
     step, flops_per_round = compile_with_flops(step, state, batch)

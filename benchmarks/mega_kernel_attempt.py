@@ -1,5 +1,5 @@
 """The mega-kernel ATTEMPT: one Pallas kernel per federated round — a
-preserved NEGATIVE result (benchmarks/RESULTS.md 'Roofline', round 4).
+preserved NEGATIVE result (PERF.md 'Earlier records', round 4).
 
 The whole round — per-client train fwd+bwd+Adam, eval confusion matrix,
 and the weighted-average accumulation — runs in a single pallas_call

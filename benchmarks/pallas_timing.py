@@ -4,7 +4,8 @@ for a multi-chip v5e topology.
 
 Adopt-on-win policy: a kernel that cannot beat XLA stays a tested
 library op and the production path keeps XLA; either way the measured
-number is recorded in benchmarks/RESULTS.md ('Pallas kernel timings').
+number is recorded in PERF.md (the round-4 timings are under 'Earlier
+records').
 
 Run: ``python benchmarks/pallas_timing.py`` (~2 min on the v5e).
 """

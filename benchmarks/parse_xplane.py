@@ -3,7 +3,7 @@
 Usage: ``python benchmarks/parse_xplane.py <trace>/plugins/profile/*/\
 *.xplane.pb`` — prints, per TPU device plane, the total duration and
 event count of every HLO op, most expensive first. This is how the
-round-4 roofline attribution (benchmarks/RESULTS.md 'Roofline') located
+round-4 roofline attribution (PERF.md 'Earlier records') located
 the activation-stream fusions that dominate the income round.
 """
 import sys, collections

@@ -15,7 +15,7 @@ marginal MFU sits near 22% and what bound it actually saturates:
    demonstrating the framework clears 40% MFU whenever the workload's
    arithmetic intensity allows it.
 
-Conclusion this script reproduces (benchmarks/RESULTS.md 'Roofline'):
+Conclusion this script reproduces (PERF.md 'Earlier records'):
 the income round is BYTE-throughput bound on its (8, 1000, {50,200})
 activation streams, which XLA already moves as bf16/u8; its 22%
 marginal MFU is that bandwidth roofline, not scheduling headroom — the

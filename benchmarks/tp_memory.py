@@ -177,7 +177,7 @@ def main():
     for r in meas:
         r["vs_1d"] = round(base / r["state_bytes_per_device"], 2)
         print(json.dumps(r))
-    # The guarantees the RESULTS table quotes: tp=2 halves, tp=4 quarters
+    # The guarantees docs/ARCHITECTURE.md quotes: tp=2 halves, tp=4 quarters
     # (within 10% — the replicated logits head and row-biases are the slack).
     assert meas[1]["vs_1d"] > 1.8 and meas[2]["vs_1d"] > 3.6, meas
     assert meas[2]["xla_peak_bytes"] < meas[0]["xla_peak_bytes"] / 2, meas

@@ -686,7 +686,7 @@ def run_cohort_experiment(cfg, dataset=None, verbose: bool = True,
     reference early-stop rule (client-mean 4-metric vector, allclose
     within ``tolerance`` for ``termination_patience`` rounds), same
     checkpoint layout (+ the store's touched records in the meta item)."""
-    from fedtpu.data import load_dataset
+    from fedtpu.data import data_notice, load_dataset
     from fedtpu.data.sharding import pack_clients
     from fedtpu.models import build_model
     from fedtpu.ops import build_optimizer
@@ -715,6 +715,7 @@ def run_cohort_experiment(cfg, dataset=None, verbose: bool = True,
                           level=tel.log_level)
 
     ds = dataset if dataset is not None else load_dataset(cfg.data)
+    log.info(data_notice(ds))
     model_cfg = cfg.model
     if model_cfg.kind == "mlp" and model_cfg.input_dim != ds.input_dim:
         model_cfg = dataclasses.replace(model_cfg, input_dim=ds.input_dim)

@@ -15,21 +15,29 @@ Three layers (ISSUE 3):
 Import-light: jax loads only when a compile/lookup actually happens.
 """
 
-from fedtpu.compilation.cache import (CACHE_FORMAT_VERSION, CacheEntry,
-                                      ProgramCache, configure_persistent_cache,
+from fedtpu.compilation.cache import (CACHE_DIR_ENV, CACHE_FORMAT_VERSION,
+                                      DEFAULT_CACHE_DIR, PROGRAMS_SUBDIR,
+                                      CacheEntry, ProgramCache,
+                                      configure_persistent_cache,
                                       environment_fingerprint,
-                                      program_fingerprint)
+                                      program_cache_dir, program_fingerprint,
+                                      resolve_cache_dir)
 from fedtpu.compilation.executor import CompileExecutor
 from fedtpu.compilation.warmup import program_config_slice, warmup_preset
 
 __all__ = [
+    "CACHE_DIR_ENV",
     "CACHE_FORMAT_VERSION",
     "CacheEntry",
     "CompileExecutor",
+    "DEFAULT_CACHE_DIR",
+    "PROGRAMS_SUBDIR",
     "ProgramCache",
     "configure_persistent_cache",
     "environment_fingerprint",
+    "program_cache_dir",
     "program_config_slice",
     "program_fingerprint",
+    "resolve_cache_dir",
     "warmup_preset",
 ]

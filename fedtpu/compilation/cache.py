@@ -38,31 +38,72 @@ import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 __all__ = [
+    "CACHE_DIR_ENV",
     "CACHE_FORMAT_VERSION",
     "CacheEntry",
+    "DEFAULT_CACHE_DIR",
+    "PROGRAMS_SUBDIR",
     "ProgramCache",
     "configure_persistent_cache",
     "environment_fingerprint",
+    "program_cache_dir",
     "program_fingerprint",
+    "resolve_cache_dir",
 ]
 
 # Bump when the on-disk layout or pickled tuple shape changes; old
 # entries are then treated as misses, never deserialized.
 CACHE_FORMAT_VERSION = 1
 
+# jax's own variable for the persistent cache directory. Whoever starts
+# the process places the cache with it (a chip machine keeps one directory
+# between calls and nothing else), so it wins over anything set in code.
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
-def configure_persistent_cache(cache_dir: str) -> str:
-    """Point jax's persistent (backend) compilation cache at ``cache_dir``.
+# Where the cache lives when nobody placed it: one fixed path inside the
+# checkout. Fixed because a directory that moves between runs (a temp
+# dir, a pid or a timestamp in the name) can never hit twice.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-    One shared entry point for the CLI, ``run_experiment``, the sweep and
-    bench, so library callers get identical behavior to ``fedtpu run
-    --compilation-cache``. Must run before the programs of interest are
+# Subdirectory of the cache dir holding ProgramCache's serialized
+# executables; the remainder is jax's persistent backend cache.
+PROGRAMS_SUBDIR = "programs"
+
+
+def resolve_cache_dir(cache_dir: Optional[str] = None) -> str:
+    """The one compile-cache directory of this process:
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else the caller's explicit
+    ``cache_dir`` (``--compilation-cache`` / ``RunConfig.compilation_cache``),
+    else :data:`DEFAULT_CACHE_DIR`."""
+    placed = os.environ.get(CACHE_DIR_ENV)
+    if placed:
+        return placed
+    if cache_dir:
+        return os.path.abspath(os.path.expanduser(cache_dir))
+    return DEFAULT_CACHE_DIR
+
+
+def program_cache_dir(cache_dir: Optional[str] = None) -> str:
+    """Directory of the serialized-executable store under the resolved
+    cache dir."""
+    return os.path.join(resolve_cache_dir(cache_dir), PROGRAMS_SUBDIR)
+
+
+def configure_persistent_cache(cache_dir: Optional[str] = None) -> str:
+    """Turn on jax's persistent (backend) compilation cache at
+    :func:`resolve_cache_dir` and return that directory.
+
+    One shared entry point for the CLI, ``run_experiment``, the sweep,
+    ``fedtpu serve``, bench and chip_smoke, so every program of the repo
+    caches in the same place. Must run before the programs of interest are
     compiled; safe to call repeatedly. Respects an explicit
     ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` from the environment.
     """
     import jax
 
-    path = os.path.abspath(os.path.expanduser(cache_dir))
+    path = resolve_cache_dir(cache_dir)
     os.makedirs(path, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", path)
     if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
@@ -194,6 +235,7 @@ class ProgramCache:
         self.hits = 0
         self.misses = 0
         self.store_errors = 0
+        self.load_errors = 0
 
     # ------------------------------------------------------------- paths
     def _paths(self, key: str) -> Tuple[str, str]:
@@ -249,13 +291,20 @@ class ProgramCache:
             with open(bin_path, "rb") as fh:
                 raw = fh.read()
             if hashlib.sha256(raw).hexdigest() != meta.get("payload_sha256"):
-                return None                   # truncated / corrupted blob
+                raise ValueError("payload checksum mismatch (truncated or "
+                                 "corrupted blob)")
             payload, in_tree, out_tree = pickle.loads(raw)
             from jax.experimental import serialize_executable as se
             compiled = se.deserialize_and_load(payload, in_tree, out_tree)
-        except Exception:
-            # Graceful fallback: any unpickle/deserialize failure (stale
-            # jaxlib internals, foreign blob) degrades to a recompile.
+        except Exception as exc:
+            # Graceful fallback: an entry that is there and current but
+            # does not load (corrupt blob, a runtime that refuses the
+            # serialized executable) degrades to a recompile — counted
+            # and traced, so a cache that never serves is visible.
+            self.load_errors += 1
+            self._count("load_errors")
+            self.tracer.event("program_cache", phase="load_error", key=key,
+                              error=repr(exc))
             return None
         dur = time.perf_counter() - t0
         return CacheEntry(compiled=compiled, key=key, warm=True,
@@ -351,6 +400,7 @@ class ProgramCache:
     def stats(self) -> Dict[str, Any]:
         return {"dir": self.cache_dir, "hits": self.hits,
                 "misses": self.misses, "store_errors": self.store_errors,
+                "load_errors": self.load_errors,
                 "entries": len(self.entries())}
 
     def manifest_info(self) -> Dict[str, Any]:

@@ -14,18 +14,14 @@ compile.
 from __future__ import annotations
 
 import dataclasses
-import os
 import time
 from typing import Any, Dict, Optional, Sequence
 
-from fedtpu.compilation.cache import (ProgramCache, configure_persistent_cache,
-                                      program_fingerprint)
+from fedtpu.compilation.cache import (ProgramCache,
+                                      configure_persistent_cache,
+                                      program_cache_dir, program_fingerprint)
 
 __all__ = ["program_config_slice", "warmup_preset"]
-
-# Subdirectory of the user-facing cache dir holding serialized
-# executables; the remainder is jax's persistent backend cache.
-PROGRAMS_SUBDIR = "programs"
 
 
 def program_config_slice(cfg) -> Dict[str, Any]:
@@ -50,7 +46,7 @@ def program_config_slice(cfg) -> Dict[str, Any]:
 
 def warmup_preset(
     preset: str = "income-8",
-    cache_dir: str = "fedtpu-cache",
+    cache_dir: Optional[str] = None,
     widths: Optional[Sequence[int]] = None,
     synthetic_rows: Optional[int] = None,
     include_eval: bool = True,
@@ -69,8 +65,8 @@ def warmup_preset(
     from fedtpu.telemetry import build_manifest
 
     t_begin = time.perf_counter()
-    configure_persistent_cache(cache_dir)
-    cache = ProgramCache(os.path.join(cache_dir, PROGRAMS_SUBDIR),
+    cache_dir = configure_persistent_cache(cache_dir)
+    cache = ProgramCache(program_cache_dir(cache_dir),
                          tracer=tracer, registry=registry)
 
     cfg = get_preset(preset)
@@ -112,7 +108,7 @@ def warmup_preset(
 
     report = {
         "preset": preset,
-        "cache_dir": os.path.abspath(cache_dir),
+        "cache_dir": cache_dir,
         "widths": [int(w) for w in widths],
         "programs": programs,
         "total_s": round(time.perf_counter() - t_begin, 4),

@@ -332,9 +332,9 @@ class RunConfig:
     # run, bad lr), writing an emergency checkpoint if checkpoint_dir is set.
     halt_on_nonfinite: bool = True
     # Overlap host-side metric processing with the NEXT chunk's device
-    # execution (one chunk kept in flight). Removes one dispatch+fetch RTT
-    # per chunk (the dominant per-chunk cost through a remote transport) at
-    # the price of stop decisions lagging one chunk. (The reference's
+    # execution (one chunk kept in flight). Removes one dispatch+fetch round
+    # trip per chunk from the critical path at the price of stop decisions
+    # lagging one chunk. (The reference's
     # stop-signal bcast is also read one loop-top late — :132 vs :195 —
     # but its doomed iteration breaks before training, so unlike this
     # mode it never trains past the stop; tests/test_stop_lag.py.)
@@ -354,14 +354,18 @@ class RunConfig:
     # (fedtpu.parallel.tp): hidden weights shard over a tensor-parallel axis
     # of this extent. MLP only; partial participation unsupported there.
     model_parallel: int = 1
-    # Persistent XLA compilation-cache directory (None = off). Applied by
-    # run_experiment / the sweep / bench via
-    # fedtpu.compilation.configure_persistent_cache, so library callers get
-    # the same warm-start behavior as the CLI's --compilation-cache flag.
+    # The persistent XLA cache is always on, at
+    # fedtpu.compilation.resolve_cache_dir: JAX_COMPILATION_CACHE_DIR when
+    # set, else this directory, else <checkout>/.jax_cache. Setting this
+    # also turns on the serialized-executable ProgramCache (under the same
+    # directory) for overlap_compile / mpmd / the sweep.
     compilation_cache: Optional[str] = None
     # Background-compile the rounds_per_step-wide chunk program while R=1
-    # warmup rounds already train (fedtpu.compilation.CompileExecutor);
-    # bitwise-identical results, shorter time-to-first-round.
+    # warmup rounds already train (fedtpu.compilation.CompileExecutor):
+    # the same math and a shorter time-to-first-round. Bitwise the eager
+    # run's results on the CPU backend (tests); on the TPU the width-1 and
+    # the scanned program differ in the last bit, so the two trajectories
+    # part after a few rounds (chip_smoke.py, PR 21).
     overlap_compile: bool = False
     # Structured telemetry (span/event sink, manifest, logger level).
     telemetry: TelemetryConfig = TelemetryConfig()

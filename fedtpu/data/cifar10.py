@@ -87,8 +87,12 @@ def load_cifar10(root: Optional[str] = None, flatten: bool = True,
         x_test, y_test = _load_batch(os.path.join(d, "test_batch"))
         x_test = x_test.astype(np.float32) / 255.0
         y_test = np.asarray(y_test, np.int32)
+        source = {"kind": "cifar10", "path": d,
+                  "rows": int(len(x_train) + len(x_test))}
     else:
         x, y = synthetic_cifar_like(synthetic_rows)
+        source = {"kind": "synthetic", "generator": "synthetic_cifar_like",
+                  "rows": int(len(x))}
         n_test = max(1, len(x) // 5)
         x_train, y_train = x[:-n_test], y[:-n_test]
         x_test, y_test = x[-n_test:], y[-n_test:]
@@ -103,4 +107,5 @@ def load_cifar10(root: Optional[str] = None, flatten: bool = True,
         num_classes=10,
         feature_names=tuple(f"px{i}" for i in range(x_train.shape[1])),
         label_classes=np.arange(10),
+        source=source,
     )

@@ -20,7 +20,7 @@ Differences from the reference, by design:
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional
+from typing import Any, Dict, Optional
 
 import numpy as np
 import pandas as pd
@@ -39,6 +39,11 @@ class Dataset:
     num_classes: int
     feature_names: tuple
     label_classes: np.ndarray  # original label values, sorted (LabelEncoder order)
+    # Where the rows came from: {"kind": "csv", "path", "parser", "rows"} or
+    # {"kind": "synthetic", "generator", "rows"}. The run loop logs it first
+    # and records it in the manifest, so a synthetic stand-in can never pass
+    # for the dataset a preset is named after.
+    source: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     @property
     def input_dim(self) -> int:
@@ -86,8 +91,9 @@ def _train_test_split(x, y, test_size: float, seed: int):
 
 
 def _load_encoded(csv_path: str, use_native: bool):
-    """Load + label-encode a CSV: ``(column_names, float64 matrix, classes)``
-    where object columns in the matrix already hold sorted-unique codes.
+    """Load + label-encode a CSV: ``(column_names, float64 matrix, classes,
+    parser)`` where object columns in the matrix already hold sorted-unique
+    codes and ``parser`` names who parsed it ('native' | 'pandas').
 
     Primary path is the native C++ loader (fedtpu.native — one parse pass,
     the host-runtime replacement for the reference's per-rank pandas +
@@ -99,10 +105,10 @@ def _load_encoded(csv_path: str, use_native: bool):
         from fedtpu import native
         if native.available():
             header, _, mat, classes = native.load_csv(csv_path)
-            return list(header), mat, classes
+            return list(header), mat, classes, "native"
     df = pd.read_csv(csv_path)
     encoders = _label_encode(df)
-    return list(df.columns), df.to_numpy(dtype=np.float64), encoders
+    return list(df.columns), df.to_numpy(dtype=np.float64), encoders, "pandas"
 
 
 def synthetic_income_like(rows: int, features: int, classes: int,
@@ -124,9 +130,13 @@ def load_tabular_dataset(cfg: DataConfig) -> Dataset:
                                      cfg.synthetic_classes)
         label_classes = np.arange(cfg.synthetic_classes)
         feature_names = tuple(f"f{i}" for i in range(x.shape[1]))
+        source = {"kind": "synthetic", "generator": "synthetic_income_like",
+                  "rows": int(len(x))}
     else:
-        loaded = _load_encoded(cfg.csv_path, cfg.native_loader)
-        columns, mat, encoders = loaded
+        columns, mat, encoders, parser = _load_encoded(cfg.csv_path,
+                                                       cfg.native_loader)
+        source = {"kind": "csv", "path": cfg.csv_path, "parser": parser,
+                  "rows": int(len(mat))}
         if cfg.label_column not in columns:
             # Same guard as FL_CustomMLP...:219-220.
             raise KeyError(
@@ -166,4 +176,5 @@ def load_tabular_dataset(cfg: DataConfig) -> Dataset:
         num_classes=num_classes,
         feature_names=feature_names,
         label_classes=np.asarray(label_classes),
+        source=source,
     )

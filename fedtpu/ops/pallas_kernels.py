@@ -15,13 +15,15 @@ are fedtpu's TPU-native equivalents for the two per-round hot paths:
   analogue of the rank-0 weighted average, FL_CustomMLP...:108-116).
 
 All kernels run in interpret mode on CPU, which is how the unit tests check
-bit-parity against the pure-XLA implementations. ``fused_mlp_forward`` grids
+bit-parity against the pure-XLA implementations; compiled, they are held to
+the v5e's own compiler in tests/test_aot_tpu_compile.py and the fused
+forward runs on the chip in chip_smoke.py's ``--use-pallas`` eval. ``fused_mlp_forward`` grids
 the row axis to stay within the VMEM budget; ``fused_eval_confusion`` holds
 one client's rows at a time and refuses shapes whose activations would not
 fit (its confusion contraction needs the whole shard in one pass).
 
-Measured on the v5e (benchmarks/RESULTS.md 'Pallas kernel timings', round 4):
-XLA beats every kernel here at the income shapes — Mosaic's matmul codegen
+Measured on the v5e in round 4 (PERF.md 'Earlier records'; not re-timed
+since): XLA beats every kernel here at the income shapes — Mosaic's matmul codegen
 for pad-dominated operands (K=14 / N=2 against the 128-lane MXU) is several
 times slower than XLA's, the same effect that sank the whole-round
 mega-kernel attempt (benchmarks/mega_kernel_attempt.py). The kernels remain
@@ -112,13 +114,10 @@ def fused_mlp_forward(params, x: jax.Array,
     out_dim = dims[-1]
     # Inside shard_map (check_vma=True) the output's varying-manual-axes must
     # be declared explicitly; propagate the input's.
-    try:
-        vma = jax.typeof(x).vma
-    except Exception:
-        vma = frozenset()
     out = pl.pallas_call(
         functools.partial(_mlp_kernel, num_layers),
-        out_shape=jax.ShapeDtypeStruct((n, out_dim), jnp.float32, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((n, out_dim), jnp.float32,
+                                       vma=jax.typeof(x).vma),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tile, out_dim), lambda i: (i, 0),
@@ -169,8 +168,8 @@ def fused_eval_confusion(params, x: jax.Array, y: jax.Array,
     in-VMEM analogue of ``vmap(local_eval)`` (fedtpu.training.client).
     Bit-parity with the XLA chain is pinned in tests/test_pallas.py;
     measured on the v5e it LOSES to the XLA chain by a wide margin
-    (benchmarks/RESULTS.md 'Pallas kernel timings': Mosaic's matmul
-    codegen at these pad-dominated shapes), so every production path
+    (PERF.md 'Earlier records': Mosaic's matmul codegen at these
+    pad-dominated shapes), so every production path
     keeps XLA and this kernel stays a library/educational op.
     ``num_classes`` must be <= 8 (the padded output tile's sublane
     count)."""

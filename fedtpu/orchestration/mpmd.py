@@ -1,10 +1,11 @@
 """MPMD round pipelining — the monolithic round chunk decomposed into a
 small static DAG of AOT sub-programs (ISSUE 18; ROADMAP item 2).
 
-The synchronous early-stopping mode pays one dispatch RTT *and* one
-metric-fetch RTT per round through a remote transport — 15x slower than
-the pipelined headline at rps=100 (BENCH_r05: 1.04e-3 vs 7.1e-5
-s/round). The round-4 roofline (benchmarks/RESULTS.md) pinned the
+The synchronous early-stopping mode pays one dispatch *and* one metric
+fetch per chunk, serialized with the device (BENCH_r05, taken through the
+earlier remote transport: 1.04e-3 vs 7.1e-5 s/round pipelined at
+rps=100; on a v5e's own host the round trip is about a millisecond —
+PERF.md). The round-4 roofline (PERF.md 'Earlier records') pinned the
 on-chip marginal at its byte-bandwidth ceiling, so the remaining lever
 is host-side: split the round into concurrently resident programs in
 the spirit of MPMD pipeline parallelism (PAPERS.md, arXiv 2412.14374)

@@ -1,22 +1,3 @@
-import jax
-
-# jax.shard_map moved to the top-level namespace after 0.4.x; on older
-# jaxlib stacks (CPU CI boxes) only jax.experimental.shard_map exists.
-# Alias it once here so every engine (round, async_fed, tp, sweep) can
-# call jax.shard_map uniformly; a no-op wherever jax already exports it.
-if not hasattr(jax, "shard_map"):  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-    jax.shard_map = _shard_map
-
-# jax.lax.pcast is the varying-manual-axes cast; pre-VMA jax has no such
-# type distinction, so the numerically-identical fallback is identity
-# (those versions' shard_map handles replicated->varying via check_rep).
-if not hasattr(jax.lax, "pcast"):  # pragma: no cover - version-dependent
-    def _pcast_compat(v, axis_name, to):
-        del axis_name, to
-        return v
-    jax.lax.pcast = _pcast_compat
-
 from fedtpu.parallel.mesh import make_mesh, client_sharding, CLIENTS_AXIS  # noqa: F401
 from fedtpu.parallel.round import build_round_fn, init_federated_state  # noqa: F401
 from fedtpu.parallel import ring  # noqa: F401  (explicit ppermute ring schedules)

@@ -103,53 +103,12 @@ def _enable_cpu_collectives() -> None:
     implicit psum inside ``device_put``'s cross-process equality check.
     gloo-over-TCP is the CPU stand-in for DCN. Must run before the
     backend is created (same contract as ``jax.distributed.initialize``);
-    TPU/GPU platforms are untouched, and older jax without the flag is
-    tolerated."""
+    TPU/GPU platforms are untouched."""
     import os
     platforms = (jax.config.jax_platforms
                  or os.environ.get("JAX_PLATFORMS", ""))
     if platforms and platforms.split(",")[0].strip() == "cpu":
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # fedtpu: noqa[FTP102] flag absent in older jax — nothing to configure there
-            pass
-
-
-def _distributed_initialize(coordinator_address, num_processes, process_id,
-                            kwargs: dict) -> None:
-    """``jax.distributed.initialize`` across jax versions. The public API
-    gained ``heartbeat_timeout_seconds`` after 0.4.x; on older jax the
-    same semantics live on the internal state initializer's
-    coordination-service knobs (interval x max-missing, defaults 10 x 10
-    = the ~100 s detection latency documented on ``initialize``), so a
-    requested timeout is translated there rather than raising TypeError
-    or silently losing the caller's detection bound."""
-    import inspect
-    kw = dict(kwargs)
-    hb = kw.pop("heartbeat_timeout_seconds", None)
-    if hb is not None:
-        params = inspect.signature(jax.distributed.initialize).parameters
-        if "heartbeat_timeout_seconds" in params:
-            kw["heartbeat_timeout_seconds"] = hb
-        else:
-            try:
-                from jax._src.distributed import global_state
-                sp = inspect.signature(global_state.initialize).parameters
-                assert "client_heartbeat_interval_seconds" in sp
-                # max_missing stays at jax's default (10); the interval
-                # carries the requested total detection bound.
-                interval = max(1, int(hb) // 10)
-                global_state.initialize(
-                    coordinator_address=coordinator_address,
-                    num_processes=num_processes, process_id=process_id,
-                    service_heartbeat_interval_seconds=interval,
-                    client_heartbeat_interval_seconds=interval, **kw)
-                return
-            except Exception:  # fedtpu: noqa[FTP102] internal-API drift on some jax version: fall back to the public API and jax's default detection latency rather than failing init
-                pass
-    jax.distributed.initialize(coordinator_address=coordinator_address,
-                               num_processes=num_processes,
-                               process_id=process_id, **kw)
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def initialize(coordinator_address: Optional[str] = None,
@@ -183,8 +142,9 @@ def initialize(coordinator_address: Optional[str] = None,
     """
     if coordinator_address is not None or num_processes is not None:
         _enable_cpu_collectives()
-        _distributed_initialize(coordinator_address, num_processes,
-                                process_id, kwargs)
+        jax.distributed.initialize(coordinator_address=coordinator_address,
+                                   num_processes=num_processes,
+                                   process_id=process_id, **kwargs)
         return
     try:
         jax.distributed.initialize(**kwargs)
@@ -211,10 +171,11 @@ def initialize_from_env() -> bool:
     can call it unconditionally before the first backend touch.
 
     Peer-death detection note: jax's own coordination-service heartbeat
-    (~100 s at the 0.4.x defaults) is NOT the recovery latency here. The
-    gang parent sees the dead child's exit directly and tears the rest
-    down with SIGTERM-then-SIGKILL, so survivors blocked in a collective
-    are bounded by the supervisor's ``--grace``, not by jax's detector.
+    (``heartbeat_timeout_seconds``, 100 s by default) is NOT the recovery
+    latency here. The gang parent sees the dead child's exit directly and
+    tears the rest down with SIGTERM-then-SIGKILL, so survivors blocked in
+    a collective are bounded by the supervisor's ``--grace``, not by jax's
+    detector.
     """
     import os
     coord = os.environ.get("FEDTPU_COORDINATOR", "")

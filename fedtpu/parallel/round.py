@@ -256,10 +256,11 @@ def build_round_fn(mesh, apply_fn: Callable, tx: optax.GradientTransformation,
     ``rounds_per_step=R`` runs R consecutive federated rounds inside ONE
     compiled program (``lax.scan`` over the round body): metric leaves gain a
     leading R axis and the host syncs once per R rounds instead of every
-    round. With a remote/tunneled accelerator the per-round host round-trip
-    dominates the loop (the round itself is ~100us); this is the fedtpu
-    answer to the reference's per-round pickled-collective overhead — not
-    just cheaper synchronization, but R-fold fewer synchronizations.
+    round. Where the per-round host dispatch+fetch outweighs the round
+    itself (the income round is tens of microseconds of device work) this
+    is the fedtpu answer to the reference's per-round pickled-collective
+    overhead — not just cheaper synchronization, but R-fold fewer
+    synchronizations.
 
     ``participation_rate < 1.0`` enables partial participation (classic
     FedAvg client sampling / straggler-dropout simulation — an extension:

@@ -250,6 +250,12 @@ def run_server(cfg, *, events: Optional[str] = None,
     # reports key per-process sections even when run_ids collide.
     tracer = make_tracer(events, role=role or "serve")
     log = TelemetryLogger(verbose=verbose, tracer=tracer)
+    if tracer.enabled:
+        # Attribution like every other program's sink: which backend and
+        # devices served, which compile cache was in force.
+        from fedtpu.telemetry import build_manifest
+        tracer.event("manifest", **build_manifest(
+            cfg=cfg, extra={"program": "serve"}))
     engine = ServingEngine(cfg, registry=registry, tracer=tracer)
     if checkpoint_dir:
         engine.spool_dir = checkpoint_dir

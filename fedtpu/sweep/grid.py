@@ -51,7 +51,6 @@ fedtpu mapping:
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Optional
 
 import jax
@@ -281,8 +280,9 @@ def run_grid_search(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
     same shapes, so results are bitwise-identical to the eager path; any
     background-build or dispatch failure falls back to that path. With
     ``cfg.run.compilation_cache`` set, launch executables additionally
-    persist through the serialized-executable ``ProgramCache``, and jax's
-    persistent backend cache is pointed at the same directory.
+    persist through the serialized-executable ``ProgramCache``, in the
+    directory jax's persistent backend cache uses
+    (``fedtpu.compilation.resolve_cache_dir``).
 
     Winner semantics: ``best`` keeps the reference's strict-``>``
     first-hit argmax in grid order (:115-119) — the labeled parity
@@ -294,11 +294,10 @@ def run_grid_search(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
     float drift). Each table row carries ``in_tie_set``."""
     hidden_grid = HIDDEN_GRID if hidden_grid is None else hidden_grid
     lr_grid = LR_GRID if lr_grid is None else lr_grid
-    if cfg.run.compilation_cache:
-        # Before any compile — the RunConfig knob gives library/sweep
-        # callers the same persistent-cache behavior as the CLI flag.
-        from fedtpu.compilation import configure_persistent_cache
-        configure_persistent_cache(cfg.run.compilation_cache)
+    # Before any compile — library/sweep callers cache in the same
+    # directory as the CLI (fedtpu.compilation.resolve_cache_dir).
+    from fedtpu.compilation import configure_persistent_cache
+    configure_persistent_cache(cfg.run.compilation_cache)
     tel = cfg.run.telemetry
     tracer = make_tracer(tel.events_path)
     # The sweep keeps its OWN registry (not default_registry): a sweep that
@@ -362,10 +361,9 @@ def run_grid_search(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
     if overlap_compile:
         from fedtpu.compilation import CompileExecutor, program_fingerprint
         if cfg.run.compilation_cache:
-            from fedtpu.compilation import ProgramCache
-            from fedtpu.compilation.warmup import PROGRAMS_SUBDIR
+            from fedtpu.compilation import ProgramCache, program_cache_dir
             pcache = ProgramCache(
-                os.path.join(cfg.run.compilation_cache, PROGRAMS_SUBDIR),
+                program_cache_dir(cfg.run.compilation_cache),
                 tracer=tracer, registry=registry)
         comp_exec = CompileExecutor(tracer=tracer, registry=registry)
         prog_cfg = {"local_steps": local_steps,

@@ -367,7 +367,7 @@ def aggregate(events: List[dict], malformed: int = 0) -> dict:
     # measured per-round durations into per-round MFU / roofline rows.
     # Without a hardware peak (FEDTPU_PEAK_FLOPS at run time) the rows
     # still carry achieved FLOP/s and arithmetic intensity — just no
-    # MFU ratio. Pinned reference numbers live in benchmarks/RESULTS.md.
+    # MFU ratio. Measured reference numbers live in PERF.md.
     prof = (manifest or {}).get("profile")
     if prof and not prof.get("error"):
         flops = float(prof.get("flops_per_round") or 0.0)

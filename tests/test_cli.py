@@ -25,7 +25,7 @@ def test_run_with_overrides_json(capsys):
 
 def test_sweep_table_jsonl(tmp_path, monkeypatch):
     # Shrink the grid (2 archs x 9 lrs) — the full 10x9 takes minutes on CPU;
-    # the full-size grid is exercised by the recorded TPU run (RESULTS.md).
+    # the full-size grid needs the chip.
     from fedtpu.sweep import grid
     monkeypatch.setattr(grid, "HIDDEN_GRID", ((8,), (8, 8)))
     path = str(tmp_path / "table.jsonl")
@@ -148,17 +148,18 @@ def test_run_compile_flags_reach_run_config(monkeypatch, tmp_path):
         rc = cli.main(["run", "--csv", "", "--rounds", "1",
                        "--compilation-cache", cache_dir,
                        "--overlap-compile", "--quiet"])
+        flagged = captured["run"]
+        # Defaults: no flag, no ProgramCache request, no overlap (the XLA
+        # cache itself is always on — tests/test_compilation.py).
+        rc_plain = cli.main(["run", "--csv", "", "--rounds", "1", "--quiet"])
     finally:
         # main() applies the cache config process-globally; scope it here.
         jax.config.update("jax_compilation_cache_dir", prev_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           prev_min)
-    assert rc == 0
-    assert captured["run"].compilation_cache == os.path.abspath(cache_dir)
-    assert captured["run"].overlap_compile is True
-    # Defaults stay off: no flag, no cache, no overlap.
-    rc = cli.main(["run", "--csv", "", "--rounds", "1", "--quiet"])
-    assert rc == 0
+    assert rc == 0 and rc_plain == 0
+    assert flagged.compilation_cache == os.path.abspath(cache_dir)
+    assert flagged.overlap_compile is True
     assert captured["run"].compilation_cache is None
     assert captured["run"].overlap_compile is False
 
@@ -186,8 +187,12 @@ def test_compilation_cache_flag_populates_cache(tmp_path):
            "--compilation-cache", str(cache), "--quiet", "--json"]
     # Threshold 0: cache even the tiny CPU test program deterministically
     # (the CLI respects the env var and must not clobber it).
+    # (conftest turns the cache off for every test process; this test of
+    # the cache turns it back on in its own child.)
     env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_ENABLE_COMPILATION_CACHE="true",
                JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     r = subprocess.run(cmd, cwd=repo, env=env, capture_output=True,
                        text=True, timeout=300)
     assert r.returncode == 0, r.stderr[-2000:]
@@ -220,8 +225,7 @@ def test_every_documented_flag_exists_in_the_parser():
                 "docs/performance.md", "docs/resilience.md",
                 "docs/serving.md", "docs/scaling.md", "docs/autoscale.md",
                 "docs/robustness.md",
-                "PARITY.md",
-                "benchmarks/RESULTS.md"):
+                "PARITY.md"):
         text = open(os.path.join(root, rel)).read()
         # Underscores ARE captured so `--dp_clip_norm`-style typos show up
         # as unknown flags instead of silently failing to match.
@@ -239,6 +243,7 @@ def test_every_documented_flag_exists_in_the_parser():
                    "--store",                      # benchmarks/scaling.py
                    "--write",     # python -m fedtpu.telemetry.timeline_sim
                    "--xla_force_host_platform_device_count",  # XLA flag
+                   "--chips", "--rehearse-cpu",    # chip_smoke.py / chiprun
                    "--hostfile", "--np"}           # mpirun (reference docs)
     missing = documented - known - other_tools
     assert not missing, f"docs mention unknown CLI flags: {sorted(missing)}"

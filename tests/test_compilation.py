@@ -39,18 +39,25 @@ def tiny_cfg(hidden=(8,), rounds=4, rows=256, rps=1, **run_kw):
 
 
 @contextlib.contextmanager
-def persistent_cache(tmpdir):
-    """Scope the process-global persistent-cache config to one test."""
-    from fedtpu.compilation import configure_persistent_cache
+def scoped_cache_config():
+    """Scope the process-global persistent-cache config (main() and
+    run_experiment set it) to one test."""
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
     try:
-        configure_persistent_cache(str(tmpdir))
         yield
     finally:
         jax.config.update("jax_compilation_cache_dir", prev_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           prev_min)
+
+
+@contextlib.contextmanager
+def persistent_cache(tmpdir):
+    from fedtpu.compilation import configure_persistent_cache
+    with scoped_cache_config():
+        configure_persistent_cache(str(tmpdir))
+        yield
 
 
 def bitwise_equal(a, b) -> bool:
@@ -101,8 +108,74 @@ def test_load_rejects_corrupted_payload(tmp_path):
     with open(bin_path, "r+b") as fh:
         fh.seek(10)
         fh.write(b"\x00\x01\x02\x03")
-    # Integrity guard: a flipped payload degrades to a miss, never a crash.
-    assert ProgramCache(str(tmp_path)).load(key) is None
+    # Integrity guard: a flipped payload degrades to a miss, never a crash
+    # — but a counted one: an entry that is there and does not load must
+    # not pass for a plain miss (chip_smoke fails on the counter).
+    reopened = ProgramCache(str(tmp_path))
+    assert reopened.load(key) is None
+    assert reopened.load_errors == 1 and reopened.stats()["load_errors"] == 1
+    assert reopened.load("no-such-key") is None     # a plain miss is not one
+    assert reopened.load_errors == 1
+
+
+# ------------------------------------------------- where the cache is placed
+@pytest.mark.parametrize("placed", [True, False], ids=["env", "no-env"])
+@pytest.mark.parametrize("flag", [True, False], ids=["flag", "no-flag"])
+def test_run_keeps_the_cache_where_it_was_placed(monkeypatch, tmp_path,
+                                                 placed, flag):
+    """JAX_COMPILATION_CACHE_DIR, when set, is where `fedtpu run` caches,
+    with or without --compilation-cache; unset, the flag's directory, else
+    the one fixed path in the checkout. The ProgramCache goes under the
+    same directory, and never is any of it under the system temp dir
+    (tmp_path stands in for "some directory placed from outside")."""
+    import tempfile
+
+    import fedtpu.cli as cli
+    import fedtpu.orchestration.loop as loop
+    from fedtpu.compilation import (CACHE_DIR_ENV, DEFAULT_CACHE_DIR,
+                                    PROGRAMS_SUBDIR, program_cache_dir,
+                                    resolve_cache_dir)
+
+    class Result:
+        def summary(self):
+            return {}
+    monkeypatch.setattr(loop, "run_experiment",
+                        lambda cfg, verbose=True, resume=False: Result())
+    env_dir, flag_dir = str(tmp_path / "placed"), str(tmp_path / "flag")
+    if placed:
+        monkeypatch.setenv(CACHE_DIR_ENV, env_dir)
+    else:
+        monkeypatch.delenv(CACHE_DIR_ENV, raising=False)
+    expect = env_dir if placed else flag_dir if flag else DEFAULT_CACHE_DIR
+    with scoped_cache_config():
+        rc = cli.main(["run", "--csv", "", "--rounds", "1", "--quiet",
+                       *(["--compilation-cache", flag_dir] if flag else [])])
+        assert rc == 0
+        assert jax.config.jax_compilation_cache_dir == expect
+    explicit = flag_dir if flag else None
+    assert resolve_cache_dir(explicit) == expect
+    assert program_cache_dir(explicit) == f"{expect}/{PROGRAMS_SUBDIR}"
+    repo = __file__.rsplit("/", 2)[0]
+    assert DEFAULT_CACHE_DIR == f"{repo}/.jax_cache"
+    assert not DEFAULT_CACHE_DIR.startswith(tempfile.gettempdir())
+
+
+def test_no_cache_directory_comes_from_tempfile():
+    """A cache that moves never hits: no program of the repo may take its
+    cache directory from tempfile (mkstemp inside the cache dir, the
+    ProgramCache's atomic publish, is not a directory)."""
+    import pathlib
+    import re
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    sources = [*repo.glob("fedtpu/**/*.py"), *repo.glob("benchmarks/*.py"),
+               repo / "bench.py", repo / "chip_smoke.py"]
+    moving = re.compile(r"mkdtemp|gettempdir|TemporaryDirectory")
+    offenders = [f"{path.relative_to(repo)}:{n}: {line.strip()}"
+                 for path in sources
+                 for n, line in enumerate(path.read_text().splitlines(), 1)
+                 if moving.search(line) and "cache" in line.lower()]
+    assert not offenders, offenders
 
 
 # ----------------------------------------------------------- key sensitivity

@@ -29,6 +29,32 @@ def test_run_experiment_history_shapes():
     assert res.final_params["layers"][0]["w"].ndim == 2  # global, no client axis
 
 
+def test_a_synthetic_stand_in_is_said_on_the_first_line_and_in_the_manifest(
+        tmp_path, capsys):
+    """A preset that finds no CSV trains on synthetic rows; that used to be
+    silent. Now the first log line, the manifest and the summary (what a
+    --quiet --json run prints) all name the stand-in and its row count."""
+    import dataclasses
+    import json
+
+    from fedtpu.config import TelemetryConfig
+    cfg = _cfg(rounds=1)
+    events = tmp_path / "ev.jsonl"
+    cfg = dataclasses.replace(cfg, run=dataclasses.replace(
+        cfg.run, telemetry=TelemetryConfig(events_path=str(events))))
+    res = run_experiment(cfg, verbose=True)
+    first = capsys.readouterr().out.lstrip().splitlines()[0]
+    rows = cfg.data.synthetic_rows
+    assert first.startswith("Data: SYNTHETIC stand-in") and str(rows) in first
+    data = res.summary()["data"]
+    assert data["kind"] == "synthetic" and data["rows"] == rows
+    assert data["train_rows"] + data["test_rows"] == rows
+    manifest = next(json.loads(ln)["payload"]
+                    for ln in events.read_text().splitlines()
+                    if json.loads(ln)["kind"] == "manifest")
+    assert manifest["data"] == data
+
+
 def test_training_improves_metrics():
     res = run_experiment(_cfg(rounds=25), verbose=False)
     acc = res.global_metrics["accuracy"]

@@ -17,8 +17,9 @@ pytestmark = pytest.mark.skipif(not native.available(),
 
 
 def _both(path):
-    cols_n, mat_n, cls_n = _load_encoded(path, use_native=True)
-    cols_p, mat_p, cls_p = _load_encoded(path, use_native=False)
+    cols_n, mat_n, cls_n, parser_n = _load_encoded(path, use_native=True)
+    cols_p, mat_p, cls_p, parser_p = _load_encoded(path, use_native=False)
+    assert (parser_n, parser_p) == ("native", "pandas")
     return (cols_n, mat_n, cls_n), (cols_p, mat_p, cls_p)
 
 
@@ -35,10 +36,31 @@ def test_income_csv_native_matches_pandas():
                                       np.asarray(cls_p[k], dtype=object))
 
 
+def test_generated_income_csv_native_matches_pandas(tmp_path):
+    """The reference's CSV is not shipped, so the byte-for-byte parity of
+    the two parsers is held on the seeded CSV of the same shape that
+    chip_smoke.py trains on (10,000 rows, 8 string columns + the label)."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from chip_smoke import write_income_csv
+
+    path = str(tmp_path / "income.csv")
+    write_income_csv(path, seed=3)
+    (cols_n, mat_n, cls_n), (cols_p, mat_p, cls_p) = _both(path)
+    assert cols_n == cols_p and mat_n.shape == (10_000, 15)
+    np.testing.assert_array_equal(mat_n, mat_p)
+    assert set(cls_n) == set(cls_p) and len(cls_n) == 9
+    for k in cls_n:
+        np.testing.assert_array_equal(np.asarray(cls_n[k], dtype=object),
+                                      np.asarray(cls_p[k], dtype=object))
+
+
 def test_quoting_crlf_and_missing_trailing_newline(tmp_path):
     p = tmp_path / "edge.csv"
     p.write_bytes(b'a,b,c\r\n1,"x,y",3.5\r\n2,"say ""hi""",\r\n3,z,7')
-    cols, mat, cls = _load_encoded(str(p), use_native=True)
+    cols, mat, cls, _ = _load_encoded(str(p), use_native=True)
     assert cols == ["a", "b", "c"]
     # b is categorical with sorted-unique codes; c has an empty cell -> NaN.
     np.testing.assert_array_equal(mat[:, 0], [1.0, 2.0, 3.0])
@@ -72,7 +94,7 @@ def test_hex_literals_stay_categorical_like_pandas(tmp_path):
 def test_embedded_newline_in_quoted_field_classes_survive(tmp_path):
     p = tmp_path / "nl.csv"
     p.write_bytes(b'a,b\n1,"x\ny"\n2,z\n')
-    cols, mat, cls = _load_encoded(str(p), use_native=True)
+    cols, mat, cls, _ = _load_encoded(str(p), use_native=True)
     assert list(cls["b"]) == sorted(["x\ny", "z"])
     np.testing.assert_array_equal(
         mat[:, 1], [sorted(["x\ny", "z"]).index("x\ny"),

@@ -4,6 +4,7 @@ on the CPU mesh; the same kernels compile natively on TPU)."""
 import numpy as np
 import jax
 import jax.numpy as jnp
+import pytest
 
 from fedtpu.models.mlp import mlp_init, mlp_apply
 from fedtpu.ops.pallas_kernels import fused_mlp_forward, weighted_average_clients
@@ -57,6 +58,29 @@ def test_experiment_with_pallas_heldout_eval_matches_xla():
                                r_xla.test_metrics["accuracy"], atol=1e-6)
 
 
+@pytest.mark.parametrize("model_kw", [
+    dict(compute_dtype="bfloat16"),
+    dict(param_dtype="bfloat16", compute_dtype="bfloat16"),
+    dict(kind="convnet"),
+])
+def test_use_pallas_the_kernel_cannot_serve_is_an_error(model_kw):
+    """--use-pallas used to give way to the XLA eval without a word when
+    the model was not the float32 MLP; a requested kernel that does not
+    run is an error."""
+    from fedtpu.config import (DataConfig, ExperimentConfig, ModelConfig,
+                               ShardConfig)
+    from fedtpu.orchestration.loop import build_experiment
+
+    cfg = ExperimentConfig(
+        data=DataConfig(csv_path=None, synthetic_rows=64,
+                        synthetic_features=(3072 if "kind" in model_kw
+                                            else 14)),
+        shard=ShardConfig(num_clients=2),
+        model=ModelConfig(use_pallas=True, **model_kw))
+    with pytest.raises(ValueError, match="use_pallas needs the float32 MLP"):
+        build_experiment(cfg)
+
+
 def test_weighted_average_kernel_matches_numpy():
     rng = np.random.default_rng(0)
     stacked = rng.normal(size=(8, 96)).astype(np.float32)
@@ -69,8 +93,9 @@ def test_weighted_average_kernel_matches_numpy():
 
 def test_fused_eval_confusion_matches_xla_chain():
     # The batched fused eval->confusion kernel (measured SLOWER than the
-    # XLA chain on the v5e — see RESULTS.md; kept as a library op) must
-    # match vmap(argmax -> confusion_matrix) exactly in interpret mode.
+    # XLA chain on the v5e — see PERF.md 'Earlier records'; kept as a
+    # library op) must match vmap(argmax -> confusion_matrix) exactly in
+    # interpret mode.
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -385,13 +385,18 @@ def test_serve_loadgen_localhost_smoke(tmp_path):
         target=run_server,
         kwargs=dict(cfg=_small_cfg(), port_file=str(pf), once=True,
                     history_path=str(tmp_path / "hist.jsonl"),
-                    verbose=False))
+                    events=str(tmp_path / "ev.jsonl"), verbose=False))
     th.start()
     try:
         res = run_loadgen(str(trace), port_file=str(pf), batch=64)
     finally:
         th.join(timeout=60)
     assert not th.is_alive()
+    # The server's sink says which backend served (chip_smoke reads it).
+    manifest = next(json.loads(ln)["payload"]
+                    for ln in (tmp_path / "ev.jsonl").read_text().splitlines()
+                    if json.loads(ln)["kind"] == "manifest")
+    assert manifest["program"] == "serve" and manifest["backend"] == "cpu"
     assert res["events_sent"] == 150
     assert sum(res["admission"].values()) == 150
     stats = res["server_stats"]

@@ -58,8 +58,11 @@ def test_event_schema_roundtrip(tmp_path):
     for e in events:
         assert e["v"] == EVENT_SCHEMA_VERSION
         assert e["run_id"] == "deadbeef"
+        # Schema v2 (fedtpu/telemetry/trace.py): the v1 fields plus the
+        # fleet identity stamp.
         assert set(e) == {"v", "run_id", "kind", "phase", "round",
-                          "t_start", "dur_s", "payload"}
+                          "t_start", "dur_s", "payload",
+                          "process_index", "pid", "launch_id", "role"}
         # t_start defaults to emission time minus dur_s: the window END
         # (t_start + dur_s) always lands at/after the tracer epoch.
         assert e["t_start"] + e["dur_s"] >= 0.0
@@ -200,6 +203,15 @@ def test_bench_json_is_last_stdout_line(tmp_path, capsys):
     assert "[bench]" not in cap.out              # details are stderr-only
     assert "[bench] detail one" in cap.err
     assert json.loads(out.read_text()) == result
+
+
+def test_bench_measurement_path_refuses_a_non_tpu_backend():
+    """A number from the CPU is not a device metric: bench.main stops at
+    its device gate here instead of writing mfu/peak for the CPU."""
+    import bench
+    with pytest.raises(SystemExit) as e:
+        bench.main(["--out", ""])
+    assert "found {'platform': 'cpu'" in str(e.value.code)
 
 
 def test_bench_parser_has_out_and_events_flags(capsys):
