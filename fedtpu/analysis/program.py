@@ -54,6 +54,7 @@ __all__ = [
     "diff_audit",
     "donation_proof",
     "engine_audit_spec",
+    "program_scopes",
     "render_audit_text",
 ]
 
@@ -64,6 +65,124 @@ _HLO_COLLECTIVE_RE = re.compile(
     r"= \S+ (all-reduce|all-gather|reduce-scatter|collective-permute|"
     r"all-to-all)(?:-start)?\("
 )
+
+
+# ---------------------------------------------------------------------------
+# stage scopes of a compiled program
+# ---------------------------------------------------------------------------
+
+_HLO_COMPUTATION_RE = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_HLO_INSTRUCTION_RE = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_HLO_OPCODE_RE = re.compile(r"[\]})] ([a-z][\w\-]*)\(")
+_HLO_SHAPE_RE = re.compile(r"[a-z][a-z0-9]*\[[\d,]*\]")
+_HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+# Computations a thunk runs instruction by instruction (what a device
+# trace lists), as opposed to fusion bodies and reducers.
+_HLO_CONTROL_RE = re.compile(
+    r"(?:body|condition|true_computation|false_computation)=%?([\w.\-]+)"
+    r"|branch_computations=\{([^}]*)\}")
+_HLO_CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
+_HLO_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+# Never executed as an operation of their own: no trace lists them.
+_HLO_NO_OP = frozenset({"parameter", "constant", "get-tuple-element",
+                        "tuple", "bitcast"})
+# What every stage's values pass through: no stage is inherited across.
+_HLO_JUNCTION = frozenset({"parameter", "tuple", "get-tuple-element",
+                           "while", "conditional", "call"})
+
+
+def _stage_of(op_name: str, stages: Sequence[str]) -> Optional[str]:
+    """Innermost of the scopes ``stages`` on a name stack such as
+    ``jit(round_step)/jit(shmap_body)/while/body/client_train/vmap(...)``;
+    transforms wrap a scope's component (``transpose(jvp(aggregate))``)."""
+    for part in reversed(op_name.split("/")):
+        inner = part.rsplit("(", 1)[-1].rstrip(")")
+        if inner in stages:
+            return inner
+    return None
+
+
+def program_scopes(compiled_text: str, stages: Sequence[str]) -> dict:
+    """Which of the ``jax.named_scope`` names ``stages`` (the builder's:
+    ``parallel.round.STAGES``) each operation of a compiled program belongs
+    to, from ``Compiled.as_text()``: ``{"scopes": {key: scope}, "unscoped":
+    [key]}``. A key is ``"<instruction> <first result shape>"`` (at most
+    120 characters), which is how a profiler trace's ``XLA Ops`` event
+    reads once cut to its name and shape: the trace carries the HLO text
+    of an instruction and no ``op_name``, so only the program can say
+    which instruction is whose. Listed are the instructions of the entry
+    computation and of every computation run through control flow (a
+    scanned body, a branch, an async call). An instruction takes the
+    innermost stage on its own ``op_name``, which for a fusion is its
+    root's: a fusion that XLA formed across two stages counts for the
+    stage of its root. One the compiler made without an ``op_name`` (a
+    copy, a prefetch into faster memory, a decomposed dot) takes the stage
+    all its users carry, else the one all its operands carry; nothing is
+    inherited across a loop, a branch, a tuple or a parameter."""
+    computations: dict[str, list[str]] = {}
+    entry = current = None
+    for line in compiled_text.splitlines():
+        head = _HLO_COMPUTATION_RE.match(line)
+        if head:
+            current = head.group(1)
+            computations[current] = []
+            if line.startswith("ENTRY"):
+                entry = current
+        elif current is not None and line.startswith(" "):
+            computations[current].append(line)
+    run = {entry}
+    for lines in computations.values():
+        for line in lines:
+            for one, many in _HLO_CONTROL_RE.findall(line):
+                run.update(n.strip().lstrip("%")
+                           for n in (one or many).split(","))
+            opcode = _HLO_OPCODE_RE.search(line)
+            if opcode and opcode.group(1) != "fusion":
+                run.update(_HLO_CALLS_RE.findall(line))
+    scopes: dict[str, str] = {}
+    unscoped: list[str] = []
+    for name in run:
+        keys, stage, operands, junctions = {}, {}, {}, set()
+        for line in computations.get(name, ()):
+            inst = _HLO_INSTRUCTION_RE.match(line)
+            opcode = inst and _HLO_OPCODE_RE.search(inst.group(2))
+            if not opcode:
+                continue
+            inst, rest = inst.groups()
+            if opcode.group(1) not in _HLO_NO_OP:
+                shape = _HLO_SHAPE_RE.search(rest)
+                keys[inst] = (inst
+                              + (" " + shape.group(0) if shape else ""))[:120]
+            op_name = _HLO_OP_NAME_RE.search(rest)
+            stage[inst] = (_stage_of(op_name.group(1), stages)
+                           if op_name else None)
+            if opcode.group(1) in _HLO_JUNCTION:
+                junctions.add(inst)
+            operands[inst] = _HLO_OPERAND_RE.findall(rest[opcode.end():])
+        users: dict[str, list[str]] = {inst: [] for inst in stage}
+        for inst, ops in operands.items():
+            for op in ops:
+                if op in users:
+                    users[op].append(inst)
+
+        def inherit(order, edges):
+            # HLO text defines an instruction before its users, so one
+            # pass in the right order resolves whole chains
+            for inst in order:
+                if stage[inst] is None and inst not in junctions:
+                    near = {stage[n] for n in edges[inst] if n in stage}
+                    near.discard(None)
+                    if len(near) == 1:
+                        stage[inst] = near.pop()
+
+        inherit(reversed(list(stage)), users)
+        inherit(list(stage), operands)
+        for inst, key in keys.items():
+            if stage[inst]:
+                scopes[key] = stage[inst]
+            else:
+                unscoped.append(key)
+    return {"scopes": scopes, "unscoped": sorted(unscoped)}
 
 
 # ---------------------------------------------------------------------------

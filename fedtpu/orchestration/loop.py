@@ -36,6 +36,7 @@ broadcasts a test split it never touches, :243-246) are recorded alongside.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import math
@@ -67,7 +68,8 @@ from fedtpu.telemetry import (TelemetryLogger, build_manifest,
                               default_registry, install_compile_probe,
                               make_tracer)
 from fedtpu.telemetry.metrics import device_memory_gauges
-from fedtpu.parallel.round import (build_round_fn, build_eval_fn,
+from fedtpu.telemetry.trace import Phase
+from fedtpu.parallel.round import (STAGES, build_round_fn, build_eval_fn,
                                    init_federated_state, global_params)
 from fedtpu.utils.timing import Timer, force_fetch
 from fedtpu.utils.trees import to_numpy
@@ -508,13 +510,49 @@ def build_experiment(cfg: ExperimentConfig,
                       apply_fn=apply_fn, tx=tx, num_classes=ds.num_classes)
 
 
+# The loop's finiteness check: its phase in the sink and the trace, and the
+# named scope of its program.
+STATE_CHECK = "state_check"
+
+
 @jax.jit
 def _tree_finite(tree) -> jax.Array:
     """Single-scalar device reduction: every floating leaf entirely finite
     (integer leaves — optimizer step counts — cannot be non-finite)."""
-    checks = [jnp.all(jnp.isfinite(l)) for l in jax.tree.leaves(tree)
-              if jnp.issubdtype(l.dtype, jnp.inexact)]
-    return jnp.all(jnp.stack(checks)) if checks else jnp.array(True)
+    with jax.named_scope(STATE_CHECK):
+        checks = [jnp.all(jnp.isfinite(l)) for l in jax.tree.leaves(tree)
+                  if jnp.issubdtype(l.dtype, jnp.inexact)]
+        return jnp.all(jnp.stack(checks)) if checks else jnp.array(True)
+
+
+def _emit_program_scopes(tracer, program: str, width: Optional[int], fn,
+                         *args) -> None:
+    """The join between a device trace and the stage scopes: a trace names
+    an operation by its HLO text, without op_name, so the run says which
+    operation of ``fn``'s program belongs to which stage
+    (analysis.program.program_scopes). After ``fn`` ran on arguments shaped
+    as ``args``, jit's own lowering cache holds the executable (else the
+    persistent cache does): reading its text compiles nothing.
+
+    JAX's cache key leaves metadata out, so an executable served from the
+    persistent cache carries the scopes of whichever checkout compiled it
+    first. Every program named here is built under a stage scope; a text
+    that names none is such an executable, and the event says
+    ``stale_metadata`` rather than pass its operations off as unscoped. (A
+    stage boundary moved over unchanged instructions cannot be told.)"""
+    try:
+        from fedtpu.analysis.program import program_scopes
+        compiled = (fn if hasattr(fn, "as_text")
+                    else fn.lower(*args).compile())
+        found = program_scopes(compiled.as_text(), STAGES + (STATE_CHECK,))
+        if not found["scopes"]:
+            found["stale_metadata"] = True
+        tracer.event("program_scopes", program=program, width=width, **found)
+    except Exception as exc:
+        # Diagnostic metadata, like the manifest's audit: a failure here
+        # must not take down the run it describes.
+        tracer.event("program_scopes", program=program, width=width,
+                     error=str(exc))
 
 
 def _bcast_into_slots(global_np, live_params):
@@ -548,12 +586,21 @@ def _drop_tail(lst: list, n: int) -> None:
 
 def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                    verbose: bool = True,
-                   resume: bool = False) -> ExperimentResult:
+                   resume: bool = False,
+                   on_chunk: Optional[Callable[[int, int], None]] = None
+                   ) -> ExperimentResult:
     """``resume=True``: restore the latest checkpoint under
     ``cfg.run.checkpoint_dir`` (full per-client state + the client-mean metric
     history) and continue the round loop from the saved round. Pooled /
     per-client / test histories restart at the resume point; the early-stop
-    comparator re-seeds from the restored history's last entry."""
+    comparator re-seeds from the restored history's last entry.
+
+    ``on_chunk(last_round, take)`` is called where the ``chunk`` span
+    closes: the chunk's metrics are on the host, so its device work has
+    finished and the next chunk is not dispatched yet (under
+    ``pipelined_stop`` / ``mpmd`` it is already in flight). The
+    cohort-store engine (``fed.cohort_size > 0``) has its own loop and
+    does not call it."""
     # Multi-process (multi-host) awareness — the reference runs its WHOLE
     # driver under `mpirun --hostfile`, so the whole loop must run under
     # jax.distributed too (tests/test_multihost_e2e.py runs it across two
@@ -1185,16 +1232,21 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
     diverged = False
     rounds_run = 0
 
-    def state_poisoned() -> bool:
+    def _checked_state() -> dict:
+        return {k: state[k] for k in
+                ("params", "opt_state", "server_opt_state",
+                 "client_cv", "server_cv", "dp_clip", "anchors")
+                if k in state}
+
+    def state_poisoned(rnd: int, take: Optional[int] = None) -> bool:
         """The full poisoned-state predicate shared by the in-loop and
         loop-exit gates: any non-finite leaf in params, client optimizer
         moments, or server optimizer state. Reads the CURRENT ``state``
-        binding (one definition — the two gates can't drift apart)."""
-        return not bool(_tree_finite(
-            {k: state[k] for k in
-             ("params", "opt_state", "server_opt_state",
-              "client_cv", "server_cv", "dp_clip", "anchors")
-             if k in state}))
+        binding (one definition — the two gates can't drift apart).
+        ``rnd`` / ``take`` label its ``state_check`` phase (a second
+        dispatch and fetch a chunk), which closes on the ``bool()``."""
+        with phase(STATE_CHECK, rnd, rounds=take):
+            return not bool(_tree_finite(_checked_state()))
 
     def halt_diverged(reason: str, label_round: int):
         """Shared divergence halt: quarantine the poisoned state under
@@ -1402,15 +1454,47 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
         jax.profiler.start_trace(cfg.run.profile_dir)
         prof_win["on"] = True
 
+    def phase(name: str, rnd: int, rounds: Optional[int] = None,
+              guard: Optional[str] = None):
+        """One phase of a round where its work happens, on every clock
+        that is on (docs/observability.md "Phases of a round"): the sink
+        span ``name``, the annotation ``fedtpu.<name>`` while the profiler
+        window is open (sink on or not), and the watchdog window ``guard``.
+        With all three off this is the NullTracer's span and nothing else:
+        no profiler object is made."""
+        span = (tracer.span(name, round=rnd) if rounds is None
+                else tracer.span(name, round=rnd, rounds=rounds))
+        if not prof_win["on"] and (watchdog is None or guard is None):
+            return span
+        return Phase(
+            span,
+            jax.profiler.TraceAnnotation(f"fedtpu.{name}", round=rnd)
+            if prof_win["on"] else None,
+            watchdog.guard(guard, rnd)
+            if watchdog is not None and guard else None)
+
+    epilogue = contextlib.ExitStack()
+    # The ``fedtpu.chunk`` step annotations open now: one from a chunk's
+    # dispatch to its fetch, two while a pipelined chunk is in flight.
+    open_steps: list = []
+
+    def close_step(ann) -> None:
+        if ann in open_steps:
+            open_steps.remove(ann)
+            ann.__exit__(None, None, None)
+
     # try/finally so a mid-run failure (OOM, Ctrl-C, I/O error) still
     # finalizes the profiler trace and closes the jsonl handle — the trace
     # exists precisely to diagnose such runs.
     try:
-        def process_chunk(rnd0, take, metrics, state_round=None):
+        def process_chunk(rnd0, take, metrics, step_ann=None,
+                          state_round=None):
             """Host-side consumption of one chunk's metrics: history, logs,
             JSONL, divergence guard, early stopping. Fetches the metrics —
             the completion proof AND (in pipelined mode) the point where
-            the host finally waits on this chunk. ``state_round``: the round
+            the host finally waits on this chunk. ``step_ann``: the chunk's
+            open ``fedtpu.chunk`` step annotation, closed on the fetch.
+            ``state_round``: the round
             the loop's CURRENT ``state`` corresponds to (in pipelined mode
             one chunk past this chunk's metrics) — used to label a
             divergence quarantine honestly."""
@@ -1426,12 +1510,14 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
             # the completion proof that closes the lap time.
             # Multi-process: replicate first (collective, every process) so
             # the client-sharded leaves become host-addressable everywhere.
-            with _guard("chunk_fetch", rnd0 + take):
+            with phase("chunk_fetch", rnd0 + take, rounds=take,
+                       guard="chunk_fetch"):
                 metrics = _rep(metrics)
                 for leaf in jax.tree.leaves(metrics):
                     if hasattr(leaf, "copy_to_host_async"):
                         leaf.copy_to_host_async()
                 metrics = jax.tree.map(np.asarray, metrics)
+            close_step(step_ann)
             per_round = _unstack_metrics(metrics, take)
             dt = timer.lap() / take
             # The chunk span closes HERE, on the np.asarray materialization
@@ -1439,6 +1525,8 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
             # has finished.
             tracer.event("span", phase="chunk", round=rnd0 + take,
                          dur_s=dt * take, rounds=take)
+            if on_chunk is not None:
+                on_chunk(rnd0 + take, take)
             # Windowed profiler control: arm after the first chunk's fetch
             # (the completion proof that compile is behind us), disarm at
             # the first chunk boundary covering >= profile_rounds rounds —
@@ -1460,116 +1548,112 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                 tracer.event("profile_window", phase="stop",
                              round=rnd0 + take,
                              rounds=rnd0 + take - prof_win["start_round"])
-            # Host-side decision window (history/log/early-stop); ended at
-            # every exit of the loop below — Span.end is idempotent.
-            sp_stop = tracer.span("stop_check", round=rnd0 + take)
+            # Host-side decision window (history/log/early-stop); every
+            # exit of the loop below leaves the with-block and closes it.
+            with phase("stop_check", rnd0 + take, rounds=take):
+                for j, m in enumerate(per_round):
+                    r = rnd0 + j
+                    client_mean = {k: float(v) for k, v in m["client_mean"].items()}
+                    per_client = {k: np.asarray(v) for k, v in m["per_client"].items()}
+                    losses.append(np.asarray(m["loss"]))
+                    sec_per_round.append(dt)
+                    rounds_run = r + 1
+                    loss_mean = float(np.mean(losses[-1]))
 
-            for j, m in enumerate(per_round):
-                r = rnd0 + j
-                client_mean = {k: float(v) for k, v in m["client_mean"].items()}
-                per_client = {k: np.asarray(v) for k, v in m["per_client"].items()}
-                losses.append(np.asarray(m["loss"]))
-                sec_per_round.append(dt)
-                rounds_run = r + 1
-                loss_mean = float(np.mean(losses[-1]))
+                    for k in METRIC_NAMES:
+                        history[k].append(client_mean[k])
+                        pooled_hist[k].append(float(m["pooled"][k]))
+                        per_client_hist[k].append(per_client[k])
+                    if "staleness" in m:        # async engine's extra metric
+                        staleness_hist.append(np.asarray(m["staleness"]))
 
-                for k in METRIC_NAMES:
-                    history[k].append(client_mean[k])
-                    pooled_hist[k].append(float(m["pooled"][k]))
-                    per_client_hist[k].append(per_client[k])
-                if "staleness" in m:        # async engine's extra metric
-                    staleness_hist.append(np.asarray(m["staleness"]))
+                    registry.counter("rounds").inc()
+                    tracer.event("round", round=r + 1, dur_s=dt,
+                                 accuracy=client_mean["accuracy"],
+                                 loss_mean=loss_mean,
+                                 **({"staleness_mean":
+                                     float(staleness_hist[-1].mean()),
+                                     "staleness_max":
+                                     float(staleness_hist[-1].max())}
+                                    if "staleness" in m else {}))
+                    if "staleness" in m:
+                        from fedtpu.parallel.async_fed import \
+                            record_tick_telemetry
+                        record_tick_telemetry(registry, tracer, r + 1,
+                                              staleness_hist[-1])
 
-                registry.counter("rounds").inc()
-                tracer.event("round", round=r + 1, dur_s=dt,
-                             accuracy=client_mean["accuracy"],
-                             loss_mean=loss_mean,
-                             **({"staleness_mean":
-                                 float(staleness_hist[-1].mean()),
-                                 "staleness_max":
-                                 float(staleness_hist[-1].max())}
-                                if "staleness" in m else {}))
-                if "staleness" in m:
-                    from fedtpu.parallel.async_fed import \
-                        record_tick_telemetry
-                    record_tick_telemetry(registry, tracer, r + 1,
-                                          staleness_hist[-1])
+                    if jsonl is not None:
+                        jsonl.write(json.dumps({
+                            "round": r + 1, "sec_per_round": dt,
+                            "client_mean": client_mean,
+                            "pooled": {k: pooled_hist[k][-1] for k in METRIC_NAMES},
+                            "loss_mean": loss_mean,
+                            **({"staleness_mean":
+                                float(staleness_hist[-1].mean())}
+                               if "staleness" in m else {}),
+                        }) + "\n")
+                        jsonl.flush()
 
-                if jsonl is not None:
-                    jsonl.write(json.dumps({
-                        "round": r + 1, "sec_per_round": dt,
-                        "client_mean": client_mean,
-                        "pooled": {k: pooled_hist[k][-1] for k in METRIC_NAMES},
-                        "loss_mean": loss_mean,
-                        **({"staleness_mean":
-                            float(staleness_hist[-1].mean())}
-                           if "staleness" in m else {}),
-                    }) + "\n")
-                    jsonl.flush()
+                    if verbose and (r % cfg.run.log_every == 0):
+                        log.parity(f"\nRound {r + 1}:\n")
+                        if cfg.run.log_per_client:
+                            # Parity with the barrier-serialized rank-ordered prints
+                            # (FL_CustomMLP...:151-162) — here just a loop, no barriers.
+                            for c in range(cfg.shard.num_clients):
+                                vals = ", ".join(f"{k}: {per_client[k][c]:.4f}"
+                                                 for k in METRIC_NAMES)
+                                log.parity(f"  CLIENT {c} - Local Metrics "
+                                           f"(Round {r + 1}): [{vals}]")
+                        gvals = ", ".join(f"{k}: {client_mean[k]:.4f}"
+                                          for k in METRIC_NAMES)
+                        stale_note = (f"  (mean staleness "
+                                      f"{staleness_hist[-1].mean():.2f})"
+                                      if "staleness" in m else "")
+                        # parity, not info: the line is reference-shaped and
+                        # must never grow a prefix; its timing suffix is what
+                        # keeps it out of the byte-identity tests.
+                        log.parity(f"  Global Metrics (Round {r + 1}): [{gvals}]  "
+                                   f"({dt * 1e3:.1f} ms/round){stale_note}")
 
-                if verbose and (r % cfg.run.log_every == 0):
-                    log.parity(f"\nRound {r + 1}:\n")
-                    if cfg.run.log_per_client:
-                        # Parity with the barrier-serialized rank-ordered prints
-                        # (FL_CustomMLP...:151-162) — here just a loop, no barriers.
-                        for c in range(cfg.shard.num_clients):
-                            vals = ", ".join(f"{k}: {per_client[k][c]:.4f}"
-                                             for k in METRIC_NAMES)
-                            log.parity(f"  CLIENT {c} - Local Metrics "
-                                       f"(Round {r + 1}): [{vals}]")
-                    gvals = ", ".join(f"{k}: {client_mean[k]:.4f}"
-                                      for k in METRIC_NAMES)
-                    stale_note = (f"  (mean staleness "
-                                  f"{staleness_hist[-1].mean():.2f})"
-                                  if "staleness" in m else "")
-                    # parity, not info: the line is reference-shaped and
-                    # must never grow a prefix; its timing suffix is what
-                    # keeps it out of the byte-identity tests.
-                    log.parity(f"  Global Metrics (Round {r + 1}): [{gvals}]  "
-                               f"({dt * 1e3:.1f} ms/round){stale_note}")
-
-                # Failure detection: a diverged step (NaN/inf loss or
-                # metrics) halts cleanly instead of burning the remaining
-                # rounds — with an emergency checkpoint of the last state.
-                cur = [client_mean[k] for k in METRIC_NAMES]
-                if cfg.run.halt_on_nonfinite and not (
-                        np.all(np.isfinite(cur))
-                        and np.all(np.isfinite(losses[-1]))):
-                    # Rollback policy first (restores + truncates + sets
-                    # resume_at; the while loop re-enters at the restored
-                    # round); only when it declines does the run halt.
-                    if not try_rollback(
-                            f"loss/metrics at round {r + 1}", r + 1,
-                            offenders=_offending_clients(m, losses[-1])):
-                        halt_diverged(f"loss/metrics at round {r + 1}",
-                                      state_round)
-                    sp_stop.end()
-                    return
-
-                # Early stopping — exact reference logic (FL_CustomMLP...:181-192).
-                if prev_metric is not None and np.allclose(
-                        cur, prev_metric, atol=cfg.fed.tolerance):
-                    termination_count -= 1
-                    if termination_count == 0:
-                        log.parity("Early stopping triggered: No significant "
-                                   "change in metrics for "
-                                   f"{cfg.fed.termination_patience} rounds.")
-                        if r + 1 < cfg.fed.rounds:
-                            # The reference's break-iteration message
-                            # (FL_CustomMLP...:135): its loop re-enters
-                            # round r+1 (0-indexed == this r+1) and
-                            # breaks before training; printed only when
-                            # there IS a next round to break out of.
-                            log.parity(f"Training stopped early at round "
-                                       f"{r + 1}.")
-                        tracer.event("early_stop", round=r + 1)
-                        stopped_early = True
-                        sp_stop.end()
+                    # Failure detection: a diverged step (NaN/inf loss or
+                    # metrics) halts cleanly instead of burning the remaining
+                    # rounds — with an emergency checkpoint of the last state.
+                    cur = [client_mean[k] for k in METRIC_NAMES]
+                    if cfg.run.halt_on_nonfinite and not (
+                            np.all(np.isfinite(cur))
+                            and np.all(np.isfinite(losses[-1]))):
+                        # Rollback policy first (restores + truncates + sets
+                        # resume_at; the while loop re-enters at the restored
+                        # round); only when it declines does the run halt.
+                        if not try_rollback(
+                                f"loss/metrics at round {r + 1}", r + 1,
+                                offenders=_offending_clients(m, losses[-1])):
+                            halt_diverged(f"loss/metrics at round {r + 1}",
+                                          state_round)
                         return
-                else:
-                    prev_metric = cur
-                    termination_count = cfg.fed.termination_patience
-            sp_stop.end()
+
+                    # Early stopping — exact reference logic (FL_CustomMLP...:181-192).
+                    if prev_metric is not None and np.allclose(
+                            cur, prev_metric, atol=cfg.fed.tolerance):
+                        termination_count -= 1
+                        if termination_count == 0:
+                            log.parity("Early stopping triggered: No significant "
+                                       "change in metrics for "
+                                       f"{cfg.fed.termination_patience} rounds.")
+                            if r + 1 < cfg.fed.rounds:
+                                # The reference's break-iteration message
+                                # (FL_CustomMLP...:135): its loop re-enters
+                                # round r+1 (0-indexed == this r+1) and
+                                # breaks before training; printed only when
+                                # there IS a next round to break out of.
+                                log.parity(f"Training stopped early at round "
+                                           f"{r + 1}.")
+                            tracer.event("early_stop", round=r + 1)
+                            stopped_early = True
+                            return
+                    else:
+                        prev_metric = cur
+                        termination_count = cfg.fed.termination_patience
 
         # ---- Elastic live reshard (docs/resilience.md) ----------------
         def _reshard_join_fn(join_map, tick_round):
@@ -1894,9 +1978,9 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                     process_chunk(*pending, state_round=rnd)
                     pending = None
                 if not stopped_early:
-                    if not (cfg.run.halt_on_nonfinite and state_poisoned()):
-                        with tracer.span("checkpoint", round=rnd), \
-                                _guard("checkpoint", rnd):
+                    if not (cfg.run.halt_on_nonfinite
+                            and state_poisoned(rnd)):
+                        with phase("checkpoint", rnd, guard="checkpoint"):
                             save_checkpoint(
                                 cfg.run.checkpoint_dir, state, history, rnd,
                                 extra_meta=ledger.checkpoint_meta(rnd),
@@ -1964,13 +2048,21 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
             if injector is not None:
                 injector.pre_round(rnd, state, batch,
                                    checkpoint_dir=cfg.run.checkpoint_dir)
+            # xprof's step view groups the device's operations by chunk:
+            # the step runs from here to the chunk's fetch (process_chunk).
+            step_ann = None
+            if prof_win["on"]:
+                step_ann = jax.profiler.StepTraceAnnotation(
+                    "fedtpu.chunk", step_num=rnd + take)
+                step_ann.__enter__()
+                open_steps.append(step_ann)
             if take not in step_fns:
                 # First call at this chunk width: trace + lower + compile
                 # happen synchronously inside the dispatch (only execution
                 # is async), so the span brackets the compile cost. The
                 # jax.monitoring probe (install_compile_probe) counts the
                 # backend-reported compile seconds alongside.
-                with tracer.span("compile", round=rnd + take, rounds=take):
+                with phase("compile", rnd + take, rounds=take):
                     state, metrics = get_step(take)(state, batch)
             else:
                 # Guarded: on the CPU/gloo backend a dispatch whose
@@ -1980,7 +2072,8 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                 # above stays unguarded — compile time must never count
                 # against --collective-timeout; a hang during a first
                 # dispatch is the supervisor --hang-timeout's job.
-                with _guard("dispatch", rnd + take):
+                with phase("dispatch", rnd + take, rounds=take,
+                           guard="dispatch"):
                     state, metrics = get_step(take)(state, batch)
             if injector is not None:
                 # After dispatch (the launched chunk holds its own array
@@ -1992,9 +2085,9 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                     # The current `state` is the just-dispatched chunk's
                     # output, ending at rnd + take.
                     process_chunk(*pending, state_round=rnd + take)
-                pending = (rnd, take, metrics)
+                pending = (rnd, take, metrics, step_ann)
             else:
-                process_chunk(rnd, take, metrics)
+                process_chunk(rnd, take, metrics, step_ann)
             rnd += take
 
             if rollback["resume_at"] is not None:
@@ -2011,7 +2104,9 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                 # The chunk overshot the stop round; don't checkpoint or eval the
                 # overshoot state (the unchunked loop's `break` skips these too).
                 # In pipelined mode `pending` is the in-flight overshoot chunk:
-                # dropped (see above).
+                # dropped (see above), its step annotation closed.
+                if pending is not None:
+                    close_step(pending[3])
                 pending = None
                 break
 
@@ -2050,7 +2145,7 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
             # params, in either mode.
             if cfg.run.halt_on_nonfinite \
                     and (not pipelined or ckpt_due or eval_due) \
-                    and state_poisoned():
+                    and state_poisoned(rnd, take):
                 # Offenders unknown here (the poison shows in the full
                 # state, not a per-client metric) — rollback without
                 # exclusion; halt when the policy declines.
@@ -2067,8 +2162,7 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                 # _rep: the global slice of a client-sharded array is not
                 # host-addressable from every process; replicated params
                 # also make the eval jit's output fetchable everywhere.
-                sp = tracer.span("eval", round=rnd)
-                with _guard("eval_fetch", rnd):
+                with phase("eval", rnd, guard="eval_fetch") as sp:
                     tm = eval_step(_rep(exp.global_fn(state)),
                                    ds.x_test, ds.y_test)
                     # Span closes on the host fetch of the eval metrics —
@@ -2092,8 +2186,7 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                 # collective (barriers internally — a process-0-only call
                 # deadlocks), and it writes each client shard from the
                 # process that owns it (true distributed checkpointing).
-                with tracer.span("checkpoint", round=rnd), \
-                        _guard("checkpoint", rnd):
+                with phase("checkpoint", rnd, guard="checkpoint"):
                     save_checkpoint(cfg.run.checkpoint_dir, state, history,
                                     rnd,
                                     extra_meta=ledger.checkpoint_meta(rnd),
@@ -2103,7 +2196,7 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
         if pending is not None and not stopped_early:
             process_chunk(*pending, state_round=rnd)
         if (pipelined or stopped_early) and not diverged \
-                and cfg.run.halt_on_nonfinite and state_poisoned():
+                and cfg.run.halt_on_nonfinite and state_poisoned(rnd):
             # The deferred state gate (see above) — in pipelined mode the
             # only between-boundary state check; in sync mode only after an
             # early-stop break, the one path the in-loop gate misses (its
@@ -2123,6 +2216,10 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
             # never come. Reached only on clean completion — on a crash
             # the supervisor's gang teardown collects the parked member.
             reshard_ctl.finish()
+        # What a job pays after its last round, to run_end: the finally
+        # block's gauges, personalisation, the final fetch of the global
+        # model and of the result's scalars. Closed on the last fetch.
+        epilogue.enter_context(phase("epilogue", rounds_run))
 
     finally:
         if watchdog is not None:
@@ -2136,6 +2233,9 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
             # Don't wait on a background compile the run never needed
             # (early stop before the first wide chunk).
             overlap_exec.shutdown()
+        for ann in list(open_steps):
+            # a chunk that raised before its fetch, or was never processed
+            close_step(ann)
         if prof_win["on"]:
             # Completion proof before finalizing the trace — a trace
             # stopped while work is still in flight would miss the device
@@ -2235,6 +2335,17 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
             tracer.event("async_starvation", round=rounds_run,
                          pending=pending,
                          buffer_size=cfg.fed.async_buffer_size)
+    epilogue.close()
+    if tracer.enabled and cfg.run.profile_dir and not cfg.run.mpmd:
+        # Sink on and a profile taken: one program_scopes event for each
+        # program of the trace. Here, after every span has closed, so that
+        # reading the executables' text falls in no window and no lap.
+        for width, fn in sorted(step_fns.items()):
+            _emit_program_scopes(tracer, "round_step", width, fn, state,
+                                 batch)
+        if cfg.run.halt_on_nonfinite:
+            _emit_program_scopes(tracer, STATE_CHECK, None, _tree_finite,
+                                 _checked_state())
     _beat("diverged" if diverged else "done", rounds_run)
     tracer.event("run_end", round=rounds_run, stopped_early=stopped_early,
                  diverged=diverged, rounds_trained=result.rounds_trained,

@@ -66,7 +66,8 @@ from fedtpu.ops.metrics import metrics_from_confusion
 from fedtpu.parallel.mesh import (CLIENTS_AXIS, replicated_sharding,
                                   submesh)
 from fedtpu.parallel.ring import make_all_reduce
-from fedtpu.parallel.round import bcast_global
+from fedtpu.parallel.round import (AGGREGATE, CLIENT_EVAL, CLIENT_TRAIN,
+                                   METRICS, bcast_global)
 from fedtpu.training.client import make_local_eval_step, make_local_train_step
 
 __all__ = [
@@ -208,25 +209,31 @@ def build_mpmd_programs(mesh, apply_fn: Callable, tx, num_classes: int, *,
     spec_c = _spec_c()
     spec_rc = P(None, CLIENTS_AXIS)
 
+    # The monolithic round's stage scopes (parallel/round.py), on the
+    # sub-programs that build the same stages.
     def train_eval(params, opt_state, x, y, mask):
-        trained, new_opt, loss = jax.vmap(local_train)(
-            params, opt_state, x, y, mask)
-        conf = jax.vmap(local_eval)(trained, x, y, mask)     # (Cb, K, K)
+        with jax.named_scope(CLIENT_TRAIN):
+            trained, new_opt, loss = jax.vmap(local_train)(
+                params, opt_state, x, y, mask)
+        with jax.named_scope(CLIENT_EVAL):
+            conf = jax.vmap(local_eval)(trained, x, y, mask)     # (Cb, K, K)
         return trained, new_opt, loss, conf
 
     def average(params, conf, mask):
-        n = mask.sum(axis=1)
-        w = n if weighting == "data_size" else jnp.ones_like(n)
-        total_w = all_reduce(w.sum())             # clients-varying
+        with jax.named_scope(AGGREGATE):
+            n = mask.sum(axis=1)
+            w = n if weighting == "data_size" else jnp.ones_like(n)
+            total_w = all_reduce(w.sum())             # clients-varying
 
-        def avg(p):
-            local = jnp.tensordot(w.astype(jnp.float32),
-                                  p.astype(jnp.float32), axes=1)
-            glob = all_reduce(local) / jnp.maximum(total_w, 1.0)
-            return jnp.where(total_w > 0, bcast_global(glob, p), p)
+            def avg(p):
+                local = jnp.tensordot(w.astype(jnp.float32),
+                                      p.astype(jnp.float32), axes=1)
+                glob = all_reduce(local) / jnp.maximum(total_w, 1.0)
+                return jnp.where(total_w > 0, bcast_global(glob, p), p)
 
-        new_params = jax.tree.map(avg, params)
-        pooled_conf = jax.lax.psum(conf.sum(axis=0), CLIENTS_AXIS)
+            new_params = jax.tree.map(avg, params)
+        with jax.named_scope(METRICS):
+            pooled_conf = jax.lax.psum(conf.sum(axis=0), CLIENTS_AXIS)
         return new_params, pooled_conf
 
     client_body = jax.shard_map(
@@ -294,6 +301,7 @@ def build_mpmd_programs(mesh, apply_fn: Callable, tx, num_classes: int, *,
     stacked = rounds_per_step > 1
 
     @partial(jax.jit, donate_argnums=(0,))
+    @jax.named_scope(METRICS)
     def metrics(raw, mask):
         loss, conf, pooled_conf = (raw["loss"], raw["conf"],
                                    raw["pooled_conf"])

@@ -57,6 +57,13 @@ AUDIT_SPEC = {
     "collective_axes": (CLIENTS_AXIS,),
 }
 
+# The stages of a round, as the ``jax.named_scope`` each is built under
+# (here, in orchestration/mpmd.py's sub-programs and in assemble_metrics):
+# what analysis.program.program_scopes puts a compiled program's operations
+# down to. Metadata only: no instruction and no cache key changes with them.
+CLIENT_TRAIN, CLIENT_EVAL, AGGREGATE, METRICS = STAGES = (
+    "client_train", "client_eval", "aggregate", "metrics")
+
 
 # PRNG domain-separation tag for the DP noise stream (vs the participation
 # stream, which folds the round index directly into key(participation_seed)).
@@ -526,356 +533,364 @@ def build_round_fn(mesh, apply_fn: Callable, tx: optax.GradientTransformation,
                 return jnp.where(cond.reshape((cb,) + (1,) * (a.ndim - 1)),
                                  a, b)
 
-            if sampling:
-                # Per-(round, client) Bernoulli draw, deterministic in the
-                # seed — the in-graph analogue of server-side client
-                # sampling. Drawn BEFORE local work so the SCAFFOLD variate
-                # refresh below can respect it.
-                round_key = jax.random.fold_in(
-                    jax.random.key(participation_seed), r)
-                u = jax.vmap(
-                    lambda i: jax.random.uniform(
-                        jax.random.fold_in(round_key, i)))(gidx)
-                part = (u < participation_rate).astype(jnp.float32)
-            if scaffold:
-                # Correction c - c_i enters every local gradient; variates
-                # then refresh from the gradient at the shared round start.
-                corr = jax.tree.map(lambda cv, ci: cv[None] - ci, scv, ccv)
-                trained, new_opt, loss = jax.vmap(local_train)(
-                    params, opt_state, x, y, mask, corr)
-                ci_plus = jax.vmap(ce_grad)(start, x, y, mask)
-                num_clients = cb * n_devices
-
-                def cv_mean(d):
-                    # Reduce in f32 regardless of variate dtype, cast back
-                    # at the carry boundary (scan carries are dtype-exact).
-                    return (jax.lax.psum(d.astype(jnp.float32).sum(axis=0),
-                                         CLIENTS_AXIS) / num_clients)
-
-                # Participants refresh to c_i+ = grad_i(x); absentees keep
-                # their (stale) variate — the paper's sampled rule.
-                new_ccv = jax.tree.map(lambda n, o: n.astype(o.dtype),
-                                       ci_plus, ccv)
+            # Stage scopes (client_train / client_eval / aggregate / metrics):
+            # op_name metadata only — the optimised program is unchanged;
+            # the program_scopes event (analysis.program) joins them to a
+            # device trace's operations.
+            with jax.named_scope(CLIENT_TRAIN):
                 if sampling:
-                    new_ccv = jax.tree.map(
-                        lambda n, o: per_client_where(part > 0, n, o),
-                        new_ccv, ccv)
-                # c+ = c + mean over ALL clients of (c_i+ - c_i) (absentees
-                # contribute zero — this IS the paper's (|S|/N)-scaled
-                # participant mean); with the zero init this keeps
-                # c == mean_i(c_i) inductively, sampled or not.
-                scv = jax.tree.map(
-                    lambda s, dm: (s + dm).astype(s.dtype), scv,
-                    jax.tree.map(cv_mean,
-                                 jax.tree.map(lambda a, b: a - b,
-                                              new_ccv, ccv)))
-                ccv = new_ccv
-            else:
-                trained, new_opt, loss = jax.vmap(local_train)(
-                    params, opt_state, x, y, mask)
+                    # Per-(round, client) Bernoulli draw, deterministic in the
+                    # seed — the in-graph analogue of server-side client
+                    # sampling. Drawn BEFORE local work so the SCAFFOLD variate
+                    # refresh below can respect it.
+                    round_key = jax.random.fold_in(
+                        jax.random.key(participation_seed), r)
+                    u = jax.vmap(
+                        lambda i: jax.random.uniform(
+                            jax.random.fold_in(round_key, i)))(gidx)
+                    part = (u < participation_rate).astype(jnp.float32)
+                if scaffold:
+                    # Correction c - c_i enters every local gradient; variates
+                    # then refresh from the gradient at the shared round start.
+                    corr = jax.tree.map(lambda cv, ci: cv[None] - ci, scv, ccv)
+                    trained, new_opt, loss = jax.vmap(local_train)(
+                        params, opt_state, x, y, mask, corr)
+                    ci_plus = jax.vmap(ce_grad)(start, x, y, mask)
+                    num_clients = cb * n_devices
 
-            if sampling:
-                select = lambda a, b: per_client_where(part > 0, a, b)
-                params = jax.tree.map(select, trained, params)
-                opt_state = jax.tree.map(
-                    lambda a, b: (select(a, b)
-                                  if getattr(a, "ndim", 0) >= 1
-                                  and a.shape[:1] == (cb,) else a),
-                    new_opt, opt_state)
-                w = base_w * part
-            else:
-                params, opt_state = trained, new_opt
-                w = base_w
+                    def cv_mean(d):
+                        # Reduce in f32 regardless of variate dtype, cast back
+                        # at the carry boundary (scan carries are dtype-exact).
+                        return (jax.lax.psum(d.astype(jnp.float32).sum(axis=0),
+                                             CLIENTS_AXIS) / num_clients)
 
-            conf = jax.vmap(local_eval)(params, x, y, mask)   # (Cb, K, K)
-
-            # Byzantine fault injection: the first k clients SUBMIT a
-            # 10x-amplified sign-flipped update (model poisoning) while
-            # their local training and metrics above stay honest — only
-            # what enters aggregation is corrupted, like a real attacker.
-            agg_params = params
-            if byzantine_clients > 0:
-                bad = gidx < byzantine_clients
-                agg_params = jax.tree.map(
-                    lambda t, s: per_client_where(bad, s - 10.0 * (t - s), t),
-                    params, start)
-
-            if delta_path:
-                # Weighted mean of per-client UPDATES as a pseudo-gradient
-                # for the server optimizer (fedtpu.ops.server_opt). Eval
-                # above ran on the trained local models, preserving the
-                # reference's metrics-before-aggregation order. Raw psum
-                # here — its result is axis-INVARIANT, unlike
-                # make_all_reduce's clients-varying typing — so the
-                # replicated server state provably stays replicated through
-                # the scan carry and the P() out-spec.
-                total_w = jax.lax.psum(w.sum(), CLIENTS_AXIS)
-                # Fixed public denominator q*C under DP+sampling (see the
-                # dp_fixed_denom note above); realized weight otherwise.
-                denom = (participation_rate * cb * n_devices
-                         if dp_fixed_denom else jnp.maximum(total_w, 1.0))
-                delta = jax.tree.map(lambda t, s: t - s, agg_params, start)
-                clip_t = dpc if dp_adaptive_clip else dp_clip_norm
-                if dp_clip_norm > 0:
-                    delta, dnorms = clip_by_global_norm(delta, clip_t)
-
-                def mean_delta_leaf(d):
-                    local = jnp.tensordot(w.astype(jnp.float32),
-                                          d.astype(jnp.float32), axes=1)
-                    return jax.lax.psum(local, CLIENTS_AXIS) / denom
-
-                mean_delta = jax.tree.map(mean_delta_leaf, delta)
-                if dp_noise_multiplier > 0:
-                    # Adaptive clipping splits the budget: deltas take the
-                    # effective z_delta (> z) so that together with the
-                    # count release below the round charges exactly z.
-                    std = dp_z_delta * clip_t / denom
-                    # Domain-separate the noise stream from the
-                    # participation stream (same fold_in(key(seed), r)
-                    # shape; both seeds default 0): fold a fixed tag in
-                    # first so the Gaussian draw is independent of the
-                    # participation coin flips.
-                    noise_key = jax.random.fold_in(
-                        jax.random.fold_in(jax.random.key(dp_seed),
-                                           _DP_NOISE_STREAM), r)
-                    mean_delta = jax.tree.map(
-                        jnp.add, mean_delta,
-                        gaussian_noise_tree(noise_key, mean_delta, std))
-                if dp_adaptive_clip:
-                    # Noisy clipped-fraction b (unit-sensitivity count over
-                    # participants), then the geometric quantile step
-                    # clip *= exp(-lr * (b - quantile)) — Andrew et al.'s
-                    # update toward the dp_target_quantile of update norms.
-                    # b is a COUNT fraction: its denominator is the
-                    # participant count (fixed q*C under DP+sampling),
-                    # never the data-size weight — a weight denominator
-                    # under weighting='data_size' would divide ~num_clients
-                    # clipped clients by the total SAMPLE count, pinning
-                    # b near 0 and growing the clip without bound
-                    # (review r4).
-                    present = (w > 0).astype(jnp.float32)
-                    count = jax.lax.psum(present.sum(), CLIENTS_AXIS)
-                    denom_b = (participation_rate * cb * n_devices
-                               if dp_fixed_denom
-                               else jnp.maximum(count, 1.0))
-                    # The released quantity is the RECENTERED sum
-                    # sum_i(indicator_i - 1/2) — add/remove sensitivity
-                    # 1/2, which is what justifies crediting the count
-                    # noise as a 2*z_count multiplier in the split
-                    # identity (Andrew et al.; noising the raw sum would
-                    # be sensitivity 1 and undercharge epsilon — review
-                    # r4). At full participation the estimate below is
-                    # numerically identical to the raw fraction.
-                    b_sum = jax.lax.psum(
-                        (present * ((dnorms <= clip_t)
-                                    .astype(jnp.float32) - 0.5)).sum(),
-                        CLIENTS_AXIS)
-                    if dp_count_noise_multiplier > 0:
-                        count_key = jax.random.fold_in(
-                            jax.random.fold_in(jax.random.key(dp_seed),
-                                               _DP_COUNT_STREAM), r)
-                        b_sum = b_sum + (dp_count_noise_multiplier
-                                         * jax.random.normal(count_key))
-                    b = b_sum / denom_b + 0.5
-                    dpc_new = dpc * jnp.exp(
-                        -dp_clip_lr * (b - dp_target_quantile))
-                    if dp_count_noise_multiplier == 0:
-                        # Noise-free quantile tracking: a zero-participant
-                        # round observed nothing — b collapses to the 0.5
-                        # prior and would still move the clip by
-                        # exp(-lr*(0.5-q)). Hold the clip instead. (With
-                        # count noise on, the release happens regardless
-                        # and must be consumed as drawn.)
-                        dpc_new = jnp.where(count > 0, dpc_new, dpc)
-                    dpc = dpc_new
-                new_step, new_sstate = server_opt.update(mean_delta, sstate)
-                if sampling and not dp_fixed_denom:
-                    # Plain FedOpt under sampling: a zero-participant round
-                    # leaves the server model AND its momentum untouched
-                    # (params carry over unchanged, like the averaging path).
-                    keep = total_w > 0
-                    new_step = jax.tree.map(
-                        lambda s: jnp.where(keep, s, jnp.zeros_like(s)),
-                        new_step)
-                    new_sstate = jax.tree.map(
-                        lambda nv, ov: jnp.where(keep, nv, ov),
-                        new_sstate, sstate)
-                sstate = new_sstate
-                g = jax.tree.map(lambda s: s[0], start)   # slots identical
-                g_new = jax.tree.map(jnp.add, g, new_step)
-                params = jax.tree.map(bcast_global, g_new, params)
-            elif compress == "int8":
-                # Bandwidth-lean exchange (fedtpu.parallel.compress): the
-                # new global is reconstructed as start + weighted-mean of
-                # int8-quantized deltas; requires every slot to start the
-                # round at the shared global (init_federated_state
-                # shared_start=True), like the delta path.
-                total_w = all_reduce(w.sum())             # clients-varying
-                delta = jax.tree.map(lambda t, s: t - s, agg_params, start)
-                mean_delta = qmean(delta, w.astype(jnp.float32), total_w)
-                g = jax.tree.map(lambda s: s[0], start)   # slots identical
-
-                def q_avg(gl, md, p):
-                    # Zero participants (under sampling): skip averaging.
-                    return jnp.where(total_w > 0, bcast_global(gl + md, p), p)
-
-                params = jax.tree.map(q_avg, g, mean_delta, params)
-            elif robust:
-                # Robust rules need every client's submitted value: gather
-                # the (corrupted-as-submitted) params across the mesh.
-                num_clients = cb * n_devices
-                k_trim = int(round(trim_ratio * num_clients))
-                if robust_aggregation == "trimmed_mean" and (
-                        2 * k_trim >= num_clients):
-                    raise ValueError(
-                        f"trim_ratio={trim_ratio} removes all "
-                        f"{num_clients} clients")
-                if robust_aggregation == "krum" and (
-                        num_clients < 2 * krum_f + 3):
-                    # Blanchard et al.'s Byzantine-resilience precondition
-                    # n > 2f + 2 — below it, f colluding clients can win
-                    # the score and the guarantee is void.
-                    raise ValueError(
-                        f"krum needs >= 2 * krum_f + 3 clients "
-                        f"(got C={num_clients}, krum_f={krum_f})")
-
-                def gather_clients(p):
-                    pg = jax.lax.all_gather(p.astype(jnp.float32),
-                                            CLIENTS_AXIS)   # (D, Cb, ...)
-                    return pg.reshape((-1,) + pg.shape[2:])  # (C, ...)
-
-                whole_update_rule = robust_aggregation in ("krum",
-                                                           "geometric_median")
-                if whole_update_rule:
-                    # krum and geometric_median both work on the JOINT
-                    # flattened update per client — one shared
-                    # gather/flatten (and its inverse below).
-                    gathered = jax.tree.map(gather_clients, agg_params)
-                    leaves = jax.tree.leaves(gathered)
-                    flat = jnp.concatenate(
-                        [g.reshape(num_clients, -1) for g in leaves], axis=1)
-
-                if robust_aggregation == "geometric_median":
-                    # Smoothed Weiszfeld (the RFA rule, Pillutla et al.):
-                    # iterate u <- sum_i u_i/max(||u_i - u||, eps) /
-                    # sum_i 1/max(||u_i - u||, eps) from the mean — the
-                    # point minimizing the SUM of distances to client
-                    # updates, robust to any <50% corrupted minority.
-                    mu = flat.mean(axis=0)
-
-                    def weiszfeld(u, _):
-                        d = jnp.sqrt(jnp.sum(jnp.square(flat - u), axis=1))
-                        wgt = 1.0 / jnp.maximum(d, 1e-8)
-                        return ((wgt[:, None] * flat).sum(axis=0)
-                                / wgt.sum()), None
-
-                    mu, _ = jax.lax.scan(weiszfeld, mu,
-                                         length=WEISZFELD_ITERS)
-                    offsets = [0]
-                    for l in leaves:
-                        offsets.append(offsets[-1]
-                                       + math.prod(l.shape[1:]))
-                    flat_leaves = [
-                        mu[offsets[i]:offsets[i + 1]].reshape(
-                            leaves[i].shape[1:])
-                        for i in range(len(leaves))]
-                    glob = jax.tree.unflatten(
-                        jax.tree.structure(gathered), flat_leaves)
-                    params = jax.tree.map(bcast_global, glob, agg_params)
-                elif robust_aggregation == "krum":
-                    # Blanchard et al. 2017: score each client by the sum
-                    # of squared distances to its C - f - 2 nearest peers;
-                    # the winner's whole update becomes the global. MXU
-                    # form: pairwise distances via the gram matrix of the
-                    # flattened updates.
-                    # Pairwise distances are invariant under any common
-                    # shift: center on the client mean BEFORE the gram
-                    # matrix, so the shared model magnitude (>> per-client
-                    # differences late in training) cancels exactly instead
-                    # of catastrophically in f32 — otherwise rounding noise
-                    # ~eps*||params||^2 can outweigh the honest-vs-poisoned
-                    # distance gap and noise-rank the scores.
-                    flat = flat - flat.mean(axis=0, keepdims=True)
-                    gram = flat @ flat.T                     # (C, C)
-                    sq = jnp.diag(gram)
-                    d2 = sq[:, None] + sq[None, :] - 2.0 * gram
-                    d2 = jnp.where(jnp.eye(num_clients, dtype=bool),
-                                   jnp.inf, d2)              # exclude self
-                    k_near = num_clients - krum_f - 2
-                    scores = jnp.sort(d2, axis=1)[:, :k_near].sum(axis=1)
-                    winner = jnp.argmin(scores)
-
-                    def select_winner(g, p):
-                        return bcast_global(jax.lax.dynamic_index_in_dim(
-                            g, winner, keepdims=False), p)
-
-                    params = jax.tree.map(select_winner, gathered,
-                                          agg_params)
-                else:
+                    # Participants refresh to c_i+ = grad_i(x); absentees keep
+                    # their (stale) variate — the paper's sampled rule.
+                    new_ccv = jax.tree.map(lambda n, o: n.astype(o.dtype),
+                                           ci_plus, ccv)
                     if sampling:
-                        # Mask-aware order statistics: the median /
-                        # trimmed mean of the PARTICIPATING subset only.
-                        # Absentee rows are pushed to +inf so they sort
-                        # past every live value; the traced participant
-                        # count n then addresses the order statistics.
-                        part_all = jax.lax.all_gather(
-                            part, CLIENTS_AXIS).reshape(-1)   # (C,)
-                        n_act = part_all.sum()
-                        n_i = n_act.astype(jnp.int32)
-                        k_t = jnp.round(trim_ratio * n_act).astype(jnp.int32)
+                        new_ccv = jax.tree.map(
+                            lambda n, o: per_client_where(part > 0, n, o),
+                            new_ccv, ccv)
+                    # c+ = c + mean over ALL clients of (c_i+ - c_i) (absentees
+                    # contribute zero — this IS the paper's (|S|/N)-scaled
+                    # participant mean); with the zero init this keeps
+                    # c == mean_i(c_i) inductively, sampled or not.
+                    scv = jax.tree.map(
+                        lambda s, dm: (s + dm).astype(s.dtype), scv,
+                        jax.tree.map(cv_mean,
+                                     jax.tree.map(lambda a, b: a - b,
+                                                  new_ccv, ccv)))
+                    ccv = new_ccv
+                else:
+                    trained, new_opt, loss = jax.vmap(local_train)(
+                        params, opt_state, x, y, mask)
 
-                    def ragg(p):
-                        allc = gather_clients(p)
-                        if not sampling:
+                if sampling:
+                    select = lambda a, b: per_client_where(part > 0, a, b)
+                    params = jax.tree.map(select, trained, params)
+                    opt_state = jax.tree.map(
+                        lambda a, b: (select(a, b)
+                                      if getattr(a, "ndim", 0) >= 1
+                                      and a.shape[:1] == (cb,) else a),
+                        new_opt, opt_state)
+                    w = base_w * part
+                else:
+                    params, opt_state = trained, new_opt
+                    w = base_w
+
+            with jax.named_scope(CLIENT_EVAL):
+                conf = jax.vmap(local_eval)(params, x, y, mask)   # (Cb, K, K)
+
+            with jax.named_scope(AGGREGATE):
+                # Byzantine fault injection: the first k clients SUBMIT a
+                # 10x-amplified sign-flipped update (model poisoning) while
+                # their local training and metrics above stay honest — only
+                # what enters aggregation is corrupted, like a real attacker.
+                agg_params = params
+                if byzantine_clients > 0:
+                    bad = gidx < byzantine_clients
+                    agg_params = jax.tree.map(
+                        lambda t, s: per_client_where(bad, s - 10.0 * (t - s), t),
+                        params, start)
+
+                if delta_path:
+                    # Weighted mean of per-client UPDATES as a pseudo-gradient
+                    # for the server optimizer (fedtpu.ops.server_opt). Eval
+                    # above ran on the trained local models, preserving the
+                    # reference's metrics-before-aggregation order. Raw psum
+                    # here — its result is axis-INVARIANT, unlike
+                    # make_all_reduce's clients-varying typing — so the
+                    # replicated server state provably stays replicated through
+                    # the scan carry and the P() out-spec.
+                    total_w = jax.lax.psum(w.sum(), CLIENTS_AXIS)
+                    # Fixed public denominator q*C under DP+sampling (see the
+                    # dp_fixed_denom note above); realized weight otherwise.
+                    denom = (participation_rate * cb * n_devices
+                             if dp_fixed_denom else jnp.maximum(total_w, 1.0))
+                    delta = jax.tree.map(lambda t, s: t - s, agg_params, start)
+                    clip_t = dpc if dp_adaptive_clip else dp_clip_norm
+                    if dp_clip_norm > 0:
+                        delta, dnorms = clip_by_global_norm(delta, clip_t)
+
+                    def mean_delta_leaf(d):
+                        local = jnp.tensordot(w.astype(jnp.float32),
+                                              d.astype(jnp.float32), axes=1)
+                        return jax.lax.psum(local, CLIENTS_AXIS) / denom
+
+                    mean_delta = jax.tree.map(mean_delta_leaf, delta)
+                    if dp_noise_multiplier > 0:
+                        # Adaptive clipping splits the budget: deltas take the
+                        # effective z_delta (> z) so that together with the
+                        # count release below the round charges exactly z.
+                        std = dp_z_delta * clip_t / denom
+                        # Domain-separate the noise stream from the
+                        # participation stream (same fold_in(key(seed), r)
+                        # shape; both seeds default 0): fold a fixed tag in
+                        # first so the Gaussian draw is independent of the
+                        # participation coin flips.
+                        noise_key = jax.random.fold_in(
+                            jax.random.fold_in(jax.random.key(dp_seed),
+                                               _DP_NOISE_STREAM), r)
+                        mean_delta = jax.tree.map(
+                            jnp.add, mean_delta,
+                            gaussian_noise_tree(noise_key, mean_delta, std))
+                    if dp_adaptive_clip:
+                        # Noisy clipped-fraction b (unit-sensitivity count over
+                        # participants), then the geometric quantile step
+                        # clip *= exp(-lr * (b - quantile)) — Andrew et al.'s
+                        # update toward the dp_target_quantile of update norms.
+                        # b is a COUNT fraction: its denominator is the
+                        # participant count (fixed q*C under DP+sampling),
+                        # never the data-size weight — a weight denominator
+                        # under weighting='data_size' would divide ~num_clients
+                        # clipped clients by the total SAMPLE count, pinning
+                        # b near 0 and growing the clip without bound
+                        # (review r4).
+                        present = (w > 0).astype(jnp.float32)
+                        count = jax.lax.psum(present.sum(), CLIENTS_AXIS)
+                        denom_b = (participation_rate * cb * n_devices
+                                   if dp_fixed_denom
+                                   else jnp.maximum(count, 1.0))
+                        # The released quantity is the RECENTERED sum
+                        # sum_i(indicator_i - 1/2) — add/remove sensitivity
+                        # 1/2, which is what justifies crediting the count
+                        # noise as a 2*z_count multiplier in the split
+                        # identity (Andrew et al.; noising the raw sum would
+                        # be sensitivity 1 and undercharge epsilon — review
+                        # r4). At full participation the estimate below is
+                        # numerically identical to the raw fraction.
+                        b_sum = jax.lax.psum(
+                            (present * ((dnorms <= clip_t)
+                                        .astype(jnp.float32) - 0.5)).sum(),
+                            CLIENTS_AXIS)
+                        if dp_count_noise_multiplier > 0:
+                            count_key = jax.random.fold_in(
+                                jax.random.fold_in(jax.random.key(dp_seed),
+                                                   _DP_COUNT_STREAM), r)
+                            b_sum = b_sum + (dp_count_noise_multiplier
+                                             * jax.random.normal(count_key))
+                        b = b_sum / denom_b + 0.5
+                        dpc_new = dpc * jnp.exp(
+                            -dp_clip_lr * (b - dp_target_quantile))
+                        if dp_count_noise_multiplier == 0:
+                            # Noise-free quantile tracking: a zero-participant
+                            # round observed nothing — b collapses to the 0.5
+                            # prior and would still move the clip by
+                            # exp(-lr*(0.5-q)). Hold the clip instead. (With
+                            # count noise on, the release happens regardless
+                            # and must be consumed as drawn.)
+                            dpc_new = jnp.where(count > 0, dpc_new, dpc)
+                        dpc = dpc_new
+                    new_step, new_sstate = server_opt.update(mean_delta, sstate)
+                    if sampling and not dp_fixed_denom:
+                        # Plain FedOpt under sampling: a zero-participant round
+                        # leaves the server model AND its momentum untouched
+                        # (params carry over unchanged, like the averaging path).
+                        keep = total_w > 0
+                        new_step = jax.tree.map(
+                            lambda s: jnp.where(keep, s, jnp.zeros_like(s)),
+                            new_step)
+                        new_sstate = jax.tree.map(
+                            lambda nv, ov: jnp.where(keep, nv, ov),
+                            new_sstate, sstate)
+                    sstate = new_sstate
+                    g = jax.tree.map(lambda s: s[0], start)   # slots identical
+                    g_new = jax.tree.map(jnp.add, g, new_step)
+                    params = jax.tree.map(bcast_global, g_new, params)
+                elif compress == "int8":
+                    # Bandwidth-lean exchange (fedtpu.parallel.compress): the
+                    # new global is reconstructed as start + weighted-mean of
+                    # int8-quantized deltas; requires every slot to start the
+                    # round at the shared global (init_federated_state
+                    # shared_start=True), like the delta path.
+                    total_w = all_reduce(w.sum())             # clients-varying
+                    delta = jax.tree.map(lambda t, s: t - s, agg_params, start)
+                    mean_delta = qmean(delta, w.astype(jnp.float32), total_w)
+                    g = jax.tree.map(lambda s: s[0], start)   # slots identical
+
+                    def q_avg(gl, md, p):
+                        # Zero participants (under sampling): skip averaging.
+                        return jnp.where(total_w > 0, bcast_global(gl + md, p), p)
+
+                    params = jax.tree.map(q_avg, g, mean_delta, params)
+                elif robust:
+                    # Robust rules need every client's submitted value: gather
+                    # the (corrupted-as-submitted) params across the mesh.
+                    num_clients = cb * n_devices
+                    k_trim = int(round(trim_ratio * num_clients))
+                    if robust_aggregation == "trimmed_mean" and (
+                            2 * k_trim >= num_clients):
+                        raise ValueError(
+                            f"trim_ratio={trim_ratio} removes all "
+                            f"{num_clients} clients")
+                    if robust_aggregation == "krum" and (
+                            num_clients < 2 * krum_f + 3):
+                        # Blanchard et al.'s Byzantine-resilience precondition
+                        # n > 2f + 2 — below it, f colluding clients can win
+                        # the score and the guarantee is void.
+                        raise ValueError(
+                            f"krum needs >= 2 * krum_f + 3 clients "
+                            f"(got C={num_clients}, krum_f={krum_f})")
+
+                    def gather_clients(p):
+                        pg = jax.lax.all_gather(p.astype(jnp.float32),
+                                                CLIENTS_AXIS)   # (D, Cb, ...)
+                        return pg.reshape((-1,) + pg.shape[2:])  # (C, ...)
+
+                    whole_update_rule = robust_aggregation in ("krum",
+                                                               "geometric_median")
+                    if whole_update_rule:
+                        # krum and geometric_median both work on the JOINT
+                        # flattened update per client — one shared
+                        # gather/flatten (and its inverse below).
+                        gathered = jax.tree.map(gather_clients, agg_params)
+                        leaves = jax.tree.leaves(gathered)
+                        flat = jnp.concatenate(
+                            [g.reshape(num_clients, -1) for g in leaves], axis=1)
+
+                    if robust_aggregation == "geometric_median":
+                        # Smoothed Weiszfeld (the RFA rule, Pillutla et al.):
+                        # iterate u <- sum_i u_i/max(||u_i - u||, eps) /
+                        # sum_i 1/max(||u_i - u||, eps) from the mean — the
+                        # point minimizing the SUM of distances to client
+                        # updates, robust to any <50% corrupted minority.
+                        mu = flat.mean(axis=0)
+
+                        def weiszfeld(u, _):
+                            d = jnp.sqrt(jnp.sum(jnp.square(flat - u), axis=1))
+                            wgt = 1.0 / jnp.maximum(d, 1e-8)
+                            return ((wgt[:, None] * flat).sum(axis=0)
+                                    / wgt.sum()), None
+
+                        mu, _ = jax.lax.scan(weiszfeld, mu,
+                                             length=WEISZFELD_ITERS)
+                        offsets = [0]
+                        for l in leaves:
+                            offsets.append(offsets[-1]
+                                           + math.prod(l.shape[1:]))
+                        flat_leaves = [
+                            mu[offsets[i]:offsets[i + 1]].reshape(
+                                leaves[i].shape[1:])
+                            for i in range(len(leaves))]
+                        glob = jax.tree.unflatten(
+                            jax.tree.structure(gathered), flat_leaves)
+                        params = jax.tree.map(bcast_global, glob, agg_params)
+                    elif robust_aggregation == "krum":
+                        # Blanchard et al. 2017: score each client by the sum
+                        # of squared distances to its C - f - 2 nearest peers;
+                        # the winner's whole update becomes the global. MXU
+                        # form: pairwise distances via the gram matrix of the
+                        # flattened updates.
+                        # Pairwise distances are invariant under any common
+                        # shift: center on the client mean BEFORE the gram
+                        # matrix, so the shared model magnitude (>> per-client
+                        # differences late in training) cancels exactly instead
+                        # of catastrophically in f32 — otherwise rounding noise
+                        # ~eps*||params||^2 can outweigh the honest-vs-poisoned
+                        # distance gap and noise-rank the scores.
+                        flat = flat - flat.mean(axis=0, keepdims=True)
+                        gram = flat @ flat.T                     # (C, C)
+                        sq = jnp.diag(gram)
+                        d2 = sq[:, None] + sq[None, :] - 2.0 * gram
+                        d2 = jnp.where(jnp.eye(num_clients, dtype=bool),
+                                       jnp.inf, d2)              # exclude self
+                        k_near = num_clients - krum_f - 2
+                        scores = jnp.sort(d2, axis=1)[:, :k_near].sum(axis=1)
+                        winner = jnp.argmin(scores)
+
+                        def select_winner(g, p):
+                            return bcast_global(jax.lax.dynamic_index_in_dim(
+                                g, winner, keepdims=False), p)
+
+                        params = jax.tree.map(select_winner, gathered,
+                                              agg_params)
+                    else:
+                        if sampling:
+                            # Mask-aware order statistics: the median /
+                            # trimmed mean of the PARTICIPATING subset only.
+                            # Absentee rows are pushed to +inf so they sort
+                            # past every live value; the traced participant
+                            # count n then addresses the order statistics.
+                            part_all = jax.lax.all_gather(
+                                part, CLIENTS_AXIS).reshape(-1)   # (C,)
+                            n_act = part_all.sum()
+                            n_i = n_act.astype(jnp.int32)
+                            k_t = jnp.round(trim_ratio * n_act).astype(jnp.int32)
+
+                        def ragg(p):
+                            allc = gather_clients(p)
+                            if not sampling:
+                                if robust_aggregation == "median":
+                                    glob = jnp.median(allc, axis=0)
+                                else:
+                                    srt = jnp.sort(allc, axis=0)
+                                    if k_trim:
+                                        srt = srt[k_trim:num_clients - k_trim]
+                                    glob = srt.mean(axis=0)
+                                return bcast_global(glob, p)
+                            live = part_all.reshape(
+                                (num_clients,) + (1,) * (allc.ndim - 1))
+                            srt = jnp.sort(jnp.where(live > 0, allc, jnp.inf),
+                                           axis=0)
                             if robust_aggregation == "median":
-                                glob = jnp.median(allc, axis=0)
+                                lo = jax.lax.dynamic_index_in_dim(
+                                    srt, jnp.maximum((n_i - 1) // 2, 0),
+                                    keepdims=False)
+                                hi = jax.lax.dynamic_index_in_dim(
+                                    srt, jnp.maximum(n_i // 2, 0),
+                                    keepdims=False)
+                                glob = 0.5 * (lo + hi)
                             else:
-                                srt = jnp.sort(allc, axis=0)
-                                if k_trim:
-                                    srt = srt[k_trim:num_clients - k_trim]
-                                glob = srt.mean(axis=0)
-                            return bcast_global(glob, p)
-                        live = part_all.reshape(
-                            (num_clients,) + (1,) * (allc.ndim - 1))
-                        srt = jnp.sort(jnp.where(live > 0, allc, jnp.inf),
-                                       axis=0)
-                        if robust_aggregation == "median":
-                            lo = jax.lax.dynamic_index_in_dim(
-                                srt, jnp.maximum((n_i - 1) // 2, 0),
-                                keepdims=False)
-                            hi = jax.lax.dynamic_index_in_dim(
-                                srt, jnp.maximum(n_i // 2, 0),
-                                keepdims=False)
-                            glob = 0.5 * (lo + hi)
-                        else:
-                            j = jax.lax.broadcasted_iota(jnp.int32,
-                                                         srt.shape, 0)
-                            keep = (j >= k_t) & (j < n_i - k_t)
-                            denom = jnp.maximum(
-                                (n_i - 2 * k_t).astype(jnp.float32), 1.0)
-                            glob = jnp.where(keep, srt,
-                                             0.0).sum(axis=0) / denom
-                        # Zero participants: params carry over unchanged,
-                        # exactly like the averaging path.
-                        return jnp.where(n_act > 0, bcast_global(glob, p),
-                                         p)
+                                j = jax.lax.broadcasted_iota(jnp.int32,
+                                                             srt.shape, 0)
+                                keep = (j >= k_t) & (j < n_i - k_t)
+                                denom = jnp.maximum(
+                                    (n_i - 2 * k_t).astype(jnp.float32), 1.0)
+                                glob = jnp.where(keep, srt,
+                                                 0.0).sum(axis=0) / denom
+                            # Zero participants: params carry over unchanged,
+                            # exactly like the averaging path.
+                            return jnp.where(n_act > 0, bcast_global(glob, p),
+                                             p)
 
-                    params = jax.tree.map(ragg, agg_params)
-            else:
-                total_w = all_reduce(w.sum())             # clients-varying
+                        params = jax.tree.map(ragg, agg_params)
+                else:
+                    total_w = all_reduce(w.sum())             # clients-varying
 
-                def avg(p):
-                    # sum_i w_i * p_i locally, then all-reduce across
-                    # devices == the rank-0 gather + weighted average +
-                    # bcast of FL_CustomMLP...:105-119.
-                    local = jnp.tensordot(w.astype(jnp.float32),
-                                          p.astype(jnp.float32), axes=1)
-                    glob = all_reduce(local) / jnp.maximum(total_w, 1.0)
-                    # Zero participants (under sampling): skip averaging.
-                    return jnp.where(total_w > 0, bcast_global(glob, p), p)
+                    def avg(p):
+                        # sum_i w_i * p_i locally, then all-reduce across
+                        # devices == the rank-0 gather + weighted average +
+                        # bcast of FL_CustomMLP...:105-119.
+                        local = jnp.tensordot(w.astype(jnp.float32),
+                                              p.astype(jnp.float32), axes=1)
+                        glob = all_reduce(local) / jnp.maximum(total_w, 1.0)
+                        # Zero participants (under sampling): skip averaging.
+                        return jnp.where(total_w > 0, bcast_global(glob, p), p)
 
-                params = jax.tree.map(avg, agg_params)
-            pooled_conf = jax.lax.psum(conf.sum(axis=0), CLIENTS_AXIS)
+                    params = jax.tree.map(avg, agg_params)
+            with jax.named_scope(METRICS):
+                pooled_conf = jax.lax.psum(conf.sum(axis=0), CLIENTS_AXIS)
             return (params, opt_state, sstate, ccv, scv, dpc, r + 1), (
                 loss, conf, pooled_conf)
 
@@ -995,15 +1010,16 @@ def assemble_metrics(loss, conf, pooled_conf, mask, rounds_per_step: int):
     client mean so one dataless client doesn't deflate the global metric /
     early-stop signal. (The reference's sklearn scripts likewise skip
     dataless ranks, FL_SkLearn...:91-93.)"""
-    per_client = jax.vmap(jax.vmap(metrics_from_confusion))(conf)
-    metrics = {
-        "loss": loss,
-        "per_client": per_client,
-        "client_mean": masked_client_mean(per_client, mask),
-        "pooled": jax.vmap(metrics_from_confusion)(pooled_conf),
-    }
-    if rounds_per_step == 1:
-        metrics = jax.tree.map(lambda v: v[0], metrics)
+    with jax.named_scope(METRICS):
+        per_client = jax.vmap(jax.vmap(metrics_from_confusion))(conf)
+        metrics = {
+            "loss": loss,
+            "per_client": per_client,
+            "client_mean": masked_client_mean(per_client, mask),
+            "pooled": jax.vmap(metrics_from_confusion)(pooled_conf),
+        }
+        if rounds_per_step == 1:
+            metrics = jax.tree.map(lambda v: v[0], metrics)
     return metrics
 
 

@@ -169,6 +169,37 @@ class Span:
         self.end(**({"error": repr(exc)} if exc is not None else {}))
 
 
+class Phase:
+    """One phase of a host loop on every clock that is on, as one context
+    manager: the sink's span, a profiler annotation that puts the same
+    window on the device trace's timeline (a ``jax.profiler.
+    TraceAnnotation``, made by the caller: this module loads without a
+    backend), and a watchdog guard. Entered guard first and span last, so
+    the span is the innermost window; yields the span
+    (``end_after_fetch`` closes it early, on the fetch)."""
+
+    __slots__ = ("_span", "_annotation", "_guard")
+
+    def __init__(self, span, annotation=None, guard=None):
+        self._span = span
+        self._annotation = annotation
+        self._guard = guard
+
+    def __enter__(self):
+        if self._guard is not None:
+            self._guard.__enter__()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return self._span.__enter__()
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self._span.__exit__(exc_type, exc, tb)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        if self._guard is not None:
+            self._guard.__exit__(exc_type, exc, tb)
+
+
 class Tracer:
     """Appends schema-v2 events to a JSONL sink. One per run; all
     timestamps are seconds since this tracer's construction (monotonic).

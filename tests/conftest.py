@@ -50,6 +50,9 @@ import pytest  # noqa: E402
 # consistency guards at the bottom of pytest_collection_modifyitems
 # below.
 QUICK_TESTS = {
+    # the stage of each operation from a compiled program's text (pure text)
+    "test_round_tracing.py::"
+    "test_program_scopes_reads_the_stage_of_each_operation",
     # AOT compiles for a described v5e:2x2 (no chip needed, ~2 s each)
     "test_aot_tpu_compile.py::test_fused_mlp_forward_compiles_for_v5e",
     "test_aot_tpu_compile.py::test_weighted_average_clients_compiles_for_v5e",
