@@ -4,9 +4,23 @@ No reference analogue — the reference is tabular-only (SURVEY.md §5,
 "long-context" bullet). This model exists to stress the FedAvg aggregation
 payload (~1M params vs the income MLP's ~11K) and the MXU conv path.
 
-Architecture: [Conv3x3 -> ReLU -> MaxPool2x2] x len(conv_channels)
+Architecture: [Conv3x3 -> MaxPool2x2 -> +bias -> ReLU] x len(conv_channels)
 -> flatten -> Dense(hidden) -> ReLU -> Dense(classes). NHWC layout (TPU
 native); convs via lax.conv_general_dilated so XLA tiles them onto the MXU.
+
+Each block computes ``relu(maxpool(conv(h, w)) + b)``. Adding a per-channel
+bias (with its rounding) and ReLU are monotone non-decreasing, so the max
+commutes with them: the values equal ``maxpool(relu(conv(h, w) + b))`` bit
+for bit in any floating-point format. Pooling first lets the backward pass
+read the convolution's own output as the pool's operand, where the other
+order keeps a second full-resolution (post-ReLU) copy of it for that.
+
+The gradient is the same subgradient. A window whose maximum is <= -b gets
+zero both ways; a strict maximum gets the whole gradient both ways. Tie
+convention: where two distinct conv outputs round to one value once the
+bias is added (common in bf16), the gradient goes to the larger conv output,
+the true argmax; values that are equal before the bias go to the first in
+window order, as ``reduce_window``'s backward does.
 """
 
 from __future__ import annotations
@@ -71,8 +85,7 @@ def convnet_apply(params, x: jax.Array, compute_dtype=None) -> jax.Array:
         h = lax.conv_general_dilated(
             h, cast(conv["w"]), window_strides=(1, 1), padding="SAME",
             dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        h = jax.nn.relu(h + cast(conv["b"]))
-        h = _maxpool2(h)
+        h = jax.nn.relu(_maxpool2(h) + cast(conv["b"]))
     h = h.reshape(h.shape[0], -1)
     h = jax.nn.relu(h @ cast(params["dense"]["w"]) + cast(params["dense"]["b"]))
     h = h @ cast(params["head"]["w"]) + cast(params["head"]["b"])
