@@ -491,7 +491,7 @@ def phase_income(args, csv: str, device: dict, cache_dir: str) -> None:
     check(compiled_kernel or device["platform"] != "tpu",
           "--use-pallas: the held-out eval holds no tpu_custom_call")
     got = exp.eval_step(params, ds.x_test, ds.y_test)
-    ref = build_eval_fn(exp.apply_fn, exp.num_classes)(
+    ref = build_eval_fn(exp.task)(
         params, ds.x_test, ds.y_test)
     pallas_gap = max(abs(float(got[k]) - float(ref[k])) for k in ref)
     check(pallas_gap <= METRIC_ATOL,

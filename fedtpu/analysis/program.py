@@ -102,7 +102,8 @@ def _stage_of(op_name: str, stages: Sequence[str]) -> Optional[str]:
     return None
 
 
-def program_scopes(compiled_text: str, stages: Sequence[str]) -> dict:
+def program_scopes(compiled_text: str, stages: Sequence[str],
+                   strict: bool = False) -> dict:
     """Which of the ``jax.named_scope`` names ``stages`` (the builder's:
     ``parallel.round.STAGES``) each operation of a compiled program belongs
     to, from ``Compiled.as_text()``: ``{"scopes": {key: scope}, "unscoped":
@@ -118,7 +119,12 @@ def program_scopes(compiled_text: str, stages: Sequence[str]) -> dict:
     stage of its root. One the compiler made without an ``op_name`` (a
     copy, a prefetch into faster memory, a decomposed dot) takes the stage
     all its users carry, else the one all its operands carry; nothing is
-    inherited across a loop, a branch, a tuple or a parameter."""
+    inherited across a loop, a branch, a tuple or a parameter.
+    ``strict``: only an instruction WITHOUT an ``op_name`` inherits. For
+    the stages, which cover a program, the difference is nil; for a second
+    level of scopes that covers only parts of it (``parallel.round.LAYERS``)
+    an instruction that names itself outside every scope (the optimizer's
+    update of a weight) stays outside, whoever made its operands."""
     computations: dict[str, list[str]] = {}
     entry = current = None
     for line in compiled_text.splitlines():
@@ -142,7 +148,7 @@ def program_scopes(compiled_text: str, stages: Sequence[str]) -> dict:
     scopes: dict[str, str] = {}
     unscoped: list[str] = []
     for name in run:
-        keys, stage, operands, junctions = {}, {}, {}, set()
+        keys, stage, operands, junctions, named = {}, {}, {}, set(), set()
         for line in computations.get(name, ()):
             inst = _HLO_INSTRUCTION_RE.match(line)
             opcode = inst and _HLO_OPCODE_RE.search(inst.group(2))
@@ -156,6 +162,8 @@ def program_scopes(compiled_text: str, stages: Sequence[str]) -> dict:
             op_name = _HLO_OP_NAME_RE.search(rest)
             stage[inst] = (_stage_of(op_name.group(1), stages)
                            if op_name else None)
+            if op_name:
+                named.add(inst)
             if opcode.group(1) in _HLO_JUNCTION:
                 junctions.add(inst)
             operands[inst] = _HLO_OPERAND_RE.findall(rest[opcode.end():])
@@ -169,7 +177,8 @@ def program_scopes(compiled_text: str, stages: Sequence[str]) -> dict:
             # HLO text defines an instruction before its users, so one
             # pass in the right order resolves whole chains
             for inst in order:
-                if stage[inst] is None and inst not in junctions:
+                if (stage[inst] is None and inst not in junctions
+                        and not (strict and inst in named)):
                     near = {stage[n] for n in edges[inst] if n in stage}
                     near.discard(None)
                     if len(near) == 1:
@@ -376,6 +385,9 @@ def engine_audit_spec(cfg) -> dict:
     if cfg.fed.cohort_size > 0:
         from fedtpu.cohort import scheduler
         return scheduler.AUDIT_SPEC
+    if cfg.fed.client_state == "stateless":
+        from fedtpu.parallel import stateless
+        return stateless.AUDIT_SPEC
     if cfg.fed.async_mode:
         from fedtpu.parallel import async_fed
         return async_fed.AUDIT_SPEC

@@ -59,6 +59,7 @@ from fedtpu.parallel.mesh import CLIENTS_AXIS, make_mesh
 from fedtpu.parallel.ring import make_all_reduce
 from fedtpu.parallel.round import bcast_global, client_init_keys
 from fedtpu.training.client import make_local_eval_step, make_local_train_step
+from fedtpu.training.task import classification_task
 
 # Read-only audit hook (fedtpu.analysis.program): the scan-over-cohorts
 # chunk donates BOTH the carry state and the streamed xs buffers.
@@ -265,7 +266,8 @@ def build_cohort_round_fn(mesh, apply_fn: Callable, tx, num_classes: int,
     local_train = make_local_train_step(apply_fn, tx,
                                         local_steps=local_steps,
                                         prox_mu=prox_mu)
-    local_eval = make_local_eval_step(apply_fn, num_classes)
+    local_eval = make_local_eval_step(
+        classification_task(apply_fn, num_classes))
     n_devices = mesh.devices.size
     all_reduce = make_all_reduce(aggregation, CLIENTS_AXIS, n_devices)
 
@@ -879,7 +881,8 @@ def run_cohort_experiment(cfg, dataset=None, verbose: bool = True,
                           and (rnd + 1 + j) % cfg.run.eval_test_every == 0)
                 if due:
                     if eval_step is None:
-                        eval_step = build_eval_fn(apply_fn, ds.num_classes)
+                        eval_step = build_eval_fn(
+                            classification_task(apply_fn, ds.num_classes))
                     glob = jax.tree.map(
                         lambda p: p[0],
                         sched.state_for_checkpoint()["params"])

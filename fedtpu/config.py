@@ -46,7 +46,7 @@ class DataConfig:
     """
 
     csv_path: Optional[str] = None       # None => synthetic income-like data
-    dataset_name: Optional[str] = None   # 'cifar10' selects the image loader (fedtpu.data.cifar10); None = tabular/CSV
+    dataset_name: Optional[str] = None   # 'cifar10' selects the image loader (fedtpu.data.cifar10); 'tokens' the synthetic federated token corpus (fedtpu.data.tokens: synthetic_rows packed sequences of synthetic_features tokens); None = tabular/CSV
     label_column: str = "income"         # FL_SkLearn...:164 ('Outcome' for the diabetes path, FL_CustomMLP...:217)
     test_size: float = 0.2               # FL_CustomMLP...:239
     split_seed: int = 42                 # random_state=42 everywhere in the reference
@@ -96,7 +96,7 @@ class ModelConfig:
     """Model family + shape. MLP is FL_CustomMLP...:12-25; ConvNet is the
     BASELINE.json config-5 CIFAR-10 stress model (new, no reference analogue)."""
 
-    kind: str = "mlp"                    # 'mlp' | 'convnet'
+    kind: str = "mlp"                    # 'mlp' | 'convnet' | 'olmoe'
     # () degenerates the MLP to a single Linear — multinomial logistic
     # regression (pinned by tests/test_round_smoke.py).
     hidden_sizes: Tuple[int, ...] = (50, 200)  # FL_CustomMLP...:40
@@ -109,6 +109,21 @@ class ModelConfig:
     # Use the Pallas fused-MLP forward kernel for evaluation (MLP, f32 only).
     # The train step stays on the XLA path (the kernel defines no custom VJP).
     use_pallas: bool = False
+    # kind='olmoe' (fedtpu.models.olmoe): the keys of the published
+    # config.json under their own names, at the values of
+    # allenai/OLMoE-1B-7B-0125-Instruct. Rows are packed sequences (token
+    # and segment ids) and the task is next-token prediction; num_classes,
+    # input_dim and hidden_sizes mean nothing to this kind.
+    hidden_size: int = 2048
+    num_attention_heads: int = 16        # head_dim = hidden_size / heads = 128
+    num_hidden_layers: int = 16
+    num_experts: int = 64
+    num_experts_per_tok: int = 8
+    intermediate_size: int = 1024        # the width of ONE expert
+    vocab_size: int = 50304
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    norm_topk_prob: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -282,6 +297,19 @@ class FedConfig:
     # Serving-trace file (fedtpu.serving.traces) whose arrival order
     # drives 'trace' sampling: cohorts are the next distinct users.
     cohort_trace: Optional[str] = None
+    # Where a client's model lives between rounds. 'resident' (every engine
+    # above): C copies of parameters and optimizer state on the clients
+    # axis. 'stateless' (fedtpu.parallel.stateless): ONE global copy; each
+    # round the clients train one after another from it and only their
+    # weighted delta is kept, so memory does not grow with the client count
+    # and a model of hundreds of millions of parameters fits. Needs
+    # optim.name='sgd' with momentum 0 (a client carries nothing over).
+    client_state: str = "resident"       # 'resident' | 'stateless'
+    # Rows of one local SGD step. 0 = the client's whole shard, the
+    # reference's full-batch step. > 0 cuts a client's epoch into
+    # minibatches of this many rows, one step each (stateless engine only;
+    # for the language model a row is one packed sequence).
+    local_batch_rows: int = 0
     # The reference reads its stop signal one loop-top late (:132 vs :195)
     # but the doomed iteration breaks before training — no extra round is
     # trained, so there is no lag to reproduce (tests/test_stop_lag.py
@@ -604,6 +632,32 @@ PRESETS = {
         fed=FedConfig(rounds=50),
     ),
 }
+
+
+def _olmoe(layers: int) -> ExperimentConfig:
+    """allenai/OLMoE-1B-7B-0125-Instruct federated over 8 silos: synthetic
+    packed 4096-token sequences (fedtpu.data.tokens), one local epoch of
+    one-sequence SGD steps a round, FedAvgM on one shared global model."""
+    return ExperimentConfig(
+        # 16 packed sequences in all, 4,096 tokens each (the model's
+        # max_position_embeddings), 1-3 a client by size skew
+        data=DataConfig(dataset_name="tokens", synthetic_rows=16,
+                        synthetic_features=4096),
+        shard=ShardConfig(num_clients=8, shuffle=False),
+        model=ModelConfig(kind="olmoe", num_hidden_layers=layers,
+                          compute_dtype="bfloat16"),
+        optim=OptimConfig(name="sgd", learning_rate=0.005, momentum=0.0,
+                          steplr_gamma=1.0),
+        fed=FedConfig(rounds=20, client_state="stateless", local_batch_rows=1,
+                      server_opt="fedavgm", server_momentum=0.9,
+                      same_init=True),
+    )
+
+
+# The published model, and the same at the depth one 16 GB chip holds (one
+# layer is a whole period of the layer pattern; 625.6M of 6.92B parameters).
+PRESETS["olmoe-1b-7b"] = _olmoe(16)
+PRESETS["olmoe-1b-7b-l1"] = _olmoe(1)
 
 
 def get_preset(name: str) -> ExperimentConfig:

@@ -123,8 +123,12 @@ def shard_indices(y: np.ndarray, cfg: ShardConfig) -> List[np.ndarray]:
 
 
 def pack_clients(x: np.ndarray, y: np.ndarray, cfg: ShardConfig,
-                 pad_multiple: int = 8) -> ClientBatch:
+                 pad_multiple: int = 8, client_of_row=None) -> ClientBatch:
     """Shard then pack into padded dense arrays (see module docstring).
+
+    ``client_of_row`` (``Dataset.client_of_row``): the rows already belong
+    to clients, and each client packs its own, in order. Integer rows (token
+    ids) stay integers; everything else packs as float32.
 
     ``pad_multiple`` rounds the per-client sample axis up so its size stays
     friendly to XLA tiling (the 8-sublane dimension on TPU).
@@ -135,7 +139,18 @@ def pack_clients(x: np.ndarray, y: np.ndarray, cfg: ShardConfig,
     the corresponding row of the full pack.
     """
     full, offset = _partition_view(cfg)
-    if full is not None:
+    if client_of_row is not None:
+        if full is not None:
+            raise ValueError("a partition window cannot re-carve rows that "
+                             "already belong to clients (client_of_row)")
+        owner = np.asarray(client_of_row)
+        if owner.max(initial=-1) >= cfg.num_clients:
+            raise ValueError(
+                f"the data name client {int(owner.max())} and the run has "
+                f"{cfg.num_clients} clients")
+        idx = [np.flatnonzero(owner == c) for c in range(cfg.num_clients)]
+        max_n = max((len(i) for i in idx), default=0)
+    elif full is not None:
         idx_all = shard_indices(y, full)
         idx = idx_all[offset:offset + cfg.num_clients]
         max_n = max((len(i) for i in idx_all), default=0)
@@ -146,7 +161,9 @@ def pack_clients(x: np.ndarray, y: np.ndarray, cfg: ShardConfig,
 
     feat_shape = x.shape[1:]
     c = cfg.num_clients
-    xp = np.zeros((c, max_n) + feat_shape, dtype=np.float32)
+    xp = np.zeros((c, max_n) + feat_shape,
+                  dtype=x.dtype if np.issubdtype(x.dtype, np.integer)
+                  else np.float32)
     yp = np.zeros((c, max_n), dtype=np.int32)
     mask = np.zeros((c, max_n), dtype=np.float32)
     counts = np.zeros((c,), dtype=np.int32)

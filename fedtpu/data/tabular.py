@@ -44,6 +44,10 @@ class Dataset:
     # and records it in the manifest, so a synthetic stand-in can never pass
     # for the dataset a preset is named after.
     source: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    # Whose each training row is, where the data are born partitioned (a
+    # federated corpus, fedtpu.data.tokens): pack_clients then keeps the
+    # clients' own rows and ShardConfig's strategy carves nothing.
+    client_of_row: Optional[np.ndarray] = None
 
     @property
     def input_dim(self) -> int:

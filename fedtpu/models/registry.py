@@ -15,7 +15,10 @@ _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16,
 
 
 def build_model(cfg: ModelConfig):
-    """Return ``(init_fn(key) -> params, apply_fn(params, x) -> logits)``."""
+    """Return ``(init_fn(key) -> params, apply_fn(params, x) -> logits)``.
+    For ``kind='olmoe'`` the second is ``stats_fn(params, x, mask) ->
+    statistics`` (fedtpu.models.olmoe.olmoe_stats): a vocabulary-sized
+    model hands out sums over tokens, never its logits."""
     param_dtype = _DTYPES[cfg.param_dtype]
     compute_dtype = (None if cfg.compute_dtype == cfg.param_dtype
                      else _DTYPES[cfg.compute_dtype])
@@ -34,4 +37,15 @@ def build_model(cfg: ModelConfig):
                                  param_dtype=param_dtype)
         apply = functools.partial(convnet_apply, compute_dtype=compute_dtype)
         return init, apply
+    if cfg.kind == "olmoe":
+        # imported here: a job of another kind never pays for it
+        from fedtpu.models.olmoe import olmoe_init, olmoe_stats
+        if cfg.hidden_size % cfg.num_attention_heads:
+            raise ValueError(f"hidden_size {cfg.hidden_size} does not divide "
+                             f"into {cfg.num_attention_heads} heads")
+        init = functools.partial(olmoe_init, cfg=cfg, param_dtype=param_dtype)
+        stats = functools.partial(
+            olmoe_stats, cfg=cfg,
+            compute_dtype=compute_dtype or param_dtype)
+        return init, stats
     raise ValueError(f"unknown model kind {cfg.kind!r}")

@@ -32,7 +32,7 @@ No bias correction (matching the published algorithms, which initialize
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Tuple
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,11 +44,21 @@ SERVER_OPTIMIZERS = ("fedavgm", "fedadagrad", "fedyogi", "fedadam")
 class ServerOptimizer:
     """``init(g) -> state``; ``update(delta, state) -> (step, state)`` with
     the server applying ``g_new = g + step``. Pure pytree-to-pytree functions:
-    they trace cleanly inside the shard_map'd round scan."""
+    they trace cleanly inside the shard_map'd round scan.
+
+    ``begin`` / ``finish``, where an optimizer has them, are the same update
+    in accumulating form, for an engine that sums the clients' normalised
+    deltas one at a time (fedtpu.parallel.stateless): ``begin(state)`` is
+    what the sum starts from and ``finish(total, state) -> (step, state)``
+    closes it, with ``finish(begin(state) + delta, state) ==
+    update(delta, state)``. FedAvgM's sum starts at ``momentum * m`` and IS
+    the new momentum, so its accumulator needs no buffer of its own."""
 
     name: str
     init: Callable
     update: Callable
+    begin: Optional[Callable] = None
+    finish: Optional[Callable] = None
 
 
 def _zeros_like_tree(tree):
@@ -82,10 +92,15 @@ def make_server_optimizer(name: str, learning_rate: float = 1.0,
         def update(delta, state):
             m = jax.tree.map(lambda mm, d: momentum * mm + d,
                              state["m"], delta)
-            step = jax.tree.map(lambda mm: learning_rate * mm, m)
-            return step, {"m": m}
+            return finish(m, state)
 
-        return ServerOptimizer(name, init, update)
+        def begin(state):
+            return jax.tree.map(lambda mm: momentum * mm, state["m"])
+
+        def finish(m, state):
+            return jax.tree.map(lambda mm: learning_rate * mm, m), {"m": m}
+
+        return ServerOptimizer(name, init, update, begin, finish)
 
     def init(g):
         return {"m": _zeros_like_tree(g), "v": _zeros_like_tree(g)}

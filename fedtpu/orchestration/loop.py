@@ -50,12 +50,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from fedtpu.config import ExperimentConfig
-from fedtpu.data import data_notice, load_dataset
+from fedtpu.data import data_notice, load_dataset, load_token_corpus
 from fedtpu.data.sharding import pack_clients
 from fedtpu.data.tabular import Dataset
 from fedtpu.models import build_model
 from fedtpu.ops import build_optimizer
-from fedtpu.ops.metrics import METRIC_NAMES
 from fedtpu.orchestration.checkpoint import (complete_steps,
                                              retain_checkpoints,
                                              save_checkpoint)
@@ -69,8 +68,11 @@ from fedtpu.telemetry import (TelemetryLogger, build_manifest,
                               make_tracer)
 from fedtpu.telemetry.metrics import device_memory_gauges
 from fedtpu.telemetry.trace import Phase
-from fedtpu.parallel.round import (STAGES, build_round_fn, build_eval_fn,
+from fedtpu.parallel.round import (LAYER_KERNELS, LAYERS, STAGES,
+                                   build_round_fn,
+                                   build_eval_fn, check_resident_fits,
                                    init_federated_state, global_params)
+from fedtpu.training.task import Task, build_task, classification_task
 from fedtpu.utils.timing import Timer, force_fetch
 from fedtpu.utils.trees import to_numpy
 
@@ -240,6 +242,10 @@ class Experiment:
     apply_fn: Optional[Callable] = None
     tx: Optional[object] = None
     num_classes: int = 0
+    # What the job learns (fedtpu.training.task): the loop's histories, its
+    # printed line, the early-stop rule and checkpoint retention go by its
+    # metric names.
+    task: Optional[Task] = None
 
 
 def build_experiment(cfg: ExperimentConfig,
@@ -252,16 +258,39 @@ def build_experiment(cfg: ExperimentConfig,
     (fedtpu.resilience.reshard) passes the agreed post-shrink submesh here —
     under jax.distributed the default would re-enroll every process,
     including the departing one."""
-    ds = dataset if dataset is not None else load_dataset(cfg.data)
+    if cfg.fed.client_state not in ("resident", "stateless"):
+        raise ValueError(f"client_state must be 'resident' or 'stateless', "
+                         f"got {cfg.fed.client_state!r}")
+    stateless = cfg.fed.client_state == "stateless"
+    if stateless:
+        from fedtpu.parallel import stateless as sl
+        sl.validate_stateless_config(cfg)
+    elif cfg.fed.local_batch_rows:
+        raise ValueError("local_batch_rows needs client_state='stateless': "
+                         "the resident engines take one full-batch step")
+    if dataset is not None:
+        ds = dataset
+    elif cfg.data.dataset_name == "tokens":
+        ds = load_token_corpus(cfg)
+    else:
+        ds = load_dataset(cfg.data)
     model_cfg = cfg.model
+    # The data say how wide the input and the output are, for the kinds
+    # that have either; a language model brings its own vocabulary.
     if model_cfg.kind == "mlp" and model_cfg.input_dim != ds.input_dim:
         model_cfg = dataclasses.replace(model_cfg, input_dim=ds.input_dim)
-    if model_cfg.num_classes != ds.num_classes:
+    if (model_cfg.kind in ("mlp", "convnet")
+            and model_cfg.num_classes != ds.num_classes):
         model_cfg = dataclasses.replace(model_cfg, num_classes=ds.num_classes)
+    if model_cfg.kind == "olmoe" and ds.num_classes != model_cfg.vocab_size:
+        raise ValueError(f"the corpus has a vocabulary of {ds.num_classes} "
+                         f"and the model one of {model_cfg.vocab_size}")
 
     init_fn, apply_fn = build_model(model_cfg)
+    task = build_task(model_cfg, apply_fn, ds.num_classes)
     tx = build_optimizer(cfg.optim)
-    packed = pack_clients(ds.x_train, ds.y_train, cfg.shard)
+    packed = pack_clients(ds.x_train, ds.y_train, cfg.shard,
+                          client_of_row=ds.client_of_row)
 
     # Fail fast on a DP config the round builders would reject later —
     # after data loading and state init (both engines share this check).
@@ -290,7 +319,24 @@ def build_experiment(cfg: ExperimentConfig,
         server = identity_server_optimizer()
 
     global_fn = global_params
-    if cfg.fed.async_mode:
+    if stateless:
+        if mesh is None:
+            mesh = make_mesh(cfg.run.mesh_devices, cfg.shard.num_clients)
+        shard = client_sharding(mesh)
+        if server is None:
+            from fedtpu.ops.server_opt import identity_server_optimizer
+            server = identity_server_optimizer()
+        state_fn = lambda: sl.init_stateless_state(
+            jax.random.key(cfg.fed.init_seed), mesh, init_fn, server)
+        step_fn = lambda r: sl.build_stateless_round_fn(
+            mesh, task, packed.counts,
+            learning_rate=cfg.optim.learning_rate,
+            steplr_step_size=cfg.optim.steplr_step_size,
+            steplr_gamma=cfg.optim.steplr_gamma,
+            weighting=cfg.fed.weighting, server_opt=server,
+            local_batch_rows=cfg.fed.local_batch_rows, rounds_per_step=r)
+        global_fn = lambda state: state["params"]
+    elif cfg.fed.async_mode:
         # The async engine replaces the whole synchronous aggregation
         # stack with the tick/arrival process — every knob of that stack
         # is meaningless (or privacy-unsound) under it, so each is
@@ -414,6 +460,7 @@ def build_experiment(cfg: ExperimentConfig,
         if mesh is None:
             mesh = make_mesh(cfg.run.mesh_devices, cfg.shard.num_clients)
         shard = client_sharding(mesh)
+        check_resident_fits(init_fn, tx, cfg.shard.num_clients, mesh)
         state_fn = lambda: init_federated_state(
             jax.random.key(cfg.fed.init_seed), mesh, cfg.shard.num_clients,
             init_fn, tx, same_init=cfg.fed.same_init, server_opt=server,
@@ -442,7 +489,7 @@ def build_experiment(cfg: ExperimentConfig,
             trim_ratio=cfg.fed.trim_ratio,
             krum_f=cfg.fed.krum_f,
             byzantine_clients=cfg.fed.byzantine_clients,
-            scaffold=cfg.fed.scaffold)
+            scaffold=cfg.fed.scaffold, task=task)
 
     # safe_put: plain device_put of a host value onto a cross-process
     # sharding runs an implicit per-array equality broadcast under
@@ -485,7 +532,7 @@ def build_experiment(cfg: ExperimentConfig,
     # scan requires in interpret mode). Opt-in for demonstration, not a perf
     # default (PERF.md: no current chip timing of it). A request the kernel
     # cannot serve is an error, never a quiet XLA eval.
-    eval_apply = apply_fn
+    eval_task = task
     if model_cfg.use_pallas:
         if not (model_cfg.kind == "mlp"
                 and model_cfg.param_dtype == "float32"
@@ -496,9 +543,9 @@ def build_experiment(cfg: ExperimentConfig,
                 f"param_dtype={model_cfg.param_dtype!r}, "
                 f"compute_dtype={model_cfg.compute_dtype!r}")
         from fedtpu.ops.pallas_kernels import fused_mlp_forward
-        eval_apply = fused_mlp_forward
+        eval_task = classification_task(fused_mlp_forward, ds.num_classes)
 
-    eval_step = build_eval_fn(eval_apply, ds.num_classes)
+    eval_step = build_eval_fn(eval_task)
     personalize_fn = None
     if cfg.fed.personalize_steps > 0:
         from fedtpu.training.personalize import build_personalize_fn
@@ -507,7 +554,8 @@ def build_experiment(cfg: ExperimentConfig,
     return Experiment(make_step=step_fn, state=state, batch=batch,
                       eval_step=eval_step, dataset=ds, mesh=mesh,
                       personalize_fn=personalize_fn, global_fn=global_fn,
-                      apply_fn=apply_fn, tx=tx, num_classes=ds.num_classes)
+                      apply_fn=apply_fn, tx=tx, num_classes=ds.num_classes,
+                      task=task)
 
 
 # The loop's finiteness check: its phase in the sink and the trace, and the
@@ -544,9 +592,17 @@ def _emit_program_scopes(tracer, program: str, width: Optional[int], fn,
         from fedtpu.analysis.program import program_scopes
         compiled = (fn if hasattr(fn, "as_text")
                     else fn.lower(*args).compile())
-        found = program_scopes(compiled.as_text(), STAGES + (STATE_CHECK,))
+        text = compiled.as_text()
+        found = program_scopes(text, STAGES + (STATE_CHECK,))
         if not found["scopes"]:
             found["stale_metadata"] = True
+        # operation -> innermost second-level scope (a model's own parts,
+        # the server's step); empty for a program that names none
+        found["layers"] = program_scopes(text, LAYERS, strict=True)["scopes"]
+        for key in [*found["scopes"], *found["unscoped"]]:
+            for prefix, layer in LAYER_KERNELS.items():
+                if key.startswith(prefix):
+                    found["layers"][key] = layer
         tracer.event("program_scopes", program=program, width=width, **found)
     except Exception as exc:
         # Diagnostic metadata, like the manifest's audit: a failure here
@@ -627,6 +683,10 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
     # surface, same ExperimentResult, bitwise-equal to this loop when
     # cohort_size == num_clients (tests/test_cohort.py).
     if cfg.fed.cohort_size > 0:
+        if cfg.fed.client_state == "stateless":
+            # this dispatch never reaches build_experiment, which refuses
+            from fedtpu.parallel.stateless import validate_stateless_config
+            validate_stateless_config(cfg)
         from fedtpu.cohort.scheduler import run_cohort_experiment
         return run_cohort_experiment(cfg, dataset=dataset, verbose=verbose,
                                      resume=resume)
@@ -716,6 +776,7 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
     with tracer.span("build"):
         exp = build_experiment(cfg, dataset)
     state, batch, eval_step, ds = exp.state, exp.batch, exp.eval_step, exp.dataset
+    names = exp.task.metric_names
     # First log line: which rows this run trains on (never a synthetic
     # stand-in passing for the preset's dataset); the manifest repeats it.
     log.info(data_notice(ds))
@@ -884,7 +945,9 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
 
     if tel.manifest:
         manifest_extra = {"program": "run",
-                          "engine": ("async" if cfg.fed.async_mode
+                          "engine": ("stateless"
+                                     if cfg.fed.client_state == "stateless"
+                                     else "async" if cfg.fed.async_mode
                                      else "tp2d" if cfg.run.model_parallel > 1
                                      else "mpmd" if cfg.run.mpmd
                                      else "sync1d"),
@@ -961,7 +1024,9 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
     # back); int8 compression quarters the f32 payload. An estimate of the
     # logical exchange, not a wire measurement — psum's actual traffic is
     # XLA-scheduled.
-    model_bytes = sum(int(np.prod(l.shape[1:]) or 1) * l.dtype.itemsize
+    # the shared-global engine's params carry no clients axis
+    lead = 0 if cfg.fed.client_state == "stateless" else 1
+    model_bytes = sum(int(np.prod(l.shape[lead:]) or 1) * l.dtype.itemsize
                       for l in jax.tree.leaves(state["params"]))
     registry.gauge("exchange_bytes_per_round_est").set(
         model_bytes * cfg.shard.num_clients
@@ -1217,10 +1282,10 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
     ledger = PrivacyLedger(cfg.fed, start_round=start_round,
                            restored_meta=restored_meta)
 
-    history = {k: [] for k in METRIC_NAMES}
-    pooled_hist = {k: [] for k in METRIC_NAMES}
-    per_client_hist = {k: [] for k in METRIC_NAMES}
-    test_hist = {k: [] for k in METRIC_NAMES}
+    history = {k: [] for k in names}
+    pooled_hist = {k: [] for k in names}
+    per_client_hist = {k: [] for k in names}
+    test_hist = {k: [] for k in names}
     staleness_hist: List[np.ndarray] = []
     losses: List[np.ndarray] = []
     sec_per_round: List[float] = []
@@ -1283,7 +1348,7 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
         """Clients with a non-finite loss or per-client metric this
         round — the rollback_exclude candidates."""
         bad = ~np.isfinite(np.asarray(loss_row))
-        for k in METRIC_NAMES:
+        for k in names:
             bad = bad | ~np.isfinite(np.asarray(m["per_client"][k]))
         return tuple(int(c) for c in np.nonzero(bad)[0])
 
@@ -1317,7 +1382,7 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
         # (authoritative through round j); the in-memory-only histories
         # drop exactly the rounds past j they hold.
         drop = max(0, rounds_run - j)
-        for k in METRIC_NAMES:
+        for k in names:
             history[k] = list(hist2.get(k, []))
             _drop_tail(pooled_hist[k], drop)
             _drop_tail(per_client_hist[k], drop)
@@ -1327,11 +1392,11 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
         if cfg.run.eval_test_every:
             edrop = sum(1 for rr in range(j + 1, rounds_run + 1)
                         if rr % cfg.run.eval_test_every == 0)
-            for k in METRIC_NAMES:
+            for k in names:
                 _drop_tail(test_hist[k], edrop)
         rounds_run = j
-        prev_metric = ([history[k][-1] for k in METRIC_NAMES]
-                       if history[METRIC_NAMES[0]] else None)
+        prev_metric = ([history[k][-1] for k in names]
+                       if history[names[0]] else None)
         termination_count = cfg.fed.termination_patience
         if cfg.run.rollback_exclude and offenders:
             fresh = sorted(set(offenders) - excluded)
@@ -1365,10 +1430,10 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
         return True
 
     if restored_history is not None:
-        for k in METRIC_NAMES:
+        for k in names:
             history[k] = list(restored_history.get(k, []))
-        if history[METRIC_NAMES[0]]:
-            prev_metric = [history[k][-1] for k in METRIC_NAMES]
+        if history[names[0]]:
+            prev_metric = [history[k][-1] for k in names]
         rounds_run = start_round
 
     # Checkpoint retention (RunConfig.keep_checkpoints > 0): after every
@@ -1560,17 +1625,28 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                     rounds_run = r + 1
                     loss_mean = float(np.mean(losses[-1]))
 
-                    for k in METRIC_NAMES:
+                    for k in names:
                         history[k].append(client_mean[k])
                         pooled_hist[k].append(float(m["pooled"][k]))
                         per_client_hist[k].append(per_client[k])
                     if "staleness" in m:        # async engine's extra metric
                         staleness_hist.append(np.asarray(m["staleness"]))
+                    extra = {}
+                    for name, value in m.get("counters", {}).items():
+                        # the task's own counters (next_token: expert load,
+                        # tokens): scalars to the registry, vectors to the
+                        # round's event
+                        if np.ndim(value):
+                            extra[name] = np.asarray(value).tolist()
+                        elif name in exp.task.gauges:
+                            registry.gauge(name).set(float(value))
+                        else:
+                            registry.counter(name).inc(int(value))
 
                     registry.counter("rounds").inc()
                     tracer.event("round", round=r + 1, dur_s=dt,
                                  accuracy=client_mean["accuracy"],
-                                 loss_mean=loss_mean,
+                                 loss_mean=loss_mean, **extra,
                                  **({"staleness_mean":
                                      float(staleness_hist[-1].mean()),
                                      "staleness_max":
@@ -1586,7 +1662,7 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                         jsonl.write(json.dumps({
                             "round": r + 1, "sec_per_round": dt,
                             "client_mean": client_mean,
-                            "pooled": {k: pooled_hist[k][-1] for k in METRIC_NAMES},
+                            "pooled": {k: pooled_hist[k][-1] for k in names},
                             "loss_mean": loss_mean,
                             **({"staleness_mean":
                                 float(staleness_hist[-1].mean())}
@@ -1601,11 +1677,11 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                             # (FL_CustomMLP...:151-162) — here just a loop, no barriers.
                             for c in range(cfg.shard.num_clients):
                                 vals = ", ".join(f"{k}: {per_client[k][c]:.4f}"
-                                                 for k in METRIC_NAMES)
+                                                 for k in names)
                                 log.parity(f"  CLIENT {c} - Local Metrics "
                                            f"(Round {r + 1}): [{vals}]")
                         gvals = ", ".join(f"{k}: {client_mean[k]:.4f}"
-                                          for k in METRIC_NAMES)
+                                          for k in names)
                         stale_note = (f"  (mean staleness "
                                       f"{staleness_hist[-1].mean():.2f})"
                                       if "staleness" in m else "")
@@ -1618,7 +1694,7 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                     # Failure detection: a diverged step (NaN/inf loss or
                     # metrics) halts cleanly instead of burning the remaining
                     # rounds — with an emergency checkpoint of the last state.
-                    cur = [client_mean[k] for k in METRIC_NAMES]
+                    cur = [client_mean[k] for k in names]
                     if cfg.run.halt_on_nonfinite and not (
                             np.all(np.isfinite(cur))
                             and np.all(np.isfinite(losses[-1]))):
@@ -1719,7 +1795,7 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
             ctl.await_acks(seq, "b", participants)
             state = new_state
             ctl.committed("grow", ctl.process_index)
-            for k in METRIC_NAMES:
+            for k in names:
                 if control.get("history", {}).get(k) is not None:
                     history[k] = list(control["history"][k])
             prev_metric = control.get("prev_metric")
@@ -1853,8 +1929,8 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                     ctl.committed("shrink", req.victim)
                     if multiproc:
                         ckpt_group = sorted(ctl.active)
-                    if history[METRIC_NAMES[0]]:
-                        prev_metric = [history[k][-1] for k in METRIC_NAMES]
+                    if history[names[0]]:
+                        prev_metric = [history[k][-1] for k in names]
                     termination_count = cfg.fed.termination_patience
                     ctl.event("reshard_done", rnd, mode="shrink",
                               target=target, block_start=block_start,
@@ -1891,7 +1967,7 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                     ctl.write_spool(ctl.seq, jm, repl, {
                         "round": rnd,
                         "history": {k: [float(v) for v in history[k]]
-                                    for k in METRIC_NAMES},
+                                    for k in names},
                         "prev_metric": prev_metric,
                         "termination_count": termination_count,
                         "ledger": {k: np.asarray(v).tolist() for k, v in
@@ -1921,8 +1997,8 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                 ckpt_group = st["ckpt_group"]
                 state, batch = new_state, exp.batch
                 ctl.committed("grow", req.victim)
-                if history[METRIC_NAMES[0]]:
-                    prev_metric = [history[k][-1] for k in METRIC_NAMES]
+                if history[names[0]]:
+                    prev_metric = [history[k][-1] for k in names]
                 termination_count = cfg.fed.termination_patience
                 ctl.event("reshard_done", rnd, mode="grow", target=orig_C,
                           steps=[s.to_json() for s in steps])
@@ -2170,7 +2246,7 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                     sp.end_after_fetch(tm)
                 registry.counter("held_out_evals").inc()
                 for _ in range(eval_due):
-                    for k in METRIC_NAMES:
+                    for k in names:
                         test_hist[k].append(float(tm[k]))
 
             # Checkpoint label semantics under chunking: a checkpoint due

@@ -69,6 +69,7 @@ from fedtpu.parallel.ring import make_all_reduce
 from fedtpu.parallel.round import (AGGREGATE, CLIENT_EVAL, CLIENT_TRAIN,
                                    METRICS, bcast_global)
 from fedtpu.training.client import make_local_eval_step, make_local_train_step
+from fedtpu.training.task import classification_task
 
 __all__ = [
     "AUDIT_SPEC", "AUDIT_SPECS", "MpmdStep", "build_mpmd_step",
@@ -203,7 +204,8 @@ def build_mpmd_programs(mesh, apply_fn: Callable, tx, num_classes: int, *,
     local_train = make_local_train_step(apply_fn, tx,
                                         local_steps=local_steps,
                                         prox_mu=prox_mu)
-    local_eval = make_local_eval_step(apply_fn, num_classes)
+    local_eval = make_local_eval_step(
+        classification_task(apply_fn, num_classes))
     n_devices = mesh.devices.size
     all_reduce = make_all_reduce(aggregation, CLIENTS_AXIS, n_devices)
     spec_c = _spec_c()

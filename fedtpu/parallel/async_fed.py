@@ -56,6 +56,7 @@ from fedtpu.parallel.round import (assemble_metrics, bcast_global,
                                    client_init_keys)
 from fedtpu.training.client import (make_local_eval_step,
                                     make_local_train_step)
+from fedtpu.training.task import classification_task
 
 # Read-only audit hook (fedtpu.analysis.program): the FedBuff tick's
 # traced entry point + donation contract, consumed by the SPMD auditor.
@@ -254,7 +255,8 @@ def build_async_round_fn(mesh, apply_fn: Callable,
     local_train = make_local_train_step(apply_fn, tx,
                                         local_steps=local_steps,
                                         prox_mu=prox_mu)
-    local_eval = make_local_eval_step(apply_fn, num_classes)
+    local_eval = make_local_eval_step(
+        classification_task(apply_fn, num_classes))
     n_devices = mesh.devices.size
 
     def tick_body(params, opt_state, anchors, pull, buf, nbuf, ring,

@@ -38,7 +38,7 @@ import optax
 from jax.sharding import PartitionSpec as P
 
 from fedtpu.ops.losses import masked_cross_entropy
-from fedtpu.ops.metrics import confusion_matrix, metrics_from_confusion
+from fedtpu.ops.metrics import metrics_from_confusion
 from fedtpu.ops.server_opt import (ServerOptimizer, clip_by_global_norm,
                                    gaussian_noise_tree,
                                    identity_server_optimizer)
@@ -46,6 +46,7 @@ from fedtpu.parallel.compress import make_quantized_weighted_mean
 from fedtpu.parallel.mesh import CLIENTS_AXIS, client_sharding
 from fedtpu.parallel.ring import make_all_reduce
 from fedtpu.training.client import make_local_train_step, make_local_eval_step
+from fedtpu.training.task import Task, classification_task
 
 # Read-only audit hook (fedtpu.analysis.program): names this engine's
 # traced entry point and the donation contract its builder applies, so
@@ -63,6 +64,19 @@ AUDIT_SPEC = {
 # down to. Metadata only: no instruction and no cache key changes with them.
 CLIENT_TRAIN, CLIENT_EVAL, AGGREGATE, METRICS = STAGES = (
     "client_train", "client_eval", "aggregate", "metrics")
+# The second level of scopes, under the stages: the server's own step inside
+# ``aggregate`` (fedtpu.parallel.stateless) and the parts of a model that
+# names its own (fedtpu.models.olmoe.LAYER_SCOPES). The ``program_scopes``
+# event maps operations to them under ``layers``.
+SERVER_UPDATE = "server_update"
+LAYERS = ("embed", "attention", "router", "expert_dispatch", "experts",
+          "lm_head_loss", SERVER_UPDATE)
+# Kernels the TPU's compiler puts in an instruction's place under a name of
+# its own, which replaces the ``op_name`` and with it every scope: whose they
+# are, by the prefix of the instruction's name. ``lax.ragged_dot`` becomes
+# ``ragged-dot-none*`` (and one ``ragged-dot-metadata`` a call), and the only
+# grouped matmuls of a program are its experts'.
+LAYER_KERNELS = {"ragged-dot": "experts"}
 
 
 # PRNG domain-separation tag for the DP noise stream (vs the participation
@@ -227,6 +241,40 @@ def init_federated_state(key: jax.Array, mesh, num_clients: int,
     return state
 
 
+def resident_state_bytes(init_fn, tx, num_clients: int, n_devices: int) -> int:
+    """Bytes a device holds of the resident engines' client state: its
+    clients' parameters and optimizer state (shapes only, nothing built)."""
+    params = jax.eval_shape(init_fn, jax.random.key(0))
+    opt = jax.eval_shape(tx.init, params)
+    one = sum(math.prod(l.shape) * l.dtype.itemsize
+              for l in jax.tree.leaves((params, opt)))
+    return one * math.ceil(num_clients / n_devices)
+
+
+def check_resident_fits(init_fn, tx, num_clients: int, mesh,
+                        limit_bytes: int | None = None) -> None:
+    """Raise, instead of running out of memory later, where the resident
+    engines' per-client copies cannot fit the device. ``limit_bytes``
+    defaults to what the first device reports (nothing on the CPU)."""
+    if limit_bytes is None:
+        # one of this process's own devices: another's cannot be asked
+        mine = [d for d in mesh.devices.flat
+                if d.process_index == jax.process_index()]
+        stats = (mine[0].memory_stats() if mine else None) or {}
+        limit_bytes = stats.get("bytes_limit")
+    if not limit_bytes:
+        return
+    need = resident_state_bytes(init_fn, tx, num_clients, mesh.devices.size)
+    if need > limit_bytes:
+        raise ValueError(
+            f"the resident engines keep a copy of the parameters and the "
+            f"optimizer state for every client: {need / 1e9:.1f} GB a device "
+            f"for {num_clients} clients, and the device has "
+            f"{limit_bytes / 1e9:.1f} GB. Set fed.client_state='stateless' "
+            "(one shared global model, clients in turn; "
+            "fedtpu.parallel.stateless)")
+
+
 def build_round_fn(mesh, apply_fn: Callable, tx: optax.GradientTransformation,
                    num_classes: int, weighting: str = "data_size",
                    rounds_per_step: int = 1,
@@ -248,12 +296,18 @@ def build_round_fn(mesh, apply_fn: Callable, tx: optax.GradientTransformation,
                    trim_ratio: float = 0.1,
                    krum_f: int = 0,
                    byzantine_clients: int = 0,
-                   scaffold: bool = False):
+                   scaffold: bool = False,
+                   task: Task | None = None):
     """Compile the full federated round. Returns
     ``round_step(state, batch) -> (state, metrics)`` where ``batch`` is a dict
     of client-sharded arrays ``x (C,N,...), y (C,N), mask (C,N)`` and
     ``metrics`` holds per-client, client-mean, and pooled views (the
     reference's two global-metric semantics, SURVEY.md §5).
+
+    ``task`` (fedtpu.training.task): the loss the clients train on, the
+    statistics their in-round evaluation sums and the metrics derived from
+    them; ``None`` is classification over ``apply_fn``'s ``num_classes``
+    logits, the reference's.
 
     ``round_step`` DONATES the input state (its buffers are consumed; params
     and optimizer state update in place on device). Always rebind:
@@ -350,9 +404,12 @@ def build_round_fn(mesh, apply_fn: Callable, tx: optax.GradientTransformation,
     inductively from the zero init, sampled or not, and is test-pinned.
     """
 
+    if task is None:
+        task = classification_task(apply_fn, num_classes)
     local_train = make_local_train_step(apply_fn, tx, local_steps=local_steps,
-                                        prox_mu=prox_mu, scaffold=scaffold)
-    local_eval = make_local_eval_step(apply_fn, num_classes)
+                                        prox_mu=prox_mu, scaffold=scaffold,
+                                        task_loss=task.loss)
+    local_eval = make_local_eval_step(task)
 
     sampling = participation_rate < 1.0
     # Reduction backend for the parameter-averaging path: psum
@@ -600,7 +657,8 @@ def build_round_fn(mesh, apply_fn: Callable, tx: optax.GradientTransformation,
                     w = base_w
 
             with jax.named_scope(CLIENT_EVAL):
-                conf = jax.vmap(local_eval)(params, x, y, mask)   # (Cb, K, K)
+                # the task's statistics: for classification (Cb, K, K)
+                conf = jax.vmap(local_eval)(params, x, y, mask)
 
             with jax.named_scope(AGGREGATE):
                 # Byzantine fault injection: the first k clients SUBMIT a
@@ -890,7 +948,8 @@ def build_round_fn(mesh, apply_fn: Callable, tx: optax.GradientTransformation,
 
                     params = jax.tree.map(avg, agg_params)
             with jax.named_scope(METRICS):
-                pooled_conf = jax.lax.psum(conf.sum(axis=0), CLIENTS_AXIS)
+                pooled_conf = jax.tree.map(
+                    lambda c: jax.lax.psum(c.sum(axis=0), CLIENTS_AXIS), conf)
             return (params, opt_state, sstate, ccv, scv, dpc, r + 1), (
                 loss, conf, pooled_conf)
 
@@ -972,7 +1031,7 @@ def build_round_fn(mesh, apply_fn: Callable, tx: optax.GradientTransformation,
             state["params"], state["opt_state"], sstate, ccv, scv, dpc,
             batch["x"], batch["y"], batch["mask"], state["round"])
         metrics = assemble_metrics(loss, conf, pooled_conf, batch["mask"],
-                                   rounds_per_step)
+                                   rounds_per_step, task.metrics)
         new_state = {"params": params, "opt_state": opt_state,
                      "round": state["round"] + rounds_per_step}
         if delta_path:
@@ -1001,23 +1060,29 @@ def masked_client_mean(per_client, mask):
                         per_client)
 
 
-def assemble_metrics(loss, conf, pooled_conf, mask, rounds_per_step: int):
-    """Per-round metric dicts from stacked confusion matrices; shared by the
-    shard_map engine above and the GSPMD 2-D engine (fedtpu.parallel.tp).
+def assemble_metrics(loss, conf, pooled_conf, mask, rounds_per_step: int,
+                     metrics_fn: Callable = metrics_from_confusion,
+                     counters_fn: Callable | None = None):
+    """Per-round metric dicts from stacked statistics of a task (by default
+    classification's confusion matrices); shared by the shard_map engine
+    above and the GSPMD 2-D engine (fedtpu.parallel.tp).
 
-    ``conf``: (R, C, K, K). Empty shards (possible under dirichlet skew or
+    ``conf``: (R, C, K, K); ``metrics_fn`` is the task's ``metrics`` and
+    ``counters_fn`` its ``counters`` (of the pooled statistics), if any. Empty shards (possible under dirichlet skew or
     clients > samples) report all-zero metrics; they are excluded from the
     client mean so one dataless client doesn't deflate the global metric /
     early-stop signal. (The reference's sklearn scripts likewise skip
     dataless ranks, FL_SkLearn...:91-93.)"""
     with jax.named_scope(METRICS):
-        per_client = jax.vmap(jax.vmap(metrics_from_confusion))(conf)
+        per_client = jax.vmap(jax.vmap(metrics_fn))(conf)
         metrics = {
             "loss": loss,
             "per_client": per_client,
             "client_mean": masked_client_mean(per_client, mask),
-            "pooled": jax.vmap(metrics_from_confusion)(pooled_conf),
+            "pooled": jax.vmap(metrics_fn)(pooled_conf),
         }
+        if counters_fn is not None:
+            metrics["counters"] = jax.vmap(counters_fn)(pooled_conf)
         if rounds_per_step == 1:
             metrics = jax.tree.map(lambda v: v[0], metrics)
     return metrics
@@ -1087,16 +1152,14 @@ def with_per_client(state, num_clients: int, new_leaves):
     return jax.tree.unflatten(treedef, out)
 
 
-def build_eval_fn(apply_fn: Callable, num_classes: int):
-    """Held-out evaluation of the global model — NEW relative to the
-    reference, which broadcasts a test split it never uses
-    (FL_CustomMLP...:243-246)."""
+def build_eval_fn(task: Task):
+    """Held-out evaluation of the global model by the task's statistics and
+    metrics — NEW relative to the reference, which broadcasts a test split
+    it never uses (FL_CustomMLP...:243-246)."""
 
     @jax.jit
     def eval_step(params, x, y):
-        preds = jnp.argmax(apply_fn(params, x), axis=-1)
         mask = jnp.ones(y.shape, jnp.float32)
-        return metrics_from_confusion(confusion_matrix(y, preds, mask,
-                                                       num_classes))
+        return task.metrics(task.stats(params, x, y, mask))
 
     return eval_step

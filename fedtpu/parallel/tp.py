@@ -42,6 +42,7 @@ from fedtpu.parallel.round import (_DP_NOISE_STREAM, assemble_metrics,
                                    bcast_global, client_init_keys)
 from fedtpu.training.client import (make_local_eval_step,
                                     make_local_train_step)
+from fedtpu.training.task import classification_task
 
 MODEL_AXIS = "model"
 
@@ -268,7 +269,8 @@ def build_round_fn_2d(mesh: Mesh, apply_fn: Callable,
     fedtpu.utils.trees)."""
     local_train = make_local_train_step(apply_fn, tx, local_steps=local_steps,
                                         prox_mu=prox_mu)
-    local_eval = make_local_eval_step(apply_fn, num_classes)
+    local_eval = make_local_eval_step(
+        classification_task(apply_fn, num_classes))
 
     delta_path = (server_opt is not None or dp_clip_norm > 0
                   or dp_noise_multiplier > 0)

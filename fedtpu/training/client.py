@@ -9,8 +9,9 @@ These are the fedtpu analogues of the reference client methods:
   (folded into the optax schedule, see fedtpu.ops.optim).
 * ``make_local_eval_step`` == ``evaluate_local`` (:75-91): argmax predictions
   on the client's own training shard (the reference never evaluates held-out
-  data in the round loop), reduced to a confusion matrix on device instead of
-  shipping predictions to host sklearn.
+  data in the round loop), reduced to the task's statistics on device (for
+  classification a confusion matrix) instead of shipping predictions to host
+  sklearn.
 
 Being pure functions of ``(params, opt_state, batch)``, they vmap over the
 per-device client block inside the shard_map round and jit anywhere on their
@@ -27,14 +28,14 @@ import jax.numpy as jnp
 import optax
 
 from fedtpu.ops.losses import masked_cross_entropy
-from fedtpu.ops.metrics import confusion_matrix
 
 
 def make_local_train_step(apply_fn: Callable,
                           tx: optax.GradientTransformation,
                           local_steps: int = 1,
                           prox_mu: float = 0.0,
-                          scaffold: bool = False) -> Callable:
+                          scaffold: bool = False,
+                          task_loss: Callable | None = None) -> Callable:
     """Returns ``step(params, opt_state, x, y, mask) ->
     (params, opt_state, loss)`` — ``local_steps`` full-batch updates.
 
@@ -53,7 +54,11 @@ def make_local_train_step(apply_fn: Callable,
     before the optimizer sees it — Karimireddy et al. 2020's local rule
     ``y <- y - lr*(g(y) - c_i + c)``, generalized to any optax optimizer
     by correcting the gradient rather than hardcoding SGD. The variate
-    bookkeeping lives in the round engine (fedtpu.parallel.round)."""
+    bookkeeping lives in the round engine (fedtpu.parallel.round).
+
+    ``task_loss``: a task's ``loss(params, x, y, mask) -> (loss, statistics)``
+    (fedtpu.training.task) in place of the masked cross-entropy of
+    ``apply_fn``'s logits; the statistics are not used here."""
 
     if local_steps < 1:
         raise ValueError(f"local_steps must be >= 1, got {local_steps}")
@@ -71,7 +76,8 @@ def make_local_train_step(apply_fn: Callable,
                 # The optimized objective may include the prox penalty, but
                 # the REPORTED loss stays plain masked CE — comparable
                 # across prox/non-prox runs and to the reference's loss.
-                ce = masked_cross_entropy(apply_fn(q, x), y, mask)
+                ce = (masked_cross_entropy(apply_fn(q, x), y, mask)
+                      if task_loss is None else task_loss(q, x, y, mask)[0])
                 obj = ce
                 if prox_mu:
                     sq = sum(jnp.sum(jnp.square(a - b))
@@ -101,11 +107,8 @@ def make_local_train_step(apply_fn: Callable,
     return step
 
 
-def make_local_eval_step(apply_fn: Callable, num_classes: int) -> Callable:
-    """Returns ``eval(params, x, y, mask) -> (K, K) confusion matrix``."""
-
-    def step(params, x, y, mask):
-        preds = jnp.argmax(apply_fn(params, x), axis=-1)
-        return confusion_matrix(y, preds, mask, num_classes)
-
-    return step
+def make_local_eval_step(task) -> Callable:
+    """Returns ``eval(params, x, y, mask) -> statistics`` of the task
+    (fedtpu.training.task): for classification the ``(K, K)`` confusion
+    matrix of the argmax predictions."""
+    return task.stats

@@ -25,6 +25,7 @@ import optax
 from fedtpu.ops.metrics import metrics_from_confusion
 from fedtpu.parallel.round import masked_client_mean
 from fedtpu.training.client import make_local_eval_step, make_local_train_step
+from fedtpu.training.task import classification_task
 
 
 def build_personalize_fn(apply_fn: Callable,
@@ -39,7 +40,8 @@ def build_personalize_fn(apply_fn: Callable,
     if steps < 1:
         raise ValueError(f"personalize steps must be >= 1, got {steps}")
     local_train = make_local_train_step(apply_fn, tx, local_steps=steps)
-    local_eval = make_local_eval_step(apply_fn, num_classes)
+    local_eval = make_local_eval_step(
+        classification_task(apply_fn, num_classes))
 
     @jax.jit
     def personalize(params, batch):

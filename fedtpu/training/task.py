@@ -1,0 +1,121 @@
+"""What a federated job learns, as one object the engines take.
+
+An engine trains parameters on client shards and reports how well; what the
+loss is, what "how well" means and how it adds up over rows and clients is
+the task's. Everything a task reports comes from *sufficient statistics*
+that sum over rows and over clients (a confusion matrix; counts of tokens),
+so a client's metric, the pooled metric and a held-out evaluation are the
+same function of differently summed statistics.
+
+* ``classification`` is the reference's: softmax cross-entropy, a ``(K, K)``
+  confusion matrix, and accuracy / weighted precision / recall / F1 from it
+  (fedtpu.ops.metrics), number for number what the engines computed before
+  this interface existed.
+* ``next_token`` is the language model's: summed next-token loss, correct
+  predictions and counted tokens, reported as token accuracy and perplexity.
+  No ``(V, V)`` array exists anywhere for it.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Callable, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from fedtpu.ops.losses import masked_cross_entropy
+from fedtpu.ops.metrics import (METRIC_NAMES, confusion_matrix,
+                                metrics_from_confusion)
+
+
+@dataclasses.dataclass(frozen=True)
+class Task:
+    """``loss(params, x, y, mask) -> (loss, stats)``: the mean loss over the
+    units the task counts (rows; tokens) and the statistics of the same
+    forward pass. ``stats(params, x, y, mask)``: the statistics alone (a
+    forward pass: in-round evaluation of the resident engines, held-out
+    evaluation). ``weight(x, y, mask)``: how many units the loss counts in
+    these rows, a function of the data alone (FedAvg's data-size weight).
+    ``metrics(stats) -> {name: scalar}`` for ``metric_names``, the first of
+    which is ``accuracy`` (what checkpoint retention ranks by).
+    ``counters(stats)``, where a task has them: what the run's registry
+    counts besides the metrics, from a round's pooled statistics; the names
+    in ``gauges`` are set, the others added."""
+
+    name: str
+    metric_names: Tuple[str, ...]
+    loss: Callable
+    stats: Callable
+    weight: Callable
+    metrics: Callable
+    counters: Optional[Callable] = None
+    gauges: Tuple[str, ...] = ()
+
+
+def classification_task(apply_fn: Callable, num_classes: int) -> Task:
+    def stats(params, x, y, mask):
+        preds = jnp.argmax(apply_fn(params, x), axis=-1)
+        return confusion_matrix(y, preds, mask, num_classes)
+
+    def loss(params, x, y, mask):
+        logits = apply_fn(params, x)
+        conf = confusion_matrix(y, jnp.argmax(logits, axis=-1), mask,
+                                num_classes)
+        return masked_cross_entropy(logits, y, mask), conf
+
+    return Task(name="classification", metric_names=METRIC_NAMES, loss=loss,
+                stats=stats, weight=lambda x, y, mask: mask.sum(),
+                metrics=metrics_from_confusion)
+
+
+def next_token_task(stats_fn: Callable, model_cfg) -> Task:
+    """``stats_fn(params, x, mask)`` is the model's own
+    (fedtpu.models.olmoe.olmoe_stats): rows ``x (N, 2, T)`` of token and
+    segment ids; labels are the rows' own next tokens, so ``y`` is unused."""
+    from fedtpu.models.olmoe import next_token_targets
+
+    def stats(params, x, y, mask):
+        return stats_fn(params, x, mask)
+
+    def loss(params, x, y, mask):
+        s = stats_fn(params, x, mask)
+        return s["loss_sum"] / jnp.maximum(s["count"], 1.0), s
+
+    def weight(x, y, mask):
+        valid = jax.vmap(lambda row: next_token_targets(row[0], row[1])[1])(x)
+        return (valid.sum(axis=1) * mask).sum()
+
+    def metrics(s):
+        n = jnp.maximum(s["count"], 1.0)
+        return {"accuracy": s["correct"] / n,
+                "perplexity": jnp.exp(s["loss_sum"] / n)}
+
+    assignments = model_cfg.num_experts_per_tok * model_cfg.num_hidden_layers
+
+    def counters(s):
+        load = s["expert_load"].astype(jnp.float32)
+        routed = load.sum()
+        return {
+            "moe_tokens_routed": routed,
+            # every assignment of a real token is computed: 0 by construction
+            "moe_tokens_dropped": assignments * s["tokens"] - routed,
+            "moe_expert_load_max_over_mean": load.max() / jnp.maximum(
+                load.mean(), 1.0),
+            "lm_tokens": s["count"],
+            "lm_padding_tokens": s["padding"],
+            "moe_expert_load": s["expert_load"],
+        }
+
+    return Task(name="next_token", metric_names=("accuracy", "perplexity"),
+                loss=loss, stats=stats, weight=weight, metrics=metrics,
+                counters=counters,
+                gauges=("moe_expert_load_max_over_mean",))
+
+
+def build_task(model_cfg, model_fn: Callable, num_classes: int) -> Task:
+    """The task of a model kind: ``model_fn`` is the second of
+    ``build_model``'s pair."""
+    if model_cfg.kind == "olmoe":
+        return next_token_task(model_fn, model_cfg)
+    return classification_task(model_fn, num_classes)

@@ -50,6 +50,10 @@ import pytest  # noqa: E402
 # consistency guards at the bottom of pytest_collection_modifyitems
 # below.
 QUICK_TESTS = {
+    # OLMoE against the plain reference; the shared-global engine's refusals
+    "test_olmoe.py::test_top_k_sets_are_the_references_in_float32",
+    "test_stateless_round.py::"
+    "test_minibatches_need_the_stateless_engine_and_a_known_client_state",
     # the stage of each operation from a compiled program's text (pure text)
     "test_round_tracing.py::"
     "test_program_scopes_reads_the_stage_of_each_operation",

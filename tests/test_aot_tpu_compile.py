@@ -178,3 +178,45 @@ def test_convnet_training_pass_keeps_one_full_resolution_copy(topo):
     assert new_full and all(new_full.values()), new_full
     # The parser does see the copy where there is one: ReLU first writes it.
     assert not all(_fusions_writing(old.as_text(), full).values())
+
+
+def test_the_olmoe_round_at_published_widths_fits_one_v5e_chip(topo):
+    """The shared-global round of the one-layer OLMoE preset (625.6M
+    parameters, 8 clients, 16 packed 4,096-token sequences, FedAvgM)
+    compiles for one described v5e chip and its account (arguments +
+    outputs - aliased + temporaries) lies between 8 and 15.0 GB of the
+    chip's 16: one global, the momentum that doubles as the delta
+    accumulator, one client's copy, its gradient and a sequence's
+    activations (13.80 GB when this was written, PERF.md section 4)."""
+    from fedtpu.config import get_preset
+    from fedtpu.models.registry import build_model
+    from fedtpu.ops.server_opt import make_server_optimizer
+    from fedtpu.parallel.stateless import build_stateless_round_fn
+    from fedtpu.training.task import build_task
+
+    cfg = get_preset("olmoe-1b-7b-l1")
+    mesh = Mesh(np.array(topo.devices[:1]), ("clients",))
+    rep, by_client = NamedSharding(mesh, P()), NamedSharding(mesh, P("clients"))
+    init_fn, stats_fn = build_model(cfg.model)
+    server = make_server_optimizer("fedavgm", cfg.fed.server_lr,
+                                   cfg.fed.server_momentum)
+    params = jax.eval_shape(init_fn, jax.random.key(0))
+    assert sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params)) == 625_616_896
+    shaped = lambda tree, sharding: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
+    state = {"params": shaped(params, rep),
+             "server_opt_state": shaped(jax.eval_shape(server.init, params), rep),
+             "round": jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)}
+    seq = cfg.data.synthetic_features
+    batch = {"x": jax.ShapeDtypeStruct((8, 8, 2, seq), jnp.int32, sharding=by_client),
+             "y": jax.ShapeDtypeStruct((8, 8), jnp.int32, sharding=by_client),
+             "mask": jax.ShapeDtypeStruct((8, 8), jnp.float32, sharding=by_client)}
+    step = build_stateless_round_fn(
+        mesh, build_task(cfg.model, stats_fn, cfg.model.vocab_size),
+        [1, 1, 2, 2, 2, 2, 3, 3], learning_rate=cfg.optim.learning_rate,
+        server_opt=server, local_batch_rows=cfg.fed.local_batch_rows)
+    ma = step.lower(state, batch).compile().memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert 8e9 <= total <= 15.0e9, total
+    assert ma.alias_size_in_bytes >= 5.0e9      # global and momentum in place

@@ -11,6 +11,7 @@ from fedtpu.ops import build_optimizer
 from fedtpu.parallel import make_mesh, client_sharding
 from fedtpu.parallel.round import (build_round_fn, init_federated_state,
                                    global_params, build_eval_fn)
+from fedtpu.training.task import classification_task
 
 
 def test_round_runs_on_8_device_mesh():
@@ -45,7 +46,7 @@ def test_round_runs_on_8_device_mesh():
         state, metrics = round_step(state, batch)
     assert float(metrics["client_mean"]["accuracy"]) > 0.8
 
-    ev = build_eval_fn(apply_fn, 2)
+    ev = build_eval_fn(classification_task(apply_fn, 2))
     m = ev(global_params(state), batch["x"][0], batch["y"][0])
     assert 0.0 <= float(m["accuracy"]) <= 1.0
 

@@ -1,0 +1,308 @@
+"""The shared-global engine (fedtpu.parallel.stateless) and the task
+interface (fedtpu.training.task): against the plain reference's FedAvgM on a
+tiny OLMoE, against the resident engine's server-optimizer path on the MLP,
+on uneven shards cut into minibatches, across a mesh, and what it refuses."""
+
+import contextlib
+import dataclasses
+import io
+import json
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fedtpu.config import (DataConfig, ExperimentConfig, FedConfig,
+                           ModelConfig, OptimConfig, RunConfig, ShardConfig,
+                           TelemetryConfig, get_preset)
+from fedtpu.data import load_dataset
+from fedtpu.models.registry import build_model
+from fedtpu.orchestration import loop
+from fedtpu.orchestration.loop import build_experiment, run_experiment
+from fedtpu.parallel import round as round_mod
+from fedtpu.parallel.round import LAYERS
+from perfbench import reference_lm
+
+SGD = OptimConfig(name="sgd", learning_rate=0.05, momentum=0.0,
+                  steplr_step_size=2, steplr_gamma=0.5)
+
+
+def tiny_olmoe(rounds=2, **run):
+    cfg = get_preset("olmoe-1b-7b-l1")
+    return cfg.replace(
+        model=dataclasses.replace(
+            cfg.model, hidden_size=32, num_attention_heads=4, num_experts=8,
+            num_experts_per_tok=2, intermediate_size=16, vocab_size=128,
+            compute_dtype="float32"),
+        data=dataclasses.replace(cfg.data, synthetic_rows=10,
+                                 synthetic_features=48),
+        shard=dataclasses.replace(cfg.shard, num_clients=4),
+        optim=dataclasses.replace(cfg.optim, learning_rate=0.5),
+        fed=dataclasses.replace(cfg.fed, rounds=rounds, init_seed=3),
+        run=dataclasses.replace(cfg.run, mesh_devices=1, **run))
+
+
+def mlp_cfg(client_state, rows=0, clients=4, devices=1, rounds=3, **fed):
+    return ExperimentConfig(
+        shard=ShardConfig(num_clients=clients, shuffle=False),
+        model=ModelConfig(hidden_sizes=(8,)), optim=SGD,
+        fed=FedConfig(rounds=rounds, server_opt="fedavgm", same_init=True,
+                      client_state=client_state, local_batch_rows=rows,
+                      termination_patience=1000, **fed),
+        run=RunConfig(mesh_devices=devices))
+
+
+def income(rows=50):
+    return load_dataset(DataConfig(synthetic_rows=rows, synthetic_features=6))
+
+
+def _gap(a, b):
+    return max(float(np.max(np.abs(np.asarray(x) - np.asarray(y))))
+               for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+
+def test_two_rounds_of_tiny_olmoe_match_the_references_fedavgm(tmp_path):
+    sink = str(tmp_path / "ev.jsonl")
+    cfg = tiny_olmoe(eval_test_every=1,
+                     telemetry=TelemetryConfig(events_path=sink))
+    result = run_experiment(cfg, verbose=False)
+    ds = build_experiment(cfg).dataset
+    rows = [ds.x_train[ds.client_of_row == c] for c in range(4)]
+    assert sorted(len(r) for r in rows) == [1, 2, 3, 4]         # size skew
+    # on the host: the reference donates (consumes) what it is handed
+    init = jax.tree.map(np.asarray, build_model(cfg.model)[0](
+        jax.random.key(cfg.fed.init_seed)))
+    model = {k: getattr(cfg.model, k) for k in
+             ("num_attention_heads", "num_experts_per_tok", "rope_theta",
+              "rms_norm_eps", "norm_topk_prob")}
+    ref_loss, ref_params = reference_lm.fedavgm_rounds(
+        init, rows, 2, model, learning_rate=cfg.optim.learning_rate,
+        momentum=cfg.fed.server_momentum, server_lr=cfg.fed.server_lr)
+    # float32 against float32: rounding, and two orders of summation
+    assert np.max(np.abs(np.stack(result.loss) - ref_loss)) <= 1e-5
+    assert _gap(result.final_params, ref_params) <= 1e-5
+    assert _gap(result.final_params, init) > 1e-3               # it moved
+    assert set(result.global_metrics) == {"accuracy", "perplexity"}
+    assert len(result.test_metrics["perplexity"]) == 2          # held-out eval
+    events = [json.loads(line) for line in open(sink)]
+    counters = [e for e in events if e["kind"] == "counters"][-1]["payload"]
+    tokens = int((ds.x_train[:, 1] > 0).sum())
+    assert counters["counters"]["moe_tokens_dropped"] == 0
+    assert counters["counters"]["moe_tokens_routed"] == 2 * 2 * tokens
+    assert counters["counters"]["lm_padding_tokens"] == 2 * (10 * 48 - tokens)
+    assert counters["gauges"]["moe_expert_load_max_over_mean"] > 1.0
+    load = [e["payload"]["moe_expert_load"] for e in events if e["kind"] == "round"]
+    assert len(load) == 2 and sum(load[0]) == 2 * tokens
+
+
+def test_mlp_equals_the_resident_engines_server_opt_path():
+    ds = income()
+    resident = run_experiment(mlp_cfg("resident"), dataset=ds, verbose=False)
+    shared = run_experiment(mlp_cfg("stateless"), dataset=ds, verbose=False)
+    # one full-batch SGD step a client from one global, FedAvgM on the
+    # data-size-weighted mean delta: the same algorithm, two programs
+    np.testing.assert_allclose(np.stack(shared.loss), np.stack(resident.loss),
+                               rtol=0, atol=1e-6)
+    assert _gap(shared.final_params, resident.final_params) <= 1e-6
+    assert set(shared.global_metrics) == set(resident.global_metrics)
+
+
+def _by_hand(cfg, ds, batch_rows):
+    """Clients in turn, minibatches in order, plain SGD; FedAvgM."""
+    from fedtpu.models.mlp import mlp_apply
+    from fedtpu.ops.losses import masked_cross_entropy
+    exp = build_experiment(cfg, ds)
+    x, y, mask = (np.asarray(exp.batch[k]) for k in ("x", "y", "mask"))
+    g = jax.tree.map(np.asarray, exp.state["params"])
+    m = jax.tree.map(np.zeros_like, g)
+    grad = jax.jit(jax.value_and_grad(
+        lambda p, xb, yb, mb: masked_cross_entropy(mlp_apply(p, xb), yb, mb)))
+    losses = []
+    for r in range(cfg.fed.rounds):
+        lr = cfg.optim.learning_rate * cfg.optim.steplr_gamma ** (
+            r // cfg.optim.steplr_step_size)
+        acc, row = jax.tree.map(np.zeros_like, g), []
+        for c in range(x.shape[0]):
+            p, n, total = g, int(mask[c].sum()), 0.0
+            for i in range(0, n, batch_rows):
+                sl = slice(i, i + batch_rows)
+                loss, d = grad(p, x[c, sl], y[c, sl], mask[c, sl])
+                total += float(loss) * float(mask[c, sl].sum())
+                p = jax.tree.map(lambda a, b: a - lr * b, p, d)
+            acc = jax.tree.map(lambda a, pc, gl: a + n * (pc - gl), acc, p, g)
+            row.append(total / n)
+        m = jax.tree.map(lambda a, b: 0.9 * a + b / mask.sum(), m, acc)
+        g = jax.tree.map(np.add, g, m)
+        losses.append(row)
+    return np.asarray(losses), g
+
+
+def test_uneven_shards_in_minibatches_match_a_loop_by_hand():
+    ds = income(rows=63)                # 40 training rows: 13, 13, 14 a client
+    cfg = mlp_cfg("stateless", rows=4, clients=3)
+    exp = build_experiment(cfg, ds)
+    counts = np.asarray(exp.batch["mask"]).sum(axis=1)
+    assert len(set(counts)) > 1 and counts.max() % 4      # uneven, ragged tail
+    got = run_experiment(cfg, dataset=ds, verbose=False)
+    want_loss, want_params = _by_hand(cfg, ds, 4)
+    np.testing.assert_allclose(np.stack(got.loss), want_loss, atol=2e-6)
+    assert _gap(got.final_params, want_params) <= 2e-6
+
+
+def test_a_mesh_of_two_devices_gives_what_one_device_gives():
+    ds = income(rows=100)
+    one = run_experiment(mlp_cfg("stateless", rows=8), dataset=ds, verbose=False)
+    two = run_experiment(mlp_cfg("stateless", rows=8, devices=2), dataset=ds,
+                         verbose=False)
+    np.testing.assert_allclose(np.stack(two.loss), np.stack(one.loss), atol=1e-6)
+    assert _gap(two.final_params, one.final_params) <= 1e-6
+    for k in one.global_metrics:
+        np.testing.assert_allclose(two.pooled_metrics[k], one.pooled_metrics[k],
+                                   atol=1e-6)
+
+
+def test_a_scanned_chunk_of_rounds_is_the_rounds_one_by_one():
+    ds = income()
+    one = run_experiment(mlp_cfg("stateless", rounds=4), dataset=ds, verbose=False)
+    cfg = mlp_cfg("stateless", rounds=4)
+    wide = run_experiment(cfg.replace(run=dataclasses.replace(
+        cfg.run, rounds_per_step=2)), dataset=ds, verbose=False)
+    np.testing.assert_allclose(np.stack(wide.loss), np.stack(one.loss), atol=1e-6)
+    assert _gap(wide.final_params, one.final_params) <= 1e-6
+
+
+@pytest.mark.parametrize("change,says", [
+    ({"optim": OptimConfig(name="adam")}, "needs optim.name='sgd'"),
+    ({"optim": dataclasses.replace(SGD, momentum=0.9)}, "momentum 0"),
+    ({"fed": {"participation_rate": 0.5}}, "no partial participation"),
+    ({"fed": {"local_steps": 2}}, "one local epoch a round"),
+    ({"fed": {"prox_mu": 0.1}}, "no FedProx term"),
+    ({"fed": {"scaffold": True, "weighting": "uniform"}}, "SCAFFOLD"),
+    ({"fed": {"dp_clip_norm": 1.0}}, "DP aggregation"),
+    ({"fed": {"compress": "int8"}}, "compressed exchange"),
+    ({"fed": {"robust_aggregation": "median", "weighting": "uniform"}},
+     "robust aggregation"),
+    ({"fed": {"aggregation": "ring"}}, "psum"),
+    ({"fed": {"async_mode": True, "weighting": "uniform"}}, "engine of its own"),
+    ({"fed": {"cohort_size": 2}}, "engine of its own"),
+    ({"fed": {"personalize_steps": 1}}, "personalize_steps"),
+    ({"fed": {"init_weights_npz": "w.npz"}}, "init_weights_npz"),
+    ({"run": {"checkpoint_dir": "ckpt", "checkpoint_every": 1}},
+     "does not write checkpoints"),
+])
+def test_what_the_engine_does_not_support_is_refused(change, says):
+    cfg = mlp_cfg("stateless")
+    for section, value in change.items():
+        if isinstance(value, dict):
+            value = dataclasses.replace(getattr(cfg, section), **value)
+        cfg = cfg.replace(**{section: value})
+    with pytest.raises(ValueError, match=says):
+        run_experiment(cfg, dataset=income(), verbose=False)
+
+
+def test_minibatches_need_the_stateless_engine_and_a_known_client_state():
+    with pytest.raises(ValueError, match="local_batch_rows needs"):
+        build_experiment(mlp_cfg("resident", rows=4), income())
+    with pytest.raises(ValueError, match="'resident' or 'stateless'"):
+        build_experiment(mlp_cfg("shared"), income())
+
+
+def test_a_resident_state_that_cannot_fit_says_so():
+    from fedtpu.ops import build_optimizer
+    init_fn, _ = build_model(ModelConfig(hidden_sizes=(8,), input_dim=6))
+    tx = build_optimizer(OptimConfig())
+    mesh = build_experiment(mlp_cfg("resident"), income()).mesh
+    need = round_mod.resident_state_bytes(init_fn, tx, 100, 1)
+    assert need >= 100 * 3 * 4 * (6 * 8 + 8 + 8 * 2 + 2)   # params, m, v
+    round_mod.check_resident_fits(init_fn, tx, 100, mesh, limit_bytes=need)
+    with pytest.raises(ValueError, match="client_state='stateless'"):
+        round_mod.check_resident_fits(init_fn, tx, 100, mesh,
+                                      limit_bytes=need - 1)
+
+
+def test_classification_through_the_task_is_bit_for_bit_what_it_was(monkeypatch):
+    """income-8's numbers with the task interface against the same run with
+    the task replaced by the functions the engines called before it existed."""
+    from fedtpu.ops.losses import masked_cross_entropy
+    from fedtpu.ops.metrics import (METRIC_NAMES, confusion_matrix,
+                                    metrics_from_confusion)
+    from fedtpu.training.task import Task
+
+    def before(model_cfg, apply_fn, num_classes):
+        def stats(p, x, y, mask):
+            return confusion_matrix(y, jnp.argmax(apply_fn(p, x), axis=-1), mask,
+                                    num_classes)
+        return Task("classification", METRIC_NAMES,
+                    loss=lambda p, x, y, m: (
+                        masked_cross_entropy(apply_fn(p, x), y, m), None),
+                    stats=stats, weight=lambda x, y, m: m.sum(),
+                    metrics=metrics_from_confusion)
+
+    cfg = get_preset("income-8")
+    cfg = cfg.replace(data=dataclasses.replace(cfg.data, csv_path=None),
+                      fed=dataclasses.replace(cfg.fed, rounds=3),
+                      run=dataclasses.replace(cfg.run, eval_test_every=1))
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        now = run_experiment(cfg)
+    monkeypatch.setattr(loop, "build_task", before)
+    was = run_experiment(cfg, verbose=False)
+    for hist in ("global_metrics", "pooled_metrics", "test_metrics"):
+        assert getattr(now, hist) == getattr(was, hist)
+        assert tuple(getattr(now, hist)) == METRIC_NAMES
+    assert all(np.array_equal(a, b) for a, b in zip(now.loss, was.loss))
+    assert _gap(now.final_params, was.final_params) == 0.0
+    line = re.findall(r"Global Metrics \(Round (\d+)\): \[accuracy: [\d.]+, "
+                      r"precision: [\d.]+, recall: [\d.]+, f1: [\d.]+\]  \(",
+                      out.getvalue())
+    assert line == ["1", "2", "3"]
+
+
+def test_the_layers_of_the_compiled_round_are_named():
+    from fedtpu.analysis.program import program_scopes
+    from fedtpu.models.olmoe import LAYER_SCOPES
+    from fedtpu.parallel.round import STAGES
+    assert LAYERS == LAYER_SCOPES + ("server_update",)
+    exp = build_experiment(tiny_olmoe())
+    text = exp.make_step(1).lower(exp.state, exp.batch).compile().as_text()
+    layers = set(program_scopes(text, LAYERS, strict=True)["scopes"].values())
+    assert {"attention", "router", "expert_dispatch", "experts",
+            "lm_head_loss", "server_update"} <= layers
+    stages = set(program_scopes(text, STAGES)["scopes"].values())
+    assert {"client_train", "aggregate"} <= stages <= set(STAGES)
+    assert "client_eval" not in stages              # no second forward
+
+
+def test_a_compiler_kernel_that_drops_its_scope_is_put_down_by_its_name():
+    """The TPU's compiler names its ragged-dot kernel itself; the event says
+    whose it is all the same."""
+    text = '''HloModule m
+
+ENTRY %main (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  %fusion.1 = f32[8]{0} fusion(%x), kind=kLoop, calls=%f, metadata={op_name="jit(s)/client_train/expert_dispatch/gather"}
+  %ragged-dot-none.2 = f32[8]{0} custom-call(%fusion.1), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %copy.3 = f32[8]{0} copy(%fusion.1)
+  ROOT %fusion.4 = f32[8]{0} fusion(%ragged-dot-none.2, %copy.3), kind=kLoop, calls=%g, metadata={op_name="jit(s)/client_train/sub"}
+}
+'''
+
+    class Program:
+        def as_text(self):
+            return text
+
+    class Sink:
+        def event(self, kind, **payload):
+            self.kind, self.payload = kind, payload
+
+    sink = Sink()
+    loop._emit_program_scopes(sink, "round_step", 1, Program())
+    assert sink.kind == "program_scopes" and "error" not in sink.payload
+    # the copy has no op_name and inherits; the update names itself outside
+    # every layer and stays outside, whoever made its operands
+    assert sink.payload["layers"] == {"fusion.1 f32[8]": "expert_dispatch",
+                                      "copy.3 f32[8]": "expert_dispatch",
+                                      "ragged-dot-none.2 f32[8]": "experts"}
+    assert sink.payload["scopes"]["ragged-dot-none.2 f32[8]"] == "client_train"
