@@ -27,6 +27,25 @@ What is this repo's own:
   backward.
 * **Scanned layers.** Layer parameters carry a leading layers axis and the
   stack is a ``lax.scan``, so depth 16 compiles as depth 1 does.
+* **An attention core with two bodies.** ``softmax(mask(q k^T / sqrt(d))) v``
+  is one function of ``(q, k, v, segs)``. Its XLA body is the definition:
+  it writes the ``[heads, T, T]`` float32 scores, the masked scores and the
+  probabilities to memory and keeps them for the backward pass. Its fused
+  body is the library's tiled kernel with an online softmax
+  (``jax.experimental.pallas.ops.tpu.flash_attention``, causal, segment
+  ids, its own backward), which never holds a ``[heads, T, T]`` array: the
+  same mask, bf16 matmul inputs, float32 accumulation, maximum, sum and
+  exponentials. Which one runs is read off what the code can see and is
+  nobody's to set (``fused_attention_applies``): the fused body when the
+  program is built for a TPU, the head width is a multiple of 128 lanes,
+  ``T`` a multiple of the kernel's block and q, k and v share one width;
+  the XLA body everywhere else (the CPU, the tests' tiny shapes, a model
+  whose q/k and v widths differ). The fused backward takes its row term
+  ``sum(o * do)`` from the bf16 ``ctx`` and feeds bf16 ``dS`` to its
+  matmuls, where the XLA body's softmax backward is float32 throughout:
+  within "bf16 matmul inputs", and measured inside the benchmark's limits
+  (PERF.md section 6, PR 26). The sequence statistics say how many
+  positions ran fused (``fused_attention``).
 
 Parameters are float32. ``compute_dtype`` (bfloat16 in the shipped presets)
 is the dtype of every large matmul's inputs; accumulation, norms, softmax,
@@ -45,6 +64,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.pallas.ops.tpu import flash_attention as flash
 
 EMBED, ATTENTION, ROUTER, EXPERT_DISPATCH, EXPERTS, LM_HEAD_LOSS = (
     "embed", "attention", "router", "expert_dispatch", "experts",
@@ -54,6 +74,17 @@ LAYER_SCOPES = (EMBED, ATTENTION, ROUTER, EXPERT_DISPATCH, EXPERTS,
 # Rows of the sequence whose logits exist at one time in the loss.
 LOSS_CHUNK = 512
 INIT_STD = 0.02
+# Rows and columns of a tile of the fused attention kernel, forward and both
+# backward kernels. Chosen on the chip (PERF.md section 6, PR 26), forward +
+# backward of one (4096, 16, 128) sequence: the library's default 128s take
+# 17.0 ms (the XLA body 16.3), 256s 7.1, 512s 3.8, 1024s 3.7 with 134 MB
+# more temporaries; no mixed shape beat 512s.
+ATTENTION_BLOCK = 512
+_ATTENTION_BLOCKS = flash.BlockSizes(
+    block_b=1, **{name: ATTENTION_BLOCK for name in (
+        "block_q", "block_k_major", "block_k", "block_q_major_dkv",
+        "block_k_major_dkv", "block_k_dkv", "block_q_dkv",
+        "block_k_major_dq", "block_k_dq", "block_q_dq")})
 
 
 def olmoe_init(key: jax.Array, cfg, param_dtype=jnp.float32):
@@ -118,6 +149,56 @@ def route(x, router_w, top_k: int, norm_topk_prob: bool):
     return gates, experts.astype(jnp.int32)
 
 
+def fused_attention_applies(q, k, v) -> bool:
+    """Whether the tiled kernel exists for these ``(T, heads, d)`` operands
+    where the program is being built: a TPU, lane-wide heads, whole blocks
+    and one head width for q, k and v.
+
+    The platform read is the PROCESS's default backend, not the one a
+    program is lowered for: a compile for a described TPU from a CPU host
+    gets the XLA body (``tests/test_aot_tpu_compile.py`` steers this rule
+    for that reason), and a CPU mesh on a TPU host at these widths would
+    get a kernel it cannot lower."""
+    t, _, d = q.shape
+    return (jax.default_backend() == "tpu" and q.shape == k.shape == v.shape
+            and d % 128 == 0 and t % ATTENTION_BLOCK == 0)
+
+
+def _xla_attention(q, k, v, segs):
+    t, _, d = q.shape
+    scores = jnp.einsum("qhd,khd->hqk", q, k,
+                        preferred_element_type=jnp.float32) / (d ** 0.5)
+    idx = jnp.arange(t)
+    # causal, and within one segment; padding (segment 0) sees padding,
+    # which keeps its rows finite and is masked out of the loss
+    allowed = (idx[:, None] >= idx[None, :]) & (segs[:, None] == segs[None, :])
+    probs = jax.nn.softmax(jnp.where(allowed[None], scores, -1e30), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", probs.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+
+
+def _fused_attention(q, k, v, segs):
+    # the kernel's layout is (batch, heads, T, d); its mask is the XLA
+    # body's: causal, and equal segment ids (padding's 0 among them)
+    heads_first = lambda a: a.transpose(1, 0, 2)[None]
+    ids = flash.SegmentIds(q=segs[None], kv=segs[None])
+    ctx = flash.flash_attention(
+        heads_first(q), heads_first(k), heads_first(v), segment_ids=ids,
+        causal=True, sm_scale=q.shape[-1] ** -0.5,
+        block_sizes=_ATTENTION_BLOCKS)
+    return ctx[0].transpose(1, 0, 2).astype(jnp.float32)
+
+
+def attention_core(q, k, v, segs, compute_dtype):
+    """``ctx (T, heads, d)`` float32: the attention of one packed sequence
+    after RoPE and before the output projection, ``q``, ``k``, ``v``
+    ``(T, heads, d)`` float32 and cast to ``compute_dtype`` for both
+    matmuls."""
+    q, k, v = (a.astype(compute_dtype) for a in (q, k, v))
+    body = _fused_attention if fused_attention_applies(q, k, v) else _xla_attention
+    return body(q, k, v, segs)
+
+
 def _block(cfg, compute_dtype, h, layer, segs, pos):
     """One decoder layer on one packed sequence ``h (T, H)``; returns the
     new ``h`` and the tokens each expert was given (padding left out)."""
@@ -135,15 +216,7 @@ def _block(cfg, compute_dtype, h, layer, segs, pos):
         v = mm(x, cast(layer["v"])).reshape(t, heads, hd)
         q = _rope(q.reshape(t, heads, hd), pos, cfg.rope_theta)
         k = _rope(k.reshape(t, heads, hd), pos, cfg.rope_theta)
-        scores = jnp.einsum("qhd,khd->hqk", cast(q), cast(k),
-                            preferred_element_type=jnp.float32) / (hd ** 0.5)
-        idx = jnp.arange(t)
-        # causal, and within one segment; padding (segment 0) sees padding,
-        # which keeps its rows finite and is masked out of the loss
-        allowed = (idx[:, None] >= idx[None, :]) & (segs[:, None] == segs[None, :])
-        probs = jax.nn.softmax(jnp.where(allowed[None], scores, -1e30), axis=-1)
-        ctx = jnp.einsum("hqk,khd->qhd", cast(probs), cast(v),
-                         preferred_element_type=jnp.float32)
+        ctx = attention_core(q, k, v, segs, compute_dtype)
         h = h + mm(cast(ctx.reshape(t, hid)), cast(layer["o"]))
 
     top_k, n_exp = cfg.num_experts_per_tok, cfg.num_experts
@@ -209,9 +282,16 @@ def olmoe_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
     of the next-token task and the expert counters, all sums over tokens:
     ``loss_sum``, ``correct``, ``count`` (tokens in the loss), ``tokens``
     (of any document), ``padding`` (tokens of segment 0), ``expert_load (E,)`` (real tokens given to each
-    expert, summed over layers)."""
+    expert, summed over layers), ``fused_attention`` (positions whose
+    attention ran in the fused body: T or 0)."""
     tokens, segs = row[0], row[1]
     pos = segment_positions(segs)
+    # the operands every layer's attention core is given: static shapes, so
+    # the rule between its bodies is read once, here
+    t, heads = tokens.shape[0], cfg.num_attention_heads
+    core = jax.ShapeDtypeStruct((t, heads, cfg.hidden_size // heads),
+                                compute_dtype)
+    fused = fused_attention_applies(core, core, core)
     with jax.named_scope(EMBED):
         h = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
 
@@ -227,7 +307,8 @@ def olmoe_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
     return {"loss_sum": loss, "correct": correct, "count": valid.sum(),
             "tokens": (segs > 0).sum().astype(jnp.float32),
             "padding": (segs == 0).sum().astype(jnp.float32),
-            "expert_load": loads.sum(axis=0)}
+            "expert_load": loads.sum(axis=0),
+            "fused_attention": jnp.float32(t if fused else 0)}
 
 
 def olmoe_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
@@ -238,7 +319,8 @@ def olmoe_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
         # a padded row is all segment 0: nothing of it is counted
         stats = olmoe_sequence_stats(params, row * m.astype(row.dtype), cfg,
                                      compute_dtype)
-        return {**stats, "padding": stats["padding"] * m}
+        return {**stats, "padding": stats["padding"] * m,
+                "fused_attention": stats["fused_attention"] * m}
 
     if x.shape[0] == 1:
         return one((x[0], mask[0]))

@@ -104,6 +104,7 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
                 load.mean(), 1.0),
             "lm_tokens": s["count"],
             "lm_padding_tokens": s["padding"],
+            "lm_fused_attention_positions": s["fused_attention"],
             "moe_expert_load": s["expert_load"],
         }
 

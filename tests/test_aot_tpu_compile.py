@@ -180,15 +180,15 @@ def test_convnet_training_pass_keeps_one_full_resolution_copy(topo):
     assert not all(_fusions_writing(old.as_text(), full).values())
 
 
-def test_the_olmoe_round_at_published_widths_fits_one_v5e_chip(topo):
-    """The shared-global round of the one-layer OLMoE preset (625.6M
-    parameters, 8 clients, 16 packed 4,096-token sequences, FedAvgM)
-    compiles for one described v5e chip and its account (arguments +
-    outputs - aliased + temporaries) lies between 8 and 15.0 GB of the
-    chip's 16: one global, the momentum that doubles as the delta
-    accumulator, one client's copy, its gradient and a sequence's
-    activations (13.80 GB when this was written, PERF.md section 4)."""
+@pytest.fixture(scope="module")
+def olmoe_round(topo):
+    """``compiled(fused)``: the shared-global round of the one-layer OLMoE
+    preset (625.6M parameters, 8 clients, 16 packed 4,096-token sequences,
+    FedAvgM) compiled for one described v5e chip, once for each attention
+    body. The rule between the bodies sees the CPU here and would pick the
+    XLA body, so the fused case steers the rule itself."""
     from fedtpu.config import get_preset
+    from fedtpu.models import olmoe
     from fedtpu.models.registry import build_model
     from fedtpu.ops.server_opt import make_server_optimizer
     from fedtpu.parallel.stateless import build_stateless_round_fn
@@ -211,12 +211,79 @@ def test_the_olmoe_round_at_published_widths_fits_one_v5e_chip(topo):
     batch = {"x": jax.ShapeDtypeStruct((8, 8, 2, seq), jnp.int32, sharding=by_client),
              "y": jax.ShapeDtypeStruct((8, 8), jnp.int32, sharding=by_client),
              "mask": jax.ShapeDtypeStruct((8, 8), jnp.float32, sharding=by_client)}
-    step = build_stateless_round_fn(
-        mesh, build_task(cfg.model, stats_fn, cfg.model.vocab_size),
-        [1, 1, 2, 2, 2, 2, 3, 3], learning_rate=cfg.optim.learning_rate,
-        server_opt=server, local_batch_rows=cfg.fed.local_batch_rows)
-    ma = step.lower(state, batch).compile().memory_analysis()
-    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
-    assert 8e9 <= total <= 15.0e9, total
-    assert ma.alias_size_in_bytes >= 5.0e9      # global and momentum in place
+    done = {}
+
+    def compiled(fused: bool):
+        if fused not in done:
+            with pytest.MonkeyPatch.context() as patch:
+                patch.setattr(olmoe, "fused_attention_applies",
+                              lambda q, k, v: fused)
+                step = build_stateless_round_fn(
+                    mesh, build_task(cfg.model, stats_fn, cfg.model.vocab_size),
+                    [1, 1, 2, 2, 2, 2, 3, 3],
+                    learning_rate=cfg.optim.learning_rate, server_opt=server,
+                    local_batch_rows=cfg.fed.local_batch_rows)
+                done[fused] = step.lower(state, batch).compile()
+        return done[fused]
+
+    return compiled
+
+
+def _account(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+
+
+def _mosaic_calls(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+def test_the_olmoe_round_at_published_widths_fits_one_v5e_chip(olmoe_round):
+    """With the XLA attention body (what this compile picks by itself, and
+    what the chip ran before PR 26) the round's account (arguments + outputs
+    - aliased + temporaries) lies between 8 and 15.0 GB of the chip's 16:
+    one global, the momentum that doubles as the delta accumulator, one
+    client's copy, its gradient and a sequence's activations (13.80 GB,
+    PERF.md section 4)."""
+    xla = olmoe_round(False)
+    assert 8e9 <= _account(xla) <= 15.0e9, _account(xla)
+    # global and momentum in place
+    assert xla.memory_analysis().alias_size_in_bytes >= 5.0e9
+
+
+def test_the_olmoe_round_with_fused_attention_drops_the_scores(olmoe_round):
+    """Steered to the fused body, as the chip picks it, the same round holds
+    the three attention kernels and no float32 16 x 4096^2 array: 12.95 GB
+    when this was written, 0.86 under the XLA body's."""
+    fused, xla = olmoe_round(True), olmoe_round(False)
+    assert _account(fused) <= 13.1e9, _account(fused)
+    assert _account(fused) <= _account(xla) - 0.7e9
+    assert fused.memory_analysis().alias_size_in_bytes >= 5.0e9
+    assert _mosaic_calls(fused) >= _mosaic_calls(xla) + 3
+
+
+def test_fused_attention_core_compiles_for_v5e_without_the_scores(topo):
+    """Forward and backward of the attention core on one published-width
+    sequence (4096, 16 heads of 128): the fused body is three Mosaic
+    kernels and under 300 MB of temporaries (53 MB when this was written)
+    where the XLA body keeps over 2,000 (2,182: the scores, the masked
+    scores, the probabilities)."""
+    from fedtpu.models import olmoe
+
+    one = SingleDeviceSharding(topo.devices[0])
+    qkv = jax.ShapeDtypeStruct((4096, 16, 128), jnp.bfloat16, sharding=one)
+    segs = jax.ShapeDtypeStruct((4096,), jnp.int32, sharding=one)
+
+    def compiled(body):
+        def with_gradients(q, k, v, w, segs):
+            return jax.value_and_grad(
+                lambda q, k, v: (body(q, k, v, segs) * w).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+        return jax.jit(with_gradients).lower(  # fedtpu: noqa[FTP006] one-shot AOT compile
+            qkv, qkv, qkv, qkv, segs).compile()
+
+    fused, xla = compiled(olmoe._fused_attention), compiled(olmoe._xla_attention)
+    assert _mosaic_calls(fused) == 3 and _mosaic_calls(xla) == 0
+    assert fused.memory_analysis().temp_size_in_bytes < 300e6
+    assert xla.memory_analysis().temp_size_in_bytes > 2000e6

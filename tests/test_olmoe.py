@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from fedtpu.config import ModelConfig
 from fedtpu.models import olmoe
@@ -18,6 +19,13 @@ TINY = ModelConfig(kind="olmoe", hidden_size=32, num_attention_heads=4,
                    num_hidden_layers=1, num_experts=8, num_experts_per_tok=2,
                    intermediate_size=16, vocab_size=64)
 T = 32
+# The size at which the fused attention body exists (lane-wide heads, whole
+# blocks), two of its blocks long; the rest as tiny as TINY.
+FUSED_T, FUSED_HEADS, FUSED_D = 2 * olmoe.ATTENTION_BLOCK, 2, 128
+FUSED = ModelConfig(kind="olmoe", hidden_size=FUSED_HEADS * FUSED_D,
+                    num_attention_heads=FUSED_HEADS, num_hidden_layers=1,
+                    num_experts=8, num_experts_per_tok=2,
+                    intermediate_size=16, vocab_size=64)
 REF_CFG = {k: getattr(TINY, k) for k in
            ("num_attention_heads", "num_experts_per_tok", "rope_theta",
             "rms_norm_eps", "norm_topk_prob")}
@@ -128,25 +136,55 @@ def test_dropless_under_a_skew_over_four_times_the_mean():
     assert abs(float(loss) - float(ref)) <= 1e-5 and _gap(g, rg) <= 1e-5
 
 
-def test_two_packed_documents_give_what_the_two_alone_give():
-    p = _params()
-    both = _row(11, docs=(12, 14))
-    alone = []
-    for i, (a, b) in enumerate(((0, 12), (12, 26))):
-        r = np.zeros_like(both)
-        r[:, :b - a] = both[:, a:b]
-        r[1, :b - a] = 1
-        alone.append(jnp.asarray(r))
-
+def _summed_loss_and_gradients(cfg):
     def total(p, rows):
-        s = [olmoe.olmoe_sequence_stats(p, r, TINY) for r in rows]
+        s = [olmoe.olmoe_sequence_stats(p, r, cfg) for r in rows]
         return sum(x["loss_sum"] for x in s), s
+    return jax.value_and_grad(total, has_aux=True)
 
-    (packed, _), g = jax.value_and_grad(total, has_aux=True)(p, [jnp.asarray(both)])
-    (apart, s), ga = jax.value_and_grad(total, has_aux=True)(p, alone)
+
+@pytest.fixture
+def fused_on_the_cpu(monkeypatch):
+    """The whole model through the fused attention body: the rule between
+    the bodies is steered to it and the kernel interpreted (always under
+    jit: the interpreter is not for eager use)."""
+    monkeypatch.setattr(olmoe, "fused_attention_applies", lambda q, k, v: True)
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+# The XLA case is the tiny model, eager, its gradients within 1e-5 as the
+# file's others are. The fused case is two of the kernel's blocks long, its
+# documents' edge 40 tokens before the blocks': the segment mask inside a
+# block, the online softmax over two key blocks and the causal skip are what
+# runs. There, gradients of 1,024 tokens summed in another order differ by
+# 1.9e-4 to 3.1e-4 on entries up to 287 over three seeds (the XLA body at
+# that size: 1.8e-4 to 2.3e-4), hence 1e-5 of the largest entry; one token
+# seen across the edge moves entries by thousandths of their size.
+@pytest.mark.parametrize("body", ["xla", "fused"])
+def test_two_packed_documents_give_what_the_two_alone_give(body, request):
+    if body == "fused":
+        request.getfixturevalue("fused_on_the_cpu")
+        cfg, t, (a, b) = FUSED, FUSED_T, (FUSED_T // 2 - 40, FUSED_T // 2 + 10)
+        f = jax.jit(_summed_loss_and_gradients(cfg))
+    else:
+        cfg, t, (a, b) = TINY, T, (12, 14)
+        f = _summed_loss_and_gradients(cfg)
+    p = _params(cfg)
+    both = _row(11, docs=(a, b), t=t)
+    alone = []
+    for lo, hi in ((0, a), (a, a + b)):
+        r = np.zeros_like(both)
+        r[:, :hi - lo] = both[:, lo:hi]
+        r[1, :hi - lo] = 1
+        alone.append(jnp.asarray(r))
+    (packed, s), g = f(p, [jnp.asarray(both)])
+    (apart, sa), ga = f(p, alone)
+    assert float(s[0]["fused_attention"]) == (t if body == "fused" else 0)
     assert abs(float(packed) - float(apart)) <= 1e-4 * abs(float(apart))
-    assert _gap(g, ga) <= 1e-5
-    assert [float(x["count"]) for x in s] == [11.0, 13.0]
+    largest = max(float(jnp.max(jnp.abs(x))) for x in jax.tree.leaves(ga))
+    assert _gap(g, ga) <= (1e-5 * largest if body == "fused" else 1e-5)
+    assert [float(x["count"]) for x in sa] == [a - 1.0, b - 1.0]
 
 
 def test_depth_two_scanned_is_two_blocks_by_hand():
@@ -176,3 +214,80 @@ def test_masked_rows_count_for_nothing():
     padded = stats_fn(p, x, jnp.asarray([1.0, 1.0, 0.0]))
     for k in full:
         np.testing.assert_allclose(padded[k], full[k], rtol=1e-6)
+
+
+# The fused attention body (the library's tiled kernel, interpreted on the
+# CPU) against the XLA body, which defines what is computed. Two blocks a
+# side, so the online softmax over several key blocks and the causal skip
+# are what runs, as at 4,096 on the chip; always under jit (the interpreter
+# is not for eager use).
+def _core_and_gradients(body, dtype, segs):
+    def f(q, k, v, w):
+        core = lambda q, k, v: body(q.astype(dtype), k.astype(dtype),
+                                    v.astype(dtype), segs)
+        grads = jax.grad(lambda q, k, v: (core(q, k, v) * w).sum(),
+                         argnums=(0, 1, 2))(q, k, v)
+        return core(q, k, v), grads
+    return jax.jit(f)
+
+
+# float32: the two bodies differ by rounding alone (over 20 seeds 4.8e-7 to
+# 9.5e-7 on ctx, 1.5e-6 to 3.8e-6 on the gradients), so 1e-5. bfloat16: the
+# kernel returns ctx in bf16 (half a unit in the last place of entries up to
+# 4.3 is 7.8e-3) and rounds its probabilities before their sum is divided
+# out: over 20 seeds 8.5e-3 to 1.36e-2 on ctx, 1.6e-2 to 3.1e-2 on gradient
+# entries up to 6.4. 3e-2 / 6e-2 hold that and fail a dropped or doubled
+# block, which moves entries by their own size.
+@pytest.mark.parametrize("dtype,ctx_tol,grad_tol", [
+    (jnp.float32, 1e-5, 1e-5), (jnp.bfloat16, 3e-2, 6e-2)])
+def test_the_fused_attention_body_is_the_xla_body(dtype, ctx_tol, grad_tol):
+    docs = tuple(int(FUSED_T * share) for share in (0.27, 0.39, 0.2))
+    segs = jnp.asarray(_row(0, docs, t=FUSED_T)[1])
+    assert int(segs.max()) == 3 and int((segs == 0).sum()) > 0
+    args = [jax.random.normal(k, (FUSED_T, FUSED_HEADS, FUSED_D))
+            for k in jax.random.split(jax.random.key(4), 4)]
+    with pltpu.force_tpu_interpret_mode():
+        ctx, grads = _core_and_gradients(olmoe._fused_attention, dtype, segs)(*args)
+    want, want_grads = _core_and_gradients(olmoe._xla_attention, dtype, segs)(*args)
+    assert ctx.dtype == want.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(ctx - want))) <= ctx_tol
+    assert _gap(grads, want_grads) <= grad_tol
+    assert float(jnp.max(jnp.abs(want_grads[0]))) > 1.0
+
+
+def test_a_row_of_padding_alone_is_finite_and_moves_nothing_when_fused(
+        fused_on_the_cpu):
+    p, row = _params(FUSED), jnp.zeros((2, FUSED_T), jnp.int32)
+    f = jax.jit(_summed_loss_and_gradients(FUSED))
+    (loss, s), g = f(p, [row])
+    assert float(loss) == 0.0 and float(s[0]["count"]) == 0.0
+    assert float(s[0]["padding"]) == FUSED_T
+    assert all(bool(jnp.all(a == 0.0)) for a in jax.tree.leaves(g))
+    args = [jax.random.normal(k, (FUSED_T, FUSED_HEADS, FUSED_D))
+            for k in jax.random.split(jax.random.key(5), 4)]
+    ctx, grads = _core_and_gradients(olmoe._fused_attention, jnp.bfloat16,
+                                     row[1])(*args)
+    assert all(bool(jnp.all(jnp.isfinite(a))) for a in (ctx, *grads))
+
+
+@pytest.mark.parametrize("backend,q,v,fused", [
+    ("tpu", (4096, 16, 128), (4096, 16, 128), True),
+    ("tpu", (32, 4, 8), (32, 4, 8), False),                 # the tests' heads
+    ("tpu", (4096 + 128, 16, 128), (4096 + 128, 16, 128), False),
+    ("tpu", (4096, 16, 192), (4096, 16, 128), False),       # Moonlight's
+    ("cpu", (4096, 16, 128), (4096, 16, 128), False)])
+def test_the_rule_between_the_attention_bodies(monkeypatch, backend, q, v, fused):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    assert olmoe.fused_attention_applies(sds(q), sds(q), sds(v)) is fused
+    ran = []
+    for name in ("_fused_attention", "_xla_attention"):
+        body = getattr(olmoe, name)
+        monkeypatch.setattr(olmoe, name, lambda *a, _name=name, _body=body: (
+            ran.append(_name), _body(*a))[1])
+    # either body traces at these shapes, and gives (T, heads, v's width)
+    ctx = jax.eval_shape(
+        lambda *a: olmoe.attention_core(*a, jnp.bfloat16), sds(q), sds(q),
+        sds(v), jax.ShapeDtypeStruct(q[:1], jnp.int32))
+    assert ran == ["_fused_attention" if fused else "_xla_attention"]
+    assert ctx.shape == q[:2] + v[2:] and ctx.dtype == jnp.float32
