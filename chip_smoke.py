@@ -421,8 +421,8 @@ def phase_income(args, csv: str, device: dict, cache_dir: str) -> None:
     base = ["--preset", "income-8", "--csv", csv]
     few, many = str(HEAD_ROUNDS), "300"
     r1 = cli_run(args, "income8_rps1",
-                 [*base, "--rounds", few, "--eval-test-every", "1",
-                  "--use-pallas"], device, cache_dir)
+                 [*base, "--rounds", few, "--eval-test-every", "1"],
+                 device, cache_dir)
     r100 = cli_run(args, "income8_rps100",
                    [*base, "--rounds", many, "--rounds-per-step", "100"],
                    device, cache_dir)
@@ -474,36 +474,11 @@ def phase_income(args, csv: str, device: dict, cache_dir: str) -> None:
           and "load_error" not in phases(first) + phases(second),
           f"ProgramCache: first {phases(first)}, second {phases(second)}")
 
-    # --use-pallas on this path means the kernel, compiled: build the same
-    # experiment, lower its held-out eval, and look for the Mosaic call;
-    # then hold the kernel to the XLA forward on the held-out rows.
-    from fedtpu.cli import _apply_overrides, build_parser
-    from fedtpu.config import get_preset
-    from fedtpu.orchestration.loop import build_experiment
-    from fedtpu.parallel.round import build_eval_fn
-    ns = build_parser().parse_args(["run", *base, "--use-pallas"])
-    exp = build_experiment(_apply_overrides(get_preset(ns.preset), ns))
-    params = exp.global_fn(exp.state)
-    ds = exp.dataset
-    text = exp.eval_step.lower(params, ds.x_test, ds.y_test) \
-        .compile().as_text()
-    compiled_kernel = "tpu_custom_call" in text
-    check(compiled_kernel or device["platform"] != "tpu",
-          "--use-pallas: the held-out eval holds no tpu_custom_call")
-    got = exp.eval_step(params, ds.x_test, ds.y_test)
-    ref = build_eval_fn(exp.task)(
-        params, ds.x_test, ds.y_test)
-    pallas_gap = max(abs(float(got[k]) - float(ref[k])) for k in ref)
-    check(pallas_gap <= METRIC_ATOL,
-          f"--use-pallas eval differs from the XLA eval by {pallas_gap}")
-
     emit("income8", ok=True, device=device,
          model="mlp 14->50->200->2, 8 clients, float32",
          data_rows=ROWS, backend=r1["manifest"]["backend"],
          rps1=r1["line"], rps100=r100["line"], aot_first=first["line"],
          aot_second=second["line"], gaps_to_rps100=gaps,
-         pallas_eval={"compiled_kernel": compiled_kernel,
-                      "vs_xla_gap": pallas_gap},
          peak_bytes_in_use=(jax.devices()[0].memory_stats() or {})
          .get("peak_bytes_in_use"))
 

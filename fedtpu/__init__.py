@@ -56,11 +56,6 @@ _LAZY = {
     # hyperparameters_tuning.py:130-132).
     "save_best_weights": ("fedtpu.sweep.grid", "save_best_weights"),
     "load_best_weights": ("fedtpu.sweep.grid", "load_best_weights"),
-    # Fetch-forced benchmark harness (the only sanctioned timing path —
-    # see fedtpu.utils.timing's round-1 postmortem).
-    "timed_rounds": ("fedtpu.utils.timing", "timed_rounds"),
-    "compile_with_flops": ("fedtpu.utils.timing", "compile_with_flops"),
-    "measured_peak_flops": ("fedtpu.utils.timing", "measured_peak_flops"),
     # Telemetry (docs/observability.md). The package itself is
     # import-light (stdlib only) but stays lazy for symmetry.
     "make_tracer": ("fedtpu.telemetry.trace", "make_tracer"),

@@ -18,10 +18,14 @@ avals).  That is the tensor footprint handed to the collective, not the
 wire traffic — algorithm-dependent wire bytes (ring vs tree all-reduce)
 are a backend choice this static account deliberately stays above.
 
-Primitive naming is empirical against the pinned jax: ``jax.lax.psum``
-traces as ``psum2`` inside ``shard_map``, ``psum_scatter`` lowers to a
-``reduce_scatter`` eqn, and ``pbroadcast`` eqns are shard_map's
+Primitive naming is empirical against the installed jax (0.9):
+``jax.lax.psum`` traces as ``psum_invariant`` inside
+``shard_map(check_vma=True)`` and as ``psum`` with the check off (``psum2``
+on older releases), ``psum_scatter`` lowers to a ``reduce_scatter`` eqn,
+and ``pvary`` / ``pcast`` / ``pbroadcast`` eqns are shard_map's
 replication-typing markers (no wire transfer) — excluded by design.
+``tests/test_program_audit.py::test_schedule_is_the_same_with_and_without_check_vma``
+is the one test a renamed primitive should fail.
 
 Import discipline: like the rest of the analysis package this module
 never imports jax at module scope (``fedtpu lint`` must stay
@@ -45,15 +49,18 @@ __all__ = [
     "schedule_digest",
 ]
 
-# eqn primitive name -> canonical collective name. Keep both spellings of
-# psum: plain `psum` appears under pmap-style tracing, `psum2` under
-# shard_map on the pinned jax.
+# eqn primitive name -> canonical collective name. Every spelling a jax
+# release has used stays, so an older jax still reads: `psum` without
+# check_vma, `psum2` under shard_map before 0.7, `*_invariant` under
+# check_vma since.
 COLLECTIVE_PRIMS = {
     "psum": "psum",
     "psum2": "psum",
+    "psum_invariant": "psum",
     "pmax": "pmax",
     "pmin": "pmin",
     "all_gather": "all_gather",
+    "all_gather_invariant": "all_gather",
     "ppermute": "ppermute",
     "pgather": "pgather",
     "reduce_scatter": "psum_scatter",

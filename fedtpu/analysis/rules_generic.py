@@ -22,7 +22,6 @@ PRINT_ALLOWLIST: tuple[str, ...] = (
     "fedtpu/cli.py",
     "fedtpu/resilience/supervisor.py",
     "fedtpu/resilience/chaos.py",
-    "bench.py",
 )
 
 # Modules allowed to terminate the process: the CLI surface and the

@@ -171,8 +171,6 @@ def _add_common_overrides(p: argparse.ArgumentParser):
                    default=None)
     p.add_argument("--compute-dtype", choices=["float32", "bfloat16"],
                    default=None)
-    p.add_argument("--use-pallas", action="store_true",
-                   help="evaluate with the Pallas fused-MLP kernel")
     p.add_argument("--checkpoint-dir", default=None)
     p.add_argument("--checkpoint-every", type=int, default=None)
     p.add_argument("--keep-checkpoints", type=int, default=None,
@@ -241,8 +239,6 @@ def _apply_overrides(cfg: ExperimentConfig, args) -> ExperimentConfig:
         model = dataclasses.replace(model, hidden_sizes=args.hidden_sizes)
     if args.compute_dtype is not None:
         model = dataclasses.replace(model, compute_dtype=args.compute_dtype)
-    if args.use_pallas:
-        model = dataclasses.replace(model, use_pallas=True)
     if args.learning_rate is not None:
         optim = dataclasses.replace(optim, learning_rate=args.learning_rate)
     if args.rounds is not None:
@@ -931,7 +927,7 @@ def build_parser() -> argparse.ArgumentParser:
                               "(golden (re)generation)")
 
     # AOT pre-compilation: populate a persistent cache with a preset's
-    # program family so later runs/sweeps start warm (docs/performance.md).
+    # program family so later runs/sweeps start warm (docs/ARCHITECTURE.md).
     warmup_p = sub.add_parser("warmup",
                               help="pre-compile a preset's program family "
                                    "into a persistent cache dir")

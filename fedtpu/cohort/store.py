@@ -370,7 +370,7 @@ class ClientStateStore:
 
     def file_block_bytes(self) -> int:
         """Actual disk blocks of the mmap file (0 for memory backend) —
-        the ground-truth sparsity measurement for BENCH_SCALE.json."""
+        the ground-truth sparsity measurement (docs/scaling.md)."""
         if self.backend != "mmap":
             return 0
         self.flush()

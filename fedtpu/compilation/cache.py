@@ -2,13 +2,12 @@
 
 fedtpu launches a *family* of XLA programs per job — one round program
 per chunk width, one sweep program per depth bucket, an eval program —
-and ROUND5 measured the cold compile of the 72-slot arch-vmap sweep
-program at 90-207 s against a 29 s warm-run win. The persistent XLA
-compilation cache (``--compilation-cache``) already amortizes the
-*backend* compile, but the first dispatch still pays tracing, lowering
-and executable construction synchronously. This module stores the
-**compiled executable itself**: ``lower().compile()`` once (the same
-AOT shape as ``fedtpu.utils.timing.compile_with_flops``), serialize via
+and the cold compile of the 72-slot arch-vmap sweep program took
+90-207 s against a 29 s warm run (PERF.md §6 "Before PR 1", a CPU
+box). The persistent XLA compilation cache (``--compilation-cache``)
+already amortizes the *backend* compile, but the first dispatch still
+pays tracing, lowering and executable construction synchronously. This module stores the
+**compiled executable itself**: ``lower().compile()`` once, serialize via
 ``jax.experimental.serialize_executable``, and on the next run
 deserialize in tens of milliseconds instead of recompiling.
 
@@ -361,9 +360,9 @@ class ProgramCache:
                        extra_meta: Optional[Dict[str, Any]] = None,
                        ) -> CacheEntry:
         """Warm path: deserialize ``key``. Cold path: ``step.lower(*args)
-        .compile()`` (the AOT shape of ``compile_with_flops``), persist,
-        return. Flops are computed at store time and carried in the meta
-        sidecar because ``cost_analysis`` is cheapest on a fresh build."""
+        .compile()``, persist, return. Flops are computed at store time
+        and carried in the meta sidecar because ``cost_analysis`` is
+        cheapest on a fresh build."""
         entry = self.load(key)
         if entry is not None:
             self.hits += 1

@@ -106,9 +106,6 @@ class ModelConfig:
     conv_channels: Tuple[int, ...] = (32, 64)
     param_dtype: str = "float32"
     compute_dtype: str = "float32"       # set 'bfloat16' to run matmuls on the MXU in bf16
-    # Use the Pallas fused-MLP forward kernel for evaluation (MLP, f32 only).
-    # The train step stays on the XLA path (the kernel defines no custom VJP).
-    use_pallas: bool = False
     # kind='olmoe' (fedtpu.models.olmoe): the keys of the published
     # config.json under their own names, at the values of
     # allenai/OLMoE-1B-7B-0125-Instruct. Rows are packed sequences (token

@@ -14,11 +14,9 @@ optax namedtuple nodes come back as namedtuples, not dicts.
 
 from __future__ import annotations
 
-import itertools
 import os
 import shutil
 import warnings
-from collections import defaultdict
 from typing import Optional, Tuple
 
 import jax
@@ -33,8 +31,6 @@ def _ckpt_path(directory: str, step: int) -> str:
     return os.path.join(os.path.abspath(directory), f"round_{step:06d}")
 
 
-
-
 def _strip_marker(state):
     """Drop the leafless 'shared_start' marker (fedtpu.parallel.round) from
     a state dict. The marker records how the LIVE state was constructed —
@@ -44,33 +40,6 @@ def _strip_marker(state):
     if isinstance(state, dict) and "shared_start" in state:
         state = {k: v for k, v in state.items() if k != "shared_start"}
     return state
-
-
-# Per-process attempt ordinal per checkpoint step — see
-# _sync_orbax_barrier_counters.
-_SAVE_ATTEMPTS: dict = defaultdict(itertools.count)
-
-
-def _sync_orbax_barrier_counters(step: int) -> None:
-    """Orbax derives collective barrier names from PROCESS-LOCAL monotonic
-    counters (orbax.checkpoint.multihost.counters). After a live shrink
-    (fedtpu.resilience.reshard) the survivors checkpoint alone while the
-    parked member's counters stand still, so the first post-grow full-gang
-    save would barrier under mismatched names — an AssertionError on the
-    sync_global_devices path, a timeout on the KV-barrier path. Every
-    member of a save group calls save_checkpoint together, so resetting
-    the counters to a base derived from (step, per-step attempt) — both
-    symmetric across the group — restores the equal-names invariant orbax
-    assumes, while keeping names unique across rounds and across repeated
-    same-round saves."""
-    if jax.process_count() == 1:
-        return
-    from orbax.checkpoint.multihost import counters as _counters
-    attempt = next(_SAVE_ATTEMPTS[step])
-    base = (step + 1) * 10_000 + attempt * 100
-    for name in ("_async_save_counter", "_composite_save_counter",
-                 "_tmp_directory_counter"):
-        setattr(_counters, name, itertools.count(base))
 
 
 def _checkpointer(step: int, process_group=None) -> ocp.Checkpointer:
@@ -119,7 +88,6 @@ def save_checkpoint(directory: str, state, history: dict, step: int,
     every member of the group (and ONLY the group) must make this call;
     see ``_checkpointer``."""
     path = _ckpt_path(directory, step)
-    _sync_orbax_barrier_counters(step)
     ckptr = _checkpointer(step, process_group)
     state_item = _strip_marker(state)
     if jax.process_count() == 1:

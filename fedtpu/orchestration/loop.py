@@ -72,7 +72,7 @@ from fedtpu.parallel.round import (LAYER_KERNELS, LAYERS, STAGES,
                                    build_round_fn,
                                    build_eval_fn, check_resident_fits,
                                    init_federated_state, global_params)
-from fedtpu.training.task import Task, build_task, classification_task
+from fedtpu.training.task import Task, build_task
 from fedtpu.utils.timing import Timer, force_fetch
 from fedtpu.utils.trees import to_numpy
 
@@ -527,25 +527,7 @@ def build_experiment(cfg: ExperimentConfig,
             # anchors (the deltas' reference points) must carry it too.
             state["anchors"] = _bcast_into_slots(loaded, state["anchors"])
 
-    # Opt-in Pallas fused forward for the held-out eval (a plain jit, outside
-    # shard_map; the in-round eval stays on the XLA path, which shard_map's
-    # scan requires in interpret mode). Opt-in for demonstration, not a perf
-    # default (PERF.md: no current chip timing of it). A request the kernel
-    # cannot serve is an error, never a quiet XLA eval.
-    eval_task = task
-    if model_cfg.use_pallas:
-        if not (model_cfg.kind == "mlp"
-                and model_cfg.param_dtype == "float32"
-                and model_cfg.compute_dtype == "float32"):
-            raise ValueError(
-                "use_pallas needs the float32 MLP (the fused forward kernel "
-                f"has no other variant); got kind={model_cfg.kind!r}, "
-                f"param_dtype={model_cfg.param_dtype!r}, "
-                f"compute_dtype={model_cfg.compute_dtype!r}")
-        from fedtpu.ops.pallas_kernels import fused_mlp_forward
-        eval_task = classification_task(fused_mlp_forward, ds.num_classes)
-
-    eval_step = build_eval_fn(eval_task)
+    eval_step = build_eval_fn(task)
     personalize_fn = None
     if cfg.fed.personalize_steps > 0:
         from fedtpu.training.personalize import build_personalize_fn

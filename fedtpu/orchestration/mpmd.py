@@ -2,13 +2,13 @@
 small static DAG of AOT sub-programs (ISSUE 18; ROADMAP item 2).
 
 The synchronous early-stopping mode pays one dispatch *and* one metric
-fetch per chunk, serialized with the device (BENCH_r05, taken through the
-earlier remote transport: 1.04e-3 vs 7.1e-5 s/round pipelined at
-rps=100; on a v5e's own host the round trip is about a millisecond —
-PERF.md). The round-4 roofline (PERF.md 'Earlier records') pinned the
-on-chip marginal at its byte-bandwidth ceiling, so the remaining lever
-is host-side: split the round into concurrently resident programs in
-the spirit of MPMD pipeline parallelism (PAPERS.md, arXiv 2412.14374)
+fetch per chunk, serialized with the device (PERF.md §6 "Before PR 1":
+1.04e-3 vs 7.1e-5 s/round pipelined at rps=100 through the earlier
+remote transport; on a v5e's own host the width-1 host share is 4.7-6.2
+ms a round, PERF.md §5). The income round's device side is bound by its
+HBM traffic (PERF.md §5), so the remaining lever is host-side: split the
+round into concurrently resident programs in the spirit of MPMD pipeline
+parallelism (PAPERS.md, arXiv 2412.14374)
 and let round k+1's client step run in flight while round k's
 aggregation output transfers to the server slice, its metrics program
 runs there, and its host fetch drains. The per-round RTT then amortizes

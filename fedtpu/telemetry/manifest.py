@@ -2,7 +2,7 @@
 
 Emitted once at run start as the sink's ``manifest`` event — config dump +
 stable hash, mesh shape, device kinds, backend, package/jax/python
-versions, process topology, and a best-effort git revision. A BENCH_*.json
+versions, process topology, and a best-effort git revision. A result file
 or events log found on disk six months later answers "what exactly
 produced this?" from the manifest alone.
 

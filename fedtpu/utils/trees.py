@@ -46,7 +46,7 @@ def per_device_bytes(tree) -> dict:
     """Measured live bytes per device id: sums each leaf's ACTUAL shard
     buffers (``addressable_shards``), so replicated leaves count fully on
     every device they occupy. The measurement behind the 2-D engine's
-    memory proof (benchmarks/tp_memory.py and its pinning test)."""
+    memory proof (tests/test_tp.py)."""
     per: dict = {}
     for leaf in jax.tree.leaves(tree):
         for s in leaf.addressable_shards:

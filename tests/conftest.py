@@ -26,6 +26,20 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags +
                                " --xla_force_host_platform_device_count=8").strip()
 
+# XLA:CPU runs a program's 8 per-device executions on ONE thread pool whose
+# size is the machine's core count (or NPROC, XLA's own variable for a CI's
+# CPU reservation), and a collective blocks one pool thread per device
+# until all 8 have arrived. On an 8-core box that leaves no thread for
+# anything else the client schedules there (the next tick's launch, its
+# transfers): two participants never get a thread, the rendezvous waits
+# ("only 6 of them arrived"), and after 60 s XLA aborts the interpreter,
+# "Fatal Python error: Aborted" in whichever test dispatches collective
+# programs back to back (test_serving.py, test_robust.py; 8 aborts in 90
+# runs of one such test alone beside five others, none in 84 with the pool
+# doubled). Twice the devices, unless the environment reserves more;
+# subprocesses inherit it.
+os.environ["NPROC"] = str(max(int(os.environ.get("NPROC") or 0), 16))
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
@@ -57,9 +71,9 @@ QUICK_TESTS = {
     # the stage of each operation from a compiled program's text (pure text)
     "test_round_tracing.py::"
     "test_program_scopes_reads_the_stage_of_each_operation",
-    # AOT compiles for a described v5e:2x2 (no chip needed, ~2 s each)
-    "test_aot_tpu_compile.py::test_fused_mlp_forward_compiles_for_v5e",
-    "test_aot_tpu_compile.py::test_weighted_average_clients_compiles_for_v5e",
+    # an AOT compile for a described v5e:2x2 (no chip needed, seconds)
+    "test_aot_tpu_compile.py::"
+    "test_convnet_training_pass_keeps_one_full_resolution_copy",
     # chip_smoke.py off the chip: the seeded CSV (pure numpy/pandas)
     "test_chip_smoke.py::"
     "test_income_csv_has_the_reference_shape_and_is_seeded",
@@ -71,7 +85,8 @@ QUICK_TESTS = {
     "test_run_keeps_the_cache_where_it_was_placed[no-flag-no-env]",
     "test_compilation.py::test_no_cache_directory_comes_from_tempfile",
     # round-3 modules
-    "test_advisor_r3.py::test_peak_flops_negative_slope_warns",
+    "test_advisor_r3.py::"
+    "test_sync_early_stop_exit_gate_catches_poisoned_state",
     "test_dp_accountant.py::test_abadi_et_al_canonical_value",
     "test_dp_accountant.py::test_full_participation_matches_closed_form",
     "test_dp_accountant.py::test_monotonicity",
@@ -134,7 +149,6 @@ QUICK_TESTS = {
     "test_optim.py::test_adam_steplr_matches_torch_trajectory",
     "test_optim.py::test_schedule_staircase_boundaries",
     "test_optim.py::test_onehot_ce_equals_gather_ce",
-    "test_pallas.py::test_weighted_average_kernel_matches_numpy",
     "test_parity.py::test_limitation_demonstrated",
     "test_participation.py::test_sampled_average_over_participants_only",
     "test_program_audit.py::test_extract_schedule_counts_psum_bytes",
@@ -178,7 +192,6 @@ QUICK_TESTS = {
     "test_timing.py::test_force_fetch_depends_on_computation",
     "test_timing.py::test_force_fetch_refuses_host_only_trees",
     "test_timing.py::test_flops_floor_passes_above_and_raises_below",
-    "test_timing.py::test_measured_peak_flops_is_positive_and_sane",
     "test_timing.py::test_timer_laps",
     "test_tp.py::test_mesh_2d_shape",
     "test_tp.py::test_unsupported_combos_raise",
@@ -193,7 +206,6 @@ QUICK_TESTS = {
     "test_timeline.py::test_flight_recorder_ring_bounds",
     "test_timeline.py::test_merged_report_keys_colliding_run_ids",
     "test_timeline.py::test_timeline_merges_and_orders_chains",
-    "test_telemetry.py::test_bench_json_is_last_stdout_line",
     "test_telemetry.py::test_drop_nonwinning_weights_frees_losers",
     "test_telemetry.py::test_no_bare_prints_outside_allowlist",
     "test_scaffold.py::test_server_cv_is_mean_of_client_cv",
@@ -343,22 +355,41 @@ def pytest_collection_modifyitems(config, items):
 
 
 # ------------------------------------------------------- native-cache hygiene
-# The full suite compiles hundreds of XLA programs across 36 modules; the
-# executables (and their buffers) accumulate memory MAPPINGS for the whole
-# pytest process lifetime. Around ~280 tests in, the map count approaches
-# the kernel's default vm.max_map_count (65530) and the next native mmap
-# fails => C++ abort => "Fatal Python error: Aborted" in whichever test
-# happens to run there (observed twice, deterministically, in
-# test_robust.py — a test that passes alone in seconds). Dropping JAX's
-# compilation caches at module boundaries releases the executables;
-# cross-module cache hits are rare (each module compiles its own shapes),
-# so the wall-clock cost is negligible next to the crash it prevents.
-@pytest.fixture(autouse=True, scope="module")
-def _clear_jax_caches_per_module():
+# The suite compiles hundreds of XLA programs; the executables (and their
+# buffers) hold memory MAPPINGS for the life of the pytest process, and
+# past the kernel's vm.max_map_count (65530 by default) the next native
+# mmap fails and aborts the interpreter. Dropping JAX's compilation caches
+# releases the executables. The cure follows what it can observe: after
+# every test the process counts its own mappings and clears once they pass
+# half the limit, however xdist deals tests to workers. Under the driver's
+# six workers no worker passed 13,300 mappings in a whole run with no
+# clearing at all (PR 27), so there it costs one read of /proc/self/maps
+# a test and no recompile; one process running the whole suite holds the
+# six workers' sum (42,000 then), which is what the guard is for.
+def _map_limit() -> float:
+    try:
+        with open("/proc/sys/vm/max_map_count", encoding="ascii") as fh:
+            return 0.5 * int(fh.read())
+    except (OSError, ValueError):
+        return 0.5 * 65530
+
+
+_MAP_LIMIT = _map_limit()
+
+
+def _mapping_count() -> int:
+    try:
+        with open("/proc/self/maps", "rb") as fh:
+            return sum(1 for _ in fh)
+    except OSError:  # no procfs: nothing to observe, nothing to clear
+        return 0
+
+
+@pytest.fixture(autouse=True)
+def _clear_jax_caches_near_map_limit():
     yield
-    import gc
+    if _mapping_count() > _MAP_LIMIT:
+        import gc
 
-    import jax
-
-    jax.clear_caches()
-    gc.collect()
+        jax.clear_caches()
+        gc.collect()

@@ -3,11 +3,10 @@
 Interpret mode (every other Pallas test here) cannot see what Mosaic
 refuses: a slice off the tiling, too much VMEM, a kernel that cannot be
 partitioned. libtpu compiles for a chip that is described and not
-attached (``jax.experimental.topologies``), so these tests hand the three
-kernels of ``fedtpu.ops.pallas_kernels`` at the income shapes, and the
-RDMA ring with its synchronisation path on a four-device mesh, to the
-v5e compiler with ``interpret=False`` and look for the Mosaic custom call
-in the compiled text. Nothing runs; a pass here is not a chip run.
+attached (``jax.experimental.topologies``), so these tests hand the RDMA
+ring with its synchronisation path on a four-device mesh to the v5e
+compiler with ``interpret=False`` and look for the Mosaic custom call in
+the compiled text. Nothing runs; a pass here is not a chip run.
 
 The last case asks the same compiler what the ConvNet's training pass
 moves through memory: the block order of ``fedtpu.models.convnet`` exists
@@ -31,15 +30,10 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from fedtpu.ops.pallas_kernels import (fused_eval_confusion,
-                                       fused_mlp_forward,
-                                       weighted_average_clients)
 from fedtpu.parallel.ring_pallas import pallas_ring_all_reduce_sum
 
-# The income model at the reference's widths (14 -> 50 -> 200 -> 2), 8
-# clients, 1000 rows a client (8000 train rows), 2000 held-out rows.
+# The income model at the reference's widths (14 -> 50 -> 200 -> 2).
 DIMS = (14, 50, 200, 2)
-CLIENTS, ROWS, TEST_ROWS = 8, 1000, 2000
 MODEL_SIZE = sum(a * b + b for a, b in zip(DIMS[:-1], DIMS[1:]))   # 11,352
 
 
@@ -67,49 +61,9 @@ def _no_persistent_cache():
     cc.reset_cache()
 
 
-def _params(sharding, clients=None):
-    lead = () if clients is None else (clients,)
-    sds = lambda *shape: jax.ShapeDtypeStruct(lead + shape, jnp.float32,
-                                              sharding=sharding)
-    return {"layers": [{"w": sds(a, b), "b": sds(b)}
-                       for a, b in zip(DIMS[:-1], DIMS[1:])]}
-
-
 def _compiled_text(fn, *args) -> str:
     lowered = jax.jit(fn).lower(*args)  # fedtpu: noqa[FTP006] one-shot AOT compile
     return lowered.compile().as_text()
-
-
-def test_fused_mlp_forward_compiles_for_v5e(topo):
-    one = SingleDeviceSharding(topo.devices[0])
-    x = jax.ShapeDtypeStruct((TEST_ROWS, DIMS[0]), jnp.float32, sharding=one)
-    text = _compiled_text(
-        lambda p, x: fused_mlp_forward(p, x, interpret=False),
-        _params(one), x)
-    assert "tpu_custom_call" in text
-
-
-def test_fused_eval_confusion_compiles_for_v5e(topo):
-    one = SingleDeviceSharding(topo.devices[0])
-    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
-    text = _compiled_text(
-        lambda p, x, y, m: fused_eval_confusion(p, x, y, m, DIMS[-1],
-                                                interpret=False),
-        _params(one, clients=CLIENTS),
-        sds((CLIENTS, ROWS, DIMS[0]), jnp.float32),
-        sds((CLIENTS, ROWS), jnp.int32),
-        sds((CLIENTS, ROWS), jnp.float32))
-    assert "tpu_custom_call" in text
-
-
-def test_weighted_average_clients_compiles_for_v5e(topo):
-    one = SingleDeviceSharding(topo.devices[0])
-    text = _compiled_text(
-        lambda s, w: weighted_average_clients(s, w, interpret=False),
-        jax.ShapeDtypeStruct((CLIENTS, MODEL_SIZE), jnp.float32,
-                             sharding=one),
-        jax.ShapeDtypeStruct((CLIENTS,), jnp.float32, sharding=one))
-    assert "tpu_custom_call" in text
 
 
 def test_pallas_ring_sync_path_compiles_for_four_v5e_chips(topo):

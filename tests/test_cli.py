@@ -222,7 +222,7 @@ def test_every_documented_flag_exists_in_the_parser():
     documented = set()
     for rel in ("README.md", "docs/API.md", "docs/ARCHITECTURE.md",
                 "docs/observability.md", "docs/analysis.md",
-                "docs/performance.md", "docs/resilience.md",
+                "docs/resilience.md",
                 "docs/serving.md", "docs/scaling.md", "docs/autoscale.md",
                 "docs/robustness.md",
                 "PARITY.md"):
@@ -232,16 +232,7 @@ def test_every_documented_flag_exists_in_the_parser():
         documented.update(re.findall(
             r"(?<![\w/-])(--[a-z][a-z0-9_-]+)(?![a-z0-9_-])", text))
     # Flags documented for OTHER executables, not fedtpu.cli.
-    other_tools = {"--reps",                       # benchmarks/*.py
-                   "--out",                        # bench.py result file
-                   "--eval-every",                 # accuracy_parity.py
-                   "--min-speedup",                # benchmarks/compile_bench.py
-                   "--socket-events",              # benchmarks/serving_bench.py
-                   "--skip-socket",                # benchmarks/serving_bench.py
-                   "--trace",                      # benchmarks/async_bench.py
-                   "--scale", "--total-clients",   # benchmarks/scaling.py
-                   "--store",                      # benchmarks/scaling.py
-                   "--write",     # python -m fedtpu.telemetry.timeline_sim
+    other_tools = {"--write",     # python -m fedtpu.telemetry.timeline_sim
                    "--xla_force_host_platform_device_count",  # XLA flag
                    "--chips", "--rehearse-cpu",    # chip_smoke.py / chiprun
                    "--hostfile", "--np"}           # mpirun (reference docs)

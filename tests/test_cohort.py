@@ -16,7 +16,7 @@ Pins the contracts docs/scaling.md documents:
 - peak host RSS is FLAT in total client count under a fixed cohort size
   (the memory-model claim; measured per-row in subprocesses).
 
-The 1M-population bench row is `slow`-marked (full tier only).
+The 1M-population row is `slow`-marked (full tier only).
 """
 
 import dataclasses
@@ -513,9 +513,9 @@ def test_state_template_matches_slot_leaves():
 # ----------------------------------------------------------- memory model
 
 def _scale_row(total, store, rounds=1, extra=()):
-    cmd = [sys.executable, os.path.join(REPO, "benchmarks", "scaling.py"),
-           "--scale-row", "--total-clients", str(total), "--store", store,
-           "--cohort-size", "64", "--scale-rounds", str(rounds), *extra]
+    cmd = [sys.executable,
+           os.path.join(REPO, "tests", "cohort_scale_row_worker.py"),
+           str(total), store, str(rounds), *extra]
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)     # real host device count, real RSS
     out = subprocess.run(cmd, capture_output=True, text=True, env=env,
@@ -546,7 +546,7 @@ def test_million_client_round_completes_flat(tmp_path):
     1M-simulated-client population (mmap store) completes on CPU with
     resident store bytes ~cohort-sized while the apparent store is GBs."""
     row = _scale_row(1_000_000, "mmap",
-                     extra=("--store-path", str(tmp_path / "store.bin")))
+                     extra=(str(tmp_path / "store.bin"),))
     assert row["rounds"] >= 1
     assert row["store_apparent_bytes"] > 10**9          # ~1.7 GB apparent
     assert row["store_resident_bytes"] < 64 * 2**20     # cohort-sized

@@ -1,6 +1,6 @@
 """Driver entry-point regression tests.
 
-Round-1 postmortem: ``MULTICHIP_r01.json`` failed rc=1 because the dryrun let
+Round-1 postmortem: the first multichip dry run failed rc=1 because it let
 stray ops (``jax.random.key``, numpy→device converts) dispatch to the default
 TPU backend, which in the driver environment was live-but-broken (libtpu
 version mismatch). The dryrun must be hermetic: CPU-only, regardless of

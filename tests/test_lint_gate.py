@@ -1,4 +1,4 @@
-"""The tier-1 lint gate: `python -m fedtpu.cli lint fedtpu/ tests/ bench.py`.
+"""The tier-1 lint gate: `python -m fedtpu.cli lint fedtpu/ tests/`.
 
 One in-process invocation of the real CLI entry point over the whole
 repo, so a new lint finding (or an unjustified suppression regression)
@@ -29,8 +29,7 @@ def test_repo_lint_gate_is_clean(capsys):
     t0 = time.perf_counter()
     rc = cli_main(["lint",
                    os.path.join(REPO, "fedtpu"),
-                   os.path.join(REPO, "tests"),
-                   os.path.join(REPO, "bench.py")])
+                   os.path.join(REPO, "tests")])
     elapsed = time.perf_counter() - t0
     out = capsys.readouterr().out
     assert rc == 0, f"fedtpu lint found regressions:\n{out}"
@@ -51,8 +50,7 @@ def test_concurrency_determinism_pass_gates_repo_wide(capsys):
     rc = cli_main(["lint", "--select", "FTP011,FTP012,FTP013",
                    "--show-suppressed",
                    os.path.join(REPO, "fedtpu"),
-                   os.path.join(REPO, "tests"),
-                   os.path.join(REPO, "bench.py")])
+                   os.path.join(REPO, "tests")])
     out = capsys.readouterr().out
     assert rc == 0, f"concurrency/determinism regressions:\n{out}"
     assert "0 findings" in out

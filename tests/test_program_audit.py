@@ -26,8 +26,9 @@ import pytest
 import fedtpu.parallel  # noqa: F401  (installs the jax.shard_map shim)
 from fedtpu.analysis.collectives import (comm_bytes, extract_schedule,
                                          schedule_digest)
-from fedtpu.analysis.program import (_PROBES, _synthetic_cfg,
-                                     donation_proof, engine_audit_spec)
+from fedtpu.analysis.program import (_PROBES, _synthetic_cfg, diff_audit,
+                                     donation_proof, engine_audit_spec,
+                                     hlo_collective_census)
 from fedtpu.parallel.mesh import make_mesh
 
 P = jax.sharding.PartitionSpec
@@ -38,9 +39,9 @@ def _mesh():
     return make_mesh(num_clients=len(jax.devices()))
 
 
-def _shard_mapped(body, mesh):
+def _shard_mapped(body, mesh, **kwargs):
     return jax.shard_map(body, mesh=mesh, in_specs=P(CLIENTS),
-                         out_specs=P(CLIENTS))
+                         out_specs=P(CLIENTS), **kwargs)
 
 
 # ------------------------------------------------------- schedule extraction
@@ -59,6 +60,29 @@ def test_extract_schedule_counts_psum_bytes():
     # per-shard operand: (1, 4) f32 = 16 bytes, one trip
     assert comm_bytes(sched.ops) == 16
     assert not sched.findings and not sched.has_dynamic
+
+
+@pytest.mark.parametrize("check_vma", [True, False])
+def test_schedule_is_the_same_with_and_without_check_vma(check_vma):
+    """jax names a collective's primitive by how shard_map types it
+    (``psum_invariant`` under check_vma, ``psum`` without): the walker
+    must read either. The one test a renamed primitive should fail."""
+    mesh = _mesh()
+
+    def body(x):
+        total = jax.lax.psum(x, CLIENTS)
+        return jax.lax.all_gather(x * total, CLIENTS, tiled=True)
+
+    n = len(jax.devices())
+    x = jnp.ones((n, 4), jnp.float32)
+    sched = extract_schedule(jax.make_jaxpr(
+        _shard_mapped(body, mesh, check_vma=check_vma))(x))
+    assert [(op.op, op.axes, op.shapes, op.trips) for op in sched.ops] == [
+        ("psum", (CLIENTS,), ((1, 4),), 1),
+        ("all_gather", (CLIENTS,), ((1, 4),), 1),
+    ]
+    assert comm_bytes(sched.ops) == 32
+    assert not sched.findings
 
 
 def test_scan_multiplies_collective_trips():
@@ -85,7 +109,7 @@ def test_branch_divergent_schedule_flags_aud001():
 
     def body(x):
         return jax.lax.cond(x.sum() > 0,
-                            lambda v: jax.lax.psum(v, CLIENTS),
+                            lambda v: jax.lax.psum(v, CLIENTS) * v,
                             lambda v: v * 2.0, x)
 
     x = jnp.ones((len(jax.devices()), 4), jnp.float32)
@@ -107,6 +131,45 @@ def test_branch_identical_schedule_is_clean():
     sched = extract_schedule(jax.make_jaxpr(_shard_mapped(body, mesh))(x))
     assert not sched.findings
     assert [op.op for op in sched.ops] == ["psum"]
+
+
+# ------------------------------------------------- what a golden pins
+
+
+def test_census_counts_combined_collectives():
+    """XLA's combiner folds several all-reduces into one whose result
+    type is a tuple (spaces inside): still one instruction."""
+    text = "\n".join([
+        "%all-reduce.5 = (f32[50]{0}, f32[], /*index=2*/f32[2,2]{1,0}) "
+        "all-reduce(%a, %b, %c), channel_id=1, to_apply=%add",
+        "%ag = f32[8,4]{1,0} all-gather-start(%x), dimensions={0}",
+        "%gte = f32[] get-tuple-element(%all-reduce.5), index=1",
+    ])
+    assert hlo_collective_census(text) == {"all-reduce": 1, "all-gather": 1}
+
+
+def _report(schedule, census):
+    return {"engines": {"e": {"schedule": schedule, "schedule_digest": "d",
+                              "hlo_collectives": census}}}
+
+
+@pytest.mark.parametrize("schedule,golden,live,mismatches", [
+    # traced schedule pins the engine: the compiled census is reported only
+    ([{"op": "psum"}], {"all-reduce": 13}, {"all-reduce": 1}, 0),
+    ([{"op": "psum"}], {"all-reduce": 13}, {}, 0),
+    # GSPMD engine (empty traced schedule): which kinds, not how many
+    ([], {"all-reduce": 15}, {"all-reduce": 2}, 0),
+    ([], {"all-reduce": 15}, {"all-reduce": 2, "all-gather": 1}, 1),
+    ([], {"all-reduce": 15}, {}, 1),
+])
+def test_diff_audit_pins_census_kinds_only_without_a_traced_schedule(
+        schedule, golden, live, mismatches):
+    out = diff_audit(_report(schedule, live), _report(schedule, golden))
+    assert len(out) == mismatches, out
+    # anything else in the contract is still compared exactly
+    other = _report(schedule, live)
+    other["engines"]["e"]["schedule_digest"] = "x"
+    assert len(diff_audit(other, _report(schedule, golden))) == mismatches + 1
 
 
 # ------------------------------------------------------------ donation proof

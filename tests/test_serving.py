@@ -13,7 +13,7 @@ Pins the contracts the serving front-end documents:
 - a real localhost serve + loadgen round trip works end to end;
 - the report pipeline renders the serving section from serve events.
 
-Subprocess SIGTERM/bench coverage is `slow`-marked (full tier only).
+Subprocess SIGTERM coverage is `slow`-marked (full tier only).
 """
 
 import json
@@ -488,25 +488,3 @@ def test_serve_sigterm_drains_checkpoints_and_exits_75(tmp_path):
     assert rc == 75
     rounds = [p for p in os.listdir(ckpt) if p.startswith("round_")]
     assert rounds, "SIGTERM drain wrote no checkpoint"
-
-
-@pytest.mark.slow
-def test_serving_bench_small_artifact(tmp_path):
-    """serving_bench end to end at toy scale: both rows present, SLO
-    keys populated, artifact is valid JSONL."""
-    out = tmp_path / "bench.jsonl"
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run(
-        [sys.executable, "benchmarks/serving_bench.py", "--users", "50000",
-         "--arrivals", "5000", "--socket-events", "1000",
-         "--json", str(out)],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=570)
-    assert r.returncode == 0, r.stderr[-2000:]
-    rows = [json.loads(l) for l in out.read_text().splitlines()]
-    kinds = {row["row"] for row in rows}
-    assert kinds == {"serving_inproc", "serving_socket"}
-    inproc = next(row for row in rows if row["row"] == "serving_inproc")
-    assert inproc["update_to_incorporation"]["p99_s"] > 0
-    assert inproc["rounds_per_sec"] > 0
-    # +1: the bench admits one warm-up offer before the timed replay.
-    assert sum(inproc["admission"].values()) == 5000 + 1

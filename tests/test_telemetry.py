@@ -188,41 +188,6 @@ def test_checkpoint_counters_roundtrip(tmp_path):
 
 
 # ------------------------------------------------------------- satellites
-def test_bench_json_is_last_stdout_line(tmp_path, capsys):
-    """BENCH regression: the harness reads the LAST stdout line; detail
-    lines must precede the (complete) JSON blob, and the blob is also
-    written to a file."""
-    from bench import emit_result
-    result = {"metric": "m", "value": 1.25, "nested": {"a": [1, 2]}}
-    out = tmp_path / "r.json"
-    emit_result(result, ["[bench] detail one", "[bench] detail two"],
-                out_path=str(out))
-    cap = capsys.readouterr()
-    lines = [ln for ln in cap.out.splitlines() if ln.strip()]
-    assert json.loads(lines[-1]) == result       # last stdout line parses
-    assert "[bench]" not in cap.out              # details are stderr-only
-    assert "[bench] detail one" in cap.err
-    assert json.loads(out.read_text()) == result
-
-
-def test_bench_measurement_path_refuses_a_non_tpu_backend():
-    """A number from the CPU is not a device metric: bench.main stops at
-    its device gate here instead of writing mfu/peak for the CPU."""
-    import bench
-    with pytest.raises(SystemExit) as e:
-        bench.main(["--out", ""])
-    assert "found {'platform': 'cpu'" in str(e.value.code)
-
-
-def test_bench_parser_has_out_and_events_flags(capsys):
-    import bench
-    with pytest.raises(SystemExit) as e:
-        bench.main(["--help"])
-    assert e.value.code == 0
-    help_text = capsys.readouterr().out
-    assert "--out" in help_text and "--events" in help_text
-
-
 def test_resume_engine_mismatch_with_equal_client_counts(tmp_path):
     """Satellite regression: same client count on both sides used to slip
     past the count comparison and die inside orbax with an opaque

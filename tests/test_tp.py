@@ -225,8 +225,7 @@ def test_run_experiment_model_parallel():
 
 
 def test_per_device_state_bytes_scale_down_with_tp():
-    """The 2-D engine's reason to exist (benchmarks/tp_memory.py pins the
-    full-size numbers): measured per-device params+opt bytes drop ~1/tp
+    """The 2-D engine's reason to exist: measured per-device params+opt bytes drop ~1/tp
     for a fixed federation as chips-per-client grow. Slack below the
     ideal 2x/4x is the model-replicated logits head and row biases."""
     from fedtpu.utils.trees import max_device_bytes
