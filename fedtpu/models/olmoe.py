@@ -21,10 +21,15 @@ What is this repo's own:
   grouped matmuls over the uneven groups (``jax.lax.ragged_dot``). There is
   no capacity factor and no auxiliary loss (HF's default
   ``output_router_logits=False`` computes none).
-* **A loss that never holds the logits whole.** The head and the
-  cross-entropy run over chunks of the sequence under ``jax.checkpoint``:
-  one chunk's ``[chunk, vocab]`` float32 logits exist at a time, forward and
-  backward.
+* **A loss that never holds the logits whole and never computes them
+  twice.** The head and the cross-entropy run over chunks of the sequence:
+  one chunk's ``[chunk, vocab]`` float32 logits exist at a time. The loss
+  is the model's last operation, so the function has its own
+  differentiation rule (``jax.custom_vjp``): the forward pass of a chunk
+  takes the loss's gradient from the logits it has and runs both gradient
+  matmuls there; the backward pass scales the result by the scalar
+  cotangent. Three matmuls over the vocabulary a chunk, where a
+  checkpointed scan ran four.
 * **Scanned layers.** Layer parameters carry a leading layers axis and the
   stack is a ``lax.scan``, so depth 16 compiles as depth 1 does.
 * **An attention core with two bodies.** ``softmax(mask(q k^T / sqrt(d))) v``
@@ -252,29 +257,85 @@ def next_token_targets(tokens, segs):
     return labels, ((segs > 0) & (nxt == segs)).astype(jnp.float32)
 
 
-def _head_loss(h, head, labels, valid, compute_dtype):
-    """``(summed loss, correct)`` over a sequence, a chunk of rows at a
-    time; each chunk's logits are recomputed in the backward pass."""
+def _loss_chunks(h, labels, valid):
+    """The head's inputs cut into ``LOSS_CHUNK`` rows, or left as one chunk
+    where the sequence is no multiple of it."""
     t = h.shape[0]
     chunk = LOSS_CHUNK if t % LOSS_CHUNK == 0 else t
+    return (h.reshape(-1, chunk, h.shape[1]), labels.reshape(-1, chunk),
+            valid.reshape(-1, chunk))
+
+
+def _chunk_loss(x, w, yc, vc):
+    """One chunk's float32 ``(logits, log-sum-exp, summed loss, correct)``
+    from ``x (chunk, H)`` and ``w (H, V)`` in the compute dtype."""
+    logits = jnp.dot(x, w, preferred_element_type=jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, yc[:, None], axis=-1)[:, 0]
+    hit = (jnp.argmax(logits, axis=-1) == yc).astype(jnp.float32)
+    return logits, lse, ((lse - picked) * vc).sum(), (hit * vc).sum()
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _head_loss(h, head, labels, valid, compute_dtype):
+    """``(summed loss, correct)`` over a sequence, a chunk of rows at a
+    time. Called plainly (held-out evaluation) this is the forward pass
+    alone. Differentiated, its own rule runs instead (``_head_loss_fwd``),
+    reverse mode only: ``jax.jvp``, ``jacfwd`` and ``hessian`` through the
+    head raise, and nothing in fedtpu uses them. ``labels`` and ``valid``
+    are data (functions of the integer row): their cotangents are zero."""
     w = head.astype(compute_dtype)
 
-    @jax.checkpoint
     def one(carry, xs):
         hc, yc, vc = xs
-        logits = jnp.dot(hc.astype(compute_dtype), w,
-                         preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, yc[:, None], axis=-1)[:, 0]
-        hit = (jnp.argmax(logits, axis=-1) == yc).astype(jnp.float32)
-        loss, correct = carry
-        return (loss + ((lse - picked) * vc).sum(), correct + (hit * vc).sum()), None
+        _, _, loss, correct = _chunk_loss(hc.astype(compute_dtype), w, yc, vc)
+        return (carry[0] + loss, carry[1] + correct), None
 
-    parts = (h.reshape(-1, chunk, h.shape[1]), labels.reshape(-1, chunk),
-             valid.reshape(-1, chunk))
-    (loss, correct), _ = lax.scan(one, (jnp.float32(0.0), jnp.float32(0.0)),
-                                  parts)
-    return loss, correct
+    zero = jnp.float32(0.0)
+    return lax.scan(one, (zero, zero), _loss_chunks(h, labels, valid))[0]
+
+
+def _head_loss_fwd(h, head, labels, valid, compute_dtype):
+    """The loss is the model's last operation and its cotangent one scalar,
+    so each chunk's logits give, while they exist, the loss AND its gradient
+    for a unit cotangent: ``dlogits = (softmax - onehot) * valid``,
+    ``dh = dlogits w^T``, ``dw += h^T dlogits``. Three matmuls over the
+    vocabulary a chunk and no recomputation; the backward rule only scales
+    ``(dh, dw)``. ``dlogits`` enters its two matmuls in the compute dtype
+    (what the MXU made of the float32 one autodiff handed it); ``dw`` is
+    summed over the chunks in the compute dtype, as autodiff summed it,
+    each chunk's float32 product added in float32 and rounded once."""
+    w = head.astype(compute_dtype)
+
+    def one(carry, xs):
+        hc, yc, vc = xs
+        loss, correct, dw = carry
+        x = hc.astype(compute_dtype)
+        logits, lse, chunk_loss, chunk_correct = _chunk_loss(x, w, yc, vc)
+        onehot = yc[:, None] == jnp.arange(logits.shape[1])[None, :]
+        dlogits = ((jnp.exp(logits - lse[:, None]) - onehot)
+                   * vc[:, None]).astype(compute_dtype)
+        dh = lax.dot_general(dlogits, w, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        dw = (dw.astype(jnp.float32) + lax.dot_general(
+            x, dlogits, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)).astype(dw.dtype)
+        return (loss + chunk_loss, correct + chunk_correct, dw), dh
+
+    zero = jnp.float32(0.0)
+    (loss, correct, dw), dh = lax.scan(
+        one, (zero, zero, jnp.zeros_like(w)), _loss_chunks(h, labels, valid))
+    return (loss, correct), (dh.reshape(h.shape).astype(h.dtype), dw, head)
+
+
+def _head_loss_bwd(compute_dtype, residuals, cotangents):
+    dh, dw, head = residuals    # head: for its dtype, the parameters'
+    g = cotangents[0]           # ``correct`` is a count: no gradient
+    return ((g * dh).astype(dh.dtype),
+            (g * dw.astype(jnp.float32)).astype(head.dtype), None, None)
+
+
+_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 
 
 def olmoe_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):

@@ -209,12 +209,47 @@ def test_the_olmoe_round_at_published_widths_fits_one_v5e_chip(olmoe_round):
 def test_the_olmoe_round_with_fused_attention_drops_the_scores(olmoe_round):
     """Steered to the fused body, as the chip picks it, the same round holds
     the three attention kernels and no float32 16 x 4096^2 array: 12.95 GB
-    when this was written, 0.86 under the XLA body's."""
+    when this was written, 0.86 under the XLA body's; 12.98 and 0.67 under
+    since the head's own differentiation rule (PR 28), with which the XLA
+    body's round peaks 0.15 lower."""
     fused, xla = olmoe_round(True), olmoe_round(False)
     assert _account(fused) <= 13.1e9, _account(fused)
-    assert _account(fused) <= _account(xla) - 0.7e9
+    assert _account(fused) <= _account(xla) - 0.6e9
     assert fused.memory_analysis().alias_size_in_bytes >= 5.0e9
     assert _mosaic_calls(fused) >= _mosaic_calls(xla) + 3
+
+
+def _convolutions_over(text: str, dim: int) -> list:
+    """The compiled module's convolutions (a matmul is one on this
+    compiler) with ``dim`` among the dimensions of their result or of an
+    operand, as ``[result, operands...]`` shapes."""
+    found = []
+    for body in text.split("\n\n"):
+        shapes = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\])",
+                                 body, re.M))
+        for result, operands in re.findall(
+                r"= (\w+\[[\d,]*\])\S* convolution\(([^)]*)\)", body):
+            all_ = [result] + [shapes.get(o.split()[-1], o)
+                               for o in operands.split(", ")]
+            if any(str(dim) in re.findall(r"\d+", s) for s in all_):
+                found.append(all_)
+    return found
+
+
+def test_the_olmoe_round_multiplies_over_the_vocabulary_three_times_a_chunk(
+        olmoe_round):
+    """The head's own differentiation rule in the compiled round: the
+    logits, ``dlogits w^T`` and ``h^T dlogits`` and no second logits matmul
+    (the checkpointed scan before PR 28 compiled to four), forward and
+    backward rule under the head's scope. The account it has to stay in is
+    the test's above."""
+    text = olmoe_round(True).as_text()
+    over_vocab = _convolutions_over(text, 50304)
+    assert len(over_vocab) == 3, over_vocab
+    assert sorted(c[0] for c in over_vocab) == [
+        "f32[2048,50304]", "f32[512,2048]", "f32[512,50304]"]
+    assert "/jvp(lm_head_loss)/" in text
+    assert "/transpose(jvp(lm_head_loss))/" in text
 
 
 def test_fused_attention_core_compiles_for_v5e_without_the_scores(topo):

@@ -206,6 +206,127 @@ def test_depth_two_scanned_is_two_blocks_by_hand():
     assert abs(float(got["loss_sum"]) - float(ref)) <= 1e-4
 
 
+# The head's own differentiation rule (olmoe._head_loss) against plain
+# autodiff of the head and loss written here whole: no chunks, no checkpoint,
+# no rule.
+def _plain_head_loss(h, head, labels, valid, dtype):
+    logits = jnp.dot(h.astype(dtype), head.astype(dtype),
+                     preferred_element_type=jnp.float32)
+    picked = jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1),
+                                 labels[:, None], axis=-1)[:, 0]
+    hit = (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
+    return -(picked * valid).sum(), (hit * valid).sum()
+
+
+def _head_inputs(t, seed=21):
+    """Hidden states and a head that give gradient entries of order 1, and
+    the targets of a packed row: two documents (the last token of each out
+    of the loss), then padding."""
+    kh, kw = jax.random.split(jax.random.key(seed))
+    h = 2.0 * jax.random.normal(kh, (t, TINY.hidden_size))
+    head = 0.5 * jax.random.normal(kw, (TINY.hidden_size, TINY.vocab_size))
+    row = _row(seed, docs=(t // 3, t // 2), t=t)
+    labels, valid = olmoe.next_token_targets(jnp.asarray(row[0]),
+                                             jnp.asarray(row[1]))
+    assert float(valid.sum()) == t // 3 + t // 2 - 2 < t - 4
+    return h, head, labels, valid
+
+
+# float32: the same sums in another order (over 20 seeds, either length: 0 to
+# 2.9e-6 on a mean loss of 12-15, 1e-7 to 4.2e-7 on gradient entries up to
+# 0.59), so 1e-5 of the loss and 1e-5 on gradients. bfloat16: both sides hand
+# a bf16 dlogits to the two gradient matmuls; the rule then rounds dw to bf16
+# once a chunk where plain autodiff rounds it once in all (1.2e-3 to 3.3e-3
+# on entries up to 0.59; the loss as in float32): the file's 3e-2 / 6e-2
+# hold that and fail a dropped chunk, which moves entries by their own size.
+@pytest.mark.parametrize("t,chunks", [(T, 4), (T - 2, 1)])
+@pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
+    (jnp.float32, 1e-5, 1e-5), (jnp.bfloat16, 3e-2, 6e-2)])
+def test_the_heads_rule_is_plain_autodiff(dtype, loss_tol, grad_tol, t, chunks):
+    h, head, labels, valid = _head_inputs(t)
+    assert olmoe._loss_chunks(h, labels, valid)[0].shape[0] == chunks
+
+    def mean_loss(body):
+        def f(h, head):
+            loss, correct = body(h, head, labels, valid, dtype)
+            return loss / valid.sum(), correct
+        return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)
+
+    (loss, correct), (dh, dw) = mean_loss(olmoe._head_loss)(h, head)
+    (want, want_correct), (want_dh, want_dw) = mean_loss(_plain_head_loss)(h, head)
+    assert abs(float(loss) - float(want)) <= loss_tol * max(1.0, float(want))
+    assert float(correct) == float(want_correct)
+    assert dh.dtype == dw.dtype == jnp.float32
+    assert _gap((dh, dw), (want_dh, want_dw)) <= grad_tol
+    assert float(jnp.max(jnp.abs(want_dw))) > 0.1
+    # rows outside the loss move nothing
+    assert bool(jnp.all(dh[valid == 0] == 0.0)) and float(valid.min()) == 0.0
+    # the undifferentiated call is the same forward pass
+    plain_call = olmoe._head_loss(h, head, labels, valid, dtype)
+    assert float(plain_call[0]) == pytest.approx(float(loss * valid.sum()), rel=1e-6)
+    assert float(plain_call[1]) == float(correct)
+
+
+def test_the_tasks_gradients_with_the_rule_are_those_of_plain_autodiff(
+        monkeypatch):
+    """Through ``next_token_task(...).loss`` the head's cotangent is one over
+    the count, not 1, and the rule sits under a ``lax.map`` over two rows:
+    every parameter's gradient is what the plain head gives in its place."""
+    from fedtpu.training.task import build_task
+    _, stats_fn = build_model(TINY)
+    task = build_task(TINY, stats_fn, TINY.vocab_size)
+    p = _params()
+    x = jnp.asarray(np.stack([_row(1), _row(2, docs=(20,))]))
+    args = (p, x, None, jnp.ones((2,)))
+    (loss, stats), g = jax.value_and_grad(task.loss, has_aux=True)(*args)
+    assert float(stats["count"]) == (12 + 14 - 2) + (20 - 1)
+    monkeypatch.setattr(olmoe, "_head_loss", _plain_head_loss)
+    (want, _), want_g = jax.value_and_grad(task.loss, has_aux=True)(*args)
+    assert abs(float(loss) - float(want)) <= 1e-5
+    assert _gap(g, want_g) <= 1e-6
+    assert float(jnp.max(jnp.abs(want_g["head"]))) > 1e-2
+
+
+def _vocab_dots(jaxpr, vocab, scans=()):
+    """For every ``dot_general`` with a vocabulary-sized dimension, here or
+    in a nested jaxpr: the scans it sits in."""
+    found = []
+    for eqn in jaxpr.eqns:
+        shapes = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)]
+        if eqn.primitive.name == "dot_general" and any(vocab in s for s in shapes):
+            found.append(scans)
+        inner = scans + (id(eqn),) if eqn.primitive.name == "scan" else scans
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _vocab_dots(sub, vocab, inner)
+    return found
+
+
+def test_the_head_multiplies_over_the_vocabulary_three_times_a_chunk_not_four():
+    """What says the rule engaged: the gradient's program holds the logits
+    matmul and the two gradient matmuls in ONE scan (a checkpointed scan
+    holds four in two), and the undifferentiated call holds the logits
+    matmul alone."""
+    h, head, labels, valid = _head_inputs(T)
+    f = lambda h, head: olmoe._head_loss(h, head, labels, valid, jnp.bfloat16)
+    grad = jax.make_jaxpr(jax.grad(lambda h, head: f(h, head)[0], argnums=(0, 1)))
+    dots = _vocab_dots(grad(h, head).jaxpr, TINY.vocab_size)
+    assert len(dots) == 3 and len(set(dots)) == 1 and len(dots[0]) == 1, dots
+    forward = _vocab_dots(jax.make_jaxpr(f)(h, head).jaxpr, TINY.vocab_size)
+    assert len(forward) == 1 and len(forward[0]) == 1, forward
+    # the whole model's gradient holds no other: the embedding is a gather
+    whole = jax.make_jaxpr(jax.grad(
+        lambda p, row: _sys_loss(TINY, jnp.bfloat16)(p, row)[0]))
+    assert len(_vocab_dots(whole(_params(), jnp.asarray(_row(3))).jaxpr,
+                           TINY.vocab_size)) == 3
+
+
+def test_forward_mode_through_the_head_is_refused():
+    h, head, labels, valid = _head_inputs(T)
+    f = lambda h: olmoe._head_loss(h, head, labels, valid, jnp.float32)[0]
+    with pytest.raises(TypeError, match="custom_vjp"):
+        jax.jvp(f, (h,), (h,))
+
+
 def test_masked_rows_count_for_nothing():
     _, stats_fn = build_model(TINY)
     p = _params()
