@@ -18,7 +18,7 @@ What is this repo's own:
   give the losses and gradients of the two alone.
 * **Dropless experts.** Every (token, expert) assignment is computed: the
   assignments are sorted by expert and the three expert matmuls run as
-  grouped matmuls over the uneven groups (``jax.lax.ragged_dot``). There is
+  grouped matmuls over the uneven groups (``grouped_matmul``). There is
   no capacity factor and no auxiliary loss (HF's default
   ``output_router_logits=False`` computes none).
 * **A loss that never holds the logits whole and never computes them
@@ -52,6 +52,20 @@ What is this repo's own:
   (PERF.md section 6, PR 26). The sequence statistics say how many
   positions ran fused (``fused_attention``).
 
+* **Expert matmuls with two bodies.** ``grouped_matmul(xs, w, sizes)`` is
+  one function too. Its XLA body, ``lax.ragged_dot``, is the definition,
+  and what the TPU's compiler makes of it runs at 27-35% of the MXU's peak
+  on these uneven groups. Its Pallas body is the library's tiled grouped
+  matmul (``jax.experimental.pallas.ops.tpu.megablox``: ``gmm`` forward,
+  ``gmm`` on the weight in place for the input's gradient, ``tgmm`` for
+  the weight's) at tiles chosen on the chip, 52-61% of the peak: the same
+  bf16 operands and float32 sums, gradients rounded to bf16 once, where
+  autodiff rounded the XLA body's float32 ones. ``grouped_matmul_applies``
+  picks, as for the attention core and as little anybody's to set: the
+  kernels on a TPU at bf16 operands, whole row tiles and widths of 1024 or
+  2048; ``lax.ragged_dot`` everywhere else. The sequence statistics say
+  how many positions ran in the kernels (``grouped_experts``).
+
 Parameters are float32. ``compute_dtype`` (bfloat16 in the shipped presets)
 is the dtype of every large matmul's inputs; accumulation, norms, softmax,
 the router (logits at ``HIGHEST`` precision, softmax, top-k) and the loss
@@ -70,6 +84,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental.pallas.ops.tpu import flash_attention as flash
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
 EMBED, ATTENTION, ROUTER, EXPERT_DISPATCH, EXPERTS, LM_HEAD_LOSS = (
     "embed", "attention", "router", "expert_dispatch", "experts",
@@ -90,6 +105,47 @@ _ATTENTION_BLOCKS = flash.BlockSizes(
         "block_q", "block_k_major", "block_k", "block_q_major_dkv",
         "block_k_major_dkv", "block_k_dkv", "block_q_dkv",
         "block_k_major_dq", "block_k_dq", "block_q_dq")})
+
+
+# Tiles of the three grouped expert kernels, ``(tm, tk, tn)`` = rows,
+# contracted width, output width of a tile. Chosen on the chip (PERF.md
+# section 6, PR 29) on the benchmark's shapes, 32,768 assignment rows in 64
+# groups as its own corpus and router give them (the fullest group 2,065 to
+# 2,976 rows, 23 to 32 groups under 128), 20 timed calls each, ms a call
+# beside ``lax.ragged_dot`` on the same operands (its kernel alone takes
+# 1.98-2.64 in the round; the calls timed here also hold its cast and its
+# transposed copy of the weight):
+#   gmm, [32768,2048].[64,2048,1024]: XLA 2.15; (256, 2048, 1024) 1.22,
+#     (128, 2048, 1024) 1.23, (256, 2048, 512) 1.33, (512, 2048, 512) 1.50,
+#     (256, 1024, 1024) 1.63, (256, 512, 512) 2.28, (128, 512, 512) 3.00.
+#   gmm, [32768,1024].[64,1024,2048]: XLA 2.25; (128, 1024, 2048) 1.27,
+#     (256, 1024, 2048) 1.30, (256, 1024, 1024) 1.36, (512, 1024, 1024)
+#     1.57, (256, 512, 2048) 1.79, (128, 512, 512) 3.00.
+#   gmm on the weight in place (transpose_rhs), to [32768,2048]: XLA 4.09;
+#     (128 or 256, 1024, 2048) 1.23, (256, 1024, 1024) 1.28, (512, 1024,
+#     2048) 1.48, (256, 512, 2048) 1.56; to [32768,1024]: XLA 3.61;
+#     (256, 2048, 1024) 1.19, (128, 2048, 1024) 1.21, (512, 2048, 512) 1.51.
+#   tgmm, to [64,2048,1024] and [64,1024,2048]: XLA 3.86; (256, 1024, 1024)
+#     1.46 / 1.47, (256, 2048, 512) 1.51, (256, 512, 1024) 1.70, (512, 1024,
+#     1024) 1.70, (1024, 1024, 512) 2.44; a float32 result +0.2 to +0.4.
+# So: 256 rows (a tile that straddles a group's edge runs once a group: 512
+# rows cost 1.2-1.3x, and 128 are no better with the weight held); the two
+# gmm's take the contracted width whole and as much of the output width as
+# one weight tile of 2048 x 1024 holds, so a group's weight is fetched once;
+# tgmm takes 1024 x 1024 of the weight's gradient at a time. Wider tiles do
+# not fit the kernel's 16 MB of the chip's own memory.
+GROUPED_ROW_TILE = 256
+GROUPED_WIDTH_TILE = 1024
+GROUPED_WEIGHT_TILE = 2048 * 1024
+
+
+def _grouped_tiles(kernel, k, n):
+    """``(tm, tk, tn)`` of one of the three grouped kernels for a contracted
+    width ``k`` and an output width ``n`` (of ``tgmm``: the weight's two)."""
+    if kernel == "weight_gradient":
+        return (GROUPED_ROW_TILE, min(k, GROUPED_WIDTH_TILE),
+                min(n, GROUPED_WIDTH_TILE))
+    return GROUPED_ROW_TILE, k, min(n, GROUPED_WEIGHT_TILE // k)
 
 
 def olmoe_init(key: jax.Array, cfg, param_dtype=jnp.float32):
@@ -204,6 +260,81 @@ def attention_core(q, k, v, segs, compute_dtype):
     return body(q, k, v, segs)
 
 
+def grouped_matmul_applies(xs, w) -> bool:
+    """Whether the tiled kernels exist for ``xs (rows, K)`` and ``w (groups,
+    K, N)`` where the program is being built: a TPU (the PROCESS's backend,
+    as ``fused_attention_applies`` reads it), the bf16 operands the tiles
+    were measured on (the kernel multiplies float32 operands in float32,
+    several MXU passes where the XLA body takes one), whole row tiles, and
+    either width whole width tiles and, as the contracted width of a kernel,
+    leaving a width tile's room in one weight tile (1024 or 2048 today)."""
+    (rows, k), n = xs.shape, w.shape[2]
+    return (jax.default_backend() == "tpu"
+            and xs.dtype == w.dtype == jnp.bfloat16
+            and rows % GROUPED_ROW_TILE == 0
+            and all(width % GROUPED_WIDTH_TILE == 0
+                    and width * GROUPED_WIDTH_TILE <= GROUPED_WEIGHT_TILE
+                    for width in (k, n)))
+
+
+def _xla_grouped_matmul(xs, w, sizes):
+    return lax.ragged_dot(xs, w, group_sizes=sizes,
+                          preferred_element_type=jnp.float32)
+
+
+def _rows_of_groups(out, sizes):
+    # the kernel visits no tile past the last group: what it left there is
+    # not zero, as the definition's is, until it is made so
+    rows = lax.broadcasted_iota(jnp.int32, (out.shape[0], 1), 0)
+    return jnp.where(rows < sizes.sum(), out, 0)
+
+
+@jax.custom_vjp
+def _pallas_grouped_matmul(xs, w, sizes):
+    """The library's grouped matmul (``megablox.gmm``) under a rule of its
+    own, reverse mode only: the input's gradient is the same kernel reading
+    the weight in place (``transpose_rhs``: no transposed copy of it
+    exists), the weight's is ``tgmm``. The cotangent enters both in the
+    operands' dtype (what the MXU made of the float32 one autodiff handed
+    the XLA body), sums are float32 over the whole contracted width, and
+    each gradient is rounded once to its primal's dtype."""
+    k, n = w.shape[1:]
+    return _rows_of_groups(gmm(
+        xs, w, sizes, jnp.float32, _grouped_tiles("forward", k, n)), sizes)
+
+
+def _pallas_grouped_matmul_fwd(xs, w, sizes):
+    return _pallas_grouped_matmul(xs, w, sizes), (xs, w, sizes)
+
+
+def _pallas_grouped_matmul_bwd(residuals, g):
+    xs, w, sizes = residuals
+    k, n = w.shape[1:]
+    g = g.astype(xs.dtype)
+    dxs = _rows_of_groups(gmm(
+        g, w, sizes, xs.dtype, _grouped_tiles("input_gradient", n, k),
+        transpose_rhs=True), sizes)
+    # tgmm takes the activations contracted-axis last and swaps them back
+    # itself: the two transposes meet under jit and no copy is made
+    dw = tgmm(xs.swapaxes(0, 1), g, sizes, w.dtype,
+              _grouped_tiles("weight_gradient", k, n))
+    return dxs, dw, None
+
+
+_pallas_grouped_matmul.defvjp(_pallas_grouped_matmul_fwd,
+                              _pallas_grouped_matmul_bwd)
+
+
+def grouped_matmul(xs, w, sizes):
+    """``out (rows, N)`` float32: rows ``sizes[:g].sum()`` to
+    ``sizes[:g + 1].sum()`` of ``xs (rows, K)`` times ``w[g] (K, N)``, for
+    every group; rows past the last group are zero. ``lax.ragged_dot`` is
+    the definition and the XLA body."""
+    body = (_pallas_grouped_matmul if grouped_matmul_applies(xs, w)
+            else _xla_grouped_matmul)
+    return body(xs, w, sizes)
+
+
 def _block(cfg, compute_dtype, h, layer, segs, pos):
     """One decoder layer on one packed sequence ``h (T, H)``; returns the
     new ``h`` and the tokens each expert was given (padding left out)."""
@@ -239,10 +370,9 @@ def _block(cfg, compute_dtype, h, layer, segs, pos):
         load = jnp.zeros((n_exp,), jnp.int32).at[flat].add(
             jnp.repeat(real, top_k))
     with jax.named_scope(EXPERTS):
-        rd = functools.partial(lax.ragged_dot, group_sizes=sizes,
-                               preferred_element_type=jnp.float32)
-        act = jax.nn.silu(rd(xs, cast(layer["gate"]))) * rd(xs, cast(layer["up"]))
-        ys = rd(cast(act), cast(layer["down"]))
+        act = (jax.nn.silu(grouped_matmul(xs, cast(layer["gate"]), sizes))
+               * grouped_matmul(xs, cast(layer["up"]), sizes))
+        ys = grouped_matmul(cast(act), cast(layer["down"]), sizes)
     with jax.named_scope(EXPERT_DISPATCH):
         back = jnp.take(ys, jnp.argsort(order), axis=0, unique_indices=True)
         h = h + (back.reshape(t, top_k, hid) * gates[..., None]).sum(axis=1)
@@ -344,7 +474,8 @@ def olmoe_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
     ``loss_sum``, ``correct``, ``count`` (tokens in the loss), ``tokens``
     (of any document), ``padding`` (tokens of segment 0), ``expert_load (E,)`` (real tokens given to each
     expert, summed over layers), ``fused_attention`` (positions whose
-    attention ran in the fused body: T or 0)."""
+    attention ran in the fused body: T or 0), ``grouped_experts`` (positions
+    whose expert matmuls ran in the tiled kernels: T or 0)."""
     tokens, segs = row[0], row[1]
     pos = segment_positions(segs)
     # the operands every layer's attention core is given: static shapes, so
@@ -353,6 +484,12 @@ def olmoe_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
     core = jax.ShapeDtypeStruct((t, heads, cfg.hidden_size // heads),
                                 compute_dtype)
     fused = fused_attention_applies(core, core, core)
+    rows, wide, narrow = (t * cfg.num_experts_per_tok, cfg.hidden_size,
+                          cfg.intermediate_size)
+    grouped = all(grouped_matmul_applies(
+        jax.ShapeDtypeStruct((rows, k), compute_dtype),
+        jax.ShapeDtypeStruct((cfg.num_experts, k, n), compute_dtype))
+        for k, n in ((wide, narrow), (narrow, wide)))
     with jax.named_scope(EMBED):
         h = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
 
@@ -369,7 +506,8 @@ def olmoe_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
             "tokens": (segs > 0).sum().astype(jnp.float32),
             "padding": (segs == 0).sum().astype(jnp.float32),
             "expert_load": loads.sum(axis=0),
-            "fused_attention": jnp.float32(t if fused else 0)}
+            "fused_attention": jnp.float32(t if fused else 0),
+            "grouped_experts": jnp.float32(t if grouped else 0)}
 
 
 def olmoe_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
@@ -381,7 +519,8 @@ def olmoe_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
         stats = olmoe_sequence_stats(params, row * m.astype(row.dtype), cfg,
                                      compute_dtype)
         return {**stats, "padding": stats["padding"] * m,
-                "fused_attention": stats["fused_attention"] * m}
+                "fused_attention": stats["fused_attention"] * m,
+                "grouped_experts": stats["grouped_experts"] * m}
 
     if x.shape[0] == 1:
         return one((x[0], mask[0]))

@@ -75,7 +75,8 @@ LAYERS = ("embed", "attention", "router", "expert_dispatch", "experts",
 # its own, which replaces the ``op_name`` and with it every scope: whose they
 # are, by the prefix of the instruction's name. ``lax.ragged_dot`` becomes
 # ``ragged-dot-none*`` (and one ``ragged-dot-metadata`` a call), and the only
-# grouped matmuls of a program are its experts'.
+# grouped matmuls of a program are its experts'. (The Pallas body of
+# ``olmoe.grouped_matmul`` needs no entry: a Mosaic call keeps its op_name.)
 LAYER_KERNELS = {"ragged-dot": "experts"}
 
 

@@ -105,6 +105,7 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
             "lm_tokens": s["count"],
             "lm_padding_tokens": s["padding"],
             "lm_fused_attention_positions": s["fused_attention"],
+            "moe_grouped_kernel_positions": s["grouped_experts"],
             "moe_expert_load": s["expert_load"],
         }
 

@@ -136,11 +136,13 @@ def test_convnet_training_pass_keeps_one_full_resolution_copy(topo):
 
 @pytest.fixture(scope="module")
 def olmoe_round(topo):
-    """``compiled(fused)``: the shared-global round of the one-layer OLMoE
+    """``compiled(kernels)``: the shared-global round of the one-layer OLMoE
     preset (625.6M parameters, 8 clients, 16 packed 4,096-token sequences,
-    FedAvgM) compiled for one described v5e chip, once for each attention
-    body. The rule between the bodies sees the CPU here and would pick the
-    XLA body, so the fused case steers the rule itself."""
+    FedAvgM) compiled for one described v5e chip, once with the XLA bodies
+    of the attention core and of the expert matmuls and once with their
+    Pallas bodies, as the chip picks them. The rules between the bodies see
+    the CPU here and would pick the XLA bodies, so both cases steer the
+    rules themselves."""
     from fedtpu.config import get_preset
     from fedtpu.models import olmoe
     from fedtpu.models.registry import build_model
@@ -172,6 +174,8 @@ def olmoe_round(topo):
             with pytest.MonkeyPatch.context() as patch:
                 patch.setattr(olmoe, "fused_attention_applies",
                               lambda q, k, v: fused)
+                patch.setattr(olmoe, "grouped_matmul_applies",
+                              lambda xs, w: fused)
                 step = build_stateless_round_fn(
                     mesh, build_task(cfg.model, stats_fn, cfg.model.vocab_size),
                     [1, 1, 2, 2, 2, 2, 3, 3],
@@ -193,6 +197,16 @@ def _mosaic_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _pallas_calls(compiled, scope: str) -> list:
+    """The names of the compiled module's Pallas kernels under the named
+    scope, one a call (the compiler's own ``ragged-dot-none`` kernels are
+    ``tpu_custom_call``s too, and name no scope)."""
+    return re.findall(
+        r'custom_call_target="tpu_custom_call".*op_name="[^"]*/%s/'
+        r'(?:[^"/]*/)*?jit\((\w+)\)/[^"]*pallas_call"' % scope,
+        compiled.as_text())
+
+
 def test_the_olmoe_round_at_published_widths_fits_one_v5e_chip(olmoe_round):
     """With the XLA attention body (what this compile picks by itself, and
     what the chip ran before PR 26) the round's account (arguments + outputs
@@ -207,16 +221,61 @@ def test_the_olmoe_round_at_published_widths_fits_one_v5e_chip(olmoe_round):
 
 
 def test_the_olmoe_round_with_fused_attention_drops_the_scores(olmoe_round):
-    """Steered to the fused body, as the chip picks it, the same round holds
-    the three attention kernels and no float32 16 x 4096^2 array: 12.95 GB
-    when this was written, 0.86 under the XLA body's; 12.98 and 0.67 under
-    since the head's own differentiation rule (PR 28), with which the XLA
-    body's round peaks 0.15 lower."""
+    """Steered to the Pallas bodies, as the chip picks them, the same round
+    holds the three attention kernels and no float32 16 x 4096^2 array:
+    12.95 GB when this was written, 0.86 under the XLA bodies'; 12.98 and
+    0.67 under since the head's own differentiation rule (PR 28); 12.80 and
+    0.85 under since the grouped expert kernels (PR 29: no transposed copy
+    of an expert weight, gradients of the expert matmuls in bf16)."""
     fused, xla = olmoe_round(True), olmoe_round(False)
     assert _account(fused) <= 13.1e9, _account(fused)
     assert _account(fused) <= _account(xla) - 0.6e9
     assert fused.memory_analysis().alias_size_in_bytes >= 5.0e9
-    assert _mosaic_calls(fused) >= _mosaic_calls(xla) + 3
+    assert sorted(_pallas_calls(fused, "attention")) == [
+        "flash_attention"] * 3 and not _pallas_calls(xla, "attention")
+
+
+def test_the_olmoe_round_runs_its_experts_in_the_grouped_kernels(olmoe_round):
+    """What says the Pallas body of the expert matmuls engaged: a layer's
+    three grouped matmuls, their three input gradients (the same kernel on
+    the weight in place) and their three weight gradients are nine Mosaic
+    calls under the experts' scope, and the compiler's own grouped kernel
+    (``ragged-dot-none``) is nowhere; with the XLA body it is the other way
+    round."""
+    pallas, xla = olmoe_round(True), olmoe_round(False)
+    assert sorted(_pallas_calls(pallas, "experts")) == ["gmm"] * 6 + ["tgmm"] * 3
+    assert "ragged-dot" not in pallas.as_text()
+    assert not _pallas_calls(xla, "experts")
+    assert xla.as_text().count(" custom-call(") and "ragged-dot-none" in xla.as_text()
+
+
+@pytest.mark.parametrize("rows,width,kernels", [
+    (32768, 1024, True), (32768 + 128, 1024, False), (32768, 1408, False)])
+def test_the_grouped_matmul_picks_its_body_by_shape_on_a_tpu(
+        topo, monkeypatch, rows, width, kernels):
+    """The rule itself, told only that the backend is a TPU: at the
+    benchmark's shapes a grouped matmul and its gradients compile to the
+    three kernels and no ``ragged-dot``; at rows that are no whole tile, or
+    a width there is no tile for, to ``ragged-dot`` and no such kernel."""
+    from fedtpu.models import olmoe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def with_gradients(xs, w, c, sizes):
+        return jax.value_and_grad(
+            lambda xs, w: (olmoe.grouped_matmul(xs, w, sizes) * c).sum(),
+            argnums=(0, 1))(xs, w)
+
+    compiled = jax.jit(with_gradients).lower(  # fedtpu: noqa[FTP006] one-shot AOT compile
+        sds((rows, 2048), jnp.bfloat16), sds((64, 2048, width), jnp.bfloat16),
+        sds((rows, width), jnp.float32), sds((64,), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r'jit\((t?gmm)\)+/pallas_call', text)
+    assert sorted(set(calls)) == (["gmm", "tgmm"] if kernels else [])
+    assert len(calls) == (3 if kernels else 0)
+    assert ("ragged-dot" in text) is not kernels
 
 
 def _convolutions_over(text: str, dim: int) -> list:

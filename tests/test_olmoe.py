@@ -26,6 +26,13 @@ FUSED = ModelConfig(kind="olmoe", hidden_size=FUSED_HEADS * FUSED_D,
                     num_attention_heads=FUSED_HEADS, num_hidden_layers=1,
                     num_experts=8, num_experts_per_tok=2,
                     intermediate_size=16, vocab_size=64)
+# The size at which the grouped expert kernels exist (lane-wide widths, whole
+# row tiles): top-2 of 8 experts over four row tiles of assignments.
+GROUPED_T = 2 * olmoe.GROUPED_ROW_TILE
+GROUPED = ModelConfig(kind="olmoe", hidden_size=128, num_attention_heads=4,
+                      num_hidden_layers=1, num_experts=8,
+                      num_experts_per_tok=2, intermediate_size=128,
+                      vocab_size=64)
 REF_CFG = {k: getattr(TINY, k) for k in
            ("num_attention_heads", "num_experts_per_tok", "rope_theta",
             "rms_norm_eps", "norm_topk_prob")}
@@ -89,14 +96,46 @@ def _gap(a, b):
 # but its input passed bf16 attention). This seed has no such flip and sits
 # at 8e-5 / 3.6e-2 (largest gradient entry 1.1): 3e-2 / 6e-2 hold bf16 and
 # fail a path that drops a term or computes in 8 bits.
+#
+# The grouped cases run the Pallas body of the expert matmuls (the library's
+# kernels, interpreted) at a size it has tiles for, 512 tokens, jitted. In
+# float32 the reference holds it as it holds the XLA body (over three seeds
+# 4.8e-7 on the loss, 3.1e-7 to 4.5e-7 on gradient entries up to 0.42). In
+# bfloat16 at that length some router choice always flips against the
+# reference (either body: 0.11 to 0.21 on the gradients), so the XLA body at
+# the same size is what is held to: nothing before the experts differs, the
+# forward kernels give the XLA body's sums (0 to 1.9e-6 on the loss) and the
+# gradients differ by the cotangent's rounding to bf16, 1.8e-3 to 1.9e-3 on
+# entries up to 0.42 (the experts' own up to 0.014): 1e-4 / 6e-3 hold that
+# and fail a dropped tile or group, which moves entries by their own size.
+@pytest.mark.parametrize("experts", ["xla", "grouped"])
 @pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
     (jnp.float32, 1e-5, 1e-5), (jnp.bfloat16, 3e-2, 6e-2)])
-def test_loss_and_gradients_match_the_reference(dtype, loss_tol, grad_tol):
-    p, row = _params(), jnp.asarray(_row(3))
-    (loss, stats), g = jax.value_and_grad(_sys_loss(TINY, dtype), has_aux=True)(p, row)
-    (ref, (ref_sum, ref_count)), rg = jax.value_and_grad(_ref_loss, has_aux=True)(p, row)
-    assert float(stats["count"]) == float(ref_count) == 12 + 14 - 2
-    assert float(stats["padding"]) == T - 26
+def test_loss_and_gradients_match_the_reference(dtype, loss_tol, grad_tol,
+                                                experts, request):
+    cfg, t, docs = TINY, T, (12, 14)
+    if experts == "grouped":
+        cfg, t = GROUPED, GROUPED_T
+        docs = (3 * t // 8, t // 2)
+    p, row = _params(cfg), jnp.asarray(_row(3, docs, t))
+    # traced when first called: before the fixture with the XLA body of the
+    # experts, after it with the Pallas body
+    system = lambda: jax.jit(jax.value_and_grad(_sys_loss(cfg, dtype), has_aux=True))
+    against_xla = experts == "grouped" and dtype == jnp.bfloat16
+    if against_xla:
+        reference, (loss_tol, grad_tol) = system(), (1e-4, 6e-3)
+    else:
+        reference = jax.jit(jax.value_and_grad(_ref_loss, has_aux=True))
+    (ref, ref_aux), rg = reference(p, row)
+    if experts == "grouped":
+        request.getfixturevalue("grouped_on_the_cpu")
+    with_gradients = system()
+    (loss, stats), g = with_gradients(p, row)
+    assert float(stats["count"]) == sum(docs) - 2
+    if not against_xla:
+        assert float(ref_aux[1]) == sum(docs) - 2
+    assert float(stats["padding"]) == t - sum(docs)
+    assert float(stats["grouped_experts"]) == (t if experts == "grouped" else 0)
     assert abs(float(loss) - float(ref)) <= loss_tol
     assert _gap(g, rg) <= grad_tol
     assert float(jnp.max(jnp.abs(rg["layers"]["gate"]))) > 1e-4   # experts do train
@@ -115,8 +154,13 @@ def test_top_k_sets_are_the_references_in_float32():
     assert float(gates.sum(-1).max()) < 1.0         # not renormalised
 
 
-def test_dropless_under_a_skew_over_four_times_the_mean():
-    cfg = dataclasses.replace(TINY, num_experts=16)
+# The grouped case is the same skew through the Pallas body at its own size:
+# one group holds half of the assignments (every token's first choice) and
+# spans two row tiles whole, and experts nobody chose are empty groups.
+@pytest.mark.parametrize("experts", ["xla", "grouped"])
+def test_dropless_under_a_skew_over_four_times_the_mean(experts, request):
+    base, t = (GROUPED, GROUPED_T) if experts == "grouped" else (TINY, T)
+    cfg = dataclasses.replace(base, num_experts=16)
     p = _params(cfg)
     # every token prefers expert 3: hidden states made positive (positive
     # embeddings, no attention output, unit gain) meet a positive column
@@ -124,15 +168,19 @@ def test_dropless_under_a_skew_over_four_times_the_mean():
     p["layers"]["o"] = jnp.zeros_like(p["layers"]["o"])
     p["layers"]["mlp_norm"] = jnp.ones_like(p["layers"]["mlp_norm"])
     p["layers"]["router"] = p["layers"]["router"].at[0, :, 3].set(0.5)
-    row = jnp.asarray(_row(7, docs=(T,)))
-    (loss, stats), g = jax.value_and_grad(_sys_loss(cfg, jnp.float32), has_aux=True)(p, row)
+    row = jnp.asarray(_row(7, docs=(t,), t=t))
+    reference = jax.jit(jax.value_and_grad(_ref_loss, has_aux=True))
+    (ref, _), rg = reference(p, row)
+    if experts == "grouped":
+        request.getfixturevalue("grouped_on_the_cpu")
+    system = jax.jit(jax.value_and_grad(_sys_loss(cfg, jnp.float32), has_aux=True))
+    (loss, stats), g = system(p, row)
     load = np.asarray(stats["expert_load"])
     routed = int(load.sum())
-    assert load[3] == T and load[3] > 4 * load.mean()   # 8x the mean here
-    assert routed == cfg.num_experts_per_tok * T    # every assignment computed
-    dropped = cfg.num_experts_per_tok * int(T - stats["padding"]) - routed
+    assert load[3] == t and load[3] > 4 * load.mean()   # 8x the mean here
+    assert routed == cfg.num_experts_per_tok * t    # every assignment computed
+    dropped = cfg.num_experts_per_tok * int(t - stats["padding"]) - routed
     assert dropped == 0
-    (ref, _), rg = jax.value_and_grad(_ref_loss, has_aux=True)(p, row)
     assert abs(float(loss) - float(ref)) <= 1e-5 and _gap(g, rg) <= 1e-5
 
 
@@ -149,6 +197,16 @@ def fused_on_the_cpu(monkeypatch):
     the bodies is steered to it and the kernel interpreted (always under
     jit: the interpreter is not for eager use)."""
     monkeypatch.setattr(olmoe, "fused_attention_applies", lambda q, k, v: True)
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+@pytest.fixture
+def grouped_on_the_cpu(monkeypatch):
+    """The whole model through the Pallas body of the expert matmuls: the
+    rule between the bodies is steered to it and the kernels interpreted
+    (always under jit)."""
+    monkeypatch.setattr(olmoe, "grouped_matmul_applies", lambda xs, w: True)
     with pltpu.force_tpu_interpret_mode():
         yield
 
@@ -187,23 +245,37 @@ def test_two_packed_documents_give_what_the_two_alone_give(body, request):
     assert [float(x["count"]) for x in sa] == [a - 1.0, b - 1.0]
 
 
-def test_depth_two_scanned_is_two_blocks_by_hand():
-    cfg = dataclasses.replace(TINY, num_hidden_layers=2)
-    p, row = _params(cfg), jnp.asarray(_row(13))
-    got = olmoe.olmoe_sequence_stats(p, row, cfg)
-    tokens, segs = row
-    pos = olmoe.segment_positions(segs)
-    h = p["embed"][tokens]
-    for i in range(2):
-        h, _ = olmoe._block(cfg, jnp.float32, h,
-                            jax.tree.map(lambda a: a[i], p["layers"]), segs, pos)
-    labels, valid = olmoe.next_token_targets(tokens, segs)
-    want, _ = olmoe._head_loss(olmoe.rms_norm(h, p["final_norm"], cfg.rms_norm_eps),
-                               p["head"], labels, valid, jnp.float32)
-    assert abs(float(got["loss_sum"]) - float(want)) <= 1e-5
+@pytest.mark.parametrize("experts", ["xla", "grouped"])
+def test_depth_two_scanned_is_two_blocks_by_hand(experts, request):
+    base, t = (GROUPED, GROUPED_T) if experts == "grouped" else (TINY, T)
+    cfg = dataclasses.replace(base, num_hidden_layers=2)
+    p, row = _params(cfg), jnp.asarray(_row(13, (3 * t // 8, t // 2), t))
+    reference = jax.jit(lambda p, row: reference_lm.sequence_loss(p, row, REF_CFG))
     with jax.default_matmul_precision("highest"):
-        ref, _ = reference_lm.sequence_loss(p, row, REF_CFG)
-    assert abs(float(got["loss_sum"]) - float(ref)) <= 1e-4
+        ref, _ = reference(p, row)
+    if experts == "grouped":
+        request.getfixturevalue("grouped_on_the_cpu")
+    scanned = jax.jit(lambda p, row: olmoe.olmoe_sequence_stats(p, row, cfg))
+    got = scanned(p, row)
+
+    @jax.jit
+    def by_hand(p, row):
+        tokens, segs = row
+        pos = olmoe.segment_positions(segs)
+        h = p["embed"][tokens]
+        for i in range(2):
+            h, _ = olmoe._block(cfg, jnp.float32, h,
+                                jax.tree.map(lambda a: a[i], p["layers"]),
+                                segs, pos)
+        labels, valid = olmoe.next_token_targets(tokens, segs)
+        return olmoe._head_loss(
+            olmoe.rms_norm(h, p["final_norm"], cfg.rms_norm_eps), p["head"],
+            labels, valid, jnp.float32)[0]
+
+    want = by_hand(p, row)
+    # sums over the sequence: 32 tokens' to 1e-5 and 1e-4, 512 in proportion
+    assert abs(float(got["loss_sum"]) - float(want)) <= 1e-5 * t / T
+    assert abs(float(got["loss_sum"]) - float(ref)) <= 1e-4 * t / T
 
 
 # The head's own differentiation rule (olmoe._head_loss) against plain
@@ -395,7 +467,7 @@ def test_a_row_of_padding_alone_is_finite_and_moves_nothing_when_fused(
     ("tpu", (4096, 16, 128), (4096, 16, 128), True),
     ("tpu", (32, 4, 8), (32, 4, 8), False),                 # the tests' heads
     ("tpu", (4096 + 128, 16, 128), (4096 + 128, 16, 128), False),
-    ("tpu", (4096, 16, 192), (4096, 16, 128), False),       # Moonlight's
+    ("tpu", (4096, 16, 192), (4096, 16, 128), False),       # q, k wider than v
     ("cpu", (4096, 16, 128), (4096, 16, 128), False)])
 def test_the_rule_between_the_attention_bodies(monkeypatch, backend, q, v, fused):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
@@ -412,3 +484,91 @@ def test_the_rule_between_the_attention_bodies(monkeypatch, backend, q, v, fused
         sds(v), jax.ShapeDtypeStruct(q[:1], jnp.int32))
     assert ran == ["_fused_attention" if fused else "_xla_attention"]
     assert ctx.shape == q[:2] + v[2:] and ctx.dtype == jnp.float32
+
+
+# The Pallas body of the expert matmuls (the library's grouped kernels,
+# interpreted on the CPU, always under jit) against ``lax.ragged_dot``, which
+# defines what is computed: values and both gradients, over four row tiles
+# and eight groups.
+ROWS = 4 * olmoe.GROUPED_ROW_TILE
+GROUP_SIZES = {
+    # an empty first, middle and last group; one under a row tile; one that
+    # starts 40 rows into the first tile, spans three and straddles two of
+    # their edges; rows past the last group, which are zero
+    "uneven": lambda tile: [0, 40, 2 * tile + 88, 100, 0, 200, 50, 0],
+    # the same kinds of group, and no row past the last
+    "exact": lambda tile: [tile // 2, 0, tile, tile + tile // 2, 6, 100, 22,
+                           tile - 128],
+    # every row in one group
+    "one_group": lambda tile: [0, 0, 0, 4 * tile, 0, 0, 0, 0],
+}
+
+
+def _matmul_and_gradients(body, sizes):
+    def f(xs, w, c):
+        out, back = jax.vjp(lambda xs, w: body(xs, w, sizes), xs, w)
+        return (out, *back(c))
+    return jax.jit(f)
+
+
+# float32: the same sums in tiles (measured 5e-7 of the largest entry on the
+# values, 6e-7 on either gradient): 1e-5 of the largest entry. bfloat16: the
+# forward kernels give the XLA body's float32 sums (1e-6 of the largest
+# entry); the gradients come back in bf16 from a cotangent rounded to bf16,
+# where the XLA body on the CPU multiplies the float32 one: one unit in the
+# last place of the largest entries (0.25 on 60, 0.5 on 79), so 2**-7 of the
+# largest. A dropped tile or a row given to the wrong group moves entries by
+# their own size.
+@pytest.mark.parametrize("groups", sorted(GROUP_SIZES))
+@pytest.mark.parametrize("dtype,out_tol,grad_tol", [
+    (jnp.float32, 1e-5, 1e-5), (jnp.bfloat16, 1e-5, 2.0 ** -7)])
+def test_the_pallas_grouped_matmul_is_ragged_dot(dtype, out_tol, grad_tol, groups):
+    sizes = np.asarray(GROUP_SIZES[groups](olmoe.GROUPED_ROW_TILE), np.int32)
+    total, k, n = int(sizes.sum()), 256, 128
+    assert (total == ROWS) == (groups != "uneven") and total <= ROWS
+    keys = jax.random.split(jax.random.key(8), 3)
+    xs = jax.random.normal(keys[0], (ROWS, k)).astype(dtype)
+    w = jax.random.normal(keys[1], (len(sizes), k, n)).astype(dtype)
+    c = jax.random.normal(keys[2], (ROWS, n))
+    with pltpu.force_tpu_interpret_mode():
+        got = _matmul_and_gradients(olmoe._pallas_grouped_matmul,
+                                    jnp.asarray(sizes))(xs, w, c)
+    want = _matmul_and_gradients(olmoe._xla_grouped_matmul,
+                                 jnp.asarray(sizes))(xs, w, c)
+    for a, b, tol in zip(got, want, (out_tol, grad_tol, grad_tol)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        b = b.astype(jnp.float32)
+        largest = float(jnp.max(jnp.abs(b)))
+        assert largest > 10.0
+        assert float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))) <= tol * largest
+    out, dxs, dw = got
+    assert out.dtype == jnp.float32 and dxs.dtype == dw.dtype == dtype
+    # rows past the last group, and the weights of a group with no row
+    assert bool(jnp.all(out[total:] == 0.0)) and bool(jnp.all(dxs[total:] == 0.0))
+    assert bool(jnp.all(dw[sizes == 0] == 0.0)) and int((sizes == 0).sum()) >= 1
+
+
+@pytest.mark.parametrize("backend,xs,w,dtype,pallas", [
+    ("tpu", (32768, 2048), (64, 2048, 1024), jnp.bfloat16, True),
+    ("tpu", (32768, 1024), (64, 1024, 2048), jnp.bfloat16, True),
+    ("tpu", (64, 32), (8, 32, 16), jnp.bfloat16, False),     # the tests' widths
+    ("tpu", (32768 + 128, 2048), (64, 2048, 1024), jnp.bfloat16, False),
+    ("tpu", (32768, 2048), (64, 2048, 1408), jnp.bfloat16, False),
+    ("tpu", (32768, 4096), (64, 4096, 1024), jnp.bfloat16, False),
+    ("tpu", (32768, 2048), (64, 2048, 1024), jnp.float32, False),
+    ("cpu", (32768, 2048), (64, 2048, 1024), jnp.bfloat16, False)])
+def test_the_rule_between_the_grouped_matmul_bodies(monkeypatch, backend, xs,
+                                                    w, dtype, pallas):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    sds = lambda shape, dt=dtype: jax.ShapeDtypeStruct(shape, dt)
+    assert olmoe.grouped_matmul_applies(sds(xs), sds(w)) is pallas
+    ran = []
+    for name in ("_pallas_grouped_matmul", "_xla_grouped_matmul"):
+        body = getattr(olmoe, name)
+        monkeypatch.setattr(olmoe, name, lambda *a, _name=name, _body=body: (
+            ran.append(_name), _body(*a))[1])
+    # either body traces at these shapes, and gives (rows, N) float32
+    out = jax.eval_shape(lambda *a: olmoe.grouped_matmul(*a), sds(xs), sds(w),
+                         sds(w[:1], jnp.int32))
+    assert ran == ["_pallas_grouped_matmul" if pallas else "_xla_grouped_matmul"]
+    assert out.shape == (xs[0], w[2]) and out.dtype == jnp.float32

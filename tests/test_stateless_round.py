@@ -92,6 +92,9 @@ def test_two_rounds_of_tiny_olmoe_match_the_references_fedavgm(tmp_path):
     assert counters["counters"]["moe_tokens_dropped"] == 0
     assert counters["counters"]["moe_tokens_routed"] == 2 * 2 * tokens
     assert counters["counters"]["lm_padding_tokens"] == 2 * (10 * 48 - tokens)
+    # the CPU and these widths: the XLA bodies of attention and experts ran
+    assert counters["counters"]["lm_fused_attention_positions"] == 0
+    assert counters["counters"]["moe_grouped_kernel_positions"] == 0
     assert counters["gauges"]["moe_expert_load_max_over_mean"] > 1.0
     load = [e["payload"]["moe_expert_load"] for e in events if e["kind"] == "round"]
     assert len(load) == 2 and sum(load[0]) == 2 * tokens
