@@ -925,7 +925,11 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
         overlap_exec.submit(overlap_key, _build_wide,
                             label=f"round[w={overlap_chunk}]")
 
-    if tel.manifest:
+    # Only where a sink will hold it: the audit and the cost model below
+    # trace the round program twice more, seconds of every job's start for
+    # a program of the language model's size, and the NullTracer drops the
+    # event.
+    if tel.manifest and tracer.enabled:
         manifest_extra = {"program": "run",
                           "engine": ("stateless"
                                      if cfg.fed.client_state == "stateless"

@@ -10,10 +10,16 @@ round}`` with no clients axis, and runs the round as its stages:
     client step   for each client in turn: start from the global, run one
                   epoch of local SGD steps over its rows (``local_batch_rows``
                   a step; 0 = the whole shard in one), no step on a padded
-                  row: a ``while`` over the client's own number of steps
-    combine       add ``w_c (p_c - global)`` to one accumulator (``w_c`` the
-                  task's data-size weight, or 1); across a mesh the clients
-                  axis is sharded and the accumulator is ``psum``med once
+                  row: ``while``s over the client's own number of steps. The
+                  first step reads the global itself; a working copy is
+                  written only by a step that another follows
+    combine       as the steps are taken: the pass that applies a gradient
+                  ``d`` also adds the step's share of the client's delta,
+                  ``-(w_c / W) lr d``, to one accumulator (``w_c`` the task's
+                  data-size weight, or 1), since ``p_c - global`` is the sum
+                  of the steps (for a leaf narrower than float32, which
+                  rounds, the step as taken); across a mesh the clients axis
+                  is sharded and the accumulator is ``psum``med once
     server apply  the server optimizer (fedtpu.ops.server_opt) on the mean
                   delta; FedAvgM accumulates straight into its momentum
                   buffer, so no separate accumulator exists
@@ -23,7 +29,10 @@ round}`` with no clients axis, and runs the round as its stages:
 A client is stateless: plain SGD (momentum 0) at the round's learning rate
 (StepLR stepped once a round, as the reference steps it), nothing carried to
 the next round. The task (fedtpu.training.task) is an argument: the MLP and
-the ConvNet run through here as the language model does.
+the ConvNet run through here as the language model does. Two counters of
+the engine's own go out beside the task's (``metrics["counters"]``):
+``stateless_client_steps`` and ``stateless_working_copy_writes``, counted in
+the loops' carries where a step is taken and where the copy is written.
 """
 
 from __future__ import annotations
@@ -131,8 +140,8 @@ def build_stateless_round_fn(mesh, task: Task, counts, *,
     clients). ``counts (C,)`` are the clients' true numbers of rows (host
     integers: they fix how many steps each client's epoch has). The state is
     donated. ``metrics`` are the resident engines' (``loss (C,)``,
-    ``per_client``, ``client_mean``, ``pooled``), plus the task's
-    ``counters`` where it has them."""
+    ``per_client``, ``client_mean``, ``pooled``), and ``counters``: the
+    task's where it has them, and the engine's two."""
     if server_opt is None:
         server_opt = identity_server_optimizer()
     if weighting not in ("data_size", "uniform"):
@@ -142,6 +151,12 @@ def build_stateless_round_fn(mesh, task: Task, counts, *,
     if len(counts) % n_devices:
         raise ValueError(f"{len(counts)} clients do not divide over "
                          f"{n_devices} devices")
+    # each client's number of steps, on the host: the device's loops run
+    # over them, and the program holds only the kinds of step some client has
+    steps = (np.ceil(counts / local_batch_rows) if local_batch_rows
+             else counts > 0).astype(np.int64)
+    single, further, between = (bool(found.any()) for found in (
+        steps == 1, steps > 1, steps > 2))
 
     def round_body(g, sstate, x, y, mask, nsteps, rnd):
         rows = x.shape[1]
@@ -164,29 +179,100 @@ def build_stateless_round_fn(mesh, task: Task, counts, *,
             lr = learning_rate * steplr_gamma ** jnp.floor(
                 r.astype(jnp.float32) / steplr_step_size)
 
-            def client(acc, inputs):
-                xc, yc, mc, n, wc, uc = inputs
+            def sgd_pass(at, acc, grads, scale):
+                """One pass over a step's gradient ``d`` at the parameters
+                ``at``: the parameters after the step, and the accumulator
+                with the step's share of the client's delta, both from one
+                read of ``d``. A step that drops the first writes only the
+                second."""
 
-                def step(i, carry):
-                    p, loss_sum, stats = carry
+                def leaf(a, m, d):
+                    step = lr * d                   # float32, whatever d is
+                    if a.dtype == jnp.float32:
+                        new, m = a - step, m - scale * step
+                    else:
+                        # a narrower leaf rounds at every step: the client's
+                        # delta is what it holds, not what its gradients sum
+                        # to, so the step is added as taken. Rounded by
+                        # ``reduce_precision``: a cast and back is the
+                        # compiler's to skip, and it does
+                        fi = jnp.finfo(a.dtype)
+                        rounded = lambda t: jax.lax.reduce_precision(
+                            t, fi.nexp, fi.nmant)
+                        old = a.astype(jnp.float32)
+                        new = rounded(old - rounded(step))
+                        m = m + scale * (new - old)
+                        new = new.astype(a.dtype)
+                    return new, m
+
+                return jax.tree.transpose(
+                    jax.tree.structure(at), None,
+                    jax.tree.map(leaf, at, acc, grads))
+
+            def client(carry, inputs):
+                acc, p, taken, written = carry
+                xc, yc, mc, n, wc, uc = inputs
+                scale = wc / total_w
+                # The global as THIS client reads it. The weights' bf16 casts
+                # are hoisted out of every loop that does not write what they
+                # read; tied to the client's own step count they stop here,
+                # once a client, and no bf16 copy of the global (1.05 GB of
+                # the language model's) lives across clients.
+                gc, n = jax.lax.optimization_barrier((g, n))
+
+                def step(i, carry, first: bool, keep: bool):
+                    """One kind of SGD step, a loop's body: from the global
+                    (a client's ``first``) or from its working copy, which
+                    is written only where ``keep``."""
+                    p, acc, loss_sum, stats, taken, written = carry
+                    if not first and not keep:
+                        # read and handed on untouched, the copy would be
+                        # invariant in this loop, and its weights' bf16 casts
+                        # hoisted to where they run whether the loop makes
+                        # its trip or not (a one-step client's, for nothing):
+                        # tied to the step
+                        p, i = jax.lax.optimization_barrier((p, i))
+                    at = gc if first else p
                     take = lambda a: jax.lax.dynamic_slice_in_dim(a, i * b, b)
                     xb, yb, mb = take(xc), take(yc), take(mc)
                     (loss, s), grads = jax.value_and_grad(
-                        task.loss, has_aux=True)(p, xb, yb, mb)
-                    p = jax.tree.map(lambda a, d: a - (lr * d).astype(a.dtype),
-                                     p, grads)
-                    return (p, loss_sum + loss * task.weight(xb, yb, mb),
-                            jax.tree.map(jnp.add, stats, s))
+                        task.loss, has_aux=True)(at, xb, yb, mb)
+                    new, acc = sgd_pass(at, acc, grads, scale)
+                    if keep:
+                        p, written = new, written + 1
+                    return (p, acc, loss_sum + loss * task.weight(xb, yb, mb),
+                            jax.tree.map(jnp.add, stats, s), taken + 1,
+                            written)
 
-                # the client's own number of steps: no step on a padded row
-                p, loss_sum, stats = jax.lax.fori_loop(
-                    0, n, step, (g, jnp.float32(0.0), stats0))
-                with jax.named_scope(AGGREGATE):
-                    acc = jax.tree.map(
-                        lambda a, pc, gl: a + (wc / total_w) * (
-                            pc.astype(jnp.float32) - gl.astype(jnp.float32)),
-                        acc, p, g)
-                return acc, (loss_sum / jnp.maximum(uc, 1.0), stats)
+                # The client's own number of steps: none on a padded row, and
+                # a client with no rows changes nothing. Each kind of step has
+                # a loop of its own, its trips read from ``n``, because what a
+                # step writes is settled where its gradient is made: the pass
+                # fuses with the backward pass's last operations (inside a
+                # ``cond`` or a loop of its own it would read a gradient
+                # written out in float32 first). A ``while`` updates its carry
+                # in place and hands it on untouched when it makes no trip, so
+                # no kind costs a copy. A kind is a whole trace of the model
+                # (a quarter of the language model's executable), so the
+                # program holds only the kinds some client's count calls for.
+                only, last = ((n == 1).astype(jnp.int32),
+                              (n > 1).astype(jnp.int32))
+                kinds = []
+                if single:
+                    kinds.append((0, only, True, False))    # a client's only
+                if further:
+                    kinds.append((0, last, True, True))     # first of several
+                if between:
+                    kinds.append((1, n - 1, False, True))
+                if further:
+                    kinds.append((n - last, n, False, False))   # their last
+                carry = (p, acc, jnp.float32(0.0), stats0, taken, written)
+                for lo, hi, first, keep in kinds:
+                    carry = jax.lax.fori_loop(
+                        lo, hi, partial(step, first=first, keep=keep), carry)
+                p, acc, loss_sum, stats, taken, written = carry
+                return ((acc, p, taken, written),
+                        (loss_sum / jnp.maximum(uc, 1.0), stats))
 
             with jax.named_scope(CLIENT_TRAIN):
                 if server_opt.begin is not None:
@@ -197,8 +283,14 @@ def build_stateless_round_fn(mesh, task: Task, counts, *,
                 else:
                     acc0 = jax.tree.map(
                         lambda a: jnp.zeros(a.shape, jnp.float32), g)
-                acc, (loss, stats) = jax.lax.scan(
-                    client, acc0, (x, y, mask, nsteps, w, units))
+                # one working copy for all clients, in the scan's carry: born
+                # at a client's first step, never read before; none where no
+                # client has a second step
+                zero = jnp.zeros((), jnp.int32)
+                p0 = jax.tree.map(jnp.zeros_like, g) if further else None
+                (acc, _, taken, written), (loss, stats) = jax.lax.scan(
+                    client, (acc0, p0, zero, zero),
+                    (x, y, mask, nsteps, w, units))
             with jax.named_scope(AGGREGATE):
                 acc = jax.tree.map(lambda a: jax.lax.psum(a, CLIENTS_AXIS),
                                    acc)
@@ -211,7 +303,10 @@ def build_stateless_round_fn(mesh, task: Task, counts, *,
                                      g, step_)
                 pooled = jax.tree.map(
                     lambda s: jax.lax.psum(s.sum(axis=0), CLIENTS_AXIS), stats)
-            return (g, sstate, r + 1), (loss, stats, pooled)
+                counters = jax.lax.psum(
+                    {"stateless_client_steps": taken,
+                     "stateless_working_copy_writes": written}, CLIENTS_AXIS)
+            return (g, sstate, r + 1), (loss, stats, pooled, counters)
 
         (g, sstate, _), stacked = jax.lax.scan(
             one_round, (g, sstate, rnd), length=rounds_per_step)
@@ -221,7 +316,7 @@ def build_stateless_round_fn(mesh, task: Task, counts, *,
     sharded_body = jax.shard_map(
         round_body, mesh=mesh,
         in_specs=(P(), P(), spec_c, spec_c, spec_c, spec_c, P()),
-        out_specs=(P(), P(), spec_rc, spec_rc, P()),
+        out_specs=(P(), P(), spec_rc, spec_rc, P(), P()),
         # the model's own scans start their carries from constants, which
         # the varying-axes check would have every model annotate; what
         # leaves replicated (global, server state, pooled statistics) comes
@@ -230,15 +325,18 @@ def build_stateless_round_fn(mesh, task: Task, counts, *,
 
     @partial(jax.jit, donate_argnums=(0,))
     def round_step(state, batch):
-        rows = batch["x"].shape[1]
-        b = local_batch_rows or rows
-        nsteps = jnp.asarray(np.ceil(counts / b), jnp.int32)
-        g, sstate, loss, stats, pooled = sharded_body(
+        g, sstate, loss, stats, pooled, counters = sharded_body(
             state["params"], state["server_opt_state"], batch["x"],
-            batch["y"], batch["mask"], nsteps, state["round"])
+            batch["y"], batch["mask"], jnp.asarray(steps, jnp.int32),
+            state["round"])
         metrics = assemble_metrics(loss, stats, pooled, batch["mask"],
                                    rounds_per_step, task.metrics,
                                    task.counters)
+        # the engine's own counters go where the task's do
+        metrics["counters"] = {
+            **metrics.get("counters", {}),
+            **{k: v[0] if rounds_per_step == 1 else v
+               for k, v in counters.items()}}
         return ({"params": g, "server_opt_state": sstate,
                  "round": state["round"] + rounds_per_step}, metrics)
 

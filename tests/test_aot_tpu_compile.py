@@ -187,6 +187,13 @@ def olmoe_round(topo):
     return compiled
 
 
+# The shared-global round holds the model's forward and backward once a kind
+# of step the clients' step counts call for (a client's only step, the first
+# of several, one between, the last: fedtpu.parallel.stateless, PR 30), so
+# what a step holds the fixture's round holds four times.
+STEP_KINDS = 4
+
+
 def _account(compiled) -> int:
     ma = compiled.memory_analysis()
     return (ma.argument_size_in_bytes + ma.output_size_in_bytes
@@ -213,7 +220,7 @@ def test_the_olmoe_round_at_published_widths_fits_one_v5e_chip(olmoe_round):
     - aliased + temporaries) lies between 8 and 15.0 GB of the chip's 16:
     one global, the momentum that doubles as the delta accumulator, one
     client's copy, its gradient and a sequence's activations (13.80 GB,
-    PERF.md section 4)."""
+    PERF.md section 4; 14.39 with the four kinds of step of PR 30)."""
     xla = olmoe_round(False)
     assert 8e9 <= _account(xla) <= 15.0e9, _account(xla)
     # global and momentum in place
@@ -226,27 +233,58 @@ def test_the_olmoe_round_with_fused_attention_drops_the_scores(olmoe_round):
     12.95 GB when this was written, 0.86 under the XLA bodies'; 12.98 and
     0.67 under since the head's own differentiation rule (PR 28); 12.80 and
     0.85 under since the grouped expert kernels (PR 29: no transposed copy
-    of an expert weight, gradients of the expert matmuls in bf16)."""
+    of an expert weight, gradients of the expert matmuls in bf16); 11.73 and
+    2.66 under since each gradient goes straight into the accumulator
+    (PR 30)."""
     fused, xla = olmoe_round(True), olmoe_round(False)
     assert _account(fused) <= 13.1e9, _account(fused)
     assert _account(fused) <= _account(xla) - 0.6e9
     assert fused.memory_analysis().alias_size_in_bytes >= 5.0e9
     assert sorted(_pallas_calls(fused, "attention")) == [
-        "flash_attention"] * 3 and not _pallas_calls(xla, "attention")
+        "flash_attention"] * 3 * STEP_KINDS
+    assert not _pallas_calls(xla, "attention")
 
 
 def test_the_olmoe_round_runs_its_experts_in_the_grouped_kernels(olmoe_round):
     """What says the Pallas body of the expert matmuls engaged: a layer's
     three grouped matmuls, their three input gradients (the same kernel on
     the weight in place) and their three weight gradients are nine Mosaic
-    calls under the experts' scope, and the compiler's own grouped kernel
-    (``ragged-dot-none``) is nowhere; with the XLA body it is the other way
-    round."""
+    calls a kind of step under the experts' scope, and the compiler's own
+    grouped kernel (``ragged-dot-none``) is nowhere; with the XLA body it is
+    the other way round."""
     pallas, xla = olmoe_round(True), olmoe_round(False)
-    assert sorted(_pallas_calls(pallas, "experts")) == ["gmm"] * 6 + ["tgmm"] * 3
+    assert sorted(_pallas_calls(pallas, "experts")) == (
+        ["gmm"] * 6 * STEP_KINDS + ["tgmm"] * 3 * STEP_KINDS)
     assert "ragged-dot" not in pallas.as_text()
     assert not _pallas_calls(xla, "experts")
     assert xla.as_text().count(" custom-call(") and "ragged-dot-none" in xla.as_text()
+
+
+PARAMETER_SHAPE = r"(?:1,)?(?:64,2048,1024|64,1024,2048|50304,2048|2048,50304)"
+
+
+def test_the_olmoe_round_passes_over_the_parameters_once_a_step(olmoe_round):
+    """PR 30, on the step counts ``[1, 1, 2, 2, 2, 2, 3, 3]``: a client's
+    first step reads the global itself, so the compiled round copies no
+    float32 array of a large parameter's shape (the parent copied the
+    global into the steps' carry, two parameter sets moved a client). The
+    first step's weights are invariant in the clients' loop, and the
+    compiler would hoist their bf16 casts out of it, 1.05 GB alive all
+    round, were the global not tied to the client's own step count: no bf16
+    array of those shapes among the operands of the clients' ``while`` (the
+    one ``main`` calls), and the account stays under the parent's
+    12,802,246,144 + 1%."""
+    fused = olmoe_round(True)
+    text = fused.as_text()
+    copies = re.findall(
+        r"= f32\[%s\]\S* copy\(" % PARAMETER_SHAPE, text)
+    assert not copies, copies
+    entry = text[text.index("\nENTRY"):]
+    clients = re.findall(r"= \((.*?)\) while\(", entry)
+    assert len(clients) == 1                        # the scan over clients
+    carried = re.findall(r"(\w+)\[%s\]" % PARAMETER_SHAPE, clients[0])
+    assert carried and set(carried) == {"f32"}, carried
+    assert _account(fused) <= 12.93e9, _account(fused)
 
 
 @pytest.mark.parametrize("rows,width,kernels", [
@@ -299,14 +337,14 @@ def test_the_olmoe_round_multiplies_over_the_vocabulary_three_times_a_chunk(
         olmoe_round):
     """The head's own differentiation rule in the compiled round: the
     logits, ``dlogits w^T`` and ``h^T dlogits`` and no second logits matmul
-    (the checkpointed scan before PR 28 compiled to four), forward and
-    backward rule under the head's scope. The account it has to stay in is
-    the test's above."""
+    (the checkpointed scan before PR 28 compiled to four) a kind of step,
+    forward and backward rule under the head's scope. The account it has to
+    stay in is the test's above."""
     text = olmoe_round(True).as_text()
     over_vocab = _convolutions_over(text, 50304)
-    assert len(over_vocab) == 3, over_vocab
-    assert sorted(c[0] for c in over_vocab) == [
-        "f32[2048,50304]", "f32[512,2048]", "f32[512,50304]"]
+    assert len(over_vocab) == 3 * STEP_KINDS, over_vocab
+    assert sorted(c[0] for c in over_vocab) == sorted([
+        "f32[2048,50304]", "f32[512,2048]", "f32[512,50304]"] * STEP_KINDS)
     assert "/jvp(lm_head_loss)/" in text
     assert "/transpose(jvp(lm_head_loss))/" in text
 

@@ -112,34 +112,41 @@ def test_mlp_equals_the_resident_engines_server_opt_path():
     assert set(shared.global_metrics) == set(resident.global_metrics)
 
 
-def _by_hand(cfg, ds, batch_rows):
-    """Clients in turn, minibatches in order, plain SGD; FedAvgM."""
+def _by_hand(cfg, ds, batch_rows, mask=None):
+    """Clients in turn, minibatches in order, plain SGD; FedAvgM. The
+    parameters keep their own dtype from step to step, a client's delta is
+    what it holds at the end less the global, and the accumulator and the
+    momentum are float32. ``mask`` replaces the experiment's own."""
     from fedtpu.models.mlp import mlp_apply
     from fedtpu.ops.losses import masked_cross_entropy
+    f32 = lambda a: np.asarray(a, np.float32)
     exp = build_experiment(cfg, ds)
-    x, y, mask = (np.asarray(exp.batch[k]) for k in ("x", "y", "mask"))
+    x, y = (np.asarray(exp.batch[k]) for k in ("x", "y"))
+    mask = np.asarray(exp.batch["mask"] if mask is None else mask)
     g = jax.tree.map(np.asarray, exp.state["params"])
-    m = jax.tree.map(np.zeros_like, g)
+    m = jax.tree.map(lambda a: np.zeros(a.shape, np.float32), g)
     grad = jax.jit(jax.value_and_grad(
         lambda p, xb, yb, mb: masked_cross_entropy(mlp_apply(p, xb), yb, mb)))
     losses = []
     for r in range(cfg.fed.rounds):
-        lr = cfg.optim.learning_rate * cfg.optim.steplr_gamma ** (
-            r // cfg.optim.steplr_step_size)
-        acc, row = jax.tree.map(np.zeros_like, g), []
+        lr = np.float32(cfg.optim.learning_rate * cfg.optim.steplr_gamma ** (
+            r // cfg.optim.steplr_step_size))
+        acc, row = jax.tree.map(np.zeros_like, m), []
         for c in range(x.shape[0]):
             p, n, total = g, int(mask[c].sum()), 0.0
             for i in range(0, n, batch_rows):
                 sl = slice(i, i + batch_rows)
                 loss, d = grad(p, x[c, sl], y[c, sl], mask[c, sl])
                 total += float(loss) * float(mask[c, sl].sum())
-                p = jax.tree.map(lambda a, b: a - lr * b, p, d)
-            acc = jax.tree.map(lambda a, pc, gl: a + n * (pc - gl), acc, p, g)
-            row.append(total / n)
+                p = jax.tree.map(
+                    lambda a, b: a - (lr * f32(b)).astype(a.dtype), p, d)
+            acc = jax.tree.map(lambda a, pc, gl: a + n * (f32(pc) - f32(gl)),
+                               acc, p, g)
+            row.append(total / max(n, 1))
         m = jax.tree.map(lambda a, b: 0.9 * a + b / mask.sum(), m, acc)
-        g = jax.tree.map(np.add, g, m)
+        g = jax.tree.map(lambda a, b: a + b.astype(a.dtype), g, m)
         losses.append(row)
-    return np.asarray(losses), g
+    return np.asarray(losses), g, m
 
 
 def test_uneven_shards_in_minibatches_match_a_loop_by_hand():
@@ -149,9 +156,56 @@ def test_uneven_shards_in_minibatches_match_a_loop_by_hand():
     counts = np.asarray(exp.batch["mask"]).sum(axis=1)
     assert len(set(counts)) > 1 and counts.max() % 4      # uneven, ragged tail
     got = run_experiment(cfg, dataset=ds, verbose=False)
-    want_loss, want_params = _by_hand(cfg, ds, 4)
+    want_loss, want_params, _ = _by_hand(cfg, ds, 4)
     np.testing.assert_allclose(np.stack(got.loss), want_loss, atol=2e-6)
     assert _gap(got.final_params, want_params) <= 2e-6
+
+
+@pytest.mark.parametrize("steps,dtype", [
+    ((1, 1, 1), "float32"),              # no client ever has a working copy
+    ((3, 3, 3), "float32"),              # first, between, last
+    ((2, 0, 3), "float32"),              # an empty shard beside full ones
+    # rounds at every step: the delta is what the client holds (its
+    # gradients' sum, which a cast and back compiles to, is 1e-3 off)
+    ((1, 2, 3), "bfloat16"),
+], ids=["one-step", "three-steps", "an-empty-client", "bf16-parameters"])
+def test_each_step_goes_straight_into_the_accumulator(steps, dtype):
+    """The accumulation as the steps are taken, against the loop by hand
+    that forms ``w_c (p_c - global)`` at each client's end, and the engine's
+    two counters against the steps the shards have: a working copy a step
+    that another follows, none for a client of one."""
+    from fedtpu.ops.server_opt import make_server_optimizer
+    from fedtpu.parallel.stateless import build_stateless_round_fn
+    ds = income(rows=63)                # 13, 13, 14 rows, padded to 16
+    cfg = mlp_cfg("stateless", rows=4, clients=3, rounds=2)
+    cfg = cfg.replace(model=dataclasses.replace(cfg.model, param_dtype=dtype))
+    exp = build_experiment(cfg, ds)
+    rows = np.asarray([max(4 * n - 1, 0) for n in steps])     # a ragged tail
+    mask = (np.arange(16) < rows[:, None]).astype(np.float32)
+    step = build_stateless_round_fn(
+        exp.mesh, exp.task, rows, learning_rate=cfg.optim.learning_rate,
+        steplr_step_size=cfg.optim.steplr_step_size,
+        steplr_gamma=cfg.optim.steplr_gamma,
+        server_opt=make_server_optimizer("fedavgm", 1.0, 0.9),
+        local_batch_rows=4)
+    batch = dict(exp.batch, mask=jax.device_put(mask, exp.batch["mask"].sharding))
+    state, losses, atol = exp.state, [], 2e-6
+    assert jax.tree.leaves(state["params"])[0].dtype == dtype
+    for _ in range(2):
+        state, metrics = step(state, batch)
+        losses.append(np.asarray(metrics["loss"]))
+        assert int(metrics["counters"]["stateless_client_steps"]) == sum(steps)
+        assert int(metrics["counters"]["stateless_working_copy_writes"]) == sum(
+            max(n - 1, 0) for n in steps)
+    want_loss, want_params, want_m = _by_hand(cfg, ds, 4, mask)
+    np.testing.assert_allclose(np.stack(losses), want_loss, atol=atol)
+    # the accumulator, float32 whatever the parameters are
+    got_m = state["server_opt_state"]["m"]
+    assert {a.dtype for a in jax.tree.leaves(got_m)} == {np.dtype("float32")}
+    assert _gap(got_m, want_m) <= atol
+    as_f32 = lambda tree: jax.tree.map(lambda a: np.asarray(a, np.float32), tree)
+    assert _gap(as_f32(state["params"]), as_f32(want_params)) <= atol
+    assert _gap(got_m, jax.tree.map(np.zeros_like, want_m)) > 1e-3    # it moved
 
 
 def test_a_mesh_of_two_devices_gives_what_one_device_gives():
