@@ -96,7 +96,7 @@ class ModelConfig:
     """Model family + shape. MLP is FL_CustomMLP...:12-25; ConvNet is the
     BASELINE.json config-5 CIFAR-10 stress model (new, no reference analogue)."""
 
-    kind: str = "mlp"                    # 'mlp' | 'convnet' | 'olmoe'
+    kind: str = "mlp"                    # 'mlp' | 'convnet' | 'olmoe' | 'nemotron_h'
     # () degenerates the MLP to a single Linear — multinomial logistic
     # regression (pinned by tests/test_round_smoke.py).
     hidden_sizes: Tuple[int, ...] = (50, 200)  # FL_CustomMLP...:40
@@ -121,6 +121,35 @@ class ModelConfig:
     rope_theta: float = 10000.0
     rms_norm_eps: float = 1e-5
     norm_topk_prob: bool = False
+    # kind='nemotron_h' (fedtpu.models.nemotron_h): the keys of the published
+    # config.json of nvidia/Nemotron-Labs-TwoTower-30B-A3B-Base-BF16 under
+    # their own names and at its values; it also reads hidden_size,
+    # num_attention_heads, num_hidden_layers (the pattern's length),
+    # num_experts_per_tok, norm_topk_prob and vocab_size above, which its
+    # preset sets. One mixer a layer, chosen by the pattern's letter: M a
+    # Mamba-2 mixer, E sparse experts beside a shared one, * attention.
+    hybrid_override_pattern: str = "MEMEM*EME"
+    layer_norm_epsilon: float = 1e-5
+    mamba_num_heads: int = 64            # inner width = heads x head_dim
+    mamba_head_dim: int = 64
+    n_groups: int = 8                    # groups of B, C and of the gated norm
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    time_step_min: float = 0.001         # the initializer's, of dt_bias
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    head_dim: int = 128                  # attention's, not hidden / heads
+    num_key_value_heads: int = 2
+    n_routed_experts: int = 128          # the router's width
+    moe_intermediate_size: int = 1856
+    moe_shared_expert_intermediate_size: int = 3712
+    routed_scaling_factor: float = 2.5
+    # This chip's share of every expert layer: it routes over all
+    # n_routed_experts and computes experts [first_expert, first_expert +
+    # experts_held) and the shared expert. 0 held = all of them.
+    experts_held: int = 0
+    first_expert: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -655,6 +684,29 @@ def _olmoe(layers: int) -> ExperimentConfig:
 # layer is a whole period of the layer pattern; 625.6M of 6.92B parameters).
 PRESETS["olmoe-1b-7b"] = _olmoe(16)
 PRESETS["olmoe-1b-7b-l1"] = _olmoe(1)
+
+
+# nvidia/Nemotron-Labs-TwoTower-30B-A3B-Base-BF16's nemotron_h tower at its
+# published widths, as one 16 GB chip of a 16-way expert-parallel stage holds
+# it: the first nine of its 52 layers (every kind near its published ratio),
+# 8 of each layer's 128 routed experts (the router stays 128 wide, top-6) and
+# an eighth of the vocabulary: 667.0M of 31.6B parameters. Federated as the
+# OLMoE presets are, on 16 packed 8,192-token sequences.
+PRESETS["nemotron-h-30b-a3b-l9"] = ExperimentConfig(
+    data=DataConfig(dataset_name="tokens", synthetic_rows=16,
+                    synthetic_features=8192),
+    shard=ShardConfig(num_clients=8, shuffle=False),
+    model=ModelConfig(kind="nemotron_h", hidden_size=2688,
+                      num_attention_heads=32, num_hidden_layers=9,
+                      hybrid_override_pattern="MEMEM*EME",
+                      num_experts_per_tok=6, norm_topk_prob=True,
+                      vocab_size=16384, experts_held=8, first_expert=0,
+                      compute_dtype="bfloat16"),
+    optim=OptimConfig(name="sgd", learning_rate=0.005, momentum=0.0,
+                      steplr_gamma=1.0),
+    fed=FedConfig(rounds=20, client_state="stateless", local_batch_rows=1,
+                  server_opt="fedavgm", server_momentum=0.9, same_init=True),
+)
 
 
 def get_preset(name: str) -> ExperimentConfig:

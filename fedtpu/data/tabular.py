@@ -23,7 +23,6 @@ import dataclasses
 from typing import Any, Dict, Optional
 
 import numpy as np
-import pandas as pd
 
 from fedtpu.config import DataConfig
 
@@ -54,13 +53,15 @@ class Dataset:
         return self.x_train.shape[1]
 
 
-def _label_encode(df: pd.DataFrame) -> Dict[str, np.ndarray]:
+def _label_encode(df: "pd.DataFrame") -> Dict[str, np.ndarray]:
     """Encode every object column to sorted-unique integer codes.
 
     Equivalent to the reference's per-column ``LabelEncoder().fit_transform``
     (FL_CustomMLP...:222-230): sklearn's LabelEncoder maps values to indices
     into ``np.unique(values)``, which is exactly pandas factorize with sorting.
     """
+    import pandas as pd
+
     encoders = {}
     for col in df.columns:
         # The reference selects ``object`` dtype columns (:224); pandas 3
@@ -110,6 +111,9 @@ def _load_encoded(csv_path: str, use_native: bool):
         if native.available():
             header, _, mat, classes = native.load_csv(csv_path)
             return list(header), mat, classes, "native"
+    # imported where a CSV goes this way: a second of every process's start
+    import pandas as pd
+
     df = pd.read_csv(csv_path)
     encoders = _label_encode(df)
     return list(df.columns), df.to_numpy(dtype=np.float64), encoders, "pandas"

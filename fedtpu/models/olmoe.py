@@ -89,8 +89,11 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 EMBED, ATTENTION, ROUTER, EXPERT_DISPATCH, EXPERTS, LM_HEAD_LOSS = (
     "embed", "attention", "router", "expert_dispatch", "experts",
     "lm_head_loss")
+# the hybrid stack's own (fedtpu.models.nemotron_h): a state-space mixer,
+# the chunked scan alone inside it (innermost), the expert every token takes
+SSM, SSM_SCAN, SHARED_EXPERT = "ssm", "ssm_scan", "shared_expert"
 LAYER_SCOPES = (EMBED, ATTENTION, ROUTER, EXPERT_DISPATCH, EXPERTS,
-                LM_HEAD_LOSS)
+                LM_HEAD_LOSS, SSM, SSM_SCAN, SHARED_EXPERT)
 # Rows of the sequence whose logits exist at one time in the loss.
 LOSS_CHUNK = 512
 INIT_STD = 0.02
@@ -335,6 +338,22 @@ def grouped_matmul(xs, w, sizes):
     return body(xs, w, sizes)
 
 
+def sorted_assignments(groups, n_groups: int):
+    """``(order, sizes)`` of the assignments ``groups (A,)`` int32, each the
+    group (expert) one row goes to: ``order`` lists the assignments group by
+    group, earlier ones first within a group, and ``sizes (n_groups,)`` are
+    the groups' loads. The dispatch of every expert layer starts here."""
+    order = jnp.argsort(groups, stable=True)
+    sizes = jnp.bincount(groups, length=n_groups).astype(jnp.int32)
+    return order, sizes
+
+
+def gather_rows(x, order, per_token: int):
+    """The tokens' rows in the order of their assignments: assignment ``a``
+    of the token-major list belongs to token ``a // per_token``."""
+    return jnp.take(x, order // per_token, axis=0)
+
+
 def _block(cfg, compute_dtype, h, layer, segs, pos):
     """One decoder layer on one packed sequence ``h (T, H)``; returns the
     new ``h`` and the tokens each expert was given (padding left out)."""
@@ -363,9 +382,8 @@ def _block(cfg, compute_dtype, h, layer, segs, pos):
         # assignments sorted by expert: row a of the sorted list is token
         # order[a] // k, and the groups' sizes are the experts' loads
         flat = experts.reshape(-1)
-        order = jnp.argsort(flat, stable=True)
-        sizes = jnp.bincount(flat, length=n_exp).astype(jnp.int32)
-        xs = jnp.take(cast(x), order // top_k, axis=0)
+        order, sizes = sorted_assignments(flat, n_exp)
+        xs = gather_rows(cast(x), order, top_k)
         real = (segs > 0).astype(jnp.int32)
         load = jnp.zeros((n_exp,), jnp.int32).at[flat].add(
             jnp.repeat(real, top_k))

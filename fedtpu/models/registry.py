@@ -16,9 +16,10 @@ _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16,
 
 def build_model(cfg: ModelConfig):
     """Return ``(init_fn(key) -> params, apply_fn(params, x) -> logits)``.
-    For ``kind='olmoe'`` the second is ``stats_fn(params, x, mask) ->
-    statistics`` (fedtpu.models.olmoe.olmoe_stats): a vocabulary-sized
-    model hands out sums over tokens, never its logits."""
+    For the language models (``kind='olmoe'``, ``'nemotron_h'``) the second
+    is ``stats_fn(params, x, mask) -> statistics``
+    (fedtpu.models.olmoe.olmoe_stats, nemotron_h.nemotron_h_stats): a
+    vocabulary-sized model hands out sums over tokens, never its logits."""
     param_dtype = _DTYPES[cfg.param_dtype]
     compute_dtype = (None if cfg.compute_dtype == cfg.param_dtype
                      else _DTYPES[cfg.compute_dtype])
@@ -46,6 +47,24 @@ def build_model(cfg: ModelConfig):
         init = functools.partial(olmoe_init, cfg=cfg, param_dtype=param_dtype)
         stats = functools.partial(
             olmoe_stats, cfg=cfg,
+            compute_dtype=compute_dtype or param_dtype)
+        return init, stats
+    if cfg.kind == "nemotron_h":
+        from fedtpu.models import nemotron_h as nh
+        nh.layer_kinds(cfg)         # the pattern's letters and its length
+        nh.experts_share(cfg)
+        if cfg.num_attention_heads % cfg.num_key_value_heads:
+            raise ValueError(
+                f"{cfg.num_attention_heads} query heads do not divide over "
+                f"{cfg.num_key_value_heads} key-value heads")
+        if cfg.mamba_num_heads % cfg.n_groups:
+            raise ValueError(
+                f"{cfg.mamba_num_heads} state-space heads do not divide "
+                f"into {cfg.n_groups} groups")
+        init = functools.partial(nh.nemotron_h_init, cfg=cfg,
+                                 param_dtype=param_dtype)
+        stats = functools.partial(
+            nh.nemotron_h_stats, cfg=cfg,
             compute_dtype=compute_dtype or param_dtype)
         return init, stats
     raise ValueError(f"unknown model kind {cfg.kind!r}")

@@ -21,10 +21,18 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-import orbax.checkpoint as ocp
 
 from fedtpu.telemetry import default_registry
 from fedtpu.utils.trees import identity, to_numpy
+
+
+def _ocp():
+    """``orbax.checkpoint``, imported where a checkpoint is written or read:
+    its import is two thirds of the whole program's (seconds of every
+    process's start: PERF.md, ``program_import_s``) and most jobs keep no
+    checkpoint."""
+    import orbax.checkpoint as ocp
+    return ocp
 
 
 def _ckpt_path(directory: str, step: int) -> str:
@@ -42,7 +50,7 @@ def _strip_marker(state):
     return state
 
 
-def _checkpointer(step: int, process_group=None) -> ocp.Checkpointer:
+def _checkpointer(step: int, process_group=None):
     """A PyTree checkpointer scoped to ``process_group`` (process indices)
     when given. After a live shrink (fedtpu.resilience.reshard) the
     departed member is parked outside every collective, so orbax's default
@@ -51,17 +59,17 @@ def _checkpointer(step: int, process_group=None) -> ocp.Checkpointer:
     barrier key prefix is derived from (group, step) so concurrent saves
     of different rounds never alias."""
     if process_group is None or jax.process_count() == 1:
-        return ocp.PyTreeCheckpointer()
+        return _ocp().PyTreeCheckpointer()
     group = sorted(int(p) for p in process_group)
-    mp_opts = ocp.options.MultiprocessingOptions(
+    mp_opts = _ocp().options.MultiprocessingOptions(
         primary_host=group[0],
         active_processes=set(group),
         barrier_sync_key_prefix=f"fedtpu_g{group[0]}x{len(group)}s{step}")
     # The handler holds its OWN barrier options (defaulting to every
     # process) — scoping only the Checkpointer leaves the handler's
     # internal save barrier waiting on the parked member forever.
-    return ocp.Checkpointer(
-        ocp.PyTreeCheckpointHandler(multiprocessing_options=mp_opts),
+    return _ocp().Checkpointer(
+        _ocp().PyTreeCheckpointHandler(multiprocessing_options=mp_opts),
         multiprocessing_options=mp_opts)
 
 
@@ -248,7 +256,7 @@ def load_checkpoint_raw(directory: str, step: Optional[int] = None
         if step is None:
             raise FileNotFoundError(f"no checkpoints under {directory}")
     path = _ckpt_path(directory, step)
-    ckptr = ocp.PyTreeCheckpointer()
+    ckptr = _ocp().PyTreeCheckpointer()
     state = ckptr.restore(os.path.join(path, "state"))
     meta = ckptr.restore(os.path.join(path, "meta"))
     history = {k: list(np.asarray(v))
@@ -265,7 +273,7 @@ def load_meta(directory: str, step: Optional[int] = None) -> dict:
         step = latest_step(directory)
         if step is None:
             raise FileNotFoundError(f"no checkpoints under {directory}")
-    return ocp.PyTreeCheckpointer().restore(
+    return _ocp().PyTreeCheckpointer().restore(
         os.path.join(_ckpt_path(directory, step), "meta"))
 
 
@@ -339,7 +347,7 @@ def load_checkpoint(directory: str, step: Optional[int] = None,
         if step is None:
             raise FileNotFoundError(f"no checkpoints under {directory}")
     path = _ckpt_path(directory, step)
-    ckptr = ocp.PyTreeCheckpointer()
+    ckptr = _ocp().PyTreeCheckpointer()
     # The 'shared_start' marker is config, not data: never on disk (see
     # _strip_marker), re-attached below from the live template.
     had_marker = isinstance(state_like, dict) and "shared_start" in state_like

@@ -72,7 +72,7 @@ from fedtpu.parallel.round import (LAYER_KERNELS, LAYERS, STAGES,
                                    build_round_fn,
                                    build_eval_fn, check_resident_fits,
                                    init_federated_state, global_params)
-from fedtpu.training.task import Task, build_task
+from fedtpu.training.task import LANGUAGE_MODELS, Task, build_task
 from fedtpu.utils.timing import Timer, force_fetch
 from fedtpu.utils.trees import to_numpy
 
@@ -248,6 +248,54 @@ class Experiment:
     task: Optional[Task] = None
 
 
+# The shared-global round program of the newest configuration, by width. A
+# later job of the same process (a sweep's next point, a benchmark's next
+# job) is handed the jitted function the job before it ran, and with it jit's
+# own executable: a language model's round is seconds to trace and, at
+# hundreds of megabytes, tens of seconds to load even from the persistent
+# cache. Beside a width's function, under ``("compiled", width)``, the
+# executable ``compile_round_program`` made of it ahead of any job. One
+# configuration at a time: a process that moves on lets the old executables
+# go.
+_ROUND_PROGRAMS: dict = {}
+
+
+def _round_program(program, width: int, build: Callable[[int], Callable]):
+    """``build(width)``, or what it returned the last time ``program`` (all
+    that ``build`` closes over, by value) was asked for at this width."""
+    if _ROUND_PROGRAMS.get("program") != program:
+        _ROUND_PROGRAMS.clear()
+        _ROUND_PROGRAMS["program"] = program
+    if width not in _ROUND_PROGRAMS:
+        _ROUND_PROGRAMS[width] = build(width)
+    return _ROUND_PROGRAMS[width]
+
+
+def compile_round_program(step: Callable, *args):
+    """``step`` (what ``Experiment.make_step`` returned) compiled for
+    arguments shaped as ``args``, ahead of the job that will dispatch it (a
+    caller with other work for the minutes a language model's round takes
+    to compile). Where ``step`` is the process's kept round program, the
+    executable is kept beside it and ``run_experiment`` dispatches it in the
+    function's place (an AOT ``Compiled`` is called exactly like the jit
+    wrapper): no second trace, no lowering, no load from the persistent
+    cache, and the program's text is read from it."""
+    compiled = step.lower(*args).compile()
+    for width, kept in list(_ROUND_PROGRAMS.items()):
+        if kept is step:
+            _ROUND_PROGRAMS["compiled", width] = compiled
+    return compiled
+
+
+def _compiled_ahead(step: Callable) -> Callable:
+    """The executable ``compile_round_program`` kept for ``step``, else
+    ``step`` itself."""
+    for width, kept in _ROUND_PROGRAMS.items():
+        if kept is step:
+            return _ROUND_PROGRAMS.get(("compiled", width), step)
+    return step
+
+
 def build_experiment(cfg: ExperimentConfig,
                      dataset: Optional[Dataset] = None,
                      mesh: Optional[object] = None) -> Experiment:
@@ -282,7 +330,8 @@ def build_experiment(cfg: ExperimentConfig,
     if (model_cfg.kind in ("mlp", "convnet")
             and model_cfg.num_classes != ds.num_classes):
         model_cfg = dataclasses.replace(model_cfg, num_classes=ds.num_classes)
-    if model_cfg.kind == "olmoe" and ds.num_classes != model_cfg.vocab_size:
+    if (model_cfg.kind in LANGUAGE_MODELS
+            and ds.num_classes != model_cfg.vocab_size):
         raise ValueError(f"the corpus has a vocabulary of {ds.num_classes} "
                          f"and the model one of {model_cfg.vocab_size}")
 
@@ -328,13 +377,21 @@ def build_experiment(cfg: ExperimentConfig,
             server = identity_server_optimizer()
         state_fn = lambda: sl.init_stateless_state(
             jax.random.key(cfg.fed.init_seed), mesh, init_fn, server)
-        step_fn = lambda r: sl.build_stateless_round_fn(
+        build_step = lambda r: sl.build_stateless_round_fn(
             mesh, task, packed.counts,
             learning_rate=cfg.optim.learning_rate,
             steplr_step_size=cfg.optim.steplr_step_size,
             steplr_gamma=cfg.optim.steplr_gamma,
             weighting=cfg.fed.weighting, server_opt=server,
             local_batch_rows=cfg.fed.local_batch_rows, rounds_per_step=r)
+        # everything the builder above is handed, by value
+        program = (mesh, model_cfg, ds.num_classes,
+                   tuple(int(n) for n in packed.counts), cfg.optim,
+                   cfg.fed.weighting, cfg.fed.server_opt, cfg.fed.server_lr,
+                   cfg.fed.server_momentum, cfg.fed.server_b1,
+                   cfg.fed.server_b2, cfg.fed.server_tau,
+                   cfg.fed.local_batch_rows)
+        step_fn = lambda r: _round_program(program, r, build_step)
         global_fn = lambda state: state["params"]
     elif cfg.fed.async_mode:
         # The async engine replaces the whole synchronous aggregation
@@ -1485,7 +1542,7 @@ def run_experiment(cfg: ExperimentConfig, dataset: Optional[Dataset] = None,
                     num_classes=exp.num_classes, state=state, batch=batch,
                     width=r, cache=mpmd_cache, tracer=tracer)
             else:
-                step_fns[r] = exp.make_step(r)
+                step_fns[r] = _compiled_ahead(exp.make_step(r))
         return step_fns[r]
 
     jsonl = (open(cfg.run.metrics_jsonl, "a")

@@ -70,7 +70,7 @@ CLIENT_TRAIN, CLIENT_EVAL, AGGREGATE, METRICS = STAGES = (
 # event maps operations to them under ``layers``.
 SERVER_UPDATE = "server_update"
 LAYERS = ("embed", "attention", "router", "expert_dispatch", "experts",
-          "lm_head_loss", SERVER_UPDATE)
+          "lm_head_loss", "ssm", "ssm_scan", "shared_expert", SERVER_UPDATE)
 # Kernels the TPU's compiler puts in an instruction's place under a name of
 # its own, which replaces the ``op_name`` and with it every scope: whose they
 # are, by the prefix of the instruction's name. ``lax.ragged_dot`` becomes

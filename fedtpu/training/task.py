@@ -69,10 +69,14 @@ def classification_task(apply_fn: Callable, num_classes: int) -> Task:
                 metrics=metrics_from_confusion)
 
 
+LANGUAGE_MODELS = ("olmoe", "nemotron_h")
+
+
 def next_token_task(stats_fn: Callable, model_cfg) -> Task:
     """``stats_fn(params, x, mask)`` is the model's own
-    (fedtpu.models.olmoe.olmoe_stats): rows ``x (N, 2, T)`` of token and
-    segment ids; labels are the rows' own next tokens, so ``y`` is unused."""
+    (fedtpu.models.olmoe.olmoe_stats, nemotron_h.nemotron_h_stats): rows
+    ``x (N, 2, T)`` of token and segment ids; labels are the rows' own next
+    tokens, so ``y`` is unused."""
     from fedtpu.models.olmoe import next_token_targets
 
     def stats(params, x, y, mask):
@@ -91,15 +95,31 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
         return {"accuracy": s["correct"] / n,
                 "perplexity": jnp.exp(s["loss_sum"] / n)}
 
+    # a model whose expert layers compute every expert they route over
+    # (olmoe) owes this many assignments a real token
     assignments = model_cfg.num_experts_per_tok * model_cfg.num_hidden_layers
+    # what a model that holds a share of its experts and runs state-space
+    # layers counts besides (nemotron_h): read off the statistics it hands out
+    share = {"moe_assignments_held": "assignments_held",
+             "moe_rows_computed": "rows_computed",
+             "ssm_positions": "ssm_positions",
+             "ssm_document_restarts": "ssm_restarts"}
 
     def counters(s):
         load = s["expert_load"].astype(jnp.float32)
         routed = load.sum()
+        if "assignments_held" in s:
+            # its own experts' assignments, all of them inside the blocks
+            # it computed; the rest belong to other chips
+            dropped = s["assignments_held"] - s["rows_held_computed"]
+            extra = {"moe_assignments_total": routed,
+                     **{name: s[key] for name, key in share.items()}}
+        else:
+            # every assignment of a real token is computed
+            dropped, extra = assignments * s["tokens"] - routed, {}
         return {
             "moe_tokens_routed": routed,
-            # every assignment of a real token is computed: 0 by construction
-            "moe_tokens_dropped": assignments * s["tokens"] - routed,
+            "moe_tokens_dropped": dropped,      # 0 by construction
             "moe_expert_load_max_over_mean": load.max() / jnp.maximum(
                 load.mean(), 1.0),
             "lm_tokens": s["count"],
@@ -107,6 +127,7 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
             "lm_fused_attention_positions": s["fused_attention"],
             "moe_grouped_kernel_positions": s["grouped_experts"],
             "moe_expert_load": s["expert_load"],
+            **extra,
         }
 
     return Task(name="next_token", metric_names=("accuracy", "perplexity"),
@@ -118,6 +139,6 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
 def build_task(model_cfg, model_fn: Callable, num_classes: int) -> Task:
     """The task of a model kind: ``model_fn`` is the second of
     ``build_model``'s pair."""
-    if model_cfg.kind == "olmoe":
+    if model_cfg.kind in LANGUAGE_MODELS:
         return next_token_task(model_fn, model_cfg)
     return classification_task(model_fn, num_classes)

@@ -373,3 +373,67 @@ def test_fused_attention_core_compiles_for_v5e_without_the_scores(topo):
     assert _mosaic_calls(fused) == 3 and _mosaic_calls(xla) == 0
     assert fused.memory_analysis().temp_size_in_bytes < 300e6
     assert xla.memory_analysis().temp_size_in_bytes > 2000e6
+
+
+@pytest.fixture(scope="module")
+def hybrid_round(topo):
+    """The shared-global round of the hybrid preset (``nemotron_h``: nine
+    layers MEMEM*EME at published widths, 8 of 128 experts and an eighth of
+    the vocabulary held, 667.0M parameters; 8 clients, 16 packed
+    8,192-token sequences, FedAvgM) compiled for one described v5e chip,
+    the attention core steered to its fused body as the chip picks it."""
+    from fedtpu.config import get_preset
+    from fedtpu.models import olmoe
+    from fedtpu.models.registry import build_model
+    from fedtpu.ops.server_opt import make_server_optimizer
+    from fedtpu.parallel.stateless import build_stateless_round_fn
+    from fedtpu.training.task import build_task
+
+    cfg = get_preset("nemotron-h-30b-a3b-l9")
+    mesh = Mesh(np.array(topo.devices[:1]), ("clients",))
+    rep, by_client = NamedSharding(mesh, P()), NamedSharding(mesh, P("clients"))
+    init_fn, stats_fn = build_model(cfg.model)
+    server = make_server_optimizer("fedavgm", cfg.fed.server_lr,
+                                   cfg.fed.server_momentum)
+    params = jax.eval_shape(init_fn, jax.random.key(0))
+    assert sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params)) == 666_963_456
+    shaped = lambda tree, sharding: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
+    state = {"params": shaped(params, rep),
+             "server_opt_state": shaped(jax.eval_shape(server.init, params), rep),
+             "round": jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)}
+    seq = cfg.data.synthetic_features
+    batch = {"x": jax.ShapeDtypeStruct((8, 8, 2, seq), jnp.int32, sharding=by_client),
+             "y": jax.ShapeDtypeStruct((8, 8), jnp.int32, sharding=by_client),
+             "mask": jax.ShapeDtypeStruct((8, 8), jnp.float32, sharding=by_client)}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(olmoe, "fused_attention_applies", lambda q, k, v: True)
+        step = build_stateless_round_fn(
+            mesh, build_task(cfg.model, stats_fn, cfg.model.vocab_size),
+            [1, 1, 2, 2, 2, 2, 3, 3],
+            learning_rate=cfg.optim.learning_rate, server_opt=server,
+            local_batch_rows=cfg.fed.local_batch_rows)
+        return step.lower(state, batch).compile()
+
+
+def test_the_hybrid_round_at_published_widths_fits_one_v5e_chip(hybrid_round):
+    """The round's account lies between the 8.0 GB the engine's 12 bytes a
+    parameter come to and the 15.7 GB the configuration file states as its
+    bound (15.4 when this was written; the chip's compiler allows 15.75
+    GiB, 16.9 GB): global and momentum in place, one working copy, the
+    layers' casts and one layer's intermediates. The compiler's scheduler
+    fills what the chip has, so the account is near the bound by its own
+    doing (PERF.md section 4)."""
+    assert 8.0e9 <= _account(hybrid_round) <= 15.7e9, _account(hybrid_round)
+    assert hybrid_round.memory_analysis().alias_size_in_bytes >= 5.3e9
+    text = hybrid_round.as_text()
+    # the one attention layer ran fused, a kind of step: three kernels each
+    assert sorted(_pallas_calls(hybrid_round, "attention")) == [
+        "flash_attention"] * 3 * STEP_KINDS
+    # width 1,856 is neither of the grouped kernels' widths: ragged_dot
+    assert not _pallas_calls(hybrid_round, "experts")
+    assert "ragged-dot-none" in text
+    # every scope the reducers read is in the program
+    for scope in ("ssm", "ssm_scan", "shared_expert", "router",
+                  "expert_dispatch", "experts", "lm_head_loss"):
+        assert f"/{scope}/" in text or f"({scope})" in text, scope

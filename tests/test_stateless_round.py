@@ -23,7 +23,7 @@ from fedtpu.orchestration import loop
 from fedtpu.orchestration.loop import build_experiment, run_experiment
 from fedtpu.parallel import round as round_mod
 from fedtpu.parallel.round import LAYERS
-from perfbench import reference_lm
+from perfbench import reference_lm, reference_nemotron_h
 
 SGD = OptimConfig(name="sgd", learning_rate=0.05, momentum=0.0,
                   steplr_step_size=2, steplr_gamma=0.5)
@@ -40,6 +40,26 @@ def tiny_olmoe(rounds=2, **run):
                                  synthetic_features=48),
         shard=dataclasses.replace(cfg.shard, num_clients=4),
         optim=dataclasses.replace(cfg.optim, learning_rate=0.5),
+        fed=dataclasses.replace(cfg.fed, rounds=rounds, init_seed=3),
+        run=dataclasses.replace(cfg.run, mesh_devices=1, **run))
+
+
+def tiny_nemotron_h(rounds=2, **run):
+    cfg = get_preset("nemotron-h-30b-a3b-l9")
+    return cfg.replace(
+        model=dataclasses.replace(
+            cfg.model, hidden_size=48, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, num_hidden_layers=5,
+            hybrid_override_pattern="ME*ME", mamba_num_heads=8,
+            mamba_head_dim=8, n_groups=2, ssm_state_size=16, chunk_size=16,
+            n_routed_experts=16, experts_held=4, first_expert=8,
+            num_experts_per_tok=3, moe_intermediate_size=24,
+            moe_shared_expert_intermediate_size=40, vocab_size=128,
+            compute_dtype="float32"),
+        data=dataclasses.replace(cfg.data, synthetic_rows=10,
+                                 synthetic_features=48),
+        shard=dataclasses.replace(cfg.shard, num_clients=4),
+        optim=dataclasses.replace(cfg.optim, learning_rate=0.1),
         fed=dataclasses.replace(cfg.fed, rounds=rounds, init_seed=3),
         run=dataclasses.replace(cfg.run, mesh_devices=1, **run))
 
@@ -98,6 +118,53 @@ def test_two_rounds_of_tiny_olmoe_match_the_references_fedavgm(tmp_path):
     assert counters["gauges"]["moe_expert_load_max_over_mean"] > 1.0
     load = [e["payload"]["moe_expert_load"] for e in events if e["kind"] == "round"]
     assert len(load) == 2 and sum(load[0]) == 2 * tokens
+
+
+def test_two_rounds_of_a_tiny_hybrid_stack_match_the_references_fedavgm(
+        tmp_path):
+    """The same entry point, engine and sinks as OLMoE: ``run_experiment``
+    on a preset of ``kind='nemotron_h'`` cut to a tiny size, against the
+    plain reference's rounds, with the share's and the scan's counters in
+    the registry's last snapshot."""
+    sink = str(tmp_path / "ev.jsonl")
+    cfg = tiny_nemotron_h(telemetry=TelemetryConfig(events_path=sink))
+    result = run_experiment(cfg, verbose=False)
+    ds = build_experiment(cfg).dataset
+    rows = [ds.x_train[ds.client_of_row == c] for c in range(4)]
+    assert sorted(len(r) for r in rows) == [1, 2, 3, 4]         # size skew
+    init = jax.tree.map(np.asarray, build_model(cfg.model)[0](
+        jax.random.key(cfg.fed.init_seed)))
+    model = {k: getattr(cfg.model, k) for k in (
+        "hybrid_override_pattern", "layer_norm_epsilon", "mamba_num_heads",
+        "mamba_head_dim", "n_groups", "ssm_state_size", "num_attention_heads",
+        "num_key_value_heads", "head_dim", "num_experts_per_tok",
+        "norm_topk_prob", "routed_scaling_factor", "first_expert")}
+    ref_loss, ref_params = reference_nemotron_h.fedavgm_rounds(
+        init, rows, 2, model, learning_rate=cfg.optim.learning_rate,
+        momentum=cfg.fed.server_momentum, server_lr=cfg.fed.server_lr)
+    assert np.max(np.abs(np.stack(result.loss) - ref_loss)) <= 2e-5
+    assert _gap(result.final_params, ref_params) <= 2e-5
+    assert _gap(result.final_params, init) > 1e-3               # it moved
+    # the selection bias only picks: no gradient, no update (the program
+    # draws it inside a jitted init: a last-bit difference from this one)
+    for layer, first in zip(result.final_params["experts"], init["experts"]):
+        assert np.abs(layer["router_bias"] - first["router_bias"]).max() <= 1e-8
+    events = [json.loads(line) for line in open(sink)]
+    counters = [e for e in events if e["kind"] == "counters"][-1]["payload"]
+    tokens = int((ds.x_train[:, 1] > 0).sum())
+    counted = counters["counters"]
+    assert counted["moe_assignments_total"] == 2 * 2 * 3 * tokens
+    assert 0 < counted["moe_assignments_held"] < counted["moe_assignments_total"]
+    assert counted["moe_rows_computed"] >= counted["moe_assignments_held"]
+    assert counted["moe_tokens_dropped"] == 0
+    assert counted["ssm_positions"] == 2 * 2 * 10 * 48
+    documents = int(sum((row[1][1:] != row[1][:-1]).sum() + 1
+                        - (row[1][-1] == 0) for row in ds.x_train))
+    assert counted["ssm_document_restarts"] == 2 * 2 * documents
+    assert counted["lm_padding_tokens"] == 2 * (10 * 48 - tokens)
+    assert counted["stateless_client_steps"] == 2 * 10
+    load = [e["payload"]["moe_expert_load"] for e in events if e["kind"] == "round"]
+    assert len(load) == 2 and len(load[0]) == 16 and sum(load[0]) == 2 * 3 * tokens
 
 
 def test_mlp_equals_the_resident_engines_server_opt_path():
@@ -218,6 +285,67 @@ def test_a_mesh_of_two_devices_gives_what_one_device_gives():
     for k in one.global_metrics:
         np.testing.assert_allclose(two.pooled_metrics[k], one.pooled_metrics[k],
                                    atol=1e-6)
+
+
+def test_a_later_job_of_a_process_runs_the_round_program_of_the_one_before():
+    """Same configuration, data shapes and mesh: the jitted function itself,
+    and with it jit's executable (no second trace, lowering or load), whatever
+    the job's length and seed; anything the builder is handed differs: a
+    program of its own, and one configuration's programs at a time."""
+    ds = income()
+    cfg = mlp_cfg("stateless", rows=8)
+    first = build_experiment(cfg, ds).make_step(1)
+    later = cfg.replace(fed=dataclasses.replace(cfg.fed, rounds=7, init_seed=5))
+    exp = build_experiment(later, ds)
+    assert exp.make_step(1) is first and exp.make_step(2) is not first
+    assert exp.make_step(2) is build_experiment(cfg, ds).make_step(2)
+    for other in (mlp_cfg("stateless", rows=4),
+                  cfg.replace(optim=dataclasses.replace(SGD, learning_rate=0.3)),
+                  mlp_cfg("stateless", rows=8, server_momentum=0.5)):
+        assert build_experiment(other, ds).make_step(1) is not first
+    assert build_experiment(cfg, income(rows=40)).make_step(1) is not first
+    assert build_experiment(cfg, ds).make_step(1) is not first
+    one = run_experiment(cfg, dataset=ds, verbose=False)
+    two = run_experiment(cfg, dataset=ds, verbose=False)
+    assert all(np.array_equal(a, b) for a, b in zip(one.loss, two.loss))
+    assert _gap(one.final_params, two.final_params) == 0.0
+
+
+def test_a_round_program_compiled_ahead_is_what_the_jobs_dispatch(tmp_path):
+    """``compile_round_program`` from shapes alone: the jobs that follow run
+    the executable (the jitted function is never called, so it never traces
+    or compiles), give what jobs without it give, and a sink's
+    ``program_scopes`` event reads the executable's text; another
+    configuration gets nothing of it."""
+    ds = income()
+    cfg = mlp_cfg("stateless", rows=8, server_momentum=0.7)
+    plain = run_experiment(cfg, dataset=ds, verbose=False)
+    exp = build_experiment(cfg, ds)
+    step = exp.make_step(1)
+    assert loop._compiled_ahead(step) is step
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
+        (exp.state, exp.batch))
+    del exp
+    compiled = loop.compile_round_program(step, *shapes)
+    assert loop._compiled_ahead(step) is compiled
+    calls = step._cache_size()
+    sink = str(tmp_path / "ev.jsonl")
+    traced = cfg.replace(run=dataclasses.replace(
+        cfg.run, telemetry=TelemetryConfig(events_path=sink),
+        profile_dir=str(tmp_path / "profile"), profile_rounds=1))
+    for job in (cfg, traced):
+        ahead = run_experiment(job, dataset=ds, verbose=False)
+        assert all(np.array_equal(a, b) for a, b in zip(plain.loss, ahead.loss))
+        assert _gap(plain.final_params, ahead.final_params) == 0.0
+    assert step._cache_size() == calls
+    events = [json.loads(line) for line in open(sink)]
+    scopes = [e["payload"] for e in events if e["kind"] == "program_scopes"
+              and e["payload"]["program"] == "round_step"]
+    assert scopes and "error" not in scopes[0] and scopes[0]["scopes"]
+    other = build_experiment(mlp_cfg("stateless", rows=4), ds).make_step(1)
+    assert loop._compiled_ahead(other) is other
+    assert loop._compiled_ahead(build_experiment(cfg, ds).make_step(1)) is not compiled
 
 
 def test_a_scanned_chunk_of_rounds_is_the_rounds_one_by_one():
