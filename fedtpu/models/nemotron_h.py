@@ -69,10 +69,15 @@ What is this repo's own:
   loop runs as many blocks as this step's held assignments fill, its trips
   read from the groups' sizes: one on nearly every step, all of them if
   every token chose only experts held here. Exact whatever the skew, and
-  the worst case costs only when it happens. The room is not free: the
-  grouped products take time by the buffer's rows, filled or not (7 ms a
-  layer and step for 4,096 rows more at the published widths, PERF.md). The statistics count
-  ``rows_computed`` against ``assignments_held``. A loop of that kind has no
+  the worst case costs only when it happens. What the room costs is the
+  body's to say: the tiled grouped kernels (a TPU at the published widths,
+  ``olmoe.grouped_matmul_applies``) visit no tile past the last group, so
+  the two products take time by the filled rows and the empty ones cost
+  the dispatch's gathers and scatter-adds alone; the TPU's
+  ``lax.ragged_dot`` takes time by the buffer's rows, filled or not (7 ms
+  a layer and step for 4,096 rows more at those widths, PERF.md section 6,
+  PR 32). The statistics count ``rows_computed`` against
+  ``assignments_held``. A loop of that kind has no
   transpose, so the function has its own differentiation rule
   (``held_experts``): the backward pass runs the same trips and
   differentiates each block inside its trip, adding up the weights'

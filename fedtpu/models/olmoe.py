@@ -62,9 +62,11 @@ What is this repo's own:
   bf16 operands and float32 sums, gradients rounded to bf16 once, where
   autodiff rounded the XLA body's float32 ones. ``grouped_matmul_applies``
   picks, as for the attention core and as little anybody's to set: the
-  kernels on a TPU at bf16 operands, whole row tiles and widths of 1024 or
-  2048; ``lax.ragged_dot`` everywhere else. The sequence statistics say
-  how many positions ran in the kernels (``grouped_experts``).
+  kernels on a TPU at bf16 operands, whole row tiles and the widths that
+  have tiles from a sweep on the chip (1024 and 2048; the hybrid stack's
+  2688 x 1856); ``lax.ragged_dot`` everywhere else. The sequence
+  statistics say how many positions ran in the kernels
+  (``grouped_experts``).
 
 Parameters are float32. ``compute_dtype`` (bfloat16 in the shipped presets)
 is the dtype of every large matmul's inputs; accumulation, norms, softmax,
@@ -140,11 +142,64 @@ _ATTENTION_BLOCKS = flash.BlockSizes(
 GROUPED_ROW_TILE = 256
 GROUPED_WIDTH_TILE = 1024
 GROUPED_WEIGHT_TILE = 2048 * 1024
+# The same three kernels at the hybrid stack's widths (fedtpu.models.
+# nemotron_h: 8 held experts of 2,688 x 1,856, neither a whole number of the
+# tiles above). Chosen on the chip (PERF.md section 6, PR 33): one 8,192-row
+# buffer in 8 groups as the cell's own corpus and router fill it (twelve
+# layer-steps: 1,644 to 3,944 rows filled, groups of 43 to 1,272, the rest
+# of the buffer past the last group), 20 timed calls a filling, mean ms a
+# call beside ``lax.ragged_dot`` on the same operands. A tile that is no
+# whole divisor of its width is cut by the kernel (1,856 = 640 + 640 + 576;
+# 2,688 = 3 x 896); 1,344 and 928 are no whole lanes and no tile:
+#   gmm, [8192,2688].[8,2688,1856], float32 out: XLA 2.17; (128, 896, 1856)
+#     0.555, (128, 2688, 640) 0.558, (128, 2688, 512) 0.577, (256, 896,
+#     1856) 0.596, (128, 2688, 768) 0.597, (256, 2688, 640) 0.598, (256,
+#     2688, 768) 0.646, (256, 896, 1024) 0.661, (256, 2688, 896) 0.696,
+#     (128, 1280, 640) 0.751; 512 rows do not fit with the width whole.
+#   gmm, [8192,1856].[8,1856,2688], float32 out: XLA 1.68; (128 or 256,
+#     1856, 896) 0.356, (128, 1856, 1408) 0.356, (256, 1856, 1024) 0.383,
+#     (256, 640, 2688) 0.384, (256, 1856, 640) 0.409, (512, 1856, 896)
+#     0.420, (128, 640, 2688) 0.503.
+#   gmm on the weight in place (transpose_rhs), bf16 out, to [8192,1856]: XLA
+#     2.07; (256, 2688, 640) 0.331, (128, 2688, 640) 0.342, (256, 896, 1856)
+#     0.350, (256, 2688, 768) 0.382, (256, 2688, 896) 0.432, (128, 896, 1856)
+#     0.479; to [8192,2688]: XLA 2.65; (128, 1856, 896) 0.401, (128, 1856,
+#     1408) 0.406, (256, 1856, 896) 0.437, (128, 640, 2688) 0.471, (256,
+#     1856, 1024) 0.473, (512, 1856, 896) 0.536.
+#   tgmm, to [8,2688,1856]: XLA 2.25; (128, 896, 1856) 0.442, (128, 384,
+#     1856) 0.478, (256, 896, 1856) 0.479, (128, 2688, 384) 0.496, (128, 896,
+#     1024) 0.517, (128, 896, 640) 0.542, (256, 1024, 1024) 0.604, (512, 896,
+#     1856) 0.574; to [8,1856,2688]: XLA 2.82; (128, 640, 2688) 0.407, (128
+#     or 256, 1856, 896) 0.408, (128, 1024, 1408) 0.442, (128, 640, 896)
+#     0.468, (256, 1024, 1024) 0.503, (512, 640, 896) 0.550.
+#   With the 1,856 padded to 1,920 = 15 x 128 (zero columns, exact): 0.323 /
+#     0.318 / 0.301 / 0.303 / 0.356 / 0.358 at the best tile of each, 1.96
+#     for the six against 2.51: not taken, a padded copy of both weights,
+#     of the activations and a cut of both gradients for 0.55 ms.
+# So: 128 rows (a group here is one to three tiles of 256, where OLMoE's are
+# eight to twelve, and a tile that straddles a group's edge runs once a
+# group); the two gmm's take the contracted width whole and a third of the
+# output width, so a group's weight is fetched once; tgmm takes a third of
+# 2,688 by the whole of 1,856. The formula above gives (256, 2688, 768),
+# (256, 1856, 1024) and (256, 1024, 1024) here: 2.99 for the six against
+# 2.51. Neither kernel visits a tile past the last group: the XLA body's
+# 1.7-2.8 ms are mostly the buffer's empty rows.
+_MEASURED_TILES = {
+    ("forward", 2688, 1856): (128, 2688, 640),
+    ("forward", 1856, 2688): (128, 1856, 896),
+    ("input_gradient", 2688, 1856): (128, 2688, 640),
+    ("input_gradient", 1856, 2688): (128, 1856, 896),
+    ("weight_gradient", 2688, 1856): (128, 896, 1856),
+    ("weight_gradient", 1856, 2688): (128, 1856, 896),
+}
 
 
 def _grouped_tiles(kernel, k, n):
     """``(tm, tk, tn)`` of one of the three grouped kernels for a contracted
     width ``k`` and an output width ``n`` (of ``tgmm``: the weight's two)."""
+    measured = _MEASURED_TILES.get((kernel, k, n))
+    if measured:
+        return measured
     if kernel == "weight_gradient":
         return (GROUPED_ROW_TILE, min(k, GROUPED_WIDTH_TILE),
                 min(n, GROUPED_WIDTH_TILE))
@@ -269,15 +324,19 @@ def grouped_matmul_applies(xs, w) -> bool:
     as ``fused_attention_applies`` reads it), the bf16 operands the tiles
     were measured on (the kernel multiplies float32 operands in float32,
     several MXU passes where the XLA body takes one), whole row tiles, and
-    either width whole width tiles and, as the contracted width of a kernel,
-    leaving a width tile's room in one weight tile (1024 or 2048 today)."""
+    a pair of widths that has tiles from a sweep on the chip: each width
+    whole width tiles and, as the contracted width of a kernel, leaving a
+    width tile's room in one weight tile (1024 or 2048), or the pair in
+    ``_MEASURED_TILES`` (2688 and 1856). Any other width (1408, 4096) runs
+    ``lax.ragged_dot`` until it has a sweep of its own."""
     (rows, k), n = xs.shape, w.shape[2]
     return (jax.default_backend() == "tpu"
             and xs.dtype == w.dtype == jnp.bfloat16
             and rows % GROUPED_ROW_TILE == 0
-            and all(width % GROUPED_WIDTH_TILE == 0
-                    and width * GROUPED_WIDTH_TILE <= GROUPED_WEIGHT_TILE
-                    for width in (k, n)))
+            and (("forward", k, n) in _MEASURED_TILES
+                 or all(width % GROUPED_WIDTH_TILE == 0
+                        and width * GROUPED_WIDTH_TILE <= GROUPED_WEIGHT_TILE
+                        for width in (k, n))))
 
 
 def _xla_grouped_matmul(xs, w, sizes):

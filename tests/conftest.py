@@ -395,3 +395,19 @@ def _clear_jax_caches_near_map_limit():
 
         jax.clear_caches()
         gc.collect()
+
+
+@pytest.fixture
+def grouped_on_the_cpu(monkeypatch):
+    """The whole model through the Pallas body of the expert matmuls
+    (``fedtpu.models.olmoe.grouped_matmul``; the hybrid stack's held experts
+    call it too): the rule between the bodies is steered to it and the
+    kernels interpreted (always under jit: the interpreter is not for eager
+    use)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from fedtpu.models import olmoe
+
+    monkeypatch.setattr(olmoe, "grouped_matmul_applies", lambda xs, w: True)
+    with pltpu.force_tpu_interpret_mode():
+        yield

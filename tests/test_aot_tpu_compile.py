@@ -207,9 +207,10 @@ def _mosaic_calls(compiled) -> int:
 def _pallas_calls(compiled, scope: str) -> list:
     """The names of the compiled module's Pallas kernels under the named
     scope, one a call (the compiler's own ``ragged-dot-none`` kernels are
-    ``tpu_custom_call``s too, and name no scope)."""
+    ``tpu_custom_call``s too, and name no scope). A transform wraps the
+    scope's component of the name (``transpose(jvp(experts))``)."""
     return re.findall(
-        r'custom_call_target="tpu_custom_call".*op_name="[^"]*/%s/'
+        r'custom_call_target="tpu_custom_call".*op_name="[^"]*/(?:\w+\()*%s\)*/'
         r'(?:[^"/]*/)*?jit\((\w+)\)/[^"]*pallas_call"' % scope,
         compiled.as_text())
 
@@ -287,14 +288,17 @@ def test_the_olmoe_round_passes_over_the_parameters_once_a_step(olmoe_round):
     assert _account(fused) <= 12.93e9, _account(fused)
 
 
-@pytest.mark.parametrize("rows,width,kernels", [
-    (32768, 1024, True), (32768 + 128, 1024, False), (32768, 1408, False)])
+@pytest.mark.parametrize("rows,groups,k,width,kernels", [
+    (32768, 64, 2048, 1024, True), (32768 + 128, 64, 2048, 1024, False),
+    (32768, 64, 2048, 1408, False), (8192, 8, 2688, 1856, True)])
 def test_the_grouped_matmul_picks_its_body_by_shape_on_a_tpu(
-        topo, monkeypatch, rows, width, kernels):
+        topo, monkeypatch, rows, groups, k, width, kernels):
     """The rule itself, told only that the backend is a TPU: at the
-    benchmark's shapes a grouped matmul and its gradients compile to the
-    three kernels and no ``ragged-dot``; at rows that are no whole tile, or
-    a width there is no tile for, to ``ragged-dot`` and no such kernel."""
+    benchmark's shapes (OLMoE's, and a block of the hybrid stack's held
+    experts, whose widths its tiles do not divide) a grouped matmul and its
+    gradients compile to the three kernels and no ``ragged-dot``; at rows
+    that are no whole tile, or a width there is no tile for, to
+    ``ragged-dot`` and no such kernel."""
     from fedtpu.models import olmoe
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -307,8 +311,8 @@ def test_the_grouped_matmul_picks_its_body_by_shape_on_a_tpu(
             argnums=(0, 1))(xs, w)
 
     compiled = jax.jit(with_gradients).lower(  # fedtpu: noqa[FTP006] one-shot AOT compile
-        sds((rows, 2048), jnp.bfloat16), sds((64, 2048, width), jnp.bfloat16),
-        sds((rows, width), jnp.float32), sds((64,), jnp.int32)).compile()
+        sds((rows, k), jnp.bfloat16), sds((groups, k, width), jnp.bfloat16),
+        sds((rows, width), jnp.float32), sds((groups,), jnp.int32)).compile()
     text = compiled.as_text()
     calls = re.findall(r'jit\((t?gmm)\)+/pallas_call', text)
     assert sorted(set(calls)) == (["gmm", "tgmm"] if kernels else [])
@@ -380,10 +384,11 @@ def hybrid_round(topo):
     """The shared-global round of the hybrid preset (``nemotron_h``: nine
     layers MEMEM*EME at published widths, 8 of 128 experts and an eighth of
     the vocabulary held, 667.0M parameters; 8 clients, 16 packed
-    8,192-token sequences, FedAvgM) compiled for one described v5e chip,
-    the attention core steered to its fused body as the chip picks it."""
+    8,192-token sequences, FedAvgM) compiled for one described v5e chip.
+    The rules between the bodies of the attention core and of the expert
+    matmuls read the process's backend, the CPU here: they are told it is
+    a TPU and answer for the cell's shapes as they do on the chip."""
     from fedtpu.config import get_preset
-    from fedtpu.models import olmoe
     from fedtpu.models.registry import build_model
     from fedtpu.ops.server_opt import make_server_optimizer
     from fedtpu.parallel.stateless import build_stateless_round_fn
@@ -407,13 +412,16 @@ def hybrid_round(topo):
              "y": jax.ShapeDtypeStruct((8, 8), jnp.int32, sharding=by_client),
              "mask": jax.ShapeDtypeStruct((8, 8), jnp.float32, sharding=by_client)}
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(olmoe, "fused_attention_applies", lambda q, k, v: True)
+        patch.setattr(jax, "default_backend", lambda: "tpu")
         step = build_stateless_round_fn(
             mesh, build_task(cfg.model, stats_fn, cfg.model.vocab_size),
             [1, 1, 2, 2, 2, 2, 3, 3],
             learning_rate=cfg.optim.learning_rate, server_opt=server,
             local_batch_rows=cfg.fed.local_batch_rows)
         return step.lower(state, batch).compile()
+
+
+E_LAYERS = 4            # of the preset's nine, MEMEM*EME
 
 
 def test_the_hybrid_round_at_published_widths_fits_one_v5e_chip(hybrid_round):
@@ -427,12 +435,17 @@ def test_the_hybrid_round_at_published_widths_fits_one_v5e_chip(hybrid_round):
     assert 8.0e9 <= _account(hybrid_round) <= 15.7e9, _account(hybrid_round)
     assert hybrid_round.memory_analysis().alias_size_in_bytes >= 5.3e9
     text = hybrid_round.as_text()
-    # the one attention layer ran fused, a kind of step: three kernels each
+    # the one attention layer ran fused, a kind of step: the forward
+    # kernel, once more in the layer's recomputation, and the two backward
     assert sorted(_pallas_calls(hybrid_round, "attention")) == [
-        "flash_attention"] * 3 * STEP_KINDS
-    # width 1,856 is neither of the grouped kernels' widths: ragged_dot
-    assert not _pallas_calls(hybrid_round, "experts")
-    assert "ragged-dot-none" in text
+        "flash_attention"] * 4 * STEP_KINDS
+    # the held experts ran in the grouped kernels (PR 33), an ``E`` layer
+    # and kind of step: two products and, recomputed, two more, their two
+    # input gradients (the same kernel on the weight in place) and their
+    # two weight gradients; the compiler's own grouped kernel is nowhere
+    assert sorted(_pallas_calls(hybrid_round, "experts")) == (
+        ["gmm"] * 6 * E_LAYERS * STEP_KINDS + ["tgmm"] * 2 * E_LAYERS * STEP_KINDS)
+    assert "ragged-dot" not in text
     # every scope the reducers read is in the program
     for scope in ("ssm", "ssm_scan", "shared_expert", "router",
                   "expert_dispatch", "experts", "lm_head_loss"):

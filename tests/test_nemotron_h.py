@@ -335,29 +335,44 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     assert float(held_sum) == 3 * T          # every assignment, exactly once
 
 
-@pytest.mark.parametrize("rows", [8, 16, 64, 256])
-def test_the_held_buffer_is_exact_in_any_number_of_blocks(monkeypatch, rows):
+# The XLA body (``lax.ragged_dot``) at any block size; the tiled body (the
+# grouped Pallas kernels, interpreted, always under jit) at whole row tiles:
+# one block longer than its groups (the kernels visit no tile past the last
+# group and leave those rows undefined, as the TPU's ``ragged_dot`` does),
+# and 256 tokens whose held assignments take two blocks.
+@pytest.mark.parametrize("body,tokens,rows", [
+    ("xla", 64, 8), ("xla", 64, 16), ("xla", 64, 64), ("xla", 64, 256),
+    ("grouped", 64, 256), ("grouped", 256, 256)])
+def test_the_held_buffer_is_exact_in_any_number_of_blocks(
+        monkeypatch, request, body, tokens, rows):
     """The same layer with the held assignments in blocks of 8 rows (many
-    trips), 16, 64 and 256 (one): the same output and the same gradients."""
-    monkeypatch.setattr(nh, "held_block_rows", lambda a, share: min(a, rows))
+    trips), 16, 64 and 256 (one), through either body of the grouped
+    matmuls: the reference's output and the reference's gradients (of the
+    input, of both expert weights and, through the router's, of the
+    gates)."""
+    if body == "grouped":
+        request.getfixturevalue("grouped_on_the_cpu")
+    # the tiled kernels take whole row tiles, as ``held_block_rows`` gives
+    rows = rows if body == "grouped" else min(rows, 3 * tokens)
+    monkeypatch.setattr(nh, "held_block_rows", lambda a, share: rows)
     cfg = dataclasses.replace(TINY, experts_held=8, first_expert=2)
     key = jax.random.key(11)
     layer = nh._experts_init(
         cfg, lambda *s: 0.3 * jax.random.normal(
             jax.random.fold_in(key, sum(s) + len(s)), s),
         lambda *s: jnp.ones(s), key, jnp.float32)
-    h = jax.random.normal(jax.random.key(12), (T, 48))
-    segs = jnp.asarray([1] * 50 + [0] * 14, jnp.int32)
-    x = ref._rms(h, layer["norm"], 1e-5)
+    real = tokens * 25 // 32
+    h = jax.random.normal(jax.random.key(12), (tokens, 48))
+    segs = jnp.asarray([1] * real + [0] * (tokens - real), jnp.int32)
 
     def mine(layer, h):
         out, stats = nh.experts_mixer(cfg, jnp.float32, h, layer, segs)
-        return (out[:50] ** 2).sum(), stats
+        return (out[:real] ** 2).sum(), stats
 
     def theirs(layer, h):
         out = ref.experts_mixer(layer, ref._rms(h, layer["norm"], 1e-5),
                                 ref_cfg(cfg))
-        return (out[:50] ** 2).sum()
+        return (out[:real] ** 2).sum()
 
     program = jax.jit(jax.value_and_grad(mine, argnums=(0, 1), has_aux=True))
     reference = jax.jit(jax.value_and_grad(theirs, argnums=(0, 1)))
@@ -366,14 +381,29 @@ def test_the_held_buffer_is_exact_in_any_number_of_blocks(monkeypatch, rows):
         want, want_grads = reference(layer, h)
     assert abs(float(loss) - float(want)) <= 1e-5 * float(want)
     gaps = jax.tree.leaves(relative_gaps(
-        (grads[0], grads[1][:50]), (want_grads[0], want_grads[1][:50])))
+        (grads[0], grads[1][:real]), (want_grads[0], want_grads[1][:real])))
     assert max(gaps) <= 2e-4, gaps
     held = int(stats["assignments_held"])
-    rows = min(rows, 3 * T)
     assert int(stats["rows_computed"]) == -(-held // rows) * rows
+    if body == "grouped":   # a block longer than its groups; two blocks
+        assert (-(-held // rows), held % rows > 0) == (1 + tokens // 256, True)
     assert int(stats["rows_held_computed"]) == held     # nothing dropped
-    # padding is routed nowhere: its 14 tokens are in no count
-    assert int(stats["expert_load"].sum()) == 3 * 50
+    # padding is routed nowhere: its tokens are in no count
+    assert int(stats["expert_load"].sum()) == 3 * real
+
+
+@pytest.mark.parametrize("body", ["xla", "grouped"])
+def test_the_counter_says_which_body_the_held_experts_ran(body, request):
+    """``grouped_experts`` reads the rule between the bodies as the held
+    experts' two products read it: every position where the tiled kernels
+    ran, none where ``lax.ragged_dot`` did (a CPU, by itself)."""
+    if body == "grouped":
+        request.getfixturevalue("grouped_on_the_cpu")
+    sequence_stats = jax.jit(lambda p, r: nh.nemotron_h_sequence_stats(
+        p, r, TINY, jnp.float32))
+    stats = sequence_stats(seeded(TINY), rows_of()[0])
+    assert int(stats["grouped_experts"]) == (T if body == "grouped" else 0)
+    assert int(stats["rows_held_computed"]) == int(stats["assignments_held"]) > 0
 
 
 # ------------------------------------------------ (e) the vocabulary slice

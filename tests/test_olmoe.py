@@ -201,16 +201,6 @@ def fused_on_the_cpu(monkeypatch):
         yield
 
 
-@pytest.fixture
-def grouped_on_the_cpu(monkeypatch):
-    """The whole model through the Pallas body of the expert matmuls: the
-    rule between the bodies is steered to it and the kernels interpreted
-    (always under jit)."""
-    monkeypatch.setattr(olmoe, "grouped_matmul_applies", lambda xs, w: True)
-    with pltpu.force_tpu_interpret_mode():
-        yield
-
-
 # The XLA case is the tiny model, eager, its gradients within 1e-5 as the
 # file's others are. The fused case is two of the kernel's blocks long, its
 # documents' edge 40 tokens before the blocks': the segment mask inside a
@@ -511,6 +501,21 @@ def _matmul_and_gradients(body, sizes):
     return jax.jit(f)
 
 
+# The hybrid stack's widths scaled down by seven lanes: 2,688 = 21 x 128 to
+# 384, 1,856 = 14.5 x 128 to 192, and its measured tiles with them (half the
+# row tile; the contracted width whole and the output width in tiles that do
+# not divide it, 192 = 128 + 64 as 1,856 = 640 + 640 + 576; ``tgmm`` a third
+# of the one width by the whole of the other).
+CUT_TILES = {
+    ("forward", 384, 192): (128, 384, 128),
+    ("forward", 192, 384): (128, 192, 128),
+    ("input_gradient", 384, 192): (128, 384, 128),
+    ("input_gradient", 192, 384): (128, 192, 128),
+    ("weight_gradient", 384, 192): (128, 128, 192),
+    ("weight_gradient", 192, 384): (128, 192, 128),
+}
+
+
 # float32: the same sums in tiles (measured 5e-7 of the largest entry on the
 # values, 6e-7 on either gradient): 1e-5 of the largest entry. bfloat16: the
 # forward kernels give the XLA body's float32 sums (1e-6 of the largest
@@ -518,14 +523,25 @@ def _matmul_and_gradients(body, sizes):
 # where the XLA body on the CPU multiplies the float32 one: one unit in the
 # last place of the largest entries (0.25 on 60, 0.5 on 79), so 2**-7 of the
 # largest. A dropped tile or a row given to the wrong group moves entries by
-# their own size.
-@pytest.mark.parametrize("groups", sorted(GROUP_SIZES))
+# their own size. The last two cases run at widths that are no whole number
+# of their tiles (``CUT_TILES``), with groups of no rows and rows past the
+# last group: what the kernel cuts off a tile is in no sum, and no column
+# past a width is written.
+@pytest.mark.parametrize("groups,k,n", [
+    *((name, 256, 128) for name in sorted(GROUP_SIZES)),
+    ("uneven", 384, 192), ("uneven", 192, 384)])
 @pytest.mark.parametrize("dtype,out_tol,grad_tol", [
     (jnp.float32, 1e-5, 1e-5), (jnp.bfloat16, 1e-5, 2.0 ** -7)])
-def test_the_pallas_grouped_matmul_is_ragged_dot(dtype, out_tol, grad_tol, groups):
+def test_the_pallas_grouped_matmul_is_ragged_dot(monkeypatch, dtype, out_tol,
+                                                 grad_tol, groups, k, n):
     sizes = np.asarray(GROUP_SIZES[groups](olmoe.GROUPED_ROW_TILE), np.int32)
-    total, k, n = int(sizes.sum()), 256, 128
+    total = int(sizes.sum())
     assert (total == ROWS) == (groups != "uneven") and total <= ROWS
+    for key, tiles in CUT_TILES.items():
+        monkeypatch.setitem(olmoe._MEASURED_TILES, key, tiles)
+    cut = ("forward", k, n) in CUT_TILES
+    assert cut == bool(n % olmoe._grouped_tiles("forward", k, n)[2]
+                       or k % olmoe._grouped_tiles("input_gradient", n, k)[2])
     keys = jax.random.split(jax.random.key(8), 3)
     xs = jax.random.normal(keys[0], (ROWS, k)).astype(dtype)
     w = jax.random.normal(keys[1], (len(sizes), k, n)).astype(dtype)
@@ -556,7 +572,14 @@ def test_the_pallas_grouped_matmul_is_ragged_dot(dtype, out_tol, grad_tol, group
     ("tpu", (32768, 2048), (64, 2048, 1408), jnp.bfloat16, False),
     ("tpu", (32768, 4096), (64, 4096, 1024), jnp.bfloat16, False),
     ("tpu", (32768, 2048), (64, 2048, 1024), jnp.float32, False),
-    ("cpu", (32768, 2048), (64, 2048, 1024), jnp.bfloat16, False)])
+    ("cpu", (32768, 2048), (64, 2048, 1024), jnp.bfloat16, False),
+    # the hybrid stack's held experts: a block of the buffer, both products
+    ("tpu", (8192, 2688), (8, 2688, 1856), jnp.bfloat16, True),
+    ("tpu", (8192, 1856), (8, 1856, 2688), jnp.bfloat16, True),
+    ("tpu", (8192, 2688), (8, 2688, 1856), jnp.float32, False),
+    ("cpu", (8192, 1856), (8, 1856, 2688), jnp.bfloat16, False),
+    # a measured width beside one it was not measured with
+    ("tpu", (8192, 2688), (8, 2688, 1024), jnp.bfloat16, False)])
 def test_the_rule_between_the_grouped_matmul_bodies(monkeypatch, backend, xs,
                                                     w, dtype, pallas):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
@@ -572,3 +595,24 @@ def test_the_rule_between_the_grouped_matmul_bodies(monkeypatch, backend, xs,
                          sds(w[:1], jnp.int32))
     assert ran == ["_pallas_grouped_matmul" if pallas else "_xla_grouped_matmul"]
     assert out.shape == (xs[0], w[2]) and out.dtype == jnp.float32
+
+
+# OLMoE's tiles are PR 29's to the number (its round program is compared
+# with the parent's whenever this rule changes); the hybrid stack's are the
+# table's, from the sweep of PR 33. Every tile is whole lanes or the whole
+# width (what a block of a Mosaic kernel may be), and its rows divide the
+# row tile the callers' buffers are whole numbers of.
+@pytest.mark.parametrize("kernel,k,n,tiles", [
+    ("forward", 2048, 1024, (256, 2048, 1024)),
+    ("forward", 1024, 2048, (256, 1024, 2048)),
+    ("input_gradient", 1024, 2048, (256, 1024, 2048)),
+    ("input_gradient", 2048, 1024, (256, 2048, 1024)),
+    ("weight_gradient", 2048, 1024, (256, 1024, 1024)),
+    ("weight_gradient", 1024, 2048, (256, 1024, 1024)),
+    *((*key, tiles) for key, tiles in sorted(olmoe._MEASURED_TILES.items()))])
+def test_the_tiles_of_the_grouped_kernels(kernel, k, n, tiles):
+    assert olmoe._grouped_tiles(kernel, k, n) == tiles
+    tm, tk, tn = tiles
+    assert olmoe.GROUPED_ROW_TILE % tm == 0
+    assert all(tile % 128 == 0 or tile == width
+               for tile, width in ((tk, k), (tn, n)))
