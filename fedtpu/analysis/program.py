@@ -94,23 +94,59 @@ _HLO_JUNCTION = frozenset({"parameter", "tuple", "get-tuple-element",
                            "while", "conditional", "call"})
 
 
+def _components(op_name: str) -> list:
+    """The scopes on a name stack such as
+    ``jit(round_step)/jit(shmap_body)/while/body/client_train/vmap(...)``,
+    outermost first, each out of the transforms that wrap its component
+    (``transpose(jvp(aggregate))`` is ``aggregate``)."""
+    return [part.rsplit("(", 1)[-1].rstrip(")")
+            for part in op_name.split("/")]
+
+
 def _stage_of(op_name: str, stages: Sequence[str]) -> Optional[str]:
-    """Innermost of the scopes ``stages`` on a name stack such as
-    ``jit(round_step)/jit(shmap_body)/while/body/client_train/vmap(...)``;
-    transforms wrap a scope's component (``transpose(jvp(aggregate))``)."""
-    for part in reversed(op_name.split("/")):
-        inner = part.rsplit("(", 1)[-1].rstrip(")")
+    """Innermost of the scopes ``stages`` on ``op_name``."""
+    for inner in reversed(_components(op_name)):
         if inner in stages:
             return inner
     return None
 
 
+# The directions ``program_scopes`` tells apart under ``passes``.
+FORWARD, RECOMPUTE, BACKWARD, UPDATE = PASSES = (
+    "forward", "recompute", "backward", "update")
+# What ``jax.checkpoint``'s backward rule (jax 0.9.0, ``ad_checkpoint``:
+# ``remat_partial_eval`` / the ``remat2`` lowering) puts on the name stack of
+# the forward body it runs again; the body's transposed operations stand
+# beside it under ``checkpoint/`` alone.
+_REMAT_RECOMPUTED = "rematted_computation"
+
+
+def _pass_of(op_name: str, update: Sequence[str],
+             recompute: Sequence[str]) -> str:
+    """The direction an operation named ``op_name`` runs in: the rule of
+    ``program_scopes``' ``passes``."""
+    inner = _components(op_name)
+    if any(scope in update for scope in inner):
+        return UPDATE
+    # ``transpose(recompute)`` is the backward pass of what was run again
+    if any((scope == _REMAT_RECOMPUTED or scope in recompute)
+           and "transpose(" not in part
+           for part, scope in zip(op_name.split("/"), inner)):
+        return RECOMPUTE
+    if "transpose(" in op_name:        # the primitive's own name has no "("
+        return BACKWARD
+    return FORWARD
+
+
 def program_scopes(compiled_text: str, stages: Sequence[str],
-                   strict: bool = False) -> dict:
+                   strict: bool = False, *, layers: Sequence[str] = (),
+                   pieces: Sequence[str] = (), update: Sequence[str] = (),
+                   recompute: Sequence[str] = ()) -> dict:
     """Which of the ``jax.named_scope`` names ``stages`` (the builder's:
     ``parallel.round.STAGES``) each operation of a compiled program belongs
     to, from ``Compiled.as_text()``: ``{"scopes": {key: scope}, "unscoped":
-    [key]}``. A key is ``"<instruction> <first result shape>"`` (at most
+    [key], "layers", "pieces", "passes"}``, all from one walk of the text. A
+    key is ``"<instruction> <first result shape>"`` (at most
     120 characters), which is how a profiler trace's ``XLA Ops`` event
     reads once cut to its name and shape: the trace carries the HLO text
     of an instruction and no ``op_name``, so only the program can say
@@ -124,10 +160,37 @@ def program_scopes(compiled_text: str, stages: Sequence[str],
     all its users carry, else the one all its operands carry; nothing is
     inherited across a loop, a branch, a tuple or a parameter.
     ``strict``: only an instruction WITHOUT an ``op_name`` inherits. For
-    the stages, which cover a program, the difference is nil; for a second
-    level of scopes that covers only parts of it (``parallel.round.LAYERS``)
-    an instruction that names itself outside every scope (the optimizer's
-    update of a weight) stays outside, whoever made its operands."""
+    the stages, which cover a program, the difference is nil; for a
+    level of scopes that covers only parts of it an
+    instruction that names itself outside every scope (the optimizer's
+    update of a weight) stays outside, whoever made its operands.
+
+    ``layers`` and ``pieces`` (``parallel.round.LAYERS``, ``PIECES``) are
+    two such levels: ``{key: innermost scope of the level}``, both strict,
+    empty where the program names none or none is asked for.
+
+    A fusion the compiler left without an ``op_name`` (a multi-output
+    fusion: its root is a tuple, which carries none) reads, for ``pieces``
+    and ``passes``, what the named instructions of its body agree on, and
+    inherits only where they do not; ``scopes`` and ``layers`` read it by
+    its neighbours, as they always have.
+
+    ``passes`` gives every key the direction it runs in, read from the
+    same ``op_name``, first match: a scope of ``update`` anywhere on the
+    stack (the engine's ``sgd_pass``, ``server_update``) is ``"update"``;
+    else ``rematted_computation`` (what ``jax.checkpoint``'s backward rule
+    names the forward body it runs again; its transposed operations stand
+    beside it under ``checkpoint/`` alone) or a scope of ``recompute`` (a
+    rule that runs a forward pass again by hand says so itself; the
+    transposed operations of what it ran, ``transpose(recompute)/...``, are
+    not) is ``"recompute"``; else a component wrapped in ``transpose(...)``
+    (``transpose(jvp(client_train))``, ``vmap(transpose(jvp()))``, a custom
+    rule's ``transpose(client_train)/jvp(experts)``) is ``"backward"``; else
+    (``jvp(...)`` alone, or no transform) ``"forward"``. The map says where
+    an operation runs, not what it computes: a custom rule that makes
+    gradients in its forward pass reads ``forward``. An instruction without
+    ``op_name`` inherits as ``strict`` does, and reads ``forward`` where
+    its neighbours disagree."""
     computations: dict[str, list[str]] = {}
     entry = current = None
     for line in compiled_text.splitlines():
@@ -148,10 +211,22 @@ def program_scopes(compiled_text: str, stages: Sequence[str],
             opcode = _HLO_OPCODE_RE.search(line)
             if opcode and opcode.group(1) != "fusion":
                 run.update(_HLO_CALLS_RE.findall(line))
-    scopes: dict[str, str] = {}
+    # one resolver a map: how an op_name reads, who may inherit, and
+    # whether a fusion without a name reads its body
+    levels = [("scopes", lambda name: _stage_of(name, stages), strict, False)]
+    if layers:
+        levels.append(
+            ("layers", lambda name: _stage_of(name, layers), True, False))
+    if pieces:
+        levels.append(
+            ("pieces", lambda name: _stage_of(name, pieces), True, True))
+    levels.append(
+        ("passes", lambda name: _pass_of(name, update, recompute), True, True))
+    found: dict[str, dict] = {"scopes": {}, "layers": {}, "pieces": {},
+                              "passes": {}}
     unscoped: list[str] = []
     for name in run:
-        keys, stage, operands, junctions, named = {}, {}, {}, set(), set()
+        keys, op_names, operands, junctions, bodies = {}, {}, {}, set(), {}
         for line in computations.get(name, ()):
             inst = _HLO_INSTRUCTION_RE.match(line)
             opcode = inst and _HLO_OPCODE_RE.search(inst.group(2))
@@ -163,38 +238,48 @@ def program_scopes(compiled_text: str, stages: Sequence[str],
                 keys[inst] = (inst
                               + (" " + shape.group(0) if shape else ""))[:120]
             op_name = _HLO_OP_NAME_RE.search(rest)
-            stage[inst] = (_stage_of(op_name.group(1), stages)
-                           if op_name else None)
-            if op_name:
-                named.add(inst)
+            op_names[inst] = op_name.group(1) if op_name else None
+            if not op_name and opcode.group(1) == "fusion":
+                bodies[inst] = [
+                    n for body in _HLO_CALLS_RE.findall(rest)
+                    for n in _HLO_OP_NAME_RE.findall(
+                        "\n".join(computations.get(body, ())))]
             if opcode.group(1) in _HLO_JUNCTION:
                 junctions.add(inst)
             operands[inst] = _HLO_OPERAND_RE.findall(rest[opcode.end():])
-        users: dict[str, list[str]] = {inst: [] for inst in stage}
+        users: dict[str, list[str]] = {inst: [] for inst in op_names}
         for inst, ops in operands.items():
             for op in ops:
                 if op in users:
                     users[op].append(inst)
-
-        def inherit(order, edges):
-            # HLO text defines an instruction before its users, so one
-            # pass in the right order resolves whole chains
-            for inst in order:
-                if (stage[inst] is None and inst not in junctions
-                        and not (strict and inst in named)):
-                    near = {stage[n] for n in edges[inst] if n in stage}
-                    near.discard(None)
-                    if len(near) == 1:
-                        stage[inst] = near.pop()
-
-        inherit(reversed(list(stage)), users)
-        inherit(list(stage), operands)
-        for inst, key in keys.items():
-            if stage[inst]:
-                scopes[key] = stage[inst]
-            else:
-                unscoped.append(key)
-    return {"scopes": scopes, "unscoped": sorted(unscoped)}
+        # HLO text defines an instruction before its users, so one pass in
+        # the right order resolves whole chains
+        defined = list(op_names)
+        for level, read, own_name_only, read_body in levels:
+            stage = {inst: read(op_name) if op_name else None
+                     for inst, op_name in op_names.items()}
+            if read_body:
+                for inst, names in bodies.items():
+                    inside = set(map(read, names)) - {None}
+                    if len(inside) == 1:
+                        stage[inst] = inside.pop()
+            for order, edges in ((reversed(defined), users),
+                                 (defined, operands)):
+                for inst in order:
+                    if (stage[inst] is None and inst not in junctions
+                            and not (own_name_only and op_names[inst])):
+                        near = {stage[n] for n in edges[inst] if n in stage}
+                        near.discard(None)
+                        if len(near) == 1:
+                            stage[inst] = near.pop()
+            for inst, key in keys.items():
+                if stage[inst]:
+                    found[level][key] = stage[inst]
+                elif level == "scopes":
+                    unscoped.append(key)
+                elif level == "passes":
+                    found[level][key] = FORWARD
+    return {**found, "unscoped": sorted(unscoped)}
 
 
 # ---------------------------------------------------------------------------

@@ -32,6 +32,7 @@ import hashlib
 import json
 import os
 import pickle
+import re
 import tempfile
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -62,9 +63,9 @@ CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 # Where the cache lives when nobody placed it: one fixed path inside the
 # checkout. Fixed because a directory that moves between runs (a temp
 # dir, a pid or a timestamp in the name) can never hit twice.
-DEFAULT_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), ".jax_cache")
+CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+DEFAULT_CACHE_DIR = os.path.join(CHECKOUT_ROOT, ".jax_cache")
 
 # Subdirectory of the cache dir holding ProgramCache's serialized
 # executables; the remainder is jax's persistent backend cache.
@@ -99,12 +100,36 @@ def configure_persistent_cache(cache_dir: Optional[str] = None) -> str:
     caches in the same place. Must run before the programs of interest are
     compiled; safe to call repeatedly. Respects an explicit
     ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` from the environment.
+
+    The key of an entry holds the program's metadata too (jax leaves it
+    out by default): the ``op_name`` of every operation, which is where the
+    round program's ``jax.named_scope``s live, and the file and line it was
+    traced from. A checkout whose scopes differ from the one that filled
+    the directory is therefore not served that one's executable, and a
+    traced run's ``program_scopes`` reads its own scopes
+    (docs/observability.md). Files are named from the checkout's root
+    (``jax_hlo_source_file_canonicalization_regex`` cuts it off), so the
+    same source at another path is served, and an operation's location is
+    the one line that made it, not the stack of calls that led there
+    (``jax_traceback_in_locations_limit`` 1; turning the tracebacks off
+    altogether would cut every ``op_name`` to its primitive): a program traced
+    again from another caller (every job builds and initialises its
+    experiment anew) has the key it had. With the stacks in, a cold run
+    compiled the language model's init program once a caller, 6-9 s each
+    (PERF.md section 6, PR 35). The price: the first run after an edit that
+    shifts a line some program is traced through compiles it again, as the
+    first run after any change to the program does.
     """
     import jax
 
     path = resolve_cache_dir(cache_dir)
     os.makedirs(path, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    if jax.config.jax_hlo_source_file_canonicalization_regex is None:
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          "^" + re.escape(CHECKOUT_ROOT + os.sep))
     if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
         # Default floor skips caching sub-half-second programs; an env var
         # set by the caller (e.g. CPU tests caching tiny programs) wins.

@@ -99,10 +99,11 @@ from jax import lax
 
 from fedtpu.models import olmoe
 from fedtpu.models.olmoe import (ATTENTION, EMBED, EXPERT_DISPATCH, EXPERTS,
-                                 INIT_STD, LM_HEAD_LOSS, ROUTER,
-                                 SHARED_EXPERT, SSM, SSM_SCAN, _head_loss,
-                                 attention_core, gather_rows, grouped_matmul,
-                                 next_token_targets, rms_norm,
+                                 INIT_STD, LM_HEAD_LOSS, RECOMPUTE, ROUTER,
+                                 SHARED_EXPERT, SSM, SSM_CONV, SSM_GATE_NORM,
+                                 SSM_IN_PROJ, SSM_OUT_PROJ, SSM_SCAN,
+                                 _head_loss, attention_core, gather_rows,
+                                 grouped_matmul, next_token_targets, rms_norm,
                                  sorted_assignments)
 
 KINDS = {"M": "mamba", "E": "experts", "*": "attention"}
@@ -326,22 +327,26 @@ def mamba_mixer(cfg, compute_dtype, h, layer, segs):
     cast = lambda arr: arr.astype(compute_dtype)
     run, starts = document_runs(segs)
     with jax.named_scope(SSM):
-        x = cast(rms_norm(h, layer["norm"], cfg.layer_norm_epsilon))
-        z, xbc, dt = jnp.split(_mm(x, cast(layer["in_proj"])),
-                               [width, 2 * width + 2 * state], axis=-1)
-        xbc = jax.nn.silu(causal_conv(xbc, layer["conv_w"], layer["conv_b"],
-                                      run))
-        xs, b, c = jnp.split(xbc, [width, width + state], axis=-1)
-        xs = xs.reshape(t, heads, p)
-        dt = jax.nn.softplus(dt + layer["dt_bias"])
+        with jax.named_scope(SSM_IN_PROJ):
+            x = cast(rms_norm(h, layer["norm"], cfg.layer_norm_epsilon))
+            z, xbc, dt = jnp.split(_mm(x, cast(layer["in_proj"])),
+                                   [width, 2 * width + 2 * state], axis=-1)
+        with jax.named_scope(SSM_CONV):
+            xbc = jax.nn.silu(causal_conv(xbc, layer["conv_w"],
+                                          layer["conv_b"], run))
+            xs, b, c = jnp.split(xbc, [width, width + state], axis=-1)
+            xs = xs.reshape(t, heads, p)
+            dt = jax.nn.softplus(dt + layer["dt_bias"])
         with jax.named_scope(SSM_SCAN):
             y = ssd_scan(xs, dt, -jnp.exp(layer["A_log"].astype(jnp.float32)),
                          b.reshape(t, groups, n), c.reshape(t, groups, n),
                          run, cfg.chunk_size, compute_dtype)
-        y = (y + layer["D"][:, None] * xs).reshape(t, width)
-        y = gated_group_norm(y, z, layer["gate_norm"], groups,
-                             cfg.layer_norm_epsilon)
-        out = _mm(cast(y), cast(layer["out_proj"]))
+        with jax.named_scope(SSM_GATE_NORM):
+            y = (y + layer["D"][:, None] * xs).reshape(t, width)
+            y = gated_group_norm(y, z, layer["gate_norm"], groups,
+                                 cfg.layer_norm_epsilon)
+        with jax.named_scope(SSM_OUT_PROJ):
+            out = _mm(cast(y), cast(layer["out_proj"]))
     real = segs > 0
     return out, {"ssm_positions": jnp.float32(t),
                  "ssm_restarts": (starts & real).sum().astype(jnp.float32)}
@@ -453,10 +458,11 @@ def _held_experts_bwd(rows, per_token, compute_dtype, residuals, g):
     def step(i, grads):
         # a block is differentiated inside its own trip: what it keeps for
         # its backward pass lives and dies there
-        _, pull = jax.vjp(
-            lambda *primals: _held_block(
-                *primals, order, sizes, i, rows=rows, per_token=per_token,
-                compute_dtype=compute_dtype), x, up, down, gates)
+        with jax.named_scope(RECOMPUTE):
+            _, pull = jax.vjp(
+                lambda *primals: _held_block(
+                    *primals, order, sizes, i, rows=rows, per_token=per_token,
+                    compute_dtype=compute_dtype), x, up, down, gates)
         return jax.tree.map(jnp.add, grads, pull(g))
 
     grads = lax.fori_loop(0, held_blocks(sizes, rows), step,

@@ -73,7 +73,8 @@ is the dtype of every large matmul's inputs; accumulation, norms, softmax,
 the router (logits at ``HIGHEST`` precision, softmax, top-k) and the loss
 stay float32.
 
-The second-level ``jax.named_scope``s (``LAYER_SCOPES``) are what
+The second-level ``jax.named_scope``s (``LAYER_SCOPES``) and the third-level
+ones inside them (``PIECE_SCOPES``) are what
 ``analysis.program.program_scopes`` puts a compiled program's operations down
 to under the round's stages.
 """
@@ -96,6 +97,14 @@ EMBED, ATTENTION, ROUTER, EXPERT_DISPATCH, EXPERTS, LM_HEAD_LOSS = (
 SSM, SSM_SCAN, SHARED_EXPERT = "ssm", "ssm_scan", "shared_expert"
 LAYER_SCOPES = (EMBED, ATTENTION, ROUTER, EXPERT_DISPATCH, EXPERTS,
                 LM_HEAD_LOSS, SSM, SSM_SCAN, SHARED_EXPERT)
+# The third level (``parallel.round.PIECES``): the four parts of a state-space
+# mixer around its scan, and the attention core alone inside ``attention``.
+SSM_IN_PROJ, SSM_CONV, SSM_GATE_NORM, SSM_OUT_PROJ, ATTN_CORE = (
+    PIECE_SCOPES) = ("ssm_in_proj", "ssm_conv", "ssm_gate_norm",
+                     "ssm_out_proj", "attn_core")
+# A forward pass run again by hand inside a backward rule
+# (``parallel.round.RECOMPUTE``): a direction, not a piece.
+RECOMPUTE = "recompute"
 # Rows of the sequence whose logits exist at one time in the loss.
 LOSS_CHUNK = 512
 INIT_STD = 0.02
@@ -315,7 +324,8 @@ def attention_core(q, k, v, segs, compute_dtype):
     matmuls."""
     q, k, v = (a.astype(compute_dtype) for a in (q, k, v))
     body = _fused_attention if fused_attention_applies(q, k, v) else _xla_attention
-    return body(q, k, v, segs)
+    with jax.named_scope(ATTN_CORE):
+        return body(q, k, v, segs)
 
 
 def grouped_matmul_applies(xs, w) -> bool:

@@ -43,6 +43,7 @@ import math
 import os
 import signal
 import threading
+import time
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -68,7 +69,8 @@ from fedtpu.telemetry import (TelemetryLogger, build_manifest,
                               make_tracer)
 from fedtpu.telemetry.metrics import device_memory_gauges
 from fedtpu.telemetry.trace import Phase
-from fedtpu.parallel.round import (LAYER_KERNELS, LAYERS, STAGES,
+from fedtpu.parallel.round import (LAYER_KERNELS, LAYERS, PIECES, RECOMPUTE,
+                                   SERVER_UPDATE, SGD_PASS, STAGES,
                                    build_round_fn,
                                    build_eval_fn, check_resident_fits,
                                    init_federated_state, global_params)
@@ -614,40 +616,45 @@ def _tree_finite(tree) -> jax.Array:
 
 def _emit_program_scopes(tracer, program: str, width: Optional[int], fn,
                          *args) -> None:
-    """The join between a device trace and the stage scopes: a trace names
-    an operation by its HLO text, without op_name, so the run says which
-    operation of ``fn``'s program belongs to which stage
+    """The join between a device trace and the scopes: a trace names an
+    operation by its HLO text, without op_name, so the run says which
+    operation of ``fn``'s program belongs to which stage (``scopes``),
+    second-level scope (``layers``), third-level one (``pieces``) and
+    direction (``passes``), all from one walk of the executable's text
     (analysis.program.program_scopes). After ``fn`` ran on arguments shaped
     as ``args``, jit's own lowering cache holds the executable (else the
-    persistent cache does): reading its text compiles nothing.
+    persistent cache does): reading its text compiles nothing. The event's
+    ``dur_s`` is what reading and walking the text took.
 
-    JAX's cache key leaves metadata out, so an executable served from the
-    persistent cache carries the scopes of whichever checkout compiled it
-    first. Every program named here is built under a stage scope; a text
-    that names none is such an executable, and the event says
-    ``stale_metadata`` rather than pass its operations off as unscoped. (A
-    stage boundary moved over unchanged instructions cannot be told.)"""
+    ``configure_persistent_cache`` keys the persistent cache by the
+    metadata too, so the executable a run is served carries its own
+    checkout's scopes. One from elsewhere (a ``ProgramCache`` entry, a cache
+    directory filled before that) can still carry another's: every program
+    named here is built under a stage scope; a text that names none is such
+    an executable, and the event says ``stale_metadata`` rather than pass
+    its operations off as unscoped."""
+    t0 = time.perf_counter()
     try:
         from fedtpu.analysis.program import program_scopes
         compiled = (fn if hasattr(fn, "as_text")
                     else fn.lower(*args).compile())
-        text = compiled.as_text()
-        found = program_scopes(text, STAGES + (STATE_CHECK,))
+        found = program_scopes(
+            compiled.as_text(), STAGES + (STATE_CHECK,), layers=LAYERS,
+            pieces=PIECES, update=(SGD_PASS, SERVER_UPDATE),
+            recompute=(RECOMPUTE,))
         if not found["scopes"]:
             found["stale_metadata"] = True
-        # operation -> innermost second-level scope (a model's own parts,
-        # the server's step); empty for a program that names none
-        found["layers"] = program_scopes(text, LAYERS, strict=True)["scopes"]
         for key in [*found["scopes"], *found["unscoped"]]:
             for prefix, layer in LAYER_KERNELS.items():
                 if key.startswith(prefix):
                     found["layers"][key] = layer
-        tracer.event("program_scopes", program=program, width=width, **found)
+        tracer.event("program_scopes", dur_s=time.perf_counter() - t0,
+                     program=program, width=width, **found)
     except Exception as exc:
         # Diagnostic metadata, like the manifest's audit: a failure here
         # must not take down the run it describes.
-        tracer.event("program_scopes", program=program, width=width,
-                     error=str(exc))
+        tracer.event("program_scopes", dur_s=time.perf_counter() - t0,
+                     program=program, width=width, error=str(exc))
 
 
 def _bcast_into_slots(global_np, live_params):

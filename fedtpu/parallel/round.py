@@ -71,6 +71,20 @@ CLIENT_TRAIN, CLIENT_EVAL, AGGREGATE, METRICS = STAGES = (
 SERVER_UPDATE = "server_update"
 LAYERS = ("embed", "attention", "router", "expert_dispatch", "experts",
           "lm_head_loss", "ssm", "ssm_scan", "shared_expert", SERVER_UPDATE)
+# The third level, inside a layer or outside every one, set where the work
+# happens: the four parts of a state-space mixer around its scan
+# (fedtpu.models.nemotron_h.mamba_mixer), the attention core alone (whichever
+# body of olmoe.attention_core runs; the rest of ``attention`` is the
+# projections'), and the one fused pass a step that applies a gradient and
+# adds the step's share to the accumulator (fedtpu.parallel.stateless). The
+# event maps operations to them under ``pieces``.
+SGD_PASS = "sgd_pass"
+PIECES = ("ssm_in_proj", "ssm_conv", "ssm_gate_norm", "ssm_out_proj",
+          "attn_core", SGD_PASS)
+# Not a piece but a direction: a forward pass run again by hand inside a
+# backward rule (nemotron_h._held_experts_bwd) names itself so, as remat's
+# lowering names its own; ``program_scopes`` reads both under ``passes``.
+RECOMPUTE = "recompute"
 # Kernels the TPU's compiler puts in an instruction's place under a name of
 # its own, which replaces the ``op_name`` and with it every scope: whose they
 # are, by the prefix of the instruction's name. ``lax.ragged_dot`` becomes

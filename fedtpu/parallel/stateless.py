@@ -47,7 +47,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from fedtpu.ops.server_opt import ServerOptimizer, identity_server_optimizer
 from fedtpu.parallel.mesh import CLIENTS_AXIS
 from fedtpu.parallel.round import (AGGREGATE, CLIENT_TRAIN, SERVER_UPDATE,
-                                   assemble_metrics)
+                                   SGD_PASS, assemble_metrics)
 from fedtpu.training.task import Task
 
 AUDIT_SPEC = {
@@ -237,7 +237,8 @@ def build_stateless_round_fn(mesh, task: Task, counts, *,
                     xb, yb, mb = take(xc), take(yc), take(mc)
                     (loss, s), grads = jax.value_and_grad(
                         task.loss, has_aux=True)(at, xb, yb, mb)
-                    new, acc = sgd_pass(at, acc, grads, scale)
+                    with jax.named_scope(SGD_PASS):
+                        new, acc = sgd_pass(at, acc, grads, scale)
                     if keep:
                         p, written = new, written + 1
                     return (p, acc, loss_sum + loss * task.weight(xb, yb, mb),

@@ -296,6 +296,18 @@ def test_tracing_changes_no_result_and_says_which_operation_is_whose(tmp_path):
     assert set(said["round_step"]["scopes"].values()) == set(STAGES)
     assert set(said["state_check"]["scopes"].values()) == {STATE_CHECK}
     assert not any("stale_metadata" in p for p in said.values())
+    # the four maps of one walk: this program names no layer and no piece,
+    # and every operation has a direction (no update scope here: the
+    # resident engine's optimizer step is part of its training pass)
+    for payload in said.values():
+        assert payload["layers"] == {} and payload["pieces"] == {}
+        assert set(payload["passes"]) == {*payload["scopes"],
+                                          *payload["unscoped"]}
+    assert set(said["round_step"]["passes"].values()) == {"forward",
+                                                          "backward"}
+    # what the join cost is on the event's own clock
+    took = [e["dur_s"] for e in _events(path) if e["kind"] == "program_scopes"]
+    assert len(took) == 2 and all(0.0 < d < 30.0 for d in took)
 
 
 def test_an_executable_that_names_no_stage_is_said_to_be_stale():
@@ -314,3 +326,75 @@ def test_an_executable_that_names_no_stage_is_said_to_be_stale():
     (_, stale), (_, fresh) = said
     assert stale["stale_metadata"] is True and not stale["scopes"]
     assert "stale_metadata" not in fresh and fresh["scopes"]
+
+
+# --------------------------------------------- the persistent cache's key
+_PROBE = """
+import sys
+import numpy as np
+import jax
+import jax.numpy as jnp
+from jax import monitoring
+from fedtpu.compilation import configure_persistent_cache
+hits = []
+monitoring.register_event_listener(
+    lambda event, **kw: hits.append(event)
+    if event == "/jax/compilation_cache/cache_hits" else None)
+configure_persistent_cache()
+
+@jax.jit
+def f(x):
+    with jax.named_scope(sys.argv[1]):
+        return jnp.tanh(x @ x).sum()
+
+def another_caller(x):
+    return f(x)
+
+(another_caller if sys.argv[2:] else f)(
+    np.ones((32, 32), np.float32)).block_until_ready()
+print("HITS", len(hits))
+# what keeps the callers out of the key keeps the scopes in the program
+x = np.ones((32, 32), np.float32)
+assert f"/{sys.argv[1]}/" in f.lower(x).compile().as_text()
+"""
+
+
+@pytest.mark.parametrize("second,served", [
+    (("a", "other_scope"), False), (("b", "one_scope"), True),
+    (("a", "one_scope", "traced from another caller"), True)],
+    ids=["another_scope_is_not_served", "another_path_is_served",
+         "another_caller_is_served"])
+def test_a_cached_executable_is_its_own_checkouts(tmp_path, second, served):
+    """``configure_persistent_cache`` keys an entry by the metadata too,
+    names files from the checkout's root and leaves the stack of callers out
+    of a location: a function compiled under one scope and again under
+    another is not served the first one's executable (whose ``op_name``s are
+    the first one's); the same source at another path is, and so is the
+    same function traced from another caller (every job of a process builds
+    its programs anew, each from its own call path). Two checkouts: the
+    package linked under two roots, the traced function in a file of each."""
+    import subprocess
+    import sys
+    import fedtpu
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env.update(JAX_PLATFORMS="cpu", JAX_ENABLE_COMPILATION_CACHE="true",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    env.pop("JAX_HLO_SOURCE_FILE_CANONICALIZATION_REGEX", None)
+
+    def hits(root, *args):
+        root = tmp_path / root
+        if not root.exists():
+            root.mkdir()
+            os.symlink(os.path.dirname(fedtpu.__file__), root / "fedtpu")
+            (root / "probe.py").write_text(_PROBE)
+        done = subprocess.run([sys.executable, "probe.py", *args], cwd=root,
+                              env=env, capture_output=True, text=True,
+                              timeout=300)
+        assert done.returncode == 0, done.stderr[-2000:]
+        return int(done.stdout.strip().splitlines()[-1].split()[1])
+
+    assert hits("a", "one_scope") == 0           # cold: the entry is written
+    assert hits("a", "one_scope") == 1           # and found again
+    assert hits(*second) == (1 if served else 0)

@@ -5,6 +5,7 @@ on uneven shards cut into minibatches, across a mesh, and what it refuses."""
 
 import contextlib
 import dataclasses
+import functools
 import io
 import json
 import re
@@ -343,6 +344,10 @@ def test_a_round_program_compiled_ahead_is_what_the_jobs_dispatch(tmp_path):
     scopes = [e["payload"] for e in events if e["kind"] == "program_scopes"
               and e["payload"]["program"] == "round_step"]
     assert scopes and "error" not in scopes[0] and scopes[0]["scopes"]
+    # the engine's own piece, and its direction
+    fused = [k for k, piece in scopes[0]["pieces"].items()
+             if piece == "sgd_pass"]
+    assert fused and {scopes[0]["passes"][k] for k in fused} == {"update"}
     other = build_experiment(mlp_cfg("stateless", rows=4), ds).make_step(1)
     assert loop._compiled_ahead(other) is other
     assert loop._compiled_ahead(build_experiment(cfg, ds).make_step(1)) is not compiled
@@ -458,6 +463,112 @@ def test_the_layers_of_the_compiled_round_are_named():
     stages = set(program_scopes(text, STAGES)["scopes"].values())
     assert {"client_train", "aggregate"} <= stages <= set(STAGES)
     assert "client_eval" not in stages              # no second forward
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_round(which):
+    """``(text, the one-walk program_scopes of it)`` of a tiny round
+    program: the resident engine's (``sync``), the shared-global engine's on
+    OLMoE and on the hybrid stack."""
+    from fedtpu.analysis.program import program_scopes
+    from fedtpu.parallel.round import (PIECES, RECOMPUTE, SERVER_UPDATE,
+                                       SGD_PASS, STAGES)
+    cfg, ds = {"sync": lambda: (mlp_cfg("resident"), income()),
+               "olmoe": lambda: (tiny_olmoe(), None),
+               "hybrid": lambda: (tiny_nemotron_h(), None)}[which]()
+    exp = build_experiment(cfg, ds)
+    text = exp.make_step(1).lower(exp.state, exp.batch).compile().as_text()
+    return text, program_scopes(
+        text, STAGES + (loop.STATE_CHECK,), layers=LAYERS, pieces=PIECES,
+        update=(SGD_PASS, SERVER_UPDATE), recompute=(RECOMPUTE,))
+
+
+@pytest.mark.parametrize("which", ["sync", "olmoe", "hybrid"])
+def test_one_walk_of_the_text_gives_what_a_call_a_level_gave(which):
+    """``scopes`` / ``unscoped`` and ``layers`` of the one walk are what the
+    two calls over the text returned before there were four maps."""
+    from fedtpu.analysis.program import PASSES, program_scopes
+    from fedtpu.parallel.round import STAGES
+    text, walk = _compiled_round(which)
+    stages = program_scopes(text, STAGES + (loop.STATE_CHECK,))
+    assert walk["scopes"] == stages["scopes"] and stages["scopes"]
+    assert walk["unscoped"] == stages["unscoped"]
+    assert walk["layers"] == program_scopes(text, LAYERS, strict=True)["scopes"]
+    assert bool(walk["layers"]) is (which != "sync")
+    # every operation has a direction, a piece only where a scope says so
+    keys = {*walk["scopes"], *walk["unscoped"]}
+    assert set(walk["passes"]) == keys and set(walk["pieces"]) <= keys
+    assert set(walk["passes"].values()) <= set(PASSES)
+    assert set(walk["pieces"].values()) <= (
+        {"sync": set(), "olmoe": {"attn_core", "sgd_pass"},
+         "hybrid": set(round_mod.PIECES)}[which])
+
+
+def _named(text, marker, listed):
+    """Those of the keys ``listed`` whose own ``op_name`` holds ``marker``
+    (an instruction inside a fusion's body is no operation of its own)."""
+    found = re.findall(r"^\s+(?:ROOT )?%?([\w.\-]+) = \S*?([a-z]\w*\[[\d,]*\])"
+                       r".*op_name=\"[^\"]*" + re.escape(marker), text, re.M)
+    return [key for key in (f"{inst} {shape}"[:120] for inst, shape in found)
+            if key in listed]
+
+
+@pytest.mark.parametrize("what", ["ssm_pieces", "attn_core", "sgd_pass",
+                                  "recompute"])
+def test_the_pieces_and_passes_of_a_tiny_hybrid_round(what):
+    from fedtpu.analysis.program import PASSES
+    from fedtpu.models.olmoe import PIECE_SCOPES, RECOMPUTE
+    assert round_mod.PIECES == PIECE_SCOPES + ("sgd_pass",)
+    assert round_mod.RECOMPUTE == RECOMPUTE
+    text, walk = _compiled_round("hybrid")
+    layers, pieces, passes = walk["layers"], walk["pieces"], walk["passes"]
+    heavy = lambda key: key.startswith(("fusion", "dot", "convolution"))
+    if what == "ssm_pieces":
+        # the mixer outside its scan is its four pieces; what is left (an
+        # instruction of the compiler's whose neighbours disagree) is the
+        # reducers' ``ssm_rest_ms``, and no fusion or product is in it
+        mixer = [k for k, layer in layers.items() if layer == "ssm"]
+        assert {pieces[k] for k in mixer if k in pieces} >= set(
+            round_mod.PIECES[:4])
+        rest = [k for k in mixer if pieces.get(k) not in round_mod.PIECES[:4]]
+        assert len(rest) <= 0.1 * len(mixer) and not any(map(heavy, rest))
+    elif what == "attn_core":
+        core = [k for k, piece in pieces.items() if piece == "attn_core"]
+        assert core and all(layers[k] == "attention" for k in core)
+        assert any(layers[k] == "attention" and k not in pieces
+                   and heavy(k) for k in layers)        # the projections
+    elif what == "sgd_pass":
+        # an operation that names itself under either scope of the update
+        for marker in ("/sgd_pass/", "/server_update/"):
+            update = _named(text, marker, passes)
+            assert update and all(passes[k] == "update" for k in update)
+        assert all(pieces[k] == "sgd_pass"
+                   for k in _named(text, "/sgd_pass/", passes))
+    else:
+        assert set(passes.values()) == set(PASSES)
+        # remat's recomputed body, and the held experts' by hand
+        for marker in ("/rematted_computation/", "/recompute/"):
+            again = _named(text, marker, passes)
+            assert again and all(passes[k] == "recompute" for k in again)
+        by_hand = _named(text, "/recompute/", passes)
+        # the gradients of the block it ran again are the backward pass's
+        # (the TPU's kernels name themselves so; here they are fused away)
+        from fedtpu.analysis.program import _pass_of
+        assert "/transpose(recompute)/" in text
+        assert all(passes[k] == "backward"
+                   for k in _named(text, "/transpose(recompute)/", passes))
+        stack = "jit(s)/client_train/transpose(jvp())/checkpoint/while/body/"
+        assert [_pass_of(stack + rest, ("sgd_pass",), ("recompute",))
+                for rest in ("recompute/jvp(experts)/jit(gmm)/pallas_call",
+                             "transpose(recompute)/jvp(experts)/jit(tgmm)/"
+                             "pallas_call", "rematted_computation/ssm/mul",
+                             "ssm/mul")] == ["recompute", "backward",
+                                             "recompute", "backward"]
+        assert {layers.get(k) for k in by_hand} & {"experts",
+                                                   "expert_dispatch"}
+        # a transposed operation outside both is the backward pass's
+        assert any(passes[k] == "backward"
+                   for k in _named(text, "/transpose(jvp(", passes))
 
 
 def test_a_compiler_kernel_that_drops_its_scope_is_put_down_by_its_name():
