@@ -56,6 +56,27 @@ What is this repo's own:
   (autodiff through the above). ``dt``, ``A``, the decays, their cumulative
   sums, the state carry and the norm are float32; the chunk products take
   ``compute_dtype`` inputs and sum in float32.
+* **The mixer's two float32 passes, two bodies each.** Between ``W_in``
+  and the scan the convolution and its SiLU pass over ``xBC``, between the
+  scan and ``W_out`` the ``D`` skip, the gate and the grouped norm over
+  ``y``, ``x`` and ``z``: elementwise but for a window of four rows and a
+  mean over a group's lanes. ``causal_conv`` and ``gated_group_norm`` are
+  the definitions, the CPU's path and tier-1's, and autodiff's to
+  differentiate. Where ``fused_passes_apply`` says the tiled bodies exist
+  (a TPU, whole row tiles, widths and a group of whole lane tiles: shape and
+  platform only) each pass is ONE row-tiled kernel forward and one backward
+  under a differentiation rule of its own (``fedtpu.ops.ssm_passes``): the
+  operands are read once, in place out of ``W_in``'s product and the
+  convolution's output, every result is written once, the gate's in
+  ``compute_dtype`` (the next operation cast it), the backward kernels
+  recompute what they need in the tile and add up the weights' gradients
+  across the row tiles. And they meet the scan in the form XLA keeps the
+  scan's arrays in, a chunk's positions on the lanes: ``x`` leaves the
+  convolution once more with the positions last, ``y`` enters the gate as
+  the scan leaves it, the cotangents likewise, so no transposing copy
+  stands between a kernel and the scan. Float32 as the definitions; only
+  the order of the sums over rows differs. ``ssm_fused_passes`` counts the
+  positions that ran them.
 * **An expert layer that holds a share.** The layer is told ``experts_held``
   and ``first_expert``: it scores and selects over ALL routed experts and
   computes, droplessly, exactly the assignments of real tokens that fall on
@@ -105,6 +126,7 @@ from fedtpu.models.olmoe import (ATTENTION, EMBED, EXPERT_DISPATCH, EXPERTS,
                                  _head_loss, attention_core, gather_rows,
                                  grouped_matmul, next_token_targets, rms_norm,
                                  sorted_assignments)
+from fedtpu.ops import ssm_passes
 
 KINDS = {"M": "mamba", "E": "experts", "*": "attention"}
 # The held assignments are computed in blocks of whole tiles of this many
@@ -318,6 +340,21 @@ def gated_group_norm(y, z, gain, groups: int, eps):
     return parts.reshape(y.shape) * gain
 
 
+def fused_passes_apply(cfg, t: int) -> bool:
+    """Whether the tiled bodies of the mixer's two float32 passes
+    (``fedtpu.ops.ssm_passes``: the convolution under its SiLU; the skip,
+    the gate and the grouped norm) exist for a sequence of ``t`` positions
+    where the program is being built: a TPU (the PROCESS's backend, as
+    ``olmoe.fused_attention_applies`` reads it), ``t`` whole row tiles, and
+    the inner width, ``xBC``'s width and a group of the norm whole lane
+    tiles. ``causal_conv`` and ``gated_group_norm`` are the definitions and
+    the body everywhere else."""
+    width = cfg.mamba_num_heads * cfg.mamba_head_dim
+    return jax.default_backend() == "tpu" and ssm_passes.tiles_apply(
+        t, min(cfg.chunk_size, t), cfg.conv_kernel, width,
+        width + 2 * cfg.n_groups * cfg.ssm_state_size, width, cfg.n_groups)
+
+
 def mamba_mixer(cfg, compute_dtype, h, layer, segs):
     """``(mixer(RMSNorm(h)), statistics)`` of one ``M`` layer."""
     t = h.shape[0]
@@ -326,15 +363,25 @@ def mamba_mixer(cfg, compute_dtype, h, layer, segs):
     width, state = heads * p, groups * n
     cast = lambda arr: arr.astype(compute_dtype)
     run, starts = document_runs(segs)
+    fused = fused_passes_apply(cfg, t)
     with jax.named_scope(SSM):
         with jax.named_scope(SSM_IN_PROJ):
             x = cast(rms_norm(h, layer["norm"], cfg.layer_norm_epsilon))
-            z, xbc, dt = jnp.split(_mm(x, cast(layer["in_proj"])),
-                                   [width, 2 * width + 2 * state], axis=-1)
+            proj = _mm(x, cast(layer["in_proj"]))
+            z, xbc, dt = jnp.split(proj, [width, 2 * width + 2 * state],
+                                   axis=-1)
         with jax.named_scope(SSM_CONV):
-            xbc = jax.nn.silu(causal_conv(xbc, layer["conv_w"],
-                                          layer["conv_b"], run))
-            xs, b, c = jnp.split(xbc, [width, width + state], axis=-1)
+            if fused:   # ``xBC`` read out of the product in place; ``x``
+                # comes once more with the positions last, the scan's form
+                xbc, xs = ssm_passes.conv_silu(
+                    proj, layer["conv_w"], layer["conv_b"], run, width,
+                    width + 2 * state, width)
+                _, b, c = jnp.split(xbc, [width, width + state], axis=-1)
+                xs = xs.T
+            else:
+                xbc = jax.nn.silu(causal_conv(xbc, layer["conv_w"],
+                                              layer["conv_b"], run))
+                xs, b, c = jnp.split(xbc, [width, width + state], axis=-1)
             xs = xs.reshape(t, heads, p)
             dt = jax.nn.softplus(dt + layer["dt_bias"])
         with jax.named_scope(SSM_SCAN):
@@ -342,9 +389,17 @@ def mamba_mixer(cfg, compute_dtype, h, layer, segs):
                          b.reshape(t, groups, n), c.reshape(t, groups, n),
                          run, cfg.chunk_size, compute_dtype)
         with jax.named_scope(SSM_GATE_NORM):
-            y = (y + layer["D"][:, None] * xs).reshape(t, width)
-            y = gated_group_norm(y, z, layer["gate_norm"], groups,
-                                 cfg.layer_norm_epsilon)
+            if fused:   # ``y`` as the scan leaves it, ``x`` and ``z`` in
+                # place; rounded here, once
+                y = ssm_passes.skip_gate_norm(
+                    ssm_passes.chunk_transposed(y.reshape(t, width),
+                                                min(cfg.chunk_size, t)),
+                    xbc, proj, layer["D"], layer["gate_norm"], groups,
+                    cfg.layer_norm_epsilon, compute_dtype)
+            else:
+                y = (y + layer["D"][:, None] * xs).reshape(t, width)
+                y = gated_group_norm(y, z, layer["gate_norm"], groups,
+                                     cfg.layer_norm_epsilon)
         with jax.named_scope(SSM_OUT_PROJ):
             out = _mm(cast(y), cast(layer["out_proj"]))
     real = segs > 0
@@ -533,8 +588,10 @@ def nemotron_h_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
     """One packed row ``(2, T)`` through the model: ``olmoe_sequence_stats``'s
     sums over tokens (``loss_sum``, ``correct``, ``count``, ``tokens``,
     ``padding``, ``expert_load`` over ALL routed experts and summed over
-    layers, ``fused_attention``, ``grouped_experts``) and the share's and
-    the scan's own, summed over layers: ``assignments_held`` (real tokens'
+    layers, ``fused_attention``, ``grouped_experts``), ``ssm_fused_passes``
+    (positions whose ``M`` layers ran the tiled passes: T or 0) and the
+    share's and the scan's own, summed over layers: ``assignments_held``
+    (real tokens'
     assignments on held experts), ``rows_computed`` (the buffer the expert
     matmuls ran over), ``rows_held_computed`` (held assignments inside it:
     all of them), ``ssm_positions`` (positions the scan ran over),
@@ -555,6 +612,7 @@ def nemotron_h_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
         jax.ShapeDtypeStruct((rows, k), compute_dtype),
         jax.ShapeDtypeStruct((held, k, n), compute_dtype))
         for k, n in ((wide, narrow), (narrow, wide)))
+    tiled = "mamba" in kinds and fused_passes_apply(cfg, t)
     with jax.named_scope(EMBED):
         h = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
 
@@ -579,7 +637,8 @@ def nemotron_h_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
             "tokens": (segs > 0).sum().astype(jnp.float32),
             "padding": (segs == 0).sum().astype(jnp.float32),
             "fused_attention": jnp.float32(t if fused else 0),
-            "grouped_experts": jnp.float32(t if grouped else 0), **stats}
+            "grouped_experts": jnp.float32(t if grouped else 0),
+            "ssm_fused_passes": jnp.float32(t if tiled else 0), **stats}
 
 
 def nemotron_h_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
@@ -590,8 +649,8 @@ def nemotron_h_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
         stats = nemotron_h_sequence_stats(
             params, row * m.astype(row.dtype), cfg, compute_dtype)
         return {**stats, **{k: stats[k] * m for k in (
-            "padding", "fused_attention", "grouped_experts", "ssm_positions",
-            "rows_computed")}}
+            "padding", "fused_attention", "grouped_experts",
+            "ssm_fused_passes", "ssm_positions", "rows_computed")}}
 
     if x.shape[0] == 1:
         return one((x[0], mask[0]))

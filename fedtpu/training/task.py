@@ -103,7 +103,8 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
     share = {"moe_assignments_held": "assignments_held",
              "moe_rows_computed": "rows_computed",
              "ssm_positions": "ssm_positions",
-             "ssm_document_restarts": "ssm_restarts"}
+             "ssm_document_restarts": "ssm_restarts",
+             "ssm_fused_pass_positions": "ssm_fused_passes"}
 
     def counters(s):
         load = s["expert_load"].astype(jnp.float32)

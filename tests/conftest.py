@@ -5,6 +5,7 @@ platform to expose 8 devices so multi-client mesh code runs (and collectives
 execute) without TPU hardware. Must be set before jax initializes.
 """
 
+import contextlib
 import os
 import sys
 
@@ -410,4 +411,30 @@ def grouped_on_the_cpu(monkeypatch):
 
     monkeypatch.setattr(olmoe, "grouped_matmul_applies", lambda xs, w: True)
     with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+@contextlib.contextmanager
+def tiled_passes_interpreted(patch):
+    """The hybrid stack's state-space mixers through the tiled bodies of
+    their two float32 passes (``fedtpu.ops.ssm_passes``) on the CPU: the
+    rule between the bodies (``nemotron_h.fused_passes_apply``) is steered
+    to them through ``patch`` (a ``MonkeyPatch``) and the kernels
+    interpreted (always under jit, as above). The interpreter works through
+    ordered callbacks, which ``jax.checkpoint`` cannot hold, so the stack's
+    layers are not recomputed here: that moves no value."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from fedtpu.models import nemotron_h
+
+    patch.setattr(nemotron_h, "fused_passes_apply", lambda cfg, t: True)
+    patch.setattr(jax, "checkpoint", lambda fn, **policy: fn)
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+@pytest.fixture
+def tiled_passes_on_the_cpu(monkeypatch):
+    """``tiled_passes_interpreted`` for one test."""
+    with tiled_passes_interpreted(monkeypatch):
         yield

@@ -385,9 +385,10 @@ def hybrid_round(topo):
     layers MEMEM*EME at published widths, 8 of 128 experts and an eighth of
     the vocabulary held, 667.0M parameters; 8 clients, 16 packed
     8,192-token sequences, FedAvgM) compiled for one described v5e chip.
-    The rules between the bodies of the attention core and of the expert
-    matmuls read the process's backend, the CPU here: they are told it is
-    a TPU and answer for the cell's shapes as they do on the chip."""
+    The rules between the bodies of the attention core, of the expert
+    matmuls and of the mixers' two float32 passes read the process's
+    backend, the CPU here: they are told it is a TPU and answer for the
+    cell's shapes as they do on the chip."""
     from fedtpu.config import get_preset
     from fedtpu.models.registry import build_model
     from fedtpu.ops.server_opt import make_server_optimizer
@@ -421,7 +422,17 @@ def hybrid_round(topo):
         return step.lower(state, batch).compile()
 
 
-E_LAYERS = 4            # of the preset's nine, MEMEM*EME
+E_LAYERS = M_LAYERS = 4   # of the preset's nine, MEMEM*EME
+
+
+def _named_kernels(compiled, piece: str) -> list:
+    """``(kernel's name, the rest of its op_name before it)`` of the
+    compiled module's Pallas kernels that are called by name under the scope
+    ``piece`` (``pl.pallas_call(name=...)``: the name stands where a jitted
+    library kernel's ``jit(...)`` does)."""
+    return [(name, before) for before, name in re.findall(
+        r'custom_call_target="tpu_custom_call".*op_name="([^"]*/%s)/(\w+)/'
+        r'pallas_call"' % piece, compiled.as_text())]
 
 
 def test_the_hybrid_round_at_published_widths_fits_one_v5e_chip(hybrid_round):
@@ -446,7 +457,41 @@ def test_the_hybrid_round_at_published_widths_fits_one_v5e_chip(hybrid_round):
     assert sorted(_pallas_calls(hybrid_round, "experts")) == (
         ["gmm"] * 6 * E_LAYERS * STEP_KINDS + ["tgmm"] * 2 * E_LAYERS * STEP_KINDS)
     assert "ragged-dot" not in text
+    # and these, with the mixers' six a layer (PR 36, the test below), are
+    # all the program's Mosaic calls
+    assert _mosaic_calls(hybrid_round) == STEP_KINDS * (
+        4 + 8 * E_LAYERS + 6 * M_LAYERS)
     # every scope the reducers read is in the program
     for scope in ("ssm", "ssm_scan", "shared_expert", "router",
                   "expert_dispatch", "experts", "lm_head_loss"):
         assert f"/{scope}/" in text or f"({scope})" in text, scope
+
+
+@pytest.mark.parametrize("piece,forward,backward", [
+    ("ssm_conv", "ssm_conv_forward", "ssm_conv_backward"),
+    ("ssm_gate_norm", "ssm_gate_norm_forward", "ssm_gate_norm_backward")])
+def test_the_hybrid_round_runs_the_mixers_passes_in_the_tiled_kernels(
+        hybrid_round, piece, forward, backward):
+    """PR 36, the rule told the backend is a TPU: each of a state-space
+    mixer's two float32 passes is one Mosaic call forward, the same once
+    more in the layer's recomputation, and one backward, an ``M`` layer and
+    kind of step; each call stands under its piece's scope (what
+    ``ssm_conv_ms`` / ``ssm_gate_norm_ms`` read) and its ``op_name`` tells
+    the direction as ``analysis.program`` reads it: nothing, remat's
+    ``rematted_computation``, a ``transpose(``."""
+    from fedtpu.analysis.program import (BACKWARD, FORWARD, RECOMPUTE,
+                                         _pass_of, _stage_of)
+    from fedtpu.parallel.round import PIECES
+
+    calls = _named_kernels(hybrid_round, piece)
+    each = M_LAYERS * STEP_KINDS
+    assert sorted(name for name, _ in calls) == (
+        [backward] * each + [forward] * 2 * each)
+    directions = {FORWARD: 0, RECOMPUTE: 0, BACKWARD: 0}
+    for name, before in calls:
+        op_name = f"{before}/{name}/pallas_call"
+        assert _stage_of(op_name, PIECES) == piece
+        direction = _pass_of(op_name, (), ())
+        assert (direction == BACKWARD) == (name == backward), op_name
+        directions[direction] += 1
+    assert directions == {FORWARD: each, RECOMPUTE: each, BACKWARD: each}

@@ -8,18 +8,22 @@ at chunk sizes that do and do not divide them; the shares of an expert
 layer adding up to the uncut layer; the loss over a vocabulary slice; the
 blocks of the held-assignments buffer; what the registry refuses."""
 
+import contextlib
 import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from fedtpu.config import ModelConfig, get_preset
 from fedtpu.models import nemotron_h as nh
 from fedtpu.models.registry import build_model
+from fedtpu.ops import ssm_passes
 from fedtpu.training.task import build_task
 from perfbench import flops_nemotron_h, reference_nemotron_h as ref
+from tests.conftest import tiled_passes_interpreted
 
 T = 64
 TINY = ModelConfig(
@@ -90,18 +94,30 @@ def relative_gaps(a, b):
 
 # ------------------------------------------------- (a) loss and gradients
 @pytest.fixture(scope="module")
-def both_sides():
+def reference_side():
+    """``(loss, grads)`` of the reference on the tiny stack's two rows."""
+    reference = jax.jit(jax.value_and_grad(
+        lambda p: reference_loss(TINY, p, rows_of())))
+    with jax.default_matmul_precision("highest"):
+        return reference(seeded(TINY))
+
+
+@pytest.fixture(scope="module", params=["definitions", "tiled"])
+def both_sides(request, reference_side):
     """``(program (loss, stats, grads), reference (loss, grads))`` of the
-    tiny stack on two rows, of two and of three documents."""
+    tiny stack on two rows, of two and of three documents; the program's
+    state-space mixers through the definitions of their two float32 passes
+    (what a CPU runs by itself) and through the tiled bodies, interpreted."""
     params, x = seeded(TINY), rows_of()
     program = jax.jit(jax.value_and_grad(
         lambda p: program_loss(TINY, p, x), has_aux=True))
-    reference = jax.jit(jax.value_and_grad(
-        lambda p: reference_loss(TINY, p, x)))
-    (loss, stats), grads = program(params)
-    with jax.default_matmul_precision("highest"):
-        ref_loss, ref_grads = reference(params)
-    return (loss, stats, grads), (ref_loss, ref_grads)
+    with pytest.MonkeyPatch.context() as patch, (
+            tiled_passes_interpreted(patch) if request.param == "tiled"
+            else contextlib.nullcontext()):
+        (loss, stats), grads = program(params)
+    assert float(stats["ssm_fused_passes"]) == (
+        2 * T if request.param == "tiled" else 0)
+    return (loss, stats, grads), reference_side
 
 
 def test_the_loss_is_the_references(both_sides):
@@ -261,25 +277,163 @@ def test_two_packed_documents_scan_as_the_two_alone(chunk):
                                atol=2e-5)
 
 
-def test_the_convolution_does_not_read_across_a_documents_edge():
+@pytest.mark.parametrize("body", ["definition", "tiled"])
+def test_the_convolution_does_not_read_across_a_documents_edge(
+        body, monkeypatch):
+    """Through the definition, and through the tiled body (four tiles of 16
+    rows: the first document ends mid-tile, the second a tile's row 13),
+    which returns the convolution under its SiLU."""
     key = jax.random.key(3)
     x = jax.random.normal(key, (64, 6))
     w = jax.random.normal(jax.random.key(4), (4, 6))
     bias = jax.random.normal(jax.random.key(5), (6,))
     segs = jnp.asarray([1] * 24 + [2] * 37 + [0] * 3, jnp.int32)
     run, starts = nh.document_runs(segs)
-    packed = nh.causal_conv(x, w, bias, run)
+    if body == "tiled":
+        after = jax.nn.silu
+        monkeypatch.setattr(ssm_passes, "CONV_TILE", (16, 6))
+        tiled = jax.jit(lambda x, run: ssm_passes.conv_silu(
+            x, w, bias, run, 0, 6, 6)[0])
+        with pltpu.force_tpu_interpret_mode():
+            packed = tiled(x, run)
+    else:
+        after = lambda pre: pre
+        packed = nh.causal_conv(x, w, bias, run)
     ones = lambda n: jnp.ones((n,), jnp.int32)
-    alone = [nh.causal_conv(x[lo:hi], w, bias, ones(hi - lo))
+    alone = [after(nh.causal_conv(x[lo:hi], w, bias, ones(hi - lo)))
              for lo, hi in ((0, 24), (24, 61), (61, 64))]
     np.testing.assert_allclose(np.asarray(packed), np.concatenate(alone),
                                rtol=0, atol=1e-6)
     np.testing.assert_allclose(np.asarray(packed),
-                               np.asarray(ref.conv(x, w, bias, starts)),
+                               np.asarray(after(ref.conv(x, w, bias, starts))),
                                rtol=0, atol=1e-6)
     # the taps' order is the published one: w[K-1] weighs the token itself
     np.testing.assert_allclose(np.asarray(packed[0]),
-                               np.asarray(x[0] * w[3] + bias), atol=1e-6)
+                               np.asarray(after(x[0] * w[3] + bias)), atol=1e-6)
+
+
+# ------------------- (c') the tiled passes against their definitions
+def _segments(t, edges, padding=0):
+    """Segment ids of ``t`` positions: a new document at every one of
+    ``edges``, the last ``padding`` positions padding."""
+    segs = 1 + np.searchsorted(np.asarray(edges), np.arange(t), side="right")
+    segs[t - padding:] = 0
+    return jnp.asarray(segs, jnp.int32)
+
+
+def _close(got, want, names):
+    for name, gap in zip(names, relative_gaps(tuple(got), tuple(want))):
+        assert gap <= 2e-6, (name, gap)
+
+
+def _with_gradients(body, weigh, n_args):
+    """``args -> (body(*args), its gradients under the weights ``weigh``)``,
+    jitted."""
+    return jax.jit(lambda *args: (body(*args), jax.grad(
+        lambda *a: (body(*a).astype(jnp.float32) * weigh).sum(),
+        argnums=tuple(range(n_args)))(*args)))
+
+
+# a tile is 16 rows here: where the runs' edges lie in it
+RUN_EDGES = {
+    "a_tiles_first_row": dict(edges=(16, 32, 48)),
+    "a_tiles_row_1": dict(edges=(17, 49)),
+    "a_tiles_row_2": dict(edges=(2, 18, 34)),
+    "a_tiles_row_3": dict(edges=(3, 19, 51)),
+    "a_tiles_last_rows": dict(edges=(13, 30, 47, 63)),
+    "mid_tile": dict(edges=(8, 24, 40)),
+    "a_run_longer_than_a_tile": dict(edges=(5, 50)),
+    "one_run": dict(edges=()),
+    "every_row_a_run": dict(edges=tuple(range(1, 64))),
+    "a_padding_run_at_the_end": dict(edges=(30,), padding=7),
+}
+
+
+@pytest.mark.parametrize("first", [0, 64])
+@pytest.mark.parametrize("where", RUN_EDGES)
+def test_the_tiled_convolution_is_its_definition(where, first, monkeypatch):
+    """``ssm_passes.conv_silu`` (interpreted; four tiles of 16 rows, two of
+    64 columns) against ``silu(causal_conv)``: the result, its first tile of
+    columns once more with the positions last, and the gradient of ``xBC``
+    (read in place from column ``first`` of a wider array, whose other
+    columns get a zero gradient), of the weight and of the bias, with a
+    cotangent on both results."""
+    width, width_t, total = 128, 64, 200
+    keys = jax.random.split(jax.random.key(len(where) + first), 4)
+    src = jax.random.normal(keys[0], (T, total))
+    w = jax.random.normal(keys[1], (4, width))
+    bias = jax.random.normal(keys[2], (width,))
+    weigh = jax.random.normal(keys[3], (T, width + width_t))
+    run, _ = nh.document_runs(_segments(T, **RUN_EDGES[where]))
+
+    def definition(src, w, bias):
+        out = jax.nn.silu(nh.causal_conv(src[:, first:first + width], w,
+                                         bias, run))
+        return jnp.concatenate([out, out[:, :width_t]], axis=1)
+
+    monkeypatch.setattr(ssm_passes, "CONV_TILE", (16, 64))
+
+    def tiled(src, w, bias):
+        out, first_t = ssm_passes.conv_silu(src, w, bias, run, first, width,
+                                            width_t)
+        assert first_t.shape == (width_t, T)
+        return jnp.concatenate([out, first_t.T], axis=1)
+
+    with pltpu.force_tpu_interpret_mode():
+        out, grads = _with_gradients(tiled, weigh, 3)(src, w, bias)
+    want, want_grads = _with_gradients(definition, weigh, 3)(src, w, bias)
+    _close((out, *grads), (want, *want_grads),
+           ("out", "xBC", "conv_w", "conv_b"))
+    outside = np.ones(total, bool)
+    outside[first:first + width] = False
+    assert not np.asarray(grads[0])[:, outside].any()
+
+
+@pytest.mark.parametrize("tile", [(16, 32), (32, 64), (64, 16), (8, 64)])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_tiled_gate_and_norm_is_its_definition(tile, dtype, monkeypatch):
+    """``ssm_passes.skip_gate_norm`` (interpreted; tiles of one group of 16
+    columns, of two, of all four) against the ``D`` skip and
+    ``gated_group_norm``: the result, rounded once to ``dtype``, and the
+    gradient of ``y`` (handed over chunk-transposed, chunks of 8 rows), of
+    ``x`` and ``z`` (the first columns of wider arrays, read in place), of
+    the gain and of ``D``."""
+    width, heads, groups, eps = 64, 8, 4, 1e-5
+    keys = jax.random.split(jax.random.key(sum(tile)), 6)
+    y = jax.random.normal(keys[0], (T, width))
+    xs = jax.random.normal(keys[1], (T, 128))
+    zs = jax.random.normal(keys[2], (T, 200))
+    skip = 1 + 0.3 * jax.random.normal(keys[3], (heads,))
+    gain = 1 + 0.3 * jax.random.normal(keys[4], (width,))
+    weigh = jax.random.normal(keys[5], (T, width))
+
+    def definition(y, xs, zs, skip, gain):
+        x = xs[:, :width].reshape(T, heads, -1)
+        v = (y.reshape(T, heads, -1) + skip[:, None] * x).reshape(T, width)
+        return nh.gated_group_norm(v, zs[:, :width], gain, groups,
+                                   eps).astype(dtype)
+
+    monkeypatch.setattr(ssm_passes, "GATE_TILE", tile)
+
+    def tiled(y, xs, zs, skip, gain):
+        return ssm_passes.skip_gate_norm(
+            ssm_passes.chunk_transposed(y, 8), xs, zs, skip, gain, groups,
+            eps, jnp.dtype(dtype))
+
+    args = (y, xs, zs, skip, gain)
+    with pltpu.force_tpu_interpret_mode():
+        out, grads = _with_gradients(tiled, weigh, 5)(*args)
+    want, want_grads = _with_gradients(definition, weigh, 5)(*args)
+    assert out.dtype == want.dtype == jnp.dtype(dtype)
+    # rounded once from float32 on both sides: the same value, or (a float32
+    # tie apart) its neighbour
+    out, want = out.astype(jnp.float32), want.astype(jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(want), atol=0,
+        rtol=2e-6 if dtype == "float32" else 2 ** -7)
+    _close(grads, want_grads, ("y", "x", "z", "D", "gate_norm"))
+    assert not np.asarray(grads[1])[:, width:].any()
+    assert not np.asarray(grads[2])[:, width:].any()
 
 
 def test_two_packed_documents_give_the_losses_of_the_two_alone():
@@ -404,6 +558,45 @@ def test_the_counter_says_which_body_the_held_experts_ran(body, request):
     stats = sequence_stats(seeded(TINY), rows_of()[0])
     assert int(stats["grouped_experts"]) == (T if body == "grouped" else 0)
     assert int(stats["rows_held_computed"]) == int(stats["assignments_held"]) > 0
+
+
+@pytest.mark.parametrize("body", ["definitions", "tiled"])
+def test_the_counter_says_which_body_the_two_passes_ran(body, request):
+    """``ssm_fused_passes`` reads the rule between the bodies as the mixers
+    read it: every position where the tiled passes ran, none where the
+    definitions did (a CPU, by itself); the task hands it on as
+    ``ssm_fused_pass_positions``."""
+    if body == "tiled":
+        request.getfixturevalue("tiled_passes_on_the_cpu")
+    sequence_stats = jax.jit(lambda p, r: nh.nemotron_h_sequence_stats(
+        p, r, TINY, jnp.float32))
+    stats = sequence_stats(seeded(TINY), rows_of()[0])
+    assert int(stats["ssm_fused_passes"]) == (T if body == "tiled" else 0)
+    counters = build_task(TINY, build_model(TINY)[1],
+                          TINY.vocab_size).counters(stats)
+    assert int(counters["ssm_fused_pass_positions"]) == (
+        T if body == "tiled" else 0)
+    assert int(counters["ssm_positions"]) == 2 * T
+
+
+def test_the_rule_between_the_passes_bodies_reads_shapes_and_the_backend(
+        monkeypatch):
+    """``fused_passes_apply``: on a TPU whole row tiles, and the inner
+    width, ``xBC``'s and a group of the norm whole lane tiles; nowhere on
+    a CPU."""
+    cell = get_preset("nemotron-h-30b-a3b-l9").model
+    assert not nh.fused_passes_apply(cell, 8192)         # this is a CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert nh.fused_passes_apply(cell, 8192)
+    assert nh.fused_passes_apply(cell, 1024)
+    assert not nh.fused_passes_apply(cell, 8192 + 256)   # no whole row tile
+    assert not nh.fused_passes_apply(TINY, 1024)         # widths of 64, 128
+    narrow = dataclasses.replace(cell, n_groups=64)      # a group of 64
+    assert not nh.fused_passes_apply(narrow, 8192)
+    odd = dataclasses.replace(cell, ssm_state_size=100)  # xBC 5,696 wide
+    assert not nh.fused_passes_apply(odd, 8192)
+    long = dataclasses.replace(cell, conv_kernel=12)     # past a halo block
+    assert not nh.fused_passes_apply(long, 8192)
 
 
 # ------------------------------------------------ (e) the vocabulary slice

@@ -162,6 +162,7 @@ def test_two_rounds_of_a_tiny_hybrid_stack_match_the_references_fedavgm(
     documents = int(sum((row[1][1:] != row[1][:-1]).sum() + 1
                         - (row[1][-1] == 0) for row in ds.x_train))
     assert counted["ssm_document_restarts"] == 2 * 2 * documents
+    assert counted["ssm_fused_pass_positions"] == 0     # a CPU: the definitions
     assert counted["lm_padding_tokens"] == 2 * (10 * 48 - tokens)
     assert counted["stateless_client_steps"] == 2 * 10
     load = [e["payload"]["moe_expert_load"] for e in events if e["kind"] == "round"]
