@@ -140,7 +140,8 @@ def _pass_of(op_name: str, update: Sequence[str],
 
 def program_scopes(compiled_text: str, stages: Sequence[str],
                    strict: bool = False, *, layers: Sequence[str] = (),
-                   pieces: Sequence[str] = (), update: Sequence[str] = (),
+                   pieces: Sequence[str] = (), modules: Sequence[str] = (),
+                   update: Sequence[str] = (),
                    recompute: Sequence[str] = ()) -> dict:
     """Which of the ``jax.named_scope`` names ``stages`` (the builder's:
     ``parallel.round.STAGES``) each operation of a compiled program belongs
@@ -167,7 +168,10 @@ def program_scopes(compiled_text: str, stages: Sequence[str],
 
     ``layers`` and ``pieces`` (``parallel.round.LAYERS``, ``PIECES``) are
     two such levels: ``{key: innermost scope of the level}``, both strict,
-    empty where the program names none or none is asked for.
+    empty where the program names none or none is asked for. ``modules``
+    (``parallel.round.MODULES``) is a third, of scopes that lie AROUND
+    layers (a whole multi-token-prediction module): read as ``layers`` is,
+    and given only where it is asked for.
 
     A fusion the compiler left without an ``op_name`` (a multi-output
     fusion: its root is a tuple, which carries none) reads, for ``pieces``
@@ -220,10 +224,14 @@ def program_scopes(compiled_text: str, stages: Sequence[str],
     if pieces:
         levels.append(
             ("pieces", lambda name: _stage_of(name, pieces), True, True))
+    if modules:
+        levels.append(
+            ("modules", lambda name: _stage_of(name, modules), True, False))
     levels.append(
         ("passes", lambda name: _pass_of(name, update, recompute), True, True))
     found: dict[str, dict] = {"scopes": {}, "layers": {}, "pieces": {},
-                              "passes": {}}
+                              "passes": {}, **({"modules": {}} if modules
+                                               else {})}
     unscoped: list[str] = []
     for name in run:
         keys, op_names, operands, junctions, bodies = {}, {}, {}, set(), {}

@@ -96,7 +96,7 @@ class ModelConfig:
     """Model family + shape. MLP is FL_CustomMLP...:12-25; ConvNet is the
     BASELINE.json config-5 CIFAR-10 stress model (new, no reference analogue)."""
 
-    kind: str = "mlp"                    # 'mlp' | 'convnet' | 'olmoe' | 'nemotron_h'
+    kind: str = "mlp"                    # 'mlp' | 'convnet' | 'olmoe' | 'nemotron_h' | 'xing4'
     # () degenerates the MLP to a single Linear — multinomial logistic
     # regression (pinned by tests/test_round_smoke.py).
     hidden_sizes: Tuple[int, ...] = (50, 200)  # FL_CustomMLP...:40
@@ -150,6 +150,38 @@ class ModelConfig:
     # experts_held) and the shared expert. 0 held = all of them.
     experts_held: int = 0
     first_expert: int = 0
+    # kind='xing4' (fedtpu.models.xing4): the keys of the published
+    # config.json of XingChen-AGI/Xing4.0-29B-A4B under their own names and
+    # at its values (its nested ``rope_scaling`` group flat, each key behind
+    # ``rope_scaling_``); it also reads hidden_size, num_attention_heads,
+    # num_hidden_layers, intermediate_size (here the width of a leading
+    # DENSE layer), vocab_size, rope_theta, rms_norm_eps, norm_topk_prob,
+    # num_experts_per_tok, n_routed_experts, moe_intermediate_size,
+    # routed_scaling_factor, experts_held and first_expert above, which its
+    # preset sets. Latent attention behind two low-rank bottlenecks, a
+    # residual of hc_mult streams mixed around every sublayer, the first
+    # first_k_dense_replace layers a plain gated MLP and the others sparse
+    # experts beside n_shared_experts shared ones, then
+    # num_nextn_predict_layers multi-token-prediction modules (0 or 1).
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_scaling_factor: float = 64.0
+    rope_scaling_original_max_position_embeddings: int = 4096
+    rope_scaling_beta_fast: float = 32.0
+    rope_scaling_beta_slow: float = 1.0
+    rope_scaling_mscale: float = 1.0
+    rope_scaling_mscale_all_dim: float = 1.0
+    first_k_dense_replace: int = 2
+    n_shared_experts: int = 1
+    num_nextn_predict_layers: int = 0       # published: 1; a preset states it
+    hc_mult: int = 4
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -336,6 +368,13 @@ class FedConfig:
     # minibatches of this many rows, one step each (stateless engine only;
     # for the language model a row is one packed sequence).
     local_batch_rows: int = 0
+    # Stateless engine: every local step runs from the client's working
+    # copy, which the client's start fills from the global, so the round
+    # program holds one trace of the model instead of one for each kind of
+    # step the clients' counts call for (only / first / between / last): a
+    # third to a quarter of a deep model's compile and executable, for one
+    # more pass over the parameters a client.
+    one_step_kind: bool = False
     # The reference reads its stop signal one loop-top late (:132 vs :195)
     # but the doomed iteration breaks before training — no extra round is
     # trained, so there is no lag to reproduce (tests/test_stop_lag.py
@@ -706,6 +745,36 @@ PRESETS["nemotron-h-30b-a3b-l9"] = ExperimentConfig(
                       steplr_gamma=1.0),
     fed=FedConfig(rounds=20, client_state="stateless", local_batch_rows=1,
                   server_opt="fedavgm", server_momentum=0.9, same_init=True),
+)
+
+
+# XingChen-AGI/Xing4.0-29B-A4B at its published widths, as one 16 GB chip of
+# an 8-way expert-parallel stage holds it: one leading dense layer and four
+# expert layers of its 40 (every layer latent attention on a four-stream
+# residual), 8 of each layer's 64 routed experts (the router stays 64 wide,
+# top-4), an eighth of the vocabulary, and its multi-token-prediction module
+# with the second loss: 913.5M of 30.3B parameters. Federated as the other two
+# language models' presets are, on 16 packed 4,096-token sequences, with one
+# kind of step (``one_step_kind``): four traces of six unrolled blocks
+# neither compile under the chip's memory beside 10.96 GB of engine state
+# nor in a benchmark run's time (PERF.md section 6, PR 37).
+PRESETS["xing4-29b-a4b-l5-mtp1"] = ExperimentConfig(
+    data=DataConfig(dataset_name="tokens", synthetic_rows=16,
+                    synthetic_features=4096),
+    shard=ShardConfig(num_clients=8, shuffle=False),
+    model=ModelConfig(kind="xing4", hidden_size=3584, num_attention_heads=32,
+                      num_hidden_layers=5, first_k_dense_replace=1,
+                      num_nextn_predict_layers=1,
+                      intermediate_size=9216, n_routed_experts=64,
+                      moe_intermediate_size=1024, num_experts_per_tok=4,
+                      norm_topk_prob=True, routed_scaling_factor=2.0,
+                      rms_norm_eps=1e-6, vocab_size=16384, experts_held=8,
+                      first_expert=0, compute_dtype="bfloat16"),
+    optim=OptimConfig(name="sgd", learning_rate=0.005, momentum=0.0,
+                      steplr_gamma=1.0),
+    fed=FedConfig(rounds=20, client_state="stateless", local_batch_rows=1,
+                  one_step_kind=True, server_opt="fedavgm",
+                  server_momentum=0.9, same_init=True),
 )
 
 

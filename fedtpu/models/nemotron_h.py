@@ -443,7 +443,16 @@ def route(x, router_w, bias, top_k: int, norm_topk_prob: bool, scale: float):
     return gates * scale, experts.astype(jnp.int32)
 
 
-def _held_block(x, up, down, gates, order, sizes, block, rows: int,
+def _activation(products):
+    """An expert's activation from its first matmuls' products: ``relu(up)^2``
+    of one (this tower's), ``silu(gate) * up`` of two (the gated form)."""
+    if len(products) == 1:
+        return jnp.square(jax.nn.relu(products[0]))
+    gate, up = products
+    return jax.nn.silu(gate) * up
+
+
+def _held_block(x, weights, gates, order, sizes, block, rows: int,
                 per_token: int, compute_dtype):
     """What rows ``[block * rows, (block + 1) * rows)`` of the sorted
     assignments add to the layer's output, ``(T, H)`` float32: the held
@@ -468,8 +477,9 @@ def _held_block(x, up, down, gates, order, sizes, block, rows: int,
         xs = only_filled(gather_rows(cast(x), taken, per_token))
         weigh = jnp.take(gates, taken)
     with jax.named_scope(EXPERTS):
-        act = jnp.square(jax.nn.relu(only_filled(
-            grouped_matmul(xs, cast(up), inside))))
+        *into, down = weights
+        act = _activation([only_filled(grouped_matmul(xs, cast(w), inside))
+                           for w in into])
         ys = only_filled(grouped_matmul(cast(act), cast(down), inside))
     with jax.named_scope(EXPERT_DISPATCH):
         return jnp.zeros(x.shape, jnp.float32).at[taken // per_token].add(
@@ -481,18 +491,20 @@ def held_blocks(sizes, rows: int):
     return (sizes.sum() + rows - 1) // rows
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
-def held_experts(x, up, down, gates, order, sizes, rows: int, per_token: int,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def held_experts(x, weights, gates, order, sizes, rows: int, per_token: int,
                  compute_dtype):
-    """``sum over held assignments of gate * down_e relu(up_e x)^2``,
-    ``(T, H)`` float32. ``x (T, H)`` float32; ``up (held, H, I)``, ``down
-    (held, I, H)``; ``gates (T * per_token,)`` every assignment's gate,
+    """``sum over held assignments of gate * expert_e(x)``, ``(T, H)``
+    float32. ``x (T, H)`` float32; ``weights`` the held experts' matrices,
+    ``(up (held, H, I), down (held, I, H))`` of ``down relu(up x)^2`` or
+    ``(gate, up, down)`` of the gated ``down (silu(gate x) * up x)``;
+    ``gates (T * per_token,)`` every assignment's gate,
     token-major; ``order`` (padded to whole blocks), ``sizes (held,)`` from
     ``sorted_assignments`` with the held assignments first. A loop over as
     many blocks of ``rows`` as hold them, its trips read from ``sizes``:
     reverse mode only, under a rule of its own, because a loop of that kind
     has no transpose."""
-    block = functools.partial(_held_block, x, up, down, gates, order, sizes,
+    block = functools.partial(_held_block, x, weights, gates, order, sizes,
                               rows=rows, per_token=per_token,
                               compute_dtype=compute_dtype)
     return lax.fori_loop(0, held_blocks(sizes, rows),
@@ -500,15 +512,15 @@ def held_experts(x, up, down, gates, order, sizes, rows: int, per_token: int,
                          jnp.zeros(x.shape, jnp.float32))
 
 
-def _held_experts_fwd(x, up, down, gates, order, sizes, rows, per_token,
+def _held_experts_fwd(x, weights, gates, order, sizes, rows, per_token,
                       compute_dtype):
-    out = held_experts(x, up, down, gates, order, sizes, rows, per_token,
+    out = held_experts(x, weights, gates, order, sizes, rows, per_token,
                        compute_dtype)
-    return out, (x, up, down, gates, order, sizes)
+    return out, (x, weights, gates, order, sizes)
 
 
 def _held_experts_bwd(rows, per_token, compute_dtype, residuals, g):
-    x, up, down, gates, order, sizes = residuals
+    x, weights, gates, order, sizes = residuals
 
     def step(i, grads):
         # a block is differentiated inside its own trip: what it keeps for
@@ -517,26 +529,31 @@ def _held_experts_bwd(rows, per_token, compute_dtype, residuals, g):
             _, pull = jax.vjp(
                 lambda *primals: _held_block(
                     *primals, order, sizes, i, rows=rows, per_token=per_token,
-                    compute_dtype=compute_dtype), x, up, down, gates)
+                    compute_dtype=compute_dtype), x, weights, gates)
         return jax.tree.map(jnp.add, grads, pull(g))
 
     grads = lax.fori_loop(0, held_blocks(sizes, rows), step,
-                          jax.tree.map(jnp.zeros_like, (x, up, down, gates)))
+                          jax.tree.map(jnp.zeros_like, (x, weights, gates)))
     return (*grads, None, None)
 
 
 held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def experts_mixer(cfg, compute_dtype, h, layer, segs):
+def experts_mixer(cfg, compute_dtype, h, layer, segs, eps=None):
     """``(mixer(RMSNorm(h)), statistics)`` of one ``E`` layer: this chip's
-    share of the routed sum, and the shared expert."""
+    share of the routed sum, and the shared expert. A layer that has
+    ``gate`` and ``shared_gate`` beside ``up`` and ``down`` holds gated
+    experts (``fedtpu.models.xing4``'s), one without them this tower's;
+    ``eps`` is the pre-norm's where it is not this tower's."""
     t = h.shape[0]
     top_k, routed = cfg.num_experts_per_tok, cfg.n_routed_experts
     held, first_expert = experts_share(cfg)
     cast = lambda arr: arr.astype(compute_dtype)
+    gated = "gate" in layer
     with jax.named_scope(ROUTER):
-        x = rms_norm(h, layer["norm"], cfg.layer_norm_epsilon)
+        x = rms_norm(h, layer["norm"],
+                     cfg.layer_norm_epsilon if eps is None else eps)
         gates, experts = route(x, layer["router"], layer["router_bias"], top_k,
                                cfg.norm_topk_prob, cfg.routed_scaling_factor)
     with jax.named_scope(EXPERT_DISPATCH):
@@ -559,11 +576,14 @@ def experts_mixer(cfg, compute_dtype, h, layer, segs):
         covered = (jnp.take(here, order)
                    & (jnp.arange(order.shape[0]) < computed)).sum()
         order = jnp.pad(order, (0, -order.shape[0] % rows))
-    out = held_experts(x, layer["up"], layer["down"], gates.reshape(-1),
-                       order, sizes, rows, top_k, compute_dtype)
+    weights = ((layer["gate"], layer["up"], layer["down"]) if gated
+               else (layer["up"], layer["down"]))
+    out = held_experts(x, weights, gates.reshape(-1), order, sizes, rows,
+                       top_k, compute_dtype)
     with jax.named_scope(SHARED_EXPERT):
         xc = cast(x)
-        act = jnp.square(jax.nn.relu(_mm(xc, cast(layer["shared_up"]))))
+        into = ("shared_gate", "shared_up") if gated else ("shared_up",)
+        act = _activation([_mm(xc, cast(layer[name])) for name in into])
         out = out + _mm(cast(act), cast(layer["shared_down"]))
     return out, {"expert_load": load,
                  "assignments_held": total.astype(jnp.float32),

@@ -43,11 +43,13 @@ What is this repo's own:
   exponentials. Which one runs is read off what the code can see and is
   nobody's to set (``fused_attention_applies``): the fused body when the
   program is built for a TPU, the head width is a multiple of 128 lanes,
-  ``T`` a multiple of the kernel's block and q, k and v share one width;
-  the XLA body everywhere else (the CPU, the tests' tiny shapes, a model
-  whose q/k and v widths differ). The fused backward takes its row term
-  ``sum(o * do)`` from the bf16 ``ctx`` and feeds bf16 ``dS`` to its
-  matmuls, where the XLA body's softmax backward is float32 throughout:
+  ``T`` a multiple of the kernel's block and q, k and v share one width
+  (a head whose query-key and value widths differ is padded with zeros to
+  one, ``padded_head_width``, and its context cut back); the XLA body
+  everywhere else (the CPU, the tests' tiny shapes). The fused backward
+  takes its row term ``sum(o * do)`` from the bf16 ``ctx`` and feeds bf16
+  ``dS`` to its matmuls, where the XLA body's softmax backward is float32
+  throughout:
   within "bf16 matmul inputs", and measured inside the benchmark's limits
   (PERF.md section 6, PR 26). The sequence statistics say how many
   positions ran fused (``fused_attention``).
@@ -95,13 +97,24 @@ EMBED, ATTENTION, ROUTER, EXPERT_DISPATCH, EXPERTS, LM_HEAD_LOSS = (
 # the hybrid stack's own (fedtpu.models.nemotron_h): a state-space mixer,
 # the chunked scan alone inside it (innermost), the expert every token takes
 SSM, SSM_SCAN, SHARED_EXPERT = "ssm", "ssm_scan", "shared_expert"
+# the four-stream stack's own (fedtpu.models.xing4): the mixing of the
+# residual streams around every sublayer, a plain gated MLP layer, and the
+# projection that opens a multi-token-prediction module
+HYPER_CONN, DENSE_MLP, MTP_PROJ = "hyper_conn", "dense_mlp", "mtp_proj"
 LAYER_SCOPES = (EMBED, ATTENTION, ROUTER, EXPERT_DISPATCH, EXPERTS,
-                LM_HEAD_LOSS, SSM, SSM_SCAN, SHARED_EXPERT)
+                LM_HEAD_LOSS, SSM, SSM_SCAN, SHARED_EXPERT, HYPER_CONN,
+                DENSE_MLP, MTP_PROJ)
 # The third level (``parallel.round.PIECES``): the four parts of a state-space
-# mixer around its scan, and the attention core alone inside ``attention``.
-SSM_IN_PROJ, SSM_CONV, SSM_GATE_NORM, SSM_OUT_PROJ, ATTN_CORE = (
-    PIECE_SCOPES) = ("ssm_in_proj", "ssm_conv", "ssm_gate_norm",
-                     "ssm_out_proj", "attn_core")
+# mixer around its scan, the attention core alone inside ``attention``, the
+# Sinkhorn iterations alone inside ``hyper_conn``, and the low-rank
+# projections of latent attention (their norms and RoPE) beside the core.
+(SSM_IN_PROJ, SSM_CONV, SSM_GATE_NORM, SSM_OUT_PROJ, ATTN_CORE, HC_SINKHORN,
+ ATTN_LATENT) = PIECE_SCOPES = (
+    "ssm_in_proj", "ssm_conv", "ssm_gate_norm", "ssm_out_proj", "attn_core",
+    "hc_sinkhorn", "attn_latent")
+# An outer scope around a whole multi-token-prediction module, its layers'
+# own scopes inside it (``parallel.round.MODULES``).
+MTP = "mtp"
 # A forward pass run again by hand inside a backward rule
 # (``parallel.round.RECOMPUTE``): a direction, not a piece.
 RECOMPUTE = "recompute"
@@ -254,10 +267,12 @@ def segment_positions(segs):
     return idx - lax.cummax(jnp.where(starts, idx, 0))
 
 
-def _rope(x, pos, theta):
-    """Rotate-half RoPE over all of the last axis; x ``(T, heads, d)``."""
+def _rope(x, pos, theta, inv=None):
+    """Rotate-half RoPE over all of the last axis; x ``(T, heads, d)``.
+    ``inv (d / 2,)``: the frequencies, where a model scales its own."""
     d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if inv is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     ang = pos.astype(jnp.float32)[:, None] * inv[None, :]
     cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[:, None, :]
     sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[:, None, :]
@@ -277,10 +292,20 @@ def route(x, router_w, top_k: int, norm_topk_prob: bool):
     return gates, experts.astype(jnp.int32)
 
 
+def padded_head_width(q, v) -> int:
+    """The head width the tiled kernel would run ``q (T, heads, dq)`` and
+    ``v (T, heads, dv)`` at: the wider of the two, up to whole lane tiles.
+    The kernel takes one width for q, k and v; zero columns of q and k add
+    nothing to a score and zero columns of v give zero columns of the
+    context, which are cut, so the padded form is exact."""
+    return -(-max(q.shape[-1], v.shape[-1]) // 128) * 128
+
+
 def fused_attention_applies(q, k, v) -> bool:
     """Whether the tiled kernel exists for these ``(T, heads, d)`` operands
     where the program is being built: a TPU, lane-wide heads, whole blocks
-    and one head width for q, k and v.
+    and one head width for q, k and v (``attention_core`` pads a head whose
+    query-key and value widths differ to one before it asks).
 
     The platform read is the PROCESS's default backend, not the one a
     program is lowered for: a compile for a described TPU from a CPU host
@@ -292,10 +317,11 @@ def fused_attention_applies(q, k, v) -> bool:
             and d % 128 == 0 and t % ATTENTION_BLOCK == 0)
 
 
-def _xla_attention(q, k, v, segs):
+def _xla_attention(q, k, v, segs, scale=None):
     t, _, d = q.shape
     scores = jnp.einsum("qhd,khd->hqk", q, k,
-                        preferred_element_type=jnp.float32) / (d ** 0.5)
+                        preferred_element_type=jnp.float32)
+    scores = scores / (d ** 0.5) if scale is None else scores * scale
     idx = jnp.arange(t)
     # causal, and within one segment; padding (segment 0) sees padding,
     # which keeps its rows finite and is masked out of the loss
@@ -305,27 +331,41 @@ def _xla_attention(q, k, v, segs):
                       preferred_element_type=jnp.float32)
 
 
-def _fused_attention(q, k, v, segs):
+def _fused_attention(q, k, v, segs, scale=None):
     # the kernel's layout is (batch, heads, T, d); its mask is the XLA
     # body's: causal, and equal segment ids (padding's 0 among them)
     heads_first = lambda a: a.transpose(1, 0, 2)[None]
     ids = flash.SegmentIds(q=segs[None], kv=segs[None])
     ctx = flash.flash_attention(
         heads_first(q), heads_first(k), heads_first(v), segment_ids=ids,
-        causal=True, sm_scale=q.shape[-1] ** -0.5,
+        causal=True, sm_scale=q.shape[-1] ** -0.5 if scale is None else scale,
         block_sizes=_ATTENTION_BLOCKS)
     return ctx[0].transpose(1, 0, 2).astype(jnp.float32)
 
 
-def attention_core(q, k, v, segs, compute_dtype):
-    """``ctx (T, heads, d)`` float32: the attention of one packed sequence
-    after RoPE and before the output projection, ``q``, ``k``, ``v``
-    ``(T, heads, d)`` float32 and cast to ``compute_dtype`` for both
-    matmuls."""
+def attention_core(q, k, v, segs, compute_dtype, scale=None):
+    """``ctx (T, heads, dv)`` float32: the attention of one packed sequence
+    after RoPE and before the output projection, ``q``, ``k`` ``(T, heads,
+    dq)`` and ``v (T, heads, dv)`` float32 and cast to ``compute_dtype`` for
+    both matmuls; the scores are scaled by ``scale`` (``dq ** -0.5`` where
+    none is given). A head whose two widths differ (latent attention: 192
+    beside 128) reaches the tiled kernel padded with zeros to one width
+    (``padded_head_width``) and its context is cut back: exact, at the
+    padded width's cost. The XLA body takes the widths as they are."""
     q, k, v = (a.astype(compute_dtype) for a in (q, k, v))
-    body = _fused_attention if fused_attention_applies(q, k, v) else _xla_attention
+    if scale is None and q.shape == v.shape:
+        padded = q, k, v
+    else:
+        if scale is None:
+            scale = q.shape[-1] ** -0.5
+        wide = padded_head_width(q, v)
+        padded = tuple(jnp.pad(a, ((0, 0), (0, 0), (0, wide - a.shape[-1])))
+                       for a in (q, k, v))
     with jax.named_scope(ATTN_CORE):
-        return body(q, k, v, segs)
+        if not fused_attention_applies(*padded):
+            return _xla_attention(q, k, v, segs, scale)
+        ctx = _fused_attention(*padded, segs, scale)
+        return ctx if padded[2] is v else ctx[..., :v.shape[-1]]
 
 
 def grouped_matmul_applies(xs, w) -> bool:

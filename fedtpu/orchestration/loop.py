@@ -69,7 +69,8 @@ from fedtpu.telemetry import (TelemetryLogger, build_manifest,
                               make_tracer)
 from fedtpu.telemetry.metrics import device_memory_gauges
 from fedtpu.telemetry.trace import Phase
-from fedtpu.parallel.round import (LAYER_KERNELS, LAYERS, PIECES, RECOMPUTE,
+from fedtpu.parallel.round import (LAYER_KERNELS, LAYERS, MODULES, PIECES,
+                                   RECOMPUTE,
                                    SERVER_UPDATE, SGD_PASS, STAGES,
                                    build_round_fn,
                                    build_eval_fn, check_resident_fits,
@@ -315,8 +316,9 @@ def build_experiment(cfg: ExperimentConfig,
     if stateless:
         from fedtpu.parallel import stateless as sl
         sl.validate_stateless_config(cfg)
-    elif cfg.fed.local_batch_rows:
-        raise ValueError("local_batch_rows needs client_state='stateless': "
+    elif cfg.fed.local_batch_rows or cfg.fed.one_step_kind:
+        raise ValueError("local_batch_rows needs client_state='stateless' "
+                         "(and one_step_kind with it): "
                          "the resident engines take one full-batch step")
     if dataset is not None:
         ds = dataset
@@ -385,14 +387,15 @@ def build_experiment(cfg: ExperimentConfig,
             steplr_step_size=cfg.optim.steplr_step_size,
             steplr_gamma=cfg.optim.steplr_gamma,
             weighting=cfg.fed.weighting, server_opt=server,
-            local_batch_rows=cfg.fed.local_batch_rows, rounds_per_step=r)
+            local_batch_rows=cfg.fed.local_batch_rows,
+            one_step_kind=cfg.fed.one_step_kind, rounds_per_step=r)
         # everything the builder above is handed, by value
         program = (mesh, model_cfg, ds.num_classes,
                    tuple(int(n) for n in packed.counts), cfg.optim,
                    cfg.fed.weighting, cfg.fed.server_opt, cfg.fed.server_lr,
                    cfg.fed.server_momentum, cfg.fed.server_b1,
                    cfg.fed.server_b2, cfg.fed.server_tau,
-                   cfg.fed.local_batch_rows)
+                   cfg.fed.local_batch_rows, cfg.fed.one_step_kind)
         step_fn = lambda r: _round_program(program, r, build_step)
         global_fn = lambda state: state["params"]
     elif cfg.fed.async_mode:
@@ -640,7 +643,7 @@ def _emit_program_scopes(tracer, program: str, width: Optional[int], fn,
                     else fn.lower(*args).compile())
         found = program_scopes(
             compiled.as_text(), STAGES + (STATE_CHECK,), layers=LAYERS,
-            pieces=PIECES, update=(SGD_PASS, SERVER_UPDATE),
+            pieces=PIECES, modules=MODULES, update=(SGD_PASS, SERVER_UPDATE),
             recompute=(RECOMPUTE,))
         if not found["scopes"]:
             found["stale_metadata"] = True

@@ -70,17 +70,26 @@ CLIENT_TRAIN, CLIENT_EVAL, AGGREGATE, METRICS = STAGES = (
 # event maps operations to them under ``layers``.
 SERVER_UPDATE = "server_update"
 LAYERS = ("embed", "attention", "router", "expert_dispatch", "experts",
-          "lm_head_loss", "ssm", "ssm_scan", "shared_expert", SERVER_UPDATE)
+          "lm_head_loss", "ssm", "ssm_scan", "shared_expert", "hyper_conn",
+          "dense_mlp", "mtp_proj", SERVER_UPDATE)
 # The third level, inside a layer or outside every one, set where the work
 # happens: the four parts of a state-space mixer around its scan
 # (fedtpu.models.nemotron_h.mamba_mixer), the attention core alone (whichever
 # body of olmoe.attention_core runs; the rest of ``attention`` is the
-# projections'), and the one fused pass a step that applies a gradient and
-# adds the step's share to the accumulator (fedtpu.parallel.stateless). The
-# event maps operations to them under ``pieces``.
+# projections'), the Sinkhorn iterations alone inside ``hyper_conn`` and the
+# low-rank projections of latent attention beside its core
+# (fedtpu.models.xing4), and the one fused pass a step that applies a
+# gradient and adds the step's share to the accumulator
+# (fedtpu.parallel.stateless). The event maps operations to them under
+# ``pieces``.
 SGD_PASS = "sgd_pass"
 PIECES = ("ssm_in_proj", "ssm_conv", "ssm_gate_norm", "ssm_out_proj",
-          "attn_core", SGD_PASS)
+          "attn_core", "hc_sinkhorn", "attn_latent", SGD_PASS)
+# An outer scope AROUND layers: a whole multi-token-prediction module
+# (fedtpu.models.xing4), whose attention, experts and head keep their own
+# layers' names inside it. The event maps operations to it under
+# ``modules``, beside ``layers``, so a metric can read the module whole.
+MODULES = ("mtp",)
 # Not a piece but a direction: a forward pass run again by hand inside a
 # backward rule (nemotron_h._held_experts_bwd) names itself so, as remat's
 # lowering names its own; ``program_scopes`` reads both under ``passes``.

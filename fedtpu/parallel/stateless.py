@@ -133,13 +133,19 @@ def build_stateless_round_fn(mesh, task: Task, counts, *,
                              weighting: str = "data_size",
                              server_opt: ServerOptimizer | None = None,
                              local_batch_rows: int = 0,
+                             one_step_kind: bool = False,
                              rounds_per_step: int = 1):
     """Returns ``round_step(state, batch) -> (state, metrics)`` over the
     state of ``init_stateless_state`` and the batch every engine takes
     (``x (C, N, ...)``, ``y (C, N)``, ``mask (C, N)``, sharded over
     clients). ``counts (C,)`` are the clients' true numbers of rows (host
     integers: they fix how many steps each client's epoch has). The state is
-    donated. ``metrics`` are the resident engines' (``loss (C,)``,
+    donated. ``one_step_kind``: every step runs from the working copy, which
+    each client's start fills from the global, so the program holds ONE
+    trace of the model where the clients' counts would call for up to four
+    (a third to a quarter of a deep model's compile and executable), for one
+    copy of the parameters a client and the write of a client's last step.
+    ``metrics`` are the resident engines' (``loss (C,)``,
     ``per_client``, ``client_mean``, ``pooled``), and ``counters``: the
     task's where it has them, and the engine's two."""
     if server_opt is None:
@@ -258,15 +264,17 @@ def build_stateless_round_fn(mesh, task: Task, counts, *,
                 # program holds only the kinds some client's count calls for.
                 only, last = ((n == 1).astype(jnp.int32),
                               (n > 1).astype(jnp.int32))
-                kinds = []
-                if single:
-                    kinds.append((0, only, True, False))    # a client's only
-                if further:
-                    kinds.append((0, last, True, True))     # first of several
-                if between:
-                    kinds.append((1, n - 1, False, True))
-                if further:
-                    kinds.append((n - last, n, False, False))   # their last
+                if one_step_kind:
+                    # every step from the working copy, which starts as the
+                    # global (a copy: the global lives on): one trace
+                    p = gc
+                    kinds = [(0, n, False, True)]
+                else:
+                    kinds = (
+                        [(0, only, True, False)] * single       # the only
+                        + [(0, last, True, True)] * further     # the first
+                        + [(1, n - 1, False, True)] * between
+                        + [(n - last, n, False, False)] * further)  # the last
                 carry = (p, acc, jnp.float32(0.0), stats0, taken, written)
                 for lo, hi, first, keep in kinds:
                     carry = jax.lax.fori_loop(
@@ -288,7 +296,8 @@ def build_stateless_round_fn(mesh, task: Task, counts, *,
                 # at a client's first step, never read before; none where no
                 # client has a second step
                 zero = jnp.zeros((), jnp.int32)
-                p0 = jax.tree.map(jnp.zeros_like, g) if further else None
+                p0 = (jax.tree.map(jnp.zeros_like, g)
+                      if further or one_step_kind else None)
                 (acc, _, taken, written), (loss, stats) = jax.lax.scan(
                     client, (acc0, p0, zero, zero),
                     (x, y, mask, nsteps, w, units))

@@ -69,22 +69,39 @@ def classification_task(apply_fn: Callable, num_classes: int) -> Task:
                 metrics=metrics_from_confusion)
 
 
-LANGUAGE_MODELS = ("olmoe", "nemotron_h")
+LANGUAGE_MODELS = ("olmoe", "nemotron_h", "xing4")
+# The weight of a prediction module's loss in the sum that is differentiated:
+# no key of a published config (DeepSeek-V3's, arXiv:2412.19437 section 2.2).
+MTP_LOSS_WEIGHT = 0.3
 
 
 def next_token_task(stats_fn: Callable, model_cfg) -> Task:
     """``stats_fn(params, x, mask)`` is the model's own
-    (fedtpu.models.olmoe.olmoe_stats, nemotron_h.nemotron_h_stats): rows
-    ``x (N, 2, T)`` of token and segment ids; labels are the rows' own next
-    tokens, so ``y`` is unused."""
+    (fedtpu.models.olmoe.olmoe_stats, nemotron_h.nemotron_h_stats,
+    xing4.xing4_stats): rows ``x (N, 2, T)`` of token and segment ids;
+    labels are the rows' own next tokens, so ``y`` is unused.
+
+    A model with a multi-token-prediction module (``num_nextn_predict_layers``
+    1, xing4's preset) hands out a second loss's sums
+    (``mtp_loss_sum``, ``mtp_count``): the loss that is differentiated is
+    the main loss's mean plus ``MTP_LOSS_WEIGHT`` times the module's, each
+    over its own valid positions, and ``main_loss`` / ``mtp_loss`` stand
+    beside accuracy and perplexity (the main head's) among the metrics."""
     from fedtpu.models.olmoe import next_token_targets
+
+    second = model_cfg.num_nextn_predict_layers > 0
+    mean = lambda total, n: total / jnp.maximum(n, 1.0)
 
     def stats(params, x, y, mask):
         return stats_fn(params, x, mask)
 
     def loss(params, x, y, mask):
         s = stats_fn(params, x, mask)
-        return s["loss_sum"] / jnp.maximum(s["count"], 1.0), s
+        total = s["loss_sum"] / jnp.maximum(s["count"], 1.0)
+        if second:
+            total = total + MTP_LOSS_WEIGHT * mean(
+                s["mtp_loss_sum"], s["mtp_count"])
+        return total, s
 
     def weight(x, y, mask):
         valid = jax.vmap(lambda row: next_token_targets(row[0], row[1])[1])(x)
@@ -92,19 +109,27 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
 
     def metrics(s):
         n = jnp.maximum(s["count"], 1.0)
-        return {"accuracy": s["correct"] / n,
-                "perplexity": jnp.exp(s["loss_sum"] / n)}
+        found = {"accuracy": s["correct"] / n,
+                 "perplexity": jnp.exp(s["loss_sum"] / n)}
+        if second:
+            found.update(main_loss=s["loss_sum"] / n,
+                         mtp_loss=mean(s["mtp_loss_sum"], s["mtp_count"]))
+        return found
 
     # a model whose expert layers compute every expert they route over
     # (olmoe) owes this many assignments a real token
     assignments = model_cfg.num_experts_per_tok * model_cfg.num_hidden_layers
-    # what a model that holds a share of its experts and runs state-space
-    # layers counts besides (nemotron_h): read off the statistics it hands out
+    # what a model that holds a share of its experts counts besides, and
+    # what its other layers do (nemotron_h's state-space layers; xing4's
+    # residual modules and prediction module): read off the statistics it
+    # hands out
     share = {"moe_assignments_held": "assignments_held",
              "moe_rows_computed": "rows_computed",
              "ssm_positions": "ssm_positions",
              "ssm_document_restarts": "ssm_restarts",
-             "ssm_fused_pass_positions": "ssm_fused_passes"}
+             "ssm_fused_pass_positions": "ssm_fused_passes",
+             "hc_mix_positions": "hc_mix_positions",
+             "mtp_positions": "mtp_count"}
 
     def counters(s):
         load = s["expert_load"].astype(jnp.float32)
@@ -114,10 +139,23 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
             # it computed; the rest belong to other chips
             dropped = s["assignments_held"] - s["rows_held_computed"]
             extra = {"moe_assignments_total": routed,
-                     **{name: s[key] for name, key in share.items()}}
+                     **{name: s[key] for name, key in share.items()
+                        if key in s}}
         else:
             # every assignment of a real token is computed
             dropped, extra = assignments * s["tokens"] - routed, {}
+        if "hc_mix_positions" in s:
+            # gauges of the round: the width the tiled attention core ran a
+            # head at (0: the XLA body), and the mean over the round's steps
+            # of the largest |rowsum - 1|, |colsum - 1| of a step's H_res
+            steps = jnp.maximum(s["sequences"], 1.0)
+            extra.update(
+                attention_padded_width=s["attention_padded_width"]
+                / jnp.maximum(s["tokens"] + s["padding"], 1.0),
+                hc_sinkhorn_residual=s["hc_sinkhorn_residual"] / steps)
+        if second:
+            extra.update(main_loss=mean(s["loss_sum"], s["count"]),
+                         mtp_loss=mean(s["mtp_loss_sum"], s["mtp_count"]))
         return {
             "moe_tokens_routed": routed,
             "moe_tokens_dropped": dropped,      # 0 by construction
@@ -131,10 +169,14 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
             **extra,
         }
 
-    return Task(name="next_token", metric_names=("accuracy", "perplexity"),
+    names = ("accuracy", "perplexity") + (
+        ("main_loss", "mtp_loss") if second else ())
+    return Task(name="next_token", metric_names=names,
                 loss=loss, stats=stats, weight=weight, metrics=metrics,
                 counters=counters,
-                gauges=("moe_expert_load_max_over_mean",))
+                gauges=("moe_expert_load_max_over_mean",
+                        "attention_padded_width", "hc_sinkhorn_residual",
+                        "main_loss", "mtp_loss"))
 
 
 def build_task(model_cfg, model_fn: Callable, num_classes: int) -> Task:

@@ -69,6 +69,8 @@ QUICK_TESTS = {
     "test_olmoe.py::test_top_k_sets_are_the_references_in_float32",
     # the hybrid stack: the pattern's letters, the held block's size
     "test_nemotron_h.py::test_the_held_block_is_whole_tiles_at_eight_thirds_of_the_mean",
+    # the four-stream stack: the prediction module's targets
+    "test_xing4.py::test_the_modules_targets_and_validity_at_document_edges",
     "test_stateless_round.py::"
     "test_minibatches_need_the_stateless_engine_and_a_known_client_state",
     # the stage of each operation from a compiled program's text (pure text)

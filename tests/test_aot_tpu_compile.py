@@ -495,3 +495,92 @@ def test_the_hybrid_round_runs_the_mixers_passes_in_the_tiled_kernels(
         assert (direction == BACKWARD) == (name == backward), op_name
         directions[direction] += 1
     assert directions == {FORWARD: each, RECOMPUTE: each, BACKWARD: each}
+
+
+@pytest.fixture(scope="module")
+def xing4_round(topo):
+    """The shared-global round of the four-stream preset (``xing4``: one
+    dense and four expert layers and the multi-token-prediction module at
+    published widths, 8 of 64 experts and an eighth of the vocabulary held,
+    913.5M parameters; 8 clients, the preset's 16 packed sequences of 4,096
+    tokens in ONE kind of step, FedAvgM) compiled for one described v5e chip, the rules between the bodies told
+    the backend is a TPU as for the hybrid round."""
+    from fedtpu.config import get_preset
+    from fedtpu.models.registry import build_model
+    from fedtpu.ops.server_opt import make_server_optimizer
+    from fedtpu.parallel.stateless import build_stateless_round_fn
+    from fedtpu.training.task import build_task
+
+    cfg = get_preset("xing4-29b-a4b-l5-mtp1")
+    mesh = Mesh(np.array(topo.devices[:1]), ("clients",))
+    rep, by_client = NamedSharding(mesh, P()), NamedSharding(mesh, P("clients"))
+    init_fn, stats_fn = build_model(cfg.model)
+    server = make_server_optimizer("fedavgm", cfg.fed.server_lr,
+                                   cfg.fed.server_momentum)
+    params = jax.eval_shape(init_fn, jax.random.key(0))
+    assert sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params)) == 913_473_668
+    shaped = lambda tree, sharding: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
+    state = {"params": shaped(params, rep),
+             "server_opt_state": shaped(jax.eval_shape(server.init, params), rep),
+             "round": jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)}
+    from fedtpu.data.tokens import skewed_sizes
+
+    seq = cfg.data.synthetic_features
+    sizes = [int(n) for n in skewed_sizes(cfg.data.synthetic_rows, 8)]
+    # all four kinds of step, were each a trace of its own: that round is
+    # refused at 16.39 GiB of the chip's 15.75 (PERF.md section 6, PR 37)
+    assert sizes == [1, 1, 2, 2, 2, 2, 3, 3] and cfg.fed.one_step_kind
+    longest = max(sizes)
+    batch = {"x": jax.ShapeDtypeStruct((8, longest, 2, seq), jnp.int32, sharding=by_client),
+             "y": jax.ShapeDtypeStruct((8, longest), jnp.int32, sharding=by_client),
+             "mask": jax.ShapeDtypeStruct((8, longest), jnp.float32, sharding=by_client)}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        step = build_stateless_round_fn(
+            mesh, build_task(cfg.model, stats_fn, cfg.model.vocab_size), sizes,
+            learning_rate=cfg.optim.learning_rate, server_opt=server,
+            local_batch_rows=cfg.fed.local_batch_rows,
+            one_step_kind=cfg.fed.one_step_kind)
+        return step.lower(state, batch).compile()
+
+
+X4_BLOCKS = 6               # five layers and the prediction module's block
+X4_STEP_KINDS = 1           # every step from the working copy: one trace
+
+
+def test_the_four_stream_round_at_published_widths_fits_one_v5e_chip(xing4_round):
+    """The round's account (the compiler's own peak: for this program the
+    sum of the parts reads above the chip's memory,
+    ``train_xing4.program_account``) lies between the 10.96 GB the engine's
+    12 bytes a parameter come to and the bound the configuration file states,
+    and IS what the file's ``memory`` states to the byte (the chip's compiler
+    allows 15.75 GiB, 16.9 GB); global and momentum in place. Every block's
+    attention ran the tiled core at the padded head, a kind of step: the
+    forward kernel, once more in the block's recomputation, and the two
+    backward; the held experts at widths without tiles ran the compiler's
+    own grouped kernel; every scope the reducers read is in the program."""
+    import json
+    import os
+
+    from perfbench.drivers.train_xing4 import program_account
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "perfbench", "configs",
+                           "xing4-29b-a4b-l5-mtp1-fed8.json")) as fh:
+        memory = json.load(fh)["memory"]
+    account = program_account(xing4_round.memory_analysis())
+    assert account["peak"] > 0 and account["total"] == account["peak"]
+    assert 10.96e9 <= account["total"] <= memory["round_account_bound_bytes"], account
+    assert account["total"] == memory["round_account_bytes"], account
+    assert account["aliased"] >= 7.3e9
+    text = xing4_round.as_text()
+    assert sorted(_pallas_calls(xing4_round, "attention")) == [
+        "flash_attention"] * 4 * X4_BLOCKS * X4_STEP_KINDS
+    assert re.search(r"bf16\[1,32,4096,256\]", text)    # q, k, v at one width
+    assert "ragged-dot" in text and _pallas_calls(xing4_round, "experts") == []
+    for scope in ("attention", "attn_core", "attn_latent", "hyper_conn",
+                  "hc_sinkhorn", "dense_mlp", "shared_expert", "router",
+                  "expert_dispatch", "experts", "mtp", "mtp_proj",
+                  "lm_head_loss", "embed", "sgd_pass", "server_update"):
+        assert f"/{scope}/" in text or f"({scope})" in text, scope

@@ -453,13 +453,17 @@ def test_a_row_of_padding_alone_is_finite_and_moves_nothing_when_fused(
     assert all(bool(jnp.all(jnp.isfinite(a))) for a in (ctx, *grads))
 
 
-@pytest.mark.parametrize("backend,q,v,fused", [
-    ("tpu", (4096, 16, 128), (4096, 16, 128), True),
-    ("tpu", (32, 4, 8), (32, 4, 8), False),                 # the tests' heads
-    ("tpu", (4096 + 128, 16, 128), (4096 + 128, 16, 128), False),
-    ("tpu", (4096, 16, 192), (4096, 16, 128), False),       # q, k wider than v
-    ("cpu", (4096, 16, 128), (4096, 16, 128), False)])
-def test_the_rule_between_the_attention_bodies(monkeypatch, backend, q, v, fused):
+@pytest.mark.parametrize("backend,q,v,fused,core", [
+    ("tpu", (4096, 16, 128), (4096, 16, 128), True, True),
+    ("tpu", (32, 4, 8), (32, 4, 8), False, False),          # the tests' heads
+    ("tpu", (4096 + 128, 16, 128), (4096 + 128, 16, 128), False, False),
+    # q, k wider than v (latent attention): no kernel for the widths as they
+    # are; the core pads them to one lane-wide width and runs it
+    ("tpu", (4096, 16, 192), (4096, 16, 128), False, True),
+    ("cpu", (4096, 16, 192), (4096, 16, 128), False, False),
+    ("cpu", (4096, 16, 128), (4096, 16, 128), False, False)])
+def test_the_rule_between_the_attention_bodies(monkeypatch, backend, q, v,
+                                               fused, core):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32)
     assert olmoe.fused_attention_applies(sds(q), sds(q), sds(v)) is fused
@@ -467,12 +471,14 @@ def test_the_rule_between_the_attention_bodies(monkeypatch, backend, q, v, fused
     for name in ("_fused_attention", "_xla_attention"):
         body = getattr(olmoe, name)
         monkeypatch.setattr(olmoe, name, lambda *a, _name=name, _body=body: (
-            ran.append(_name), _body(*a))[1])
+            ran.append((_name, a[0].shape[-1])), _body(*a))[1])
     # either body traces at these shapes, and gives (T, heads, v's width)
     ctx = jax.eval_shape(
         lambda *a: olmoe.attention_core(*a, jnp.bfloat16), sds(q), sds(q),
         sds(v), jax.ShapeDtypeStruct(q[:1], jnp.int32))
-    assert ran == ["_fused_attention" if fused else "_xla_attention"]
+    # the tiled body at one width for q, k and v, the XLA body as they are
+    assert ran == [("_fused_attention", -(-q[2] // 128) * 128) if core
+                   else ("_xla_attention", q[2])]
     assert ctx.shape == q[:2] + v[2:] and ctx.dtype == jnp.float32
 
 

@@ -230,19 +230,26 @@ def test_uneven_shards_in_minibatches_match_a_loop_by_hand():
     assert _gap(got.final_params, want_params) <= 2e-6
 
 
-@pytest.mark.parametrize("steps,dtype", [
-    ((1, 1, 1), "float32"),              # no client ever has a working copy
-    ((3, 3, 3), "float32"),              # first, between, last
-    ((2, 0, 3), "float32"),              # an empty shard beside full ones
+@pytest.mark.parametrize("steps,dtype,one_kind", [
+    ((1, 1, 1), "float32", False),       # no client ever has a working copy
+    ((3, 3, 3), "float32", False),       # first, between, last
+    ((2, 0, 3), "float32", False),       # an empty shard beside full ones
     # rounds at every step: the delta is what the client holds (its
     # gradients' sum, which a cast and back compiles to, is 1e-3 off)
-    ((1, 2, 3), "bfloat16"),
-], ids=["one-step", "three-steps", "an-empty-client", "bf16-parameters"])
-def test_each_step_goes_straight_into_the_accumulator(steps, dtype):
+    ((1, 2, 3), "bfloat16", False),
+    # one kind of step: every step from the copy a client's start fills
+    ((1, 2, 3), "float32", True),
+    ((2, 0, 3), "float32", True),
+    ((1, 2, 3), "bfloat16", True),
+], ids=["one-step", "three-steps", "an-empty-client", "bf16-parameters",
+        "one-kind", "one-kind-an-empty-client", "one-kind-bf16-parameters"])
+def test_each_step_goes_straight_into_the_accumulator(steps, dtype, one_kind):
     """The accumulation as the steps are taken, against the loop by hand
     that forms ``w_c (p_c - global)`` at each client's end, and the engine's
     two counters against the steps the shards have: a working copy a step
-    that another follows, none for a client of one."""
+    that another follows, none for a client of one; with ``one_step_kind``
+    every step writes the copy, and the program holds one trace of the
+    model where the counts call for up to four."""
     from fedtpu.ops.server_opt import make_server_optimizer
     from fedtpu.parallel.stateless import build_stateless_round_fn
     ds = income(rows=63)                # 13, 13, 14 rows, padded to 16
@@ -256,16 +263,21 @@ def test_each_step_goes_straight_into_the_accumulator(steps, dtype):
         steplr_step_size=cfg.optim.steplr_step_size,
         steplr_gamma=cfg.optim.steplr_gamma,
         server_opt=make_server_optimizer("fedavgm", 1.0, 0.9),
-        local_batch_rows=4)
+        local_batch_rows=4, one_step_kind=one_kind)
     batch = dict(exp.batch, mask=jax.device_put(mask, exp.batch["mask"].sharding))
     state, losses, atol = exp.state, [], 2e-6
+    # a loop a kind of step (only; first and last; between) beside the scans
+    # over the rounds and over the clients
+    kinds = (1 in steps) + 2 * (max(steps) > 1) + (max(steps) > 2)
+    loops = step.lower(state, batch).as_text().count("stablehlo.while")
+    assert loops == 2 + (1 if one_kind else kinds)
     assert jax.tree.leaves(state["params"])[0].dtype == dtype
     for _ in range(2):
         state, metrics = step(state, batch)
         losses.append(np.asarray(metrics["loss"]))
         assert int(metrics["counters"]["stateless_client_steps"]) == sum(steps)
         assert int(metrics["counters"]["stateless_working_copy_writes"]) == sum(
-            max(n - 1, 0) for n in steps)
+            n if one_kind else max(n - 1, 0) for n in steps)
     want_loss, want_params, want_m = _by_hand(cfg, ds, 4, mask)
     np.testing.assert_allclose(np.stack(losses), want_loss, atol=atol)
     # the accumulator, float32 whatever the parameters are
