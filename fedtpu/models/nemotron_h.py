@@ -658,7 +658,9 @@ def nemotron_h_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
             "padding": (segs == 0).sum().astype(jnp.float32),
             "fused_attention": jnp.float32(t if fused else 0),
             "grouped_experts": jnp.float32(t if grouped else 0),
-            "ssm_fused_passes": jnp.float32(t if tiled else 0), **stats}
+            "ssm_fused_passes": jnp.float32(t if tiled else 0),
+            **olmoe.attention_blocks(segs, fused, kinds.count("attention")),
+            **stats}
 
 
 def nemotron_h_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
@@ -670,6 +672,7 @@ def nemotron_h_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
             params, row * m.astype(row.dtype), cfg, compute_dtype)
         return {**stats, **{k: stats[k] * m for k in (
             "padding", "fused_attention", "grouped_experts",
+            "attention_blocks_computed", "attention_blocks_causal",
             "ssm_fused_passes", "ssm_positions", "rows_computed")}}
 
     if x.shape[0] == 1:

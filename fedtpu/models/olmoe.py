@@ -36,11 +36,18 @@ What is this repo's own:
   is one function of ``(q, k, v, segs)``. Its XLA body is the definition:
   it writes the ``[heads, T, T]`` float32 scores, the masked scores and the
   probabilities to memory and keeps them for the backward pass. Its fused
-  body is the library's tiled kernel with an online softmax
-  (``jax.experimental.pallas.ops.tpu.flash_attention``, causal, segment
-  ids, its own backward), which never holds a ``[heads, T, T]`` array: the
-  same mask, bf16 matmul inputs, float32 accumulation, maximum, sum and
-  exponentials. Which one runs is read off what the code can see and is
+  body is three tiled kernels with an online softmax
+  (``fedtpu.ops.packed_attention``: the bodies of the library's
+  ``jax.experimental.pallas.ops.tpu.flash_attention``, causal, segment ids,
+  its own backward), which never hold a ``[heads, T, T]`` array: the same
+  mask, bf16 matmul inputs, float32 accumulation, maximum, sum and
+  exponentials. What the fused body leaves out, beside the blocks above the
+  diagonal, are the (query block, key block) pairs that lie wholly across
+  two documents: it decides from the row's own segment ids on the device,
+  the least and largest id of each block, and runs a pair only where the
+  two blocks' ranges overlap, so a row of one document runs every pair and
+  a row of fourteen runs a third of them (``attention_blocks`` counts both).
+  Which body runs is read off what the code can see and is
   nobody's to set (``fused_attention_applies``): the fused body when the
   program is built for a TPU, the head width is a multiple of 128 lanes,
   ``T`` a multiple of the kernel's block and q, k and v share one width
@@ -52,7 +59,9 @@ What is this repo's own:
   throughout:
   within "bf16 matmul inputs", and measured inside the benchmark's limits
   (PERF.md section 6, PR 26). The sequence statistics say how many
-  positions ran fused (``fused_attention``).
+  positions ran fused (``fused_attention``) and how many block pairs it ran
+  of those on or under the diagonal (``attention_blocks_computed``,
+  ``attention_blocks_causal``).
 
 * **Expert matmuls with two bodies.** ``grouped_matmul(xs, w, sizes)`` is
   one function too. Its XLA body, ``lax.ragged_dot``, is the definition,
@@ -88,8 +97,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.pallas.ops.tpu import flash_attention as flash
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+from fedtpu.ops import packed_attention
 
 EMBED, ATTENTION, ROUTER, EXPERT_DISPATCH, EXPERTS, LM_HEAD_LOSS = (
     "embed", "attention", "router", "expert_dispatch", "experts",
@@ -127,11 +137,6 @@ INIT_STD = 0.02
 # 17.0 ms (the XLA body 16.3), 256s 7.1, 512s 3.8, 1024s 3.7 with 134 MB
 # more temporaries; no mixed shape beat 512s.
 ATTENTION_BLOCK = 512
-_ATTENTION_BLOCKS = flash.BlockSizes(
-    block_b=1, **{name: ATTENTION_BLOCK for name in (
-        "block_q", "block_k_major", "block_k", "block_q_major_dkv",
-        "block_k_major_dkv", "block_k_dkv", "block_q_dkv",
-        "block_k_major_dq", "block_k_dq", "block_q_dq")})
 
 
 # Tiles of the three grouped expert kernels, ``(tm, tk, tn)`` = rows,
@@ -332,15 +337,30 @@ def _xla_attention(q, k, v, segs, scale=None):
 
 
 def _fused_attention(q, k, v, segs, scale=None):
-    # the kernel's layout is (batch, heads, T, d); its mask is the XLA
-    # body's: causal, and equal segment ids (padding's 0 among them)
-    heads_first = lambda a: a.transpose(1, 0, 2)[None]
-    ids = flash.SegmentIds(q=segs[None], kv=segs[None])
-    ctx = flash.flash_attention(
-        heads_first(q), heads_first(k), heads_first(v), segment_ids=ids,
-        causal=True, sm_scale=q.shape[-1] ** -0.5 if scale is None else scale,
-        block_sizes=_ATTENTION_BLOCKS)
-    return ctx[0].transpose(1, 0, 2).astype(jnp.float32)
+    # the kernels' layout is (heads, T, d); their mask is the XLA body's:
+    # causal, and equal segment ids (padding's 0 among them)
+    heads_first = lambda a: a.transpose(1, 0, 2)
+    ctx = packed_attention.attention(
+        heads_first(q), heads_first(k), heads_first(v), segs,
+        q.shape[-1] ** -0.5 if scale is None else scale, ATTENTION_BLOCK)
+    return ctx.transpose(1, 0, 2).astype(jnp.float32)
+
+
+def attention_blocks(segs, fused: bool, layers: int) -> dict:
+    """A sequence's two block counters: the (query block, key block) pairs
+    the fused body's forward kernel runs on the row ``segs (T,)``, from the
+    table it reads, and the pairs on or under the diagonal, a head's worth
+    for each of ``layers`` attention layers; both 0 where the XLA body ran
+    (it has no blocks)."""
+    if not fused:
+        return {"attention_blocks_computed": jnp.float32(0.0),
+                "attention_blocks_causal": jnp.float32(0.0)}
+    kept = packed_attention.pairs_kept(segs, ATTENTION_BLOCK)
+    blocks = kept.shape[0]
+    return {"attention_blocks_computed":
+            layers * kept.sum().astype(jnp.float32),
+            "attention_blocks_causal":
+            jnp.float32(layers * blocks * (blocks + 1) // 2)}
 
 
 def attention_core(q, k, v, segs, compute_dtype, scale=None):
@@ -602,7 +622,9 @@ def olmoe_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
     (of any document), ``padding`` (tokens of segment 0), ``expert_load (E,)`` (real tokens given to each
     expert, summed over layers), ``fused_attention`` (positions whose
     attention ran in the fused body: T or 0), ``grouped_experts`` (positions
-    whose expert matmuls ran in the tiled kernels: T or 0)."""
+    whose expert matmuls ran in the tiled kernels: T or 0),
+    ``attention_blocks_computed`` and ``attention_blocks_causal``
+    (``attention_blocks``)."""
     tokens, segs = row[0], row[1]
     pos = segment_positions(segs)
     # the operands every layer's attention core is given: static shapes, so
@@ -634,7 +656,8 @@ def olmoe_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
             "padding": (segs == 0).sum().astype(jnp.float32),
             "expert_load": loads.sum(axis=0),
             "fused_attention": jnp.float32(t if fused else 0),
-            "grouped_experts": jnp.float32(t if grouped else 0)}
+            "grouped_experts": jnp.float32(t if grouped else 0),
+            **attention_blocks(segs, fused, cfg.num_hidden_layers)}
 
 
 def olmoe_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
@@ -645,9 +668,9 @@ def olmoe_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
         # a padded row is all segment 0: nothing of it is counted
         stats = olmoe_sequence_stats(params, row * m.astype(row.dtype), cfg,
                                      compute_dtype)
-        return {**stats, "padding": stats["padding"] * m,
-                "fused_attention": stats["fused_attention"] * m,
-                "grouped_experts": stats["grouped_experts"] * m}
+        return {**stats, **{k: stats[k] * m for k in (
+            "padding", "fused_attention", "grouped_experts",
+            "attention_blocks_computed", "attention_blocks_causal")}}
 
     if x.shape[0] == 1:
         return one((x[0], mask[0]))

@@ -481,7 +481,10 @@ def xing4_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
             "grouped_experts": jnp.float32(t if grouped else 0),
             "attention_padded_width": jnp.float32(t * wide if fused else 0),
             "hc_mix_positions": jnp.float32(t * modules),
-            "sequences": jnp.float32(1.0), **module_stats, **stats}
+            "sequences": jnp.float32(1.0),
+            **olmoe.attention_blocks(segs, fused,
+                                     len(kinds) + len(params["mtp"])),
+            **module_stats, **stats}
 
 
 def xing4_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
@@ -493,6 +496,7 @@ def xing4_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
             params, row * m.astype(row.dtype), cfg, compute_dtype)
         return {**stats, **{k: stats[k] * m for k in (
             "padding", "fused_attention", "grouped_experts",
+            "attention_blocks_computed", "attention_blocks_causal",
             "attention_padded_width", "hc_mix_positions", "rows_computed",
             "hc_sinkhorn_residual", "sequences")}}
 

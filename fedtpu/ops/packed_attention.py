@@ -1,0 +1,341 @@
+"""Tiled causal attention within the documents of one packed row, computing
+only the block pairs that can hold an allowed (query, key) pair.
+
+The three kernels (forward with an online softmax, ``dk``/``dv``, ``dq``) are
+the bodies of the library's ``jax.experimental.pallas.ops.tpu.flash_attention``
+at one block size, causal, with segment ids and without a bias: bf16 (or
+whatever the operands are) matmul inputs, float32 accumulation, maximum, sum
+and exponentials, the same mask inside a block. The library's kernels take
+the segment ids as a mask inside a block and leave out only the blocks above
+the diagonal; a row of several documents is mostly blocks that lie wholly
+across two of them, whose every probability is zero.
+
+What decides is a table of the row, made once a call on the device:
+``block_ranges`` holds the least and the largest segment id of each block
+(padding's 0 relabelled to the largest int32: equal ids stay equal, and a row
+whose padding stands at its end has ids that never fall, so the ranges are
+tight), and ``pairs_kept`` keeps a (query block, key block) pair on or under
+the diagonal whose two ranges overlap. The test is conservative for any
+layout of ids: a pair with one allowed (query, key) has that id in both
+ranges. A kept block runs under the full mask, as in the library. A row that
+is one document keeps every pair on or under the diagonal and runs the
+library's grid.
+
+The kernels are handed two int32 tables ahead of the grid (scalar prefetch),
+one entry a grid step in the order the grid walks: whether the step runs, and
+which block of the inner operand to hold at it. A step left out holds the
+block of the next step that runs, so nothing is fetched for it and the block
+a running step needs is there before it (``_held``; the library points a
+skipped step above the diagonal at block 0 to the same end).
+
+Once a row has met an allowed key a skipped block's probabilities are exactly
+zero, and what a fully masked block adds before that (the library's online
+softmax gives its keys equal weight) is wiped by the rescaling at the first
+allowed key, which every row has in its diagonal block. So the context and
+the three gradients are the library kernel's but for the float32 rounding of
+the rescaling steps a skipped block no longer makes.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES, SUBLANES = 128, 8
+# The library's: far below any score, and finite so that a row's maximum is.
+MASK_VALUE = -0.7 * float(np.finfo(np.dtype("float32")).max)
+_TRANS_B = (((1,), (1,)), ((), ()))
+_PADDING_LAST = np.iinfo(np.int32).max
+
+
+def block_ranges(segs, block: int):
+    """``(T / block, 2)`` int32: the least and the largest segment id of each
+    block of ``segs (T,)``, padding's 0 counted as the largest int32."""
+    ids = jnp.where(segs == 0, _PADDING_LAST, segs).reshape(-1, block)
+    return jnp.stack([ids.min(axis=1), ids.max(axis=1)], axis=1)
+
+
+def pairs_kept(segs, block: int):
+    """``(blocks, blocks)`` bool, ``[query block, key block]``, of the row
+    ``segs (T,)``: on or under the diagonal, and the two blocks' ranges of
+    ids (``block_ranges``) overlap."""
+    ranges = block_ranges(segs, block)
+    lo, hi = ranges[:, 0], ranges[:, 1]
+    at = jnp.arange(ranges.shape[0])
+    return ((at[:, None] >= at[None, :]) & (lo[:, None] <= hi[None, :])
+            & (lo[None, :] <= hi[:, None]))
+
+
+def _held(kept):
+    """The inner block to hold at each step of a grid that walks ``kept
+    (outer, inner)`` row by row, ``(outer * inner,)`` int32: a running
+    step's own, else that of the next step that runs (the last step, on the
+    diagonal, always does). One comparison of every step with every other
+    (4,096 to 65,536 of them): a single small fusion, where a cumulative
+    minimum is a ladder of them."""
+    steps = kept.size
+    at = jnp.arange(steps, dtype=jnp.int32)
+    at_or_after = kept.reshape(1, -1) & (at[None, :] >= at[:, None])
+    following = jnp.min(jnp.where(at_or_after, at[None, :], steps - 1),
+                        axis=1)
+    return following % kept.shape[1]
+
+
+def _mask(q_ids_ref, k_ids_ref, qi, ki, block):
+    """Causal, and equal segment ids: ``(block, block)`` bool of the pair of
+    blocks ``(qi, ki)``."""
+    q_ids = jnp.tile(q_ids_ref[...], (1, block // LANES))
+    rows = lax.broadcasted_iota(jnp.int32, (block, block), 0) + qi * block
+    cols = lax.broadcasted_iota(jnp.int32, (block, block), 1) + ki * block
+    return jnp.logical_and(q_ids == k_ids_ref[:1, :], cols <= rows)
+
+
+def _wide(a, width):
+    return jnp.tile(a, (1, width // LANES))
+
+
+def _forward_kernel(runs_ref, held_ref, q_ref, k_ref, v_ref, q_ids_ref,
+                    k_ids_ref, o_ref, *rest, sm_scale):
+    # the rows' sums and maxima are written where a backward pass will read
+    # them, and not by a forward pass alone
+    *statistics, m_scratch, l_scratch, acc_scratch = rest
+    del held_ref
+    qi, ki, blocks = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    block, width = q_ref.shape[1:]
+
+    @pl.when(ki == 0)
+    def _():
+        m_scratch[...] = jnp.full(m_scratch.shape, -jnp.inf, jnp.float32)
+        l_scratch[...] = jnp.zeros(l_scratch.shape, jnp.float32)
+        acc_scratch[...] = jnp.zeros(acc_scratch.shape, jnp.float32)
+
+    @pl.when(runs_ref[qi * blocks + ki] != 0)
+    def _():
+        m_prev, l_prev = m_scratch[...], l_scratch[...]
+        s = lax.dot_general(q_ref[0], k_ref[0], _TRANS_B,
+                            preferred_element_type=jnp.float32)
+        if sm_scale != 1.0:
+            s *= sm_scale
+        s += jnp.where(_mask(q_ids_ref, k_ids_ref, qi, ki, block), 0.0,
+                       MASK_VALUE)
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
+        p = jnp.exp(s - _wide(m_next, block))
+        l_corr = jnp.exp(m_prev - m_next) * l_prev
+        l_next = jnp.sum(p, axis=1)[:, None] + l_corr
+        l_scratch[...], m_scratch[...] = l_next, m_next
+        l_next_inv = jnp.where(l_next == 0.0, 1.0, 1.0 / l_next)
+        acc_scratch[...] *= _wide(l_corr * l_next_inv, width)
+        v = v_ref[0]
+        acc_scratch[...] += lax.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        ) * _wide(l_next_inv, width)
+
+    @pl.when(ki == blocks - 1)
+    def _():
+        o_ref[0] = acc_scratch[...].astype(o_ref.dtype)
+        for ref, scratch in zip(statistics, (l_scratch, m_scratch)):
+            ref[0] = scratch[...]
+
+
+def _probabilities_and_ds(q, k, v, l, m, do, di, mask, sm_scale):
+    """A block pair's probabilities and the scores' gradient, float32, from
+    the forward pass's row sums ``l`` and maxima ``m``."""
+    block = k.shape[0]
+    s = lax.dot_general(q, k, _TRANS_B, preferred_element_type=jnp.float32)
+    if sm_scale != 1.0:
+        s *= sm_scale
+    s += jnp.where(mask, 0.0, MASK_VALUE)
+    p = jnp.exp(s - _wide(m, block)) * _wide(1 / l, block)
+    dp = lax.dot_general(do, v, _TRANS_B, preferred_element_type=jnp.float32)
+    ds = (dp - _wide(di, block)) * p
+    if sm_scale != 1.0:
+        ds = ds * sm_scale
+    return p, ds
+
+
+def _dkv_kernel(runs_ref, held_ref, q_ref, k_ref, v_ref, q_ids_ref, k_ids_ref,
+                l_ref, m_ref, do_ref, di_ref, dk_ref, dv_ref, dk_scratch,
+                dv_scratch, *, sm_scale):
+    del held_ref
+    ki, qi, blocks = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    block = q_ref.shape[1]
+
+    @pl.when(qi == 0)
+    def _():
+        dk_scratch[...] = jnp.zeros(dk_scratch.shape, jnp.float32)
+        dv_scratch[...] = jnp.zeros(dv_scratch.shape, jnp.float32)
+
+    @pl.when(runs_ref[qi * blocks + ki] != 0)
+    def _():
+        q, do = q_ref[0], do_ref[0]
+        p, ds = _probabilities_and_ds(
+            q, k_ref[0], v_ref[0], l_ref[0], m_ref[0], do, di_ref[0],
+            _mask(q_ids_ref, k_ids_ref, qi, ki, block), sm_scale)
+        dv_scratch[...] += lax.dot(p.T.astype(do.dtype), do,
+                                   preferred_element_type=jnp.float32)
+        dk_scratch[...] += lax.dot(ds.T.astype(do.dtype), q,
+                                   preferred_element_type=jnp.float32)
+
+    @pl.when(qi == blocks - 1)
+    def _():
+        dv_ref[0] = dv_scratch[...].astype(dv_ref.dtype)
+        dk_ref[0] = dk_scratch[...].astype(dk_ref.dtype)
+
+
+def _dq_kernel(runs_ref, held_ref, q_ref, k_ref, v_ref, q_ids_ref, k_ids_ref,
+               l_ref, m_ref, do_ref, di_ref, dq_ref, dq_scratch, *, sm_scale):
+    del held_ref
+    qi, ki, blocks = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    block = q_ref.shape[1]
+
+    @pl.when(ki == 0)
+    def _():
+        dq_scratch[...] = jnp.zeros(dq_scratch.shape, jnp.float32)
+
+    @pl.when(runs_ref[qi * blocks + ki] != 0)
+    def _():
+        k = k_ref[0]
+        _, ds = _probabilities_and_ds(
+            q_ref[0], k, v_ref[0], l_ref[0], m_ref[0], do_ref[0], di_ref[0],
+            _mask(q_ids_ref, k_ids_ref, qi, ki, block), sm_scale)
+        dq_scratch[...] += lax.dot(ds.astype(k.dtype), k,
+                                   preferred_element_type=jnp.float32)
+
+    @pl.when(ki == blocks - 1)
+    def _():
+        dq_ref[0] = dq_scratch[...].astype(dq_ref.dtype)
+
+
+def _specs(block, width, blocks, queries_inner: bool):
+    """``(rows, keys, row_ids, key_ids, row_sums)``: the block specs of an
+    operand by the query's rows, by the key's, of the two forms of the
+    segment ids and of a ``(heads, T, LANES)`` row statistic, for a grid
+    ``(heads, outer, inner)`` whose outer operand is at its own block and
+    whose inner one is held as the second table says."""
+    def q_block(h, i, j, runs, held):
+        return held[i * blocks + j] if queries_inner else i
+
+    def k_block(h, i, j, runs, held):
+        return i if queries_inner else held[i * blocks + j]
+
+    return (pl.BlockSpec((1, block, width),
+                         lambda *g: (g[0], q_block(*g), 0)),
+            pl.BlockSpec((1, block, width),
+                         lambda *g: (g[0], k_block(*g), 0)),
+            pl.BlockSpec((block, LANES), lambda *g: (q_block(*g), 0)),
+            pl.BlockSpec((SUBLANES, block), lambda *g: (0, k_block(*g))),
+            pl.BlockSpec((1, block, LANES),
+                         lambda *g: (g[0], q_block(*g), 0)))
+
+
+def _call(kernel, name, kept, queries_inner, operands, in_specs, out_specs,
+          out_shape, scratch, **kwargs):
+    """One kernel over the grid ``(heads, outer block, inner block)``, handed
+    its two tables ahead of it: whether a step runs, read at ``[query block,
+    key block]`` whichever is outermost, and the inner block to hold."""
+    blocks = kept.shape[0]
+    walked = kept.T if queries_inner else kept  # fedtpu: noqa[FTP004] the caller's constant: which kernel this is
+    tables = (kept.reshape(-1).astype(jnp.int32), _held(walked))
+    return pl.pallas_call(
+        kernel, name=name, out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(operands[0].shape[0], blocks, blocks),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary")), **kwargs)(
+        *tables, *operands)
+
+
+def _ids(segs):
+    """The segment ids as the kernels read them: a query's along the lanes,
+    a key's along the sublanes (the library's two forms)."""
+    t = segs.shape[0]
+    return (lax.broadcast_in_dim(segs, (t, LANES), (0,)),
+            lax.broadcast_in_dim(segs, (SUBLANES, t), (1,)))
+
+
+def _forward(q, k, v, segs, kept, sm_scale, block, statistics: bool):
+    """``(ctx, l, m)``; ``l`` and ``m (heads, T)``, the rows' sums and
+    maxima, only where ``statistics`` (a backward pass follows)."""
+    heads, t, width = q.shape
+    blocks = t // block
+    rows, keys, row_ids, key_ids, row_sums = _specs(block, width, blocks,
+                                                    False)
+    stat = jax.ShapeDtypeStruct((heads, t, LANES), jnp.float32)
+    o, *lm = _call(
+        functools.partial(_forward_kernel, sm_scale=sm_scale),
+        "packed_attention_forward", kept, False, (q, k, v, *_ids(segs)),
+        [rows, keys, keys, row_ids, key_ids],
+        [rows] + [row_sums] * 2 * statistics,
+        [jax.ShapeDtypeStruct(q.shape, q.dtype)] + [stat] * 2 * statistics,
+        [pltpu.VMEM((block, LANES), jnp.float32)] * 2
+        + [pltpu.VMEM((block, width), jnp.float32)],
+        cost_estimate=pl.CostEstimate(
+            flops=4 * heads * t * t * width, transcendentals=heads * t * t,
+            bytes_accessed=4 * q.size * q.dtype.itemsize))
+    return (o, *(a[..., 0] for a in lm))
+
+
+def _backward(q, k, v, segs, kept, l, m, do, di, sm_scale, block):
+    _, t, width = q.shape
+    lanes = lambda a: jnp.broadcast_to(a[..., None], (*a.shape, LANES))
+    operands = (q, k, v, *_ids(segs), lanes(l), lanes(m), do, lanes(di))
+
+    def gradients(kernel, name, queries_inner, of):
+        # one float32 sum in the chip's own memory for each of ``of``, which
+        # are the outer operand's: its block changes once the inner walk ends
+        rows, keys, row_ids, key_ids, row_sums = _specs(
+            block, width, t // block, queries_inner)
+        return _call(
+            functools.partial(kernel, sm_scale=sm_scale), name, kept,
+            queries_inner, operands,
+            [rows, keys, keys, row_ids, key_ids, row_sums, row_sums, rows,
+             row_sums], [keys if queries_inner else rows] * len(of),
+            [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in of],
+            [pltpu.VMEM((block, width), jnp.float32)] * len(of))
+
+    dk, dv = gradients(_dkv_kernel, "packed_attention_backward_dkv", True,
+                       (k, v))
+    dq, = gradients(_dq_kernel, "packed_attention_backward_dq", False, (q,))
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _attention(q, k, v, segs, sm_scale, block):
+    kept = pairs_kept(segs, block)
+    return _forward(q, k, v, segs, kept, sm_scale, block, False)[0]
+
+
+def _attention_fwd(q, k, v, segs, sm_scale, block):
+    kept = pairs_kept(segs, block)
+    o, l, m = _forward(q, k, v, segs, kept, sm_scale, block, True)
+    return o, (q, k, v, segs, kept, o, l, m)
+
+
+def _attention_bwd(sm_scale, block, residuals, do):
+    q, k, v, segs, kept, o, l, m = residuals
+    di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    dq, dk, dv = _backward(q, k, v, segs, kept, l, m, do, di, sm_scale, block)
+    return dq, dk, dv, None
+
+
+_attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+# Under ``jax.jit`` as the library's wrapper is: a model's layers of one
+# shape share one trace and one lowering of each kernel, where every call
+# site would bring its own (24 Mosaic payloads in the four-stream round for
+# 4, and 7 to 9 s of its compile: PERF.md section 6, PR 38).
+@functools.partial(jax.jit, static_argnames=("sm_scale", "block"))
+def attention(q, k, v, segs, sm_scale: float, block: int):
+    """``ctx (heads, T, d)`` in the operands' dtype: causal attention within
+    the segments of ``segs (T,)`` int32 (equal ids, padding's 0 among them),
+    ``q``, ``k``, ``v`` ``(heads, T, d)`` with ``d`` whole lane tiles and
+    ``T`` whole ``block``s of whole lanes; reverse mode only."""
+    return _attention(q, k, v, segs, sm_scale, block)

@@ -164,6 +164,8 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
             "lm_tokens": s["count"],
             "lm_padding_tokens": s["padding"],
             "lm_fused_attention_positions": s["fused_attention"],
+            "lm_attention_blocks_computed": s["attention_blocks_computed"],
+            "lm_attention_blocks_causal": s["attention_blocks_causal"],
             "moe_grouped_kernel_positions": s["grouped_experts"],
             "moe_expert_load": s["expert_load"],
             **extra,

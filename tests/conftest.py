@@ -67,6 +67,9 @@ import pytest  # noqa: E402
 QUICK_TESTS = {
     # OLMoE against the plain reference; the shared-global engine's refusals
     "test_olmoe.py::test_top_k_sets_are_the_references_in_float32",
+    # the tiled attention core's table against a numpy count (no kernel)
+    "test_packed_attention.py::"
+    "test_a_step_left_out_holds_the_block_of_the_next_step_that_runs",
     # the hybrid stack: the pattern's letters, the held block's size
     "test_nemotron_h.py::test_the_held_block_is_whole_tiles_at_eight_thirds_of_the_mean",
     # the four-stream stack: the prediction module's targets

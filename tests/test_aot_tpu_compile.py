@@ -18,6 +18,7 @@ alphabet guards nothing.
 
 from __future__ import annotations
 
+import collections
 import os
 import re
 
@@ -215,6 +216,22 @@ def _pallas_calls(compiled, scope: str) -> list:
         compiled.as_text())
 
 
+def _attention_kernels(compiled) -> dict:
+    """How often each kernel of the tiled attention core
+    (``fedtpu.ops.packed_attention``: called by name inside its jitted
+    ``attention``, under the piece ``attn_core``, which a transform may
+    wrap: ``jvp(attn_core)``) stands in the compiled module."""
+    return dict(collections.Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*op_name="[^"]*attn_core\)*/'
+        r'jit\(attention\)/(\w+)/pallas_call"', compiled.as_text())))
+
+
+def _attention_calls(forward: int, backward: int) -> dict:
+    return {"packed_attention_forward": forward,
+            "packed_attention_backward_dkv": backward,
+            "packed_attention_backward_dq": backward}
+
+
 def test_the_olmoe_round_at_published_widths_fits_one_v5e_chip(olmoe_round):
     """With the XLA attention body (what this compile picks by itself, and
     what the chip ran before PR 26) the round's account (arguments + outputs
@@ -241,9 +258,9 @@ def test_the_olmoe_round_with_fused_attention_drops_the_scores(olmoe_round):
     assert _account(fused) <= 13.1e9, _account(fused)
     assert _account(fused) <= _account(xla) - 0.6e9
     assert fused.memory_analysis().alias_size_in_bytes >= 5.0e9
-    assert sorted(_pallas_calls(fused, "attention")) == [
-        "flash_attention"] * 3 * STEP_KINDS
-    assert not _pallas_calls(xla, "attention")
+    assert _attention_kernels(fused) == _attention_calls(
+        forward=STEP_KINDS, backward=STEP_KINDS)
+    assert not _attention_kernels(xla)
 
 
 def test_the_olmoe_round_runs_its_experts_in_the_grouped_kernels(olmoe_round):
@@ -448,8 +465,8 @@ def test_the_hybrid_round_at_published_widths_fits_one_v5e_chip(hybrid_round):
     text = hybrid_round.as_text()
     # the one attention layer ran fused, a kind of step: the forward
     # kernel, once more in the layer's recomputation, and the two backward
-    assert sorted(_pallas_calls(hybrid_round, "attention")) == [
-        "flash_attention"] * 4 * STEP_KINDS
+    assert _attention_kernels(hybrid_round) == _attention_calls(
+        forward=2 * STEP_KINDS, backward=STEP_KINDS)
     # the held experts ran in the grouped kernels (PR 33), an ``E`` layer
     # and kind of step: two products and, recomputed, two more, their two
     # input gradients (the same kernel on the weight in place) and their
@@ -554,8 +571,10 @@ def test_the_four_stream_round_at_published_widths_fits_one_v5e_chip(xing4_round
     sum of the parts reads above the chip's memory,
     ``train_xing4.program_account``) lies between the 10.96 GB the engine's
     12 bytes a parameter come to and the bound the configuration file states,
-    and IS what the file's ``memory`` states to the byte (the chip's compiler
-    allows 15.75 GiB, 16.9 GB); global and momentum in place. Every block's
+    and is what the file's ``memory`` states to a thousandth of a percent
+    (the file is the benchmark's and states PR 37's 15,146,139,136; with the
+    attention core's tables of PR 38 the compile reads 9,216 bytes more; the
+    chip's compiler allows 15.75 GiB, 16.9 GB); global and momentum in place. Every block's
     attention ran the tiled core at the padded head, a kind of step: the
     forward kernel, once more in the block's recomputation, and the two
     backward; the held experts at widths without tiles ran the compiler's
@@ -572,12 +591,14 @@ def test_the_four_stream_round_at_published_widths_fits_one_v5e_chip(xing4_round
     account = program_account(xing4_round.memory_analysis())
     assert account["peak"] > 0 and account["total"] == account["peak"]
     assert 10.96e9 <= account["total"] <= memory["round_account_bound_bytes"], account
-    assert account["total"] == memory["round_account_bytes"], account
+    assert abs(account["total"] - memory["round_account_bytes"]) <= (
+        1e-5 * memory["round_account_bytes"]), account
     assert account["aliased"] >= 7.3e9
     text = xing4_round.as_text()
-    assert sorted(_pallas_calls(xing4_round, "attention")) == [
-        "flash_attention"] * 4 * X4_BLOCKS * X4_STEP_KINDS
-    assert re.search(r"bf16\[1,32,4096,256\]", text)    # q, k, v at one width
+    each = X4_BLOCKS * X4_STEP_KINDS
+    assert _attention_kernels(xing4_round) == _attention_calls(
+        forward=2 * each, backward=each)
+    assert re.search(r"bf16\[32,4096,256\]", text)      # q, k, v at one width
     assert "ragged-dot" in text and _pallas_calls(xing4_round, "experts") == []
     for scope in ("attention", "attn_core", "attn_latent", "hyper_conn",
                   "hc_sinkhorn", "dense_mlp", "shared_expert", "router",
