@@ -96,7 +96,7 @@ class ModelConfig:
     """Model family + shape. MLP is FL_CustomMLP...:12-25; ConvNet is the
     BASELINE.json config-5 CIFAR-10 stress model (new, no reference analogue)."""
 
-    kind: str = "mlp"                    # 'mlp' | 'convnet' | 'olmoe' | 'nemotron_h' | 'xing4'
+    kind: str = "mlp"                    # 'mlp' | 'convnet' | 'olmoe' | 'nemotron_h' | 'xing4' | 'kimi_linear'
     # () degenerates the MLP to a single Linear — multinomial logistic
     # regression (pinned by tests/test_round_smoke.py).
     hidden_sizes: Tuple[int, ...] = (50, 200)  # FL_CustomMLP...:40
@@ -163,7 +163,7 @@ class ModelConfig:
     # first_k_dense_replace layers a plain gated MLP and the others sparse
     # experts beside n_shared_experts shared ones, then
     # num_nextn_predict_layers multi-token-prediction modules (0 or 1).
-    q_lora_rank: int = 768
+    q_lora_rank: Optional[int] = 768     # None: the query is one projection
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -182,6 +182,29 @@ class ModelConfig:
     hc_eps: float = 1e-6
     mhc_h_res_clamp_min: float = -30.0
     mhc_h_res_clamp_max: float = 30.0
+    # kind='kimi_linear' (fedtpu.models.kimi_linear): the keys of the
+    # published config.json of moonshotai/Kimi-Linear-48B-A3B-Instruct. Its
+    # nested ``linear_attn_config`` group lies flat here (kda_layers,
+    # full_attn_layers, both 1-based as published; kda_num_heads,
+    # kda_head_dim, short_conv_kernel_size) and four of its keys go by the
+    # names the expert layer already reads: num_experts -> n_routed_experts,
+    # num_experts_per_token -> num_experts_per_tok, num_shared_experts ->
+    # n_shared_experts, moe_renormalize -> norm_topk_prob. It also reads
+    # hidden_size, num_attention_heads, num_hidden_layers, intermediate_size
+    # (the leading dense layers' width), first_k_dense_replace, kv_lora_rank,
+    # qk_nope_head_dim, qk_rope_head_dim, v_head_dim, q_lora_rank (None),
+    # moe_intermediate_size, routed_scaling_factor, rms_norm_eps, vocab_size,
+    # experts_held and first_expert above, which its preset sets. A KDA
+    # mixer (a gated delta-rule recurrence, a decay a key channel) in the
+    # layers of kda_layers, latent attention in those of full_attn_layers;
+    # mla_use_nope: the latent attention's rotary columns are kept and
+    # nothing is rotated (xing4's rotates them).
+    kda_layers: Tuple[int, ...] = (1, 2, 3)
+    full_attn_layers: Tuple[int, ...] = (4,)
+    kda_num_heads: int = 32
+    kda_head_dim: int = 128
+    short_conv_kernel_size: int = 4
+    mla_use_nope: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -769,6 +792,36 @@ PRESETS["xing4-29b-a4b-l5-mtp1"] = ExperimentConfig(
                       moe_intermediate_size=1024, num_experts_per_tok=4,
                       norm_topk_prob=True, routed_scaling_factor=2.0,
                       rms_norm_eps=1e-6, vocab_size=16384, experts_held=8,
+                      first_expert=0, compute_dtype="bfloat16"),
+    optim=OptimConfig(name="sgd", learning_rate=0.005, momentum=0.0,
+                      steplr_gamma=1.0),
+    fed=FedConfig(rounds=20, client_state="stateless", local_batch_rows=1,
+                  one_step_kind=True, server_opt="fedavgm",
+                  server_momentum=0.9, same_init=True),
+)
+
+
+# moonshotai/Kimi-Linear-48B-A3B-Instruct at its published widths, as one
+# 16 GB chip of a 32-way expert-parallel stage holds it: the model's first
+# five layers of 27 (the leading dense layer, then K K F K of its 3 : 1
+# pattern: four KDA mixers and one latent-attention layer without positions),
+# 8 of each expert layer's 256 routed experts (the router stays 256 wide,
+# top-8), an eighth of the vocabulary: 602.5M parameters. Federated as the
+# other language models' presets are, on 16 packed 4,096-token sequences, with
+# one kind of step (PERF.md section 6, PR 39).
+PRESETS["kimi-linear-48b-a3b-l5"] = ExperimentConfig(
+    data=DataConfig(dataset_name="tokens", synthetic_rows=16,
+                    synthetic_features=4096),
+    shard=ShardConfig(num_clients=8, shuffle=False),
+    model=ModelConfig(kind="kimi_linear", hidden_size=2304,
+                      num_attention_heads=32, num_hidden_layers=5,
+                      kda_layers=(1, 2, 3, 5), full_attn_layers=(4,),
+                      first_k_dense_replace=1, intermediate_size=9216,
+                      q_lora_rank=None, mla_use_nope=True,
+                      rope_scaling_factor=1.0, n_routed_experts=256,
+                      moe_intermediate_size=1024, num_experts_per_tok=8,
+                      norm_topk_prob=True, routed_scaling_factor=2.446,
+                      rms_norm_eps=1e-5, vocab_size=20480, experts_held=8,
                       first_expert=0, compute_dtype="bfloat16"),
     optim=OptimConfig(name="sgd", learning_rate=0.005, momentum=0.0,
                       steplr_gamma=1.0),

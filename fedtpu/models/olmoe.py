@@ -111,17 +111,22 @@ SSM, SSM_SCAN, SHARED_EXPERT = "ssm", "ssm_scan", "shared_expert"
 # residual streams around every sublayer, a plain gated MLP layer, and the
 # projection that opens a multi-token-prediction module
 HYPER_CONN, DENSE_MLP, MTP_PROJ = "hyper_conn", "dense_mlp", "mtp_proj"
+# the delta-rule stack's own (fedtpu.models.kimi_linear): a KDA mixer, and
+# the chunked recurrence alone inside it (innermost)
+KDA, KDA_SCAN = "kda", "kda_scan"
 LAYER_SCOPES = (EMBED, ATTENTION, ROUTER, EXPERT_DISPATCH, EXPERTS,
                 LM_HEAD_LOSS, SSM, SSM_SCAN, SHARED_EXPERT, HYPER_CONN,
-                DENSE_MLP, MTP_PROJ)
+                DENSE_MLP, MTP_PROJ, KDA, KDA_SCAN)
 # The third level (``parallel.round.PIECES``): the four parts of a state-space
 # mixer around its scan, the attention core alone inside ``attention``, the
-# Sinkhorn iterations alone inside ``hyper_conn``, and the low-rank
-# projections of latent attention (their norms and RoPE) beside the core.
+# Sinkhorn iterations alone inside ``hyper_conn``, the low-rank projections
+# of latent attention (their norms and RoPE) beside the core, and the four
+# parts of a KDA mixer around its scan.
 (SSM_IN_PROJ, SSM_CONV, SSM_GATE_NORM, SSM_OUT_PROJ, ATTN_CORE, HC_SINKHORN,
- ATTN_LATENT) = PIECE_SCOPES = (
+ ATTN_LATENT, KDA_IN_PROJ, KDA_CONV, KDA_GATES, KDA_OUT_PROJ) = PIECE_SCOPES = (
     "ssm_in_proj", "ssm_conv", "ssm_gate_norm", "ssm_out_proj", "attn_core",
-    "hc_sinkhorn", "attn_latent")
+    "hc_sinkhorn", "attn_latent", "kda_in_proj", "kda_conv", "kda_gates",
+    "kda_out_proj")
 # An outer scope around a whole multi-token-prediction module, its layers'
 # own scopes inside it (``parallel.round.MODULES``).
 MTP = "mtp"

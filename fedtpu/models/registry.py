@@ -16,10 +16,11 @@ _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16,
 
 def build_model(cfg: ModelConfig):
     """Return ``(init_fn(key) -> params, apply_fn(params, x) -> logits)``.
-    For the language models (``kind='olmoe'``, ``'nemotron_h'``, ``'xing4'``)
-    the second is ``stats_fn(params, x, mask) -> statistics``
-    (fedtpu.models.olmoe.olmoe_stats, nemotron_h.nemotron_h_stats,
-    xing4.xing4_stats): a
+    For the language models (``kind='olmoe'``, ``'nemotron_h'``, ``'xing4'``,
+    ``'kimi_linear'``) the second is ``stats_fn(params, x, mask) ->
+    statistics`` (fedtpu.models.olmoe.olmoe_stats,
+    nemotron_h.nemotron_h_stats, xing4.xing4_stats,
+    kimi_linear.kimi_linear_stats): a
     vocabulary-sized model hands out sums over tokens, never its logits."""
     param_dtype = _DTYPES[cfg.param_dtype]
     compute_dtype = (None if cfg.compute_dtype == cfg.param_dtype
@@ -76,6 +77,16 @@ def build_model(cfg: ModelConfig):
                                  param_dtype=param_dtype)
         stats = functools.partial(
             xing4.xing4_stats, cfg=cfg,
+            compute_dtype=compute_dtype or param_dtype)
+        return init, stats
+    if cfg.kind == "kimi_linear":
+        from fedtpu.models import kimi_linear
+        kimi_linear.layer_kinds(cfg)    # the two lists, no prediction module
+        kimi_linear.experts_share(cfg)
+        init = functools.partial(kimi_linear.kimi_linear_init, cfg=cfg,
+                                 param_dtype=param_dtype)
+        stats = functools.partial(
+            kimi_linear.kimi_linear_stats, cfg=cfg,
             compute_dtype=compute_dtype or param_dtype)
         return init, stats
     raise ValueError(f"unknown model kind {cfg.kind!r}")

@@ -185,31 +185,33 @@ def _ffn_init(kind, cfg, normal, ones):
             "shared_up": normal(h, s), "shared_down": normal(s, h)}
 
 
+def cut_from_one_draw(key, build, ones, param_dtype):
+    """``build(normal, ones)`` with its ``normal(*shape)`` leaves cut, in the
+    order they are asked for, out of ONE N(0, 0.02) vector drawn from
+    ``key``: a draw a leaf was a hundred random-bit programs and 26 s of the
+    init's compile for the TPU, which every job pays before its first round
+    (15 s so)."""
+    shapes = []
+    jax.eval_shape(lambda: build(
+        lambda *shape: shapes.append(shape) or jnp.zeros(shape), ones))
+    flat = INIT_STD * jax.random.normal(
+        key, (sum(map(math.prod, shapes)),), param_dtype)
+    ends = list(itertools.accumulate(map(math.prod, shapes)))
+    cut = iter(zip([0, *ends], ends))
+    return build(lambda *shape: flat[slice(*next(cut))].reshape(shape), ones)
+
+
 def xing4_init(key: jax.Array, cfg, param_dtype=jnp.float32):
     """N(0, 0.02) weights and selection biases, unit norm gains, the
     residual modules as ``_hyper_init`` draws them. Each kind's layers are a
-    tuple under the kind's name; ``mtp`` holds the prediction modules.
-
-    The weights of a block's attention are cut out of ONE draw and those of
-    its feed-forward out of another: a draw a leaf was a hundred random-bit
-    programs and 26 s of the init's compile for the TPU, which every job
-    pays before its first round (15 s so)."""
+    tuple under the kind's name; ``mtp`` holds the prediction modules. The
+    weights of a block's attention are cut out of ONE draw and those of its
+    feed-forward out of another (``cut_from_one_draw``)."""
     count = itertools.count()
     fresh = lambda: jax.random.fold_in(key, next(count))
     ones = lambda *shape: jnp.ones(shape, param_dtype)
-
-    def weights(build):
-        """``build(normal, ones)`` with its ``normal(*shape)`` leaves cut, in
-        the order they are asked for, out of one N(0, 0.02) vector."""
-        shapes = []
-        jax.eval_shape(lambda: build(
-            lambda *shape: shapes.append(shape) or jnp.zeros(shape), ones))
-        flat = INIT_STD * jax.random.normal(
-            fresh(), (sum(map(math.prod, shapes)),), param_dtype)
-        ends = list(itertools.accumulate(map(math.prod, shapes)))
-        cut = iter(zip([0, *ends], ends))
-        return build(lambda *shape: flat[slice(*next(cut))].reshape(shape),
-                     ones)
+    weights = lambda build: cut_from_one_draw(fresh(), build, ones,
+                                              param_dtype)
 
     def layer(kind):
         return {"attn": weights(functools.partial(_attention_init, cfg)),
@@ -319,24 +321,39 @@ def _pairs_apart(x):
 
 
 def latent_attention(cfg, compute_dtype, u, layer, segs, pos):
-    """``attention(RMSNorm(u))`` of one packed sequence, ``(T, C)`` float32."""
+    """``attention(RMSNorm(u))`` of one packed sequence, ``(T, C)`` float32.
+    Two things a config may ask for besides (Kimi-Linear's does both): no
+    query bottleneck (``q_lora_rank`` None: the query is ONE projection,
+    ``layer["q"]``) and no positions (``mla_use_nope``: the ``rope`` columns
+    are kept, a key's one vector for all heads still, and nothing is
+    rotated; ``pos`` is not read)."""
     t, heads, eps = u.shape[0], cfg.num_attention_heads, cfg.rms_norm_eps
     nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     cast = lambda arr: arr.astype(compute_dtype)
     with jax.named_scope(ATTENTION):
         with jax.named_scope(ATTN_LATENT):
             x = cast(rms_norm(u, layer["norm"], eps))
-            cq = rms_norm(_mm(x, cast(layer["q_a"])), layer["q_a_norm"], eps)
-            q = _mm(cast(cq), cast(layer["q_b"])).reshape(t, heads, nope + rope)
+            if cfg.q_lora_rank is None:
+                q = _mm(x, cast(layer["q"]))
+            else:
+                cq = rms_norm(_mm(x, cast(layer["q_a"])), layer["q_a_norm"],
+                              eps)
+                q = _mm(cast(cq), cast(layer["q_b"]))
+            q = q.reshape(t, heads, nope + rope)
             ckv, k_r = jnp.split(_mm(x, cast(layer["kv_a"])),
                                  [cfg.kv_lora_rank], axis=-1)
             ckv = rms_norm(ckv, layer["kv_a_norm"], eps)
             k_n, v = jnp.split(
                 _mm(cast(ckv), cast(layer["kv_b"])).reshape(
                     t, heads, nope + vd), [nope], axis=-1)
-            inv = jnp.asarray(yarn_inv_freq(cfg))
-            turn = lambda a: _rope(_pairs_apart(a), pos, cfg.rope_theta, inv)
-            q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
+            if cfg.mla_use_nope:
+                turn = lambda a: a
+            else:
+                inv = jnp.asarray(yarn_inv_freq(cfg))
+                turn = lambda a: _rope(_pairs_apart(a), pos, cfg.rope_theta,
+                                       inv)
+                q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])],
+                                    axis=-1)
             # the rotary part of the key is one vector for all heads
             k = jnp.concatenate(
                 [k_n, jnp.broadcast_to(turn(k_r[:, None]), (t, heads, rope))],

@@ -71,20 +71,23 @@ CLIENT_TRAIN, CLIENT_EVAL, AGGREGATE, METRICS = STAGES = (
 SERVER_UPDATE = "server_update"
 LAYERS = ("embed", "attention", "router", "expert_dispatch", "experts",
           "lm_head_loss", "ssm", "ssm_scan", "shared_expert", "hyper_conn",
-          "dense_mlp", "mtp_proj", SERVER_UPDATE)
+          "dense_mlp", "mtp_proj", "kda", "kda_scan", SERVER_UPDATE)
 # The third level, inside a layer or outside every one, set where the work
 # happens: the four parts of a state-space mixer around its scan
 # (fedtpu.models.nemotron_h.mamba_mixer), the attention core alone (whichever
 # body of olmoe.attention_core runs; the rest of ``attention`` is the
 # projections'), the Sinkhorn iterations alone inside ``hyper_conn`` and the
 # low-rank projections of latent attention beside its core
-# (fedtpu.models.xing4), and the one fused pass a step that applies a
-# gradient and adds the step's share to the accumulator
-# (fedtpu.parallel.stateless). The event maps operations to them under
-# ``pieces``.
+# (fedtpu.models.xing4), the four parts of a KDA mixer around its scan
+# (fedtpu.models.kimi_linear.kda_mixer: the input projections, the three
+# short convolutions, the decay / step / norms / output gate, the output
+# projection), and the one fused pass a step that applies a gradient and
+# adds the step's share to the accumulator (fedtpu.parallel.stateless). The
+# event maps operations to them under ``pieces``.
 SGD_PASS = "sgd_pass"
 PIECES = ("ssm_in_proj", "ssm_conv", "ssm_gate_norm", "ssm_out_proj",
-          "attn_core", "hc_sinkhorn", "attn_latent", SGD_PASS)
+          "attn_core", "hc_sinkhorn", "attn_latent", "kda_in_proj",
+          "kda_conv", "kda_gates", "kda_out_proj", SGD_PASS)
 # An outer scope AROUND layers: a whole multi-token-prediction module
 # (fedtpu.models.xing4), whose attention, experts and head keep their own
 # layers' names inside it. The event maps operations to it under

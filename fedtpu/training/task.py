@@ -69,7 +69,7 @@ def classification_task(apply_fn: Callable, num_classes: int) -> Task:
                 metrics=metrics_from_confusion)
 
 
-LANGUAGE_MODELS = ("olmoe", "nemotron_h", "xing4")
+LANGUAGE_MODELS = ("olmoe", "nemotron_h", "xing4", "kimi_linear")
 # The weight of a prediction module's loss in the sum that is differentiated:
 # no key of a published config (DeepSeek-V3's, arXiv:2412.19437 section 2.2).
 MTP_LOSS_WEIGHT = 0.3
@@ -78,7 +78,7 @@ MTP_LOSS_WEIGHT = 0.3
 def next_token_task(stats_fn: Callable, model_cfg) -> Task:
     """``stats_fn(params, x, mask)`` is the model's own
     (fedtpu.models.olmoe.olmoe_stats, nemotron_h.nemotron_h_stats,
-    xing4.xing4_stats): rows ``x (N, 2, T)`` of token and segment ids;
+    xing4.xing4_stats, kimi_linear.kimi_linear_stats): rows ``x (N, 2, T)`` of token and segment ids;
     labels are the rows' own next tokens, so ``y`` is unused.
 
     A model with a multi-token-prediction module (``num_nextn_predict_layers``
@@ -121,15 +121,17 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
     assignments = model_cfg.num_experts_per_tok * model_cfg.num_hidden_layers
     # what a model that holds a share of its experts counts besides, and
     # what its other layers do (nemotron_h's state-space layers; xing4's
-    # residual modules and prediction module): read off the statistics it
-    # hands out
+    # residual modules and prediction module; kimi_linear's delta-rule
+    # recurrences): read off the statistics it hands out
     share = {"moe_assignments_held": "assignments_held",
              "moe_rows_computed": "rows_computed",
              "ssm_positions": "ssm_positions",
              "ssm_document_restarts": "ssm_restarts",
              "ssm_fused_pass_positions": "ssm_fused_passes",
              "hc_mix_positions": "hc_mix_positions",
-             "mtp_positions": "mtp_count"}
+             "mtp_positions": "mtp_count",
+             "kda_positions": "kda_positions",
+             "kda_document_restarts": "kda_restarts"}
 
     def counters(s):
         load = s["expert_load"].astype(jnp.float32)
@@ -153,6 +155,12 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
                 attention_padded_width=s["attention_padded_width"]
                 / jnp.maximum(s["tokens"] + s["padding"], 1.0),
                 hc_sinkhorn_residual=s["hc_sinkhorn_residual"] / steps)
+        if "kda_log_decay_min" in s:
+            # a gauge of the round: the mean over its steps of the most
+            # negative cumulative log-decay inside one chunk of a step's
+            # recurrences (under -88, ``exp(-G)`` would overflow float32)
+            extra.update(kda_log_decay_min=s["kda_log_decay_min"]
+                         / jnp.maximum(s["sequences"], 1.0))
         if second:
             extra.update(main_loss=mean(s["loss_sum"], s["count"]),
                          mtp_loss=mean(s["mtp_loss_sum"], s["mtp_count"]))
@@ -178,7 +186,7 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
                 counters=counters,
                 gauges=("moe_expert_load_max_over_mean",
                         "attention_padded_width", "hc_sinkhorn_residual",
-                        "main_loss", "mtp_loss"))
+                        "main_loss", "mtp_loss", "kda_log_decay_min"))
 
 
 def build_task(model_cfg, model_fn: Callable, num_classes: int) -> Task:

@@ -74,6 +74,8 @@ QUICK_TESTS = {
     "test_nemotron_h.py::test_the_held_block_is_whole_tiles_at_eight_thirds_of_the_mean",
     # the four-stream stack: the prediction module's targets
     "test_xing4.py::test_the_modules_targets_and_validity_at_document_edges",
+    # the delta-rule stack: what its layer lists and its scan refuse
+    "test_kimi_linear.py::test_what_the_registry_refuses",
     "test_stateless_round.py::"
     "test_minibatches_need_the_stateless_engine_and_a_known_client_state",
     # the stage of each operation from a compiled program's text (pure text)

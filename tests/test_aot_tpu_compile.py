@@ -514,28 +514,25 @@ def test_the_hybrid_round_runs_the_mixers_passes_in_the_tiled_kernels(
     assert directions == {FORWARD: each, RECOMPUTE: each, BACKWARD: each}
 
 
-@pytest.fixture(scope="module")
-def xing4_round(topo):
-    """The shared-global round of the four-stream preset (``xing4``: one
-    dense and four expert layers and the multi-token-prediction module at
-    published widths, 8 of 64 experts and an eighth of the vocabulary held,
-    913.5M parameters; 8 clients, the preset's 16 packed sequences of 4,096
-    tokens in ONE kind of step, FedAvgM) compiled for one described v5e chip, the rules between the bodies told
-    the backend is a TPU as for the hybrid round."""
+def _one_step_kind_round(topo, preset: str, parameters: int):
+    """The shared-global round of a language-model preset whose clients take
+    ONE kind of step (8 clients, the preset's 16 packed sequences of 4,096
+    tokens, FedAvgM) compiled for one described v5e chip, the rules between
+    the bodies told the backend is a TPU as for the hybrid round."""
     from fedtpu.config import get_preset
     from fedtpu.models.registry import build_model
     from fedtpu.ops.server_opt import make_server_optimizer
     from fedtpu.parallel.stateless import build_stateless_round_fn
     from fedtpu.training.task import build_task
 
-    cfg = get_preset("xing4-29b-a4b-l5-mtp1")
+    cfg = get_preset(preset)
     mesh = Mesh(np.array(topo.devices[:1]), ("clients",))
     rep, by_client = NamedSharding(mesh, P()), NamedSharding(mesh, P("clients"))
     init_fn, stats_fn = build_model(cfg.model)
     server = make_server_optimizer("fedavgm", cfg.fed.server_lr,
                                    cfg.fed.server_momentum)
     params = jax.eval_shape(init_fn, jax.random.key(0))
-    assert sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params)) == 913_473_668
+    assert sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params)) == parameters
     shaped = lambda tree, sharding: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
     state = {"params": shaped(params, rep),
@@ -545,8 +542,6 @@ def xing4_round(topo):
 
     seq = cfg.data.synthetic_features
     sizes = [int(n) for n in skewed_sizes(cfg.data.synthetic_rows, 8)]
-    # all four kinds of step, were each a trace of its own: that round is
-    # refused at 16.39 GiB of the chip's 15.75 (PERF.md section 6, PR 37)
     assert sizes == [1, 1, 2, 2, 2, 2, 3, 3] and cfg.fed.one_step_kind
     longest = max(sizes)
     batch = {"x": jax.ShapeDtypeStruct((8, longest, 2, seq), jnp.int32, sharding=by_client),
@@ -560,6 +555,17 @@ def xing4_round(topo):
             local_batch_rows=cfg.fed.local_batch_rows,
             one_step_kind=cfg.fed.one_step_kind)
         return step.lower(state, batch).compile()
+
+
+@pytest.fixture(scope="module")
+def xing4_round(topo):
+    """The round of the four-stream preset (``xing4``: one dense and four
+    expert layers and the multi-token-prediction module at published widths,
+    8 of 64 experts and an eighth of the vocabulary held, 913.5M
+    parameters). With all four kinds of step, were each a trace of its own,
+    that round is refused at 16.39 GiB of the chip's 15.75 (PERF.md section
+    6, PR 37)."""
+    return _one_step_kind_round(topo, "xing4-29b-a4b-l5-mtp1", 913_473_668)
 
 
 X4_BLOCKS = 6               # five layers and the prediction module's block
@@ -604,4 +610,54 @@ def test_the_four_stream_round_at_published_widths_fits_one_v5e_chip(xing4_round
                   "hc_sinkhorn", "dense_mlp", "shared_expert", "router",
                   "expert_dispatch", "experts", "mtp", "mtp_proj",
                   "lm_head_loss", "embed", "sgd_pass", "server_update"):
+        assert f"/{scope}/" in text or f"({scope})" in text, scope
+
+
+@pytest.fixture(scope="module")
+def kimi_linear_round(topo):
+    """The round of the delta-rule preset (``kimi_linear``: the first five
+    layers of Kimi-Linear-48B-A3B at published widths, four KDA mixers and
+    one latent-attention layer, 8 of 256 experts and an eighth of the
+    vocabulary held, 602.5M parameters)."""
+    return _one_step_kind_round(topo, "kimi-linear-48b-a3b-l5", 602_450_816)
+
+
+def test_the_delta_rule_round_at_published_widths_fits_one_v5e_chip(
+        kimi_linear_round):
+    """The round's account (the compiler's own peak, as the four-stream
+    round's) lies between the 7.23 GB the engine's 12 bytes a parameter come
+    to and the bound the configuration file states (over 4.3 GB, under 15.0:
+    ISSUE 39), and is what the file's ``memory`` states to a thousandth of a
+    percent; global and momentum in place. The one attention layer ran the
+    tiled core at the padded head (the forward kernel, once more in the
+    layer's recomputation, and the two backward); the held experts at widths
+    without tiles ran the compiler's own grouped kernel; every scope the
+    reducers read is in the program."""
+    import json
+    import os
+
+    from perfbench.drivers.train_xing4 import program_account
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "perfbench", "configs",
+                           "kimi-linear-48b-a3b-l5-fed8.json")) as fh:
+        memory = json.load(fh)["memory"]
+    account = program_account(kimi_linear_round.memory_analysis())
+    assert account["peak"] > 0 and account["total"] == account["peak"]
+    assert 4.3e9 < 7.23e9 <= account["total"] <= memory[
+        "round_account_bound_bytes"] == 15.0e9, account
+    assert abs(account["total"] - memory["round_account_bytes"]) <= (
+        1e-5 * memory["round_account_bytes"]), account
+    assert account["aliased"] >= 4.8e9
+    text = kimi_linear_round.as_text()
+    assert _attention_kernels(kimi_linear_round) == _attention_calls(
+        forward=2, backward=1)
+    assert re.search(r"bf16\[32,4096,256\]", text)      # q, k, v at one width
+    assert "ragged-dot" in text and _pallas_calls(kimi_linear_round,
+                                                  "experts") == []
+    for scope in ("kda", "kda_scan", "kda_in_proj", "kda_conv", "kda_gates",
+                  "kda_out_proj", "attention", "attn_core", "attn_latent",
+                  "dense_mlp", "shared_expert", "router", "expert_dispatch",
+                  "experts", "lm_head_loss", "embed", "sgd_pass",
+                  "server_update"):
         assert f"/{scope}/" in text or f"({scope})" in text, scope
