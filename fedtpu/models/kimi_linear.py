@@ -45,6 +45,17 @@ the benchmark's configuration file under ``assumed``. Every layer is
   float32; the chunk's large products take ``compute_dtype`` inputs and sum
   in float32. Autodiff differentiates all of it but the inverse, which has
   the rule ``-T^T dT T^T``.
+* **Which body runs where.** ``kda_scan`` below is the definition: XLA's
+  passes over ``(chunks, heads, C, d)`` arrays, the body on a CPU and at
+  shapes without tiles, and the oracle of the kernels' tests. Where
+  ``fused_scan_applies`` (a TPU, keys and values of whole lane tiles, whole
+  chunks of whole sub-chunks: the published widths at any row of whole
+  chunks) the same algebra at the same precision runs as two Mosaic kernels
+  under a differentiation rule of their own, ``fedtpu.ops.kda_scan``: a
+  head's state stays in the chip's memory across its chunks, the operands
+  are read in place as the convolutions leave them, and nothing of a chunk
+  but ``o`` (and, for the backward pass, the state that entered it) is
+  written. ``kda_fused_scan`` among the statistics says which ran.
 * **Latent attention** (layers ``full_attn_layers``): ``xing4.
   latent_attention`` with no query bottleneck and nothing rotated
   (``q_lora_rank`` None, ``mla_use_nope``).
@@ -76,6 +87,7 @@ from fedtpu.models.nemotron_h import (causal_conv, document_runs,
 from fedtpu.models.olmoe import (EMBED, KDA, KDA_CONV, KDA_GATES, KDA_IN_PROJ,
                                  KDA_OUT_PROJ, KDA_SCAN, LM_HEAD_LOSS,
                                  _head_loss, next_token_targets, rms_norm)
+from fedtpu.ops import kda_scan as scan_kernels
 
 # Positions of a chunk of the recurrence, and of a sub-chunk of the decay-
 # weighted scores inside it (pairwise on the diagonal, two factors below).
@@ -275,6 +287,19 @@ def _decayed_scores(lefts, k, cum, sub: int, compute_dtype):
     return out
 
 
+def fused_scan_applies(t: int, d_k: int, d_v: int, chunk: int,
+                       sub: int) -> bool:
+    """Whether the recurrence's tiled kernels (``fedtpu.ops.kda_scan``: one
+    forward, one backward, a head's state in the chip's own memory) exist for
+    a row of ``t`` positions, keys ``d_k`` and values ``d_v`` wide, where the
+    program is being built: a TPU (the PROCESS's backend, as ``olmoe.
+    fused_attention_applies`` reads it), keys and values of whole lane tiles,
+    ``t`` whole chunks and a chunk whole sub-chunks. The chunked form below
+    is the definition and the body everywhere else."""
+    return jax.default_backend() == "tpu" and scan_kernels.tiles_apply(
+        t, d_k, d_v, chunk, sub)
+
+
 def kda_scan(q, k, v, g, beta, run, chunk: int, compute_dtype,
              sub: int = KDA_SUB):
     """``o (T, heads, d_v)`` float32 of the recurrence ``S_t = (I - beta_t k_t
@@ -283,8 +308,12 @@ def kda_scan(q, k, v, g, beta, run, chunk: int, compute_dtype,
     ``k (T, heads, d_k)``, ``v (T, heads, d_v)``, ``g (T, heads, d_k)`` the
     log-decay, NEVER positive, ``beta (T, heads)``, all float32; ``run (T,)``
     from ``document_runs``; ``T`` is whole chunks (or one shorter chunk) and
-    a chunk whole sub-chunks."""
+    a chunk whole sub-chunks. Where ``fused_scan_applies`` the kernels run,
+    named for their direction so that their ``op_name`` keeps it."""
     t, heads, _ = k.shape
+    if fused_scan_applies(t, k.shape[-1], v.shape[-1], chunk, sub):
+        return scan_kernels.kda_scan(q, k, v, g, beta, run, chunk, sub,
+                                     compute_dtype)
     c = min(chunk, t)
     sub = min(sub, c)
     if t % c or c % sub:
@@ -346,12 +375,35 @@ def _l2_normed(x):
     return x * lax.rsqrt((x * x).sum(axis=-1, keepdims=True) + L2_EPS)
 
 
+def _head_tiles(x, heads: int, rows: int):
+    """``x (T, heads * d)`` as ``(T / rows, heads, rows, d)``, a head's ``d``
+    last. At ``rows`` 8 these are the tiles a TPU holds the array in (eight
+    rows of one head's lanes), so a view of what the convolutions leave and
+    of what the recurrence's kernels read and write, where ``(T, heads, d)``
+    is a transposing copy of the whole array each way (96 of them a step at
+    the published widths, 67 MB each: PERF.md section 6, PR 40); at ``rows``
+    1 it is ``(T, heads, 1, d)``, the plain form."""
+    t = x.shape[0]
+    return x.reshape(t // rows, rows, heads, -1).transpose(0, 2, 1, 3)
+
+
+def _rows(x):
+    """``_head_tiles``' inverse: ``(T, heads * d)``."""
+    n, heads, rows, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(n * rows, heads * d)
+
+
 def kda_mixer(cfg, compute_dtype, h, layer, segs):
     """``(mixer(RMSNorm(h)), statistics)`` of one KDA layer."""
     t = h.shape[0]
     heads, d = cfg.kda_num_heads, cfg.kda_head_dim
     cast = lambda arr: arr.astype(compute_dtype)
     two = lambda x, a, b: _mm(cast(_mm(x, cast(layer[a]))), cast(layer[b]))
+    by_head = lambda arr: arr.reshape(t, heads, d)
+    fused = fused_scan_applies(t, d, d, KDA_CHUNK, KDA_SUB)
+    # where the kernels read the arrays in place, the gates on their tiles
+    tiles = functools.partial(_head_tiles, heads=heads,
+                              rows=scan_kernels.SUBLANES if fused else 1)
     run, starts = document_runs(segs)
     with jax.named_scope(KDA):
         with jax.named_scope(KDA_IN_PROJ):
@@ -362,26 +414,30 @@ def kda_mixer(cfg, compute_dtype, h, layer, segs):
             step = _mm(x, cast(layer["b_proj"]))
         with jax.named_scope(KDA_CONV):
             q, k, v = (jax.nn.silu(causal_conv(a, layer[name], 0.0, run))
-                       .reshape(t, heads, d)
                        for a, name in ((q, "q_conv"), (k, "k_conv"),
                                        (v, "v_conv")))
         with jax.named_scope(KDA_GATES):
-            q, k = _l2_normed(q) * d ** -0.5, _l2_normed(k)
-            g = (-jnp.exp(layer["A_log"].astype(jnp.float32))[:, None]
-                 * jax.nn.softplus((decay + layer["dt_bias"]).reshape(
-                     t, heads, d)))
-            beta = jax.nn.sigmoid(step)
+            q = _rows(_l2_normed(tiles(q)) * d ** -0.5)
+            k = _rows(_l2_normed(tiles(k)))
+            fall = (-jnp.exp(layer["A_log"].astype(jnp.float32))[:, None, None]
+                    * jax.nn.softplus(tiles(decay + layer["dt_bias"])))
+            g, beta = _rows(fall), jax.nn.sigmoid(step)
         with jax.named_scope(KDA_SCAN):
-            o = kda_scan(q, k, v, g, beta, run, KDA_CHUNK, compute_dtype)
+            o = kda_scan(by_head(q), by_head(k), by_head(v), by_head(g), beta,
+                         run, KDA_CHUNK, compute_dtype)
         with jax.named_scope(KDA_GATES):
-            deepest = lax.stop_gradient(g.reshape(
-                -1, min(KDA_CHUNK, t), heads, d).sum(axis=1).min())
-            y = (rms_norm(o, layer["o_norm"], cfg.rms_norm_eps)
-                 * jax.nn.sigmoid((gate + layer["g_bias"]).reshape(
-                     t, heads, d)))
+            # a chunk is whole tiles of rows (or the row is one chunk)
+            rows = fall.shape[2]
+            deepest = lax.stop_gradient(fall.reshape(
+                -1, min(KDA_CHUNK, t) // rows, heads, rows, d).sum(
+                    axis=(1, 3)).min())
+            y = _rows(rms_norm(tiles(o.reshape(t, heads * d)),
+                               layer["o_norm"], cfg.rms_norm_eps)
+                      * jax.nn.sigmoid(tiles(gate + layer["g_bias"])))
         with jax.named_scope(KDA_OUT_PROJ):
-            out = _mm(cast(y.reshape(t, heads * d)), cast(layer["o_proj"]))
+            out = _mm(cast(y), cast(layer["o_proj"]))
     return out, {"kda_positions": jnp.float32(t),
+                 "kda_fused_scan": jnp.float32(t if fused else 0),
                  "kda_restarts": (starts & (segs > 0)).sum().astype(
                      jnp.float32),
                  "kda_log_decay_min": deepest}
@@ -411,14 +467,17 @@ def _zero_stats(cfg):
     return {"expert_load": jnp.zeros((cfg.n_routed_experts,), jnp.int32),
             "assignments_held": zero, "rows_computed": zero,
             "rows_held_computed": zero, "kda_positions": zero,
-            "kda_restarts": zero}
+            "kda_fused_scan": zero, "kda_restarts": zero}
 
 
 def kimi_linear_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
     """One packed row ``(2, T)`` through the model: ``nemotron_h_sequence_
     stats``'s sums without the state-space layer's, and this stack's own,
     summed over its KDA layers: ``kda_positions`` (positions the recurrence
-    ran over), ``kda_restarts`` (documents whose state started at zero),
+    ran over), ``kda_fused_scan`` (the positions whose recurrences ran in the
+    tiled kernels, ``fused_scan_applies``: the mean over the KDA layers, so
+    the sequence's positions or 0), ``kda_restarts`` (documents whose state
+    started at zero),
     ``kda_log_decay_min`` (the most negative cumulative log-decay of any
     chunk, head and channel of the sequence: at most 0, and under -88 where
     ``exp(-G)`` would have overflowed float32) and ``sequences`` (1)."""
@@ -452,6 +511,8 @@ def kimi_linear_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
             block, kind, cfg, compute_dtype, segs=segs))(h, layer)
         deepest = jnp.minimum(deepest, own.pop("kda_log_decay_min", 0.0))
         stats = {**stats, **{k: stats[k] + v for k, v in own.items()}}
+    stats["kda_fused_scan"] = stats["kda_fused_scan"] / max(
+        sum(mixer == "kda" for mixer, _ in kinds), 1)
     with jax.named_scope(LM_HEAD_LOSS):
         labels, valid = next_token_targets(tokens, segs)
         loss, correct = _head_loss(
@@ -476,8 +537,8 @@ def kimi_linear_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
         return {**stats, **{k: stats[k] * m for k in (
             "padding", "fused_attention", "grouped_experts",
             "attention_blocks_computed", "attention_blocks_causal",
-            "rows_computed", "kda_positions", "kda_log_decay_min",
-            "sequences")}}
+            "rows_computed", "kda_positions", "kda_fused_scan",
+            "kda_log_decay_min", "sequences")}}
 
     if x.shape[0] == 1:
         return one((x[0], mask[0]))

@@ -131,7 +131,8 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
              "hc_mix_positions": "hc_mix_positions",
              "mtp_positions": "mtp_count",
              "kda_positions": "kda_positions",
-             "kda_document_restarts": "kda_restarts"}
+             "kda_document_restarts": "kda_restarts",
+             "kda_fused_scan_positions": "kda_fused_scan"}
 
     def counters(s):
         load = s["expert_load"].astype(jnp.float32)

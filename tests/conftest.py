@@ -76,6 +76,8 @@ QUICK_TESTS = {
     "test_xing4.py::test_the_modules_targets_and_validity_at_document_edges",
     # the delta-rule stack: what its layer lists and its scan refuse
     "test_kimi_linear.py::test_what_the_registry_refuses",
+    # the recurrence's kernels: the one operand their masks come from
+    "test_kda_scan_kernels.py::test_how_far_back_a_positions_run_reaches",
     "test_stateless_round.py::"
     "test_minibatches_need_the_stateless_engine_and_a_known_client_state",
     # the stage of each operation from a compiled program's text (pure text)

@@ -613,6 +613,12 @@ def test_the_four_stream_round_at_published_widths_fits_one_v5e_chip(xing4_round
         assert f"/{scope}/" in text or f"({scope})" in text, scope
 
 
+KDA_LAYERS = 4              # of the preset's five, K K K F K
+# The delta-rule round's account with the recurrence in its kernels (the
+# compiler's own peak, this file's compile for a described v5e).
+KIMI_ROUND_ACCOUNT = 9_388_397_056
+
+
 @pytest.fixture(scope="module")
 def kimi_linear_round(topo):
     """The round of the delta-rule preset (``kimi_linear``: the first five
@@ -646,8 +652,13 @@ def test_the_delta_rule_round_at_published_widths_fits_one_v5e_chip(
     assert account["peak"] > 0 and account["total"] == account["peak"]
     assert 4.3e9 < 7.23e9 <= account["total"] <= memory[
         "round_account_bound_bytes"] == 15.0e9, account
-    assert abs(account["total"] - memory["round_account_bytes"]) <= (
-        1e-5 * memory["round_account_bytes"]), account
+    # the file is the benchmark's and states PR 39's account, the recurrence
+    # in its XLA form; in the kernels of PR 40 its scores, triangular
+    # inverses and chunk products are no arrays, and the compile reads
+    # 2.06 GB less
+    assert memory["round_account_bytes"] == 11_445_461_504
+    assert abs(account["total"] - KIMI_ROUND_ACCOUNT) <= (
+        1e-5 * KIMI_ROUND_ACCOUNT), account
     assert account["aliased"] >= 4.8e9
     text = kimi_linear_round.as_text()
     assert _attention_kernels(kimi_linear_round) == _attention_calls(
@@ -661,3 +672,32 @@ def test_the_delta_rule_round_at_published_widths_fits_one_v5e_chip(
                   "experts", "lm_head_loss", "embed", "sgd_pass",
                   "server_update"):
         assert f"/{scope}/" in text or f"({scope})" in text, scope
+
+
+def test_the_delta_rule_round_runs_its_recurrences_in_the_tiled_kernels(
+        kimi_linear_round):
+    """PR 40, the rule told the backend is a TPU: a KDA layer's recurrence
+    is one Mosaic call forward, the same once more in the layer's
+    recomputation (there it writes the chunks' entering states too), and one
+    backward; each call stands under ``kda/kda_scan`` (what ``kl_kda_scan_ms``
+    reads: the layer ``kda_scan``, no piece) and its ``op_name`` tells the
+    direction as ``analysis.program`` reads it."""
+    from fedtpu.analysis.program import (BACKWARD, FORWARD, RECOMPUTE,
+                                         _pass_of, _stage_of)
+    from fedtpu.parallel.round import LAYERS, PIECES
+
+    calls = _named_kernels(kimi_linear_round, "kda_scan")
+    assert sorted(name for name, _ in calls) == (
+        ["kda_scan_backward"] * KDA_LAYERS + ["kda_scan_forward"] * 2 * KDA_LAYERS)
+    directions = {FORWARD: 0, RECOMPUTE: 0, BACKWARD: 0}
+    for name, before in calls:
+        op_name = f"{before}/{name}/pallas_call"
+        assert "kda/kda_scan/" in op_name or "kda)/kda_scan/" in op_name, op_name
+        assert _stage_of(op_name, LAYERS) == "kda_scan"
+        assert _stage_of(op_name, PIECES) is None
+        direction = _pass_of(op_name, (), ())
+        assert (direction == BACKWARD) == (name == "kda_scan_backward"), op_name
+        directions[direction] += 1
+    assert directions == dict.fromkeys((FORWARD, RECOMPUTE, BACKWARD), KDA_LAYERS)
+    # no (chunks, heads, C, C) plane of scores is an array of the program
+    assert "f32[64,32,64,64]" not in kimi_linear_round.as_text()

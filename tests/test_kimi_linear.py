@@ -493,6 +493,41 @@ def test_the_scopes_of_a_tiny_round_name_this_stacks_layers_and_pieces():
     assert {"forward", "recompute", "backward"} <= passes
 
 
+def test_the_recurrences_kernels_keep_the_scans_scope_in_a_tiny_round(
+        monkeypatch):
+    """The rule between the bodies told yes and the kernels interpreted
+    (``jax.checkpoint`` a pass-through: the interpreter's callbacks cannot
+    stand under it), a tiny round LOWERED names ``kda_scan_forward`` and
+    ``kda_scan_backward`` on its operations' name stacks, each under
+    ``kda/kda_scan`` and so the layer ``kda_scan`` (what ``kl_kda_scan_ms``
+    reads), the second in the backward pass. (The compiled round at
+    published widths holds the same of the Mosaic calls themselves:
+    ``tests/test_aot_tpu_compile.py``.)"""
+    import re
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    from fedtpu.analysis.program import BACKWARD, _pass_of, _stage_of
+    from fedtpu.parallel.round import LAYERS
+
+    monkeypatch.setattr(kl, "fused_scan_applies", lambda *shapes: True)
+    monkeypatch.setattr(jax, "checkpoint", lambda fn, **policy: fn)
+    with pltpu.force_tpu_interpret_mode():
+        exp = build_experiment(tiny_kimi_linear())
+        text = exp.make_step(1).lower(exp.state, exp.batch).as_text(
+            debug_info=True)
+    names = set(re.findall(
+        r'"([^"]*/kda_scan_(?:forward|backward)/[^"]*)"', text))
+    kernels = {re.search(r"kda_scan_(?:forward|backward)", n).group(0)
+               for n in names}
+    assert kernels == {"kda_scan_forward", "kda_scan_backward"}
+    for name in names:
+        assert re.search(r"kda\)*/kda_scan/kda_scan_(forward|backward)/", name)
+        assert _stage_of(name, LAYERS) == "kda_scan", name
+        assert (_pass_of(name, (), ()) == BACKWARD) == (
+            "kda_scan_backward" in name), name
+
+
 def test_what_the_registry_refuses():
     with pytest.raises(ValueError, match="do not name each"):
         build_model(dataclasses.replace(TINY, kda_layers=(1, 2, 3)))
