@@ -25,6 +25,18 @@ file under ``assumed``.
   differentiates through the loop. The streams lie ``(n, T, C)``, a stream a
   plane, and the maps ``(n, T)`` / ``(n, n, T)``, positions on the lanes: a
   ``(T, 4, 4)`` array would fill a thirty-second of its tiles.
+  **Which body runs where.** The three functions are the definition: XLA's
+  passes, the body on a CPU and at shapes without tiles, and the oracle of
+  the kernels' tests. Where ``hyper_passes_apply`` (a TPU, float32 streams,
+  ``C`` whole lane tiles, ``T`` whole row tiles, a tile within the chip's own
+  memory at this ``n``: the published widths at any row of whole tiles) the
+  same algebra at the same precision runs as Mosaic kernels under
+  differentiation rules of their own, ``fedtpu.ops.hyper_conn``: ``mix_read``
+  (the norm, the logits' product and the read from ONE visit of the streams)
+  and ``write``, and their two transposes, the second of which writes the
+  streams' whole cotangent once; the scale and bias, ``H_post``, the clip
+  and the Sinkhorn turns stay in XLA (``_hyper_maps``, both bodies' own).
+  ``hc_fused`` among the statistics says which ran.
 * **Latent attention** (``transformers``' ``DeepseekV3Attention``): the
   query through a bottleneck of ``q_lora_rank`` behind an RMSNorm, keys and
   values through one of ``kv_lora_rank`` behind another; a head's query and
@@ -79,6 +91,7 @@ from fedtpu.models.olmoe import (ATTENTION, ATTN_LATENT, DENSE_MLP, EMBED,
                                  LM_HEAD_LOSS, MTP, MTP_PROJ, _head_loss,
                                  _rope, attention_core, next_token_targets,
                                  rms_norm, segment_positions)
+from fedtpu.ops import hyper_conn as hyper_passes
 
 KINDS = ("dense", "experts")
 # The start of a residual module (assumed: the published config has no key
@@ -253,6 +266,20 @@ def sinkhorn(logits, cfg):
                              cfg.mhc_h_res_clamp_max)))
 
 
+def _hyper_maps(z, module, cfg):
+    """``(H_pre, H_post, H_res)`` from the normed raw logits ``z (n (n + 2),
+    T)``: the scale and the bias, then the two sigmoids and the Sinkhorn
+    turns (under ``hyper_conn`` and ``hc_sinkhorn``: the caller's scope)."""
+    n = cfg.hc_mult
+    scale = jnp.repeat(module["alpha"], np.array([n, n, n * n]),
+                       total_repeat_length=n * (n + 2))
+    logits = z * scale[:, None] + module["bias"][:, None]
+    pre = jax.nn.sigmoid(logits[:n])
+    post = 2.0 * jax.nn.sigmoid(logits[n:2 * n])
+    res = sinkhorn(logits[2 * n:].reshape(n, n, -1), cfg)
+    return pre, post, res
+
+
 def hyper_mix(x, module, cfg):
     """The three maps of one residual module from the streams ``x (n, T,
     C)`` float32: ``(H_pre (n, T), H_post (n, T), H_res (n, n, T))``, where
@@ -266,13 +293,7 @@ def hyper_mix(x, module, cfg):
             phi[:, i], x[i], (((1,), (1,)), ((), ())),
             precision=lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32) for i in range(n))
-        scale = jnp.repeat(module["alpha"], np.array([n, n, n * n]),
-                           total_repeat_length=n * (n + 2))
-        logits = (logits * inv * scale[:, None]) + module["bias"][:, None]
-        pre = jax.nn.sigmoid(logits[:n])
-        post = 2.0 * jax.nn.sigmoid(logits[n:2 * n])
-        res = sinkhorn(logits[2 * n:].reshape(n, n, -1), cfg)
-    return pre, post, res
+        return _hyper_maps(logits * inv, module, cfg)
 
 
 def hyper_read(x, pre):
@@ -299,14 +320,43 @@ def sinkhorn_residual(res):
                        jnp.abs(res.sum(axis=0) - 1.0).max())
 
 
+def hyper_passes_apply(x) -> bool:
+    """Whether the tiled bodies of a residual module's passes over the
+    streams (``fedtpu.ops.hyper_conn``: ``mix_read`` and ``write``, a
+    differentiation rule each) exist for the streams ``x (n, T, C)`` where
+    the program is being built: a TPU (the PROCESS's backend, as
+    ``olmoe.fused_attention_applies`` reads it), float32 streams, ``C`` whole
+    lane tiles, ``T`` whole row tiles, the tile within the chip's own memory
+    at this ``n``. ``hyper_mix``, ``hyper_read`` and ``hyper_write`` are the
+    definitions and the body everywhere else."""
+    return (jax.default_backend() == "tpu" and x.dtype == jnp.float32
+            and hyper_passes.tiles_apply(*x.shape))
+
+
 def sublayer(cfg, x, module, fn):
     """One sublayer ``fn(u) -> (y, statistics)`` on the streams: ``(streams,
-    statistics, the module's Sinkhorn residual)``."""
-    pre, post, res = hyper_mix(x, module, cfg)
-    y, stats = fn(hyper_read(x, pre))
+    statistics, the module's Sinkhorn residual)``. Where
+    ``hyper_passes_apply`` the streams are passed over by the kernels, named
+    for their direction so that their ``op_name`` keeps it."""
+    fused = hyper_passes_apply(x)
+    if fused:
+        n = x.shape[0]
+        with jax.named_scope(HYPER_CONN):
+            u, z, x = hyper_passes.mix_read(
+                x, module["phi"], jnp.broadcast_to(module["alpha"][0], (n,)),
+                module["bias"][:n], cfg.rms_norm_eps)
+            _, post, res = _hyper_maps(z, module, cfg)
+    else:
+        pre, post, res = hyper_mix(x, module, cfg)
+        u = hyper_read(x, pre)
+    y, stats = fn(u)
     with jax.named_scope(HYPER_CONN):
         off = lax.stop_gradient(sinkhorn_residual(res))
-    return hyper_write(x, y, post, res), stats, off
+        if fused:
+            x = hyper_passes.write(x, y, post, res)
+    if not fused:
+        x = hyper_write(x, y, post, res)
+    return x, stats, off
 
 
 # ------------------------------------------------------- latent attention
@@ -413,7 +463,9 @@ def xing4_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
     and ``count`` are the MAIN loss's), and this stack's own: ``mtp_loss_sum``
     and ``mtp_count`` (the prediction module's summed loss and valid targets;
     absent where the model has no module), ``hc_mix_positions`` (positions
-    times residual modules mixed), ``hc_sinkhorn_residual`` (the largest
+    times residual modules mixed), ``hc_fused`` (the positions where the
+    modules' passes ran in the tiled kernels, ``hyper_passes_apply``; 0
+    where the definitions ran), ``hc_sinkhorn_residual`` (the largest
     ``|rowsum - 1|``, ``|colsum - 1|`` of any ``H_res`` of the sequence),
     ``attention_padded_width`` (positions times the head width the tiled
     core ran at; 0 where the XLA body ran) and ``sequences`` (1)."""
@@ -498,6 +550,9 @@ def xing4_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
             "grouped_experts": jnp.float32(t if grouped else 0),
             "attention_padded_width": jnp.float32(t * wide if fused else 0),
             "hc_mix_positions": jnp.float32(t * modules),
+            "hc_fused": jnp.float32(t if hyper_passes_apply(
+                jax.ShapeDtypeStruct((n, t, cfg.hidden_size), jnp.float32))
+                else 0),
             "sequences": jnp.float32(1.0),
             **olmoe.attention_blocks(segs, fused,
                                      len(kinds) + len(params["mtp"])),
@@ -514,8 +569,8 @@ def xing4_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
         return {**stats, **{k: stats[k] * m for k in (
             "padding", "fused_attention", "grouped_experts",
             "attention_blocks_computed", "attention_blocks_causal",
-            "attention_padded_width", "hc_mix_positions", "rows_computed",
-            "hc_sinkhorn_residual", "sequences")}}
+            "attention_padded_width", "hc_mix_positions", "hc_fused",
+            "rows_computed", "hc_sinkhorn_residual", "sequences")}}
 
     if x.shape[0] == 1:
         return one((x[0], mask[0]))

@@ -129,6 +129,7 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
              "ssm_document_restarts": "ssm_restarts",
              "ssm_fused_pass_positions": "ssm_fused_passes",
              "hc_mix_positions": "hc_mix_positions",
+             "hc_fused_positions": "hc_fused",
              "mtp_positions": "mtp_count",
              "kda_positions": "kda_positions",
              "kda_document_restarts": "kda_restarts",

@@ -78,6 +78,8 @@ QUICK_TESTS = {
     "test_kimi_linear.py::test_what_the_registry_refuses",
     # the recurrence's kernels: the one operand their masks come from
     "test_kda_scan_kernels.py::test_how_far_back_a_positions_run_reaches",
+    # the residual modules' kernels: the rule between the two bodies
+    "test_hyper_conn_kernels.py::test_the_rule_between_the_bodies",
     "test_stateless_round.py::"
     "test_minibatches_need_the_stateless_engine_and_a_known_client_state",
     # the stage of each operation from a compiled program's text (pure text)
@@ -446,4 +448,21 @@ def tiled_passes_interpreted(patch):
 def tiled_passes_on_the_cpu(monkeypatch):
     """``tiled_passes_interpreted`` for one test."""
     with tiled_passes_interpreted(monkeypatch):
+        yield
+
+
+@pytest.fixture
+def hyper_passes_on_the_cpu(monkeypatch):
+    """The four-stream stack's residual modules through the tiled bodies of
+    their passes over the streams (``fedtpu.ops.hyper_conn``) on the CPU, as
+    ``tiled_passes_interpreted`` drives the hybrid stack's: the rule between
+    the bodies (``xing4.hyper_passes_apply``) steered to them, the kernels
+    interpreted (always under jit), no layer recomputed."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from fedtpu.models import xing4
+
+    monkeypatch.setattr(xing4, "hyper_passes_apply", lambda x: True)
+    monkeypatch.setattr(jax, "checkpoint", lambda fn, **policy: fn)
+    with pltpu.force_tpu_interpret_mode():
         yield

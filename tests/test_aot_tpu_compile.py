@@ -570,6 +570,9 @@ def xing4_round(topo):
 
 X4_BLOCKS = 6               # five layers and the prediction module's block
 X4_STEP_KINDS = 1           # every step from the working copy: one trace
+# The four-stream round's account with the residual modules' passes in their
+# kernels (the compiler's own peak, this file's compile for a described v5e).
+XING4_ROUND_ACCOUNT = 14_917_739_520
 
 
 def test_the_four_stream_round_at_published_widths_fits_one_v5e_chip(xing4_round):
@@ -577,10 +580,12 @@ def test_the_four_stream_round_at_published_widths_fits_one_v5e_chip(xing4_round
     sum of the parts reads above the chip's memory,
     ``train_xing4.program_account``) lies between the 10.96 GB the engine's
     12 bytes a parameter come to and the bound the configuration file states,
-    and is what the file's ``memory`` states to a thousandth of a percent
-    (the file is the benchmark's and states PR 37's 15,146,139,136; with the
-    attention core's tables of PR 38 the compile reads 9,216 bytes more; the
-    chip's compiler allows 15.75 GiB, 16.9 GB); global and momentum in place. Every block's
+    and is this file's ``XING4_ROUND_ACCOUNT`` to a thousandth of a percent
+    (the configuration file is the benchmark's and states PR 37's
+    15,146,139,136; with the residual modules' passes in the kernels of PR 41
+    the streams' layout copies and the cotangents' sums are no arrays and
+    the compile reads 228 MB less; the chip's compiler allows 15.75 GiB,
+    16.9 GB); global and momentum in place. Every block's
     attention ran the tiled core at the padded head, a kind of step: the
     forward kernel, once more in the block's recomputation, and the two
     backward; the held experts at widths without tiles ran the compiler's
@@ -596,9 +601,11 @@ def test_the_four_stream_round_at_published_widths_fits_one_v5e_chip(xing4_round
         memory = json.load(fh)["memory"]
     account = program_account(xing4_round.memory_analysis())
     assert account["peak"] > 0 and account["total"] == account["peak"]
-    assert 10.96e9 <= account["total"] <= memory["round_account_bound_bytes"], account
-    assert abs(account["total"] - memory["round_account_bytes"]) <= (
-        1e-5 * memory["round_account_bytes"]), account
+    assert 10.96e9 <= account["total"] <= memory[
+        "round_account_bound_bytes"] == 15.7e9, account
+    assert memory["round_account_bytes"] == 15_146_139_136
+    assert abs(account["total"] - XING4_ROUND_ACCOUNT) <= (
+        1e-5 * XING4_ROUND_ACCOUNT), account
     assert account["aliased"] >= 7.3e9
     text = xing4_round.as_text()
     each = X4_BLOCKS * X4_STEP_KINDS
@@ -611,6 +618,43 @@ def test_the_four_stream_round_at_published_widths_fits_one_v5e_chip(xing4_round
                   "expert_dispatch", "experts", "mtp", "mtp_proj",
                   "lm_head_loss", "embed", "sgd_pass", "server_update"):
         assert f"/{scope}/" in text or f"({scope})" in text, scope
+
+
+def test_the_four_stream_round_runs_its_residual_modules_in_the_tiled_kernels(
+        xing4_round):
+    """PR 41, the rule told the backend is a TPU: a residual module's passes
+    over the streams are Mosaic calls of ``fedtpu.ops.hyper_conn``, two a
+    direction: ``mix_read`` and ``write`` forward, the same once more in the
+    block's recomputation (but for the write of a block's SECOND module,
+    whose result no backward pass reads: the compiler drops it) and their two
+    transposes; each call stands under ``hyper_conn`` and in no piece (what
+    ``x4_hyper_conn_ms`` reads) and its ``op_name`` tells the direction as
+    ``analysis.program`` reads it; no array of the streams' shape is copied
+    or transposed around them."""
+    from fedtpu.analysis.program import (BACKWARD, FORWARD, RECOMPUTE,
+                                         _pass_of, _stage_of)
+    from fedtpu.parallel.round import LAYERS, PIECES
+
+    text = xing4_round.as_text()
+    calls = re.findall(
+        r'custom_call_target="tpu_custom_call".*op_name="([^"]*hyper_conn\)*/'
+        r'hyper_conn_(\w+)/pallas_call)"', text)
+    modules = 2 * X4_BLOCKS * X4_STEP_KINDS
+    found = collections.Counter()
+    for op_name, kernel in calls:
+        assert _stage_of(op_name, LAYERS) == "hyper_conn", op_name
+        assert _stage_of(op_name, PIECES) is None, op_name
+        direction = _pass_of(op_name, (), ())
+        assert (direction == BACKWARD) == kernel.endswith("_backward"), op_name
+        found[kernel, direction] += 1
+    assert found == {
+        ("mix_read_forward", FORWARD): modules,
+        ("write_forward", FORWARD): modules,
+        ("mix_read_forward", RECOMPUTE): modules,
+        ("write_forward", RECOMPUTE): modules // 2,
+        ("mix_read_backward", BACKWARD): modules,
+        ("write_backward", BACKWARD): modules}, found      # 66 in all
+    assert not re.search(r"= f32\[4,4096,3584\]\S* (?:copy|transpose)\(", text)
 
 
 KDA_LAYERS = 4              # of the preset's five, K K K F K

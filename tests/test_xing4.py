@@ -159,6 +159,7 @@ def test_two_rounds_through_run_experiment_match_the_references_fedavgm(
         10 if one_step_kind else 10 - 4)
     assert counted["hc_mix_positions"] == 2 * 10 * 48 * 2 * (3 + modules)
     assert counted["lm_fused_attention_positions"] == 0     # a CPU
+    assert counted["hc_fused_positions"] == 0       # the definitions ran
     assert gauges["attention_padded_width"] == 0
     assert 0 < gauges["hc_sinkhorn_residual"] < 0.05
     if modules:
@@ -621,6 +622,49 @@ def test_the_scopes_of_a_tiny_round_name_this_stacks_layers_and_its_module():
     assert 0 < len(modules) < len(layers)
     # a program that is asked for no module gives no such map
     assert "modules" not in program_scopes(text, STAGES, layers=LAYERS)
+
+
+def test_the_residual_kernels_keep_their_scopes_in_a_tiny_round(
+        tmp_path, hyper_passes_on_the_cpu):
+    """The rule between the bodies told yes and the kernels interpreted
+    (``jax.checkpoint`` a pass-through), a tiny round LOWERED names the four
+    kernels on its operations' name stacks, each under ``hyper_conn`` (what
+    ``x4_hyper_conn_ms`` reads) and in no piece, the two transposes in the
+    backward pass, with the Sinkhorn turns still under ``hc_sinkhorn``; and
+    the round RUN counts every position as fused (a block on the kernels
+    against the plain one: ``tests/test_hyper_conn_kernels.py``). (The compiled round at published widths holds the same
+    of the Mosaic calls themselves: ``tests/test_aot_tpu_compile.py``.)"""
+    import re
+
+    from fedtpu.analysis.program import BACKWARD, _pass_of, _stage_of
+    from fedtpu.parallel.round import LAYERS, PIECES
+
+    exp = build_experiment(tiny_xing4(rounds=1))
+    text = exp.make_step(1).lower(exp.state, exp.batch).as_text(
+        debug_info=True)
+    names = set(re.findall(r'"([^"]*/hyper_conn_\w+/[^"]*)"', text))
+    kernels = {re.search(r"hyper_conn_(\w+)", n).group(1) for n in names}
+    assert kernels == {"mix_read_forward", "mix_read_backward",
+                       "write_forward", "write_backward"}
+    for name in names:
+        assert _stage_of(name, LAYERS) == "hyper_conn", name
+        assert _stage_of(name, PIECES) is None, name
+        assert (_pass_of(name, (), ()) == BACKWARD) == (
+            "_backward/" in name), name
+    assert re.search(r'hyper_conn/hc_sinkhorn/', text)
+    sink = str(tmp_path / "ev.jsonl")
+    cfg = tiny_xing4(rounds=1, modules=0,
+                     telemetry=TelemetryConfig(events_path=sink))
+    cfg = cfg.replace(      # two blocks, a row a client: four modules a step
+        model=dataclasses.replace(cfg.model, num_hidden_layers=2),
+        data=dataclasses.replace(cfg.data, synthetic_rows=4))
+    result = run_experiment(cfg, verbose=False)
+    events = [json.loads(line) for line in open(sink)]
+    counted = [e for e in events
+               if e["kind"] == "counters"][-1]["payload"]["counters"]
+    assert counted["hc_fused_positions"] == 4 * 48
+    assert counted["hc_mix_positions"] == 4 * 48 * 2 * 2
+    assert np.all(np.isfinite(np.stack(result.loss)))
 
 
 def test_what_the_registry_refuses():
