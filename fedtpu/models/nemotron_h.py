@@ -41,9 +41,9 @@ What is this repo's own:
   ``lax.scan`` over blocks of one period of the pattern was built and
   measured, and lost: the compiler casts the whole stacked weights before
   the scan and the stacked gradient lives whole. PERF.md section 6, PR 32.)
-* **Packed rows**, as ``fedtpu.models.olmoe``: a row is ``(2, T)`` token and
-  segment ids, 0 marking padding. Attention stays within a document; the
-  state-space layer's state is zero at a document's first token and its
+* **Packed rows**: a row is ``(2, T)`` token and segment ids, 0 marking
+  padding. Attention stays within a document; the state-space layer's state
+  is zero at a document's first token and its
   convolution does not read across the edge; the loss leaves out padding
   and each document's last token. Two documents packed into one row give
   what the two alone give.
@@ -56,58 +56,22 @@ What is this repo's own:
   (autodiff through the above). ``dt``, ``A``, the decays, their cumulative
   sums, the state carry and the norm are float32; the chunk products take
   ``compute_dtype`` inputs and sum in float32.
-* **The mixer's two float32 passes, two bodies each.** Between ``W_in``
-  and the scan the convolution and its SiLU pass over ``xBC``, between the
-  scan and ``W_out`` the ``D`` skip, the gate and the grouped norm over
-  ``y``, ``x`` and ``z``: elementwise but for a window of four rows and a
-  mean over a group's lanes. ``causal_conv`` and ``gated_group_norm`` are
-  the definitions, the CPU's path and tier-1's, and autodiff's to
-  differentiate. Where ``fused_passes_apply`` says the tiled bodies exist
-  (a TPU, whole row tiles, widths and a group of whole lane tiles: shape and
-  platform only) each pass is ONE row-tiled kernel forward and one backward
-  under a differentiation rule of its own (``fedtpu.ops.ssm_passes``): the
-  operands are read once, in place out of ``W_in``'s product and the
-  convolution's output, every result is written once, the gate's in
-  ``compute_dtype`` (the next operation cast it), the backward kernels
-  recompute what they need in the tile and add up the weights' gradients
-  across the row tiles. And they meet the scan in the form XLA keeps the
-  scan's arrays in, a chunk's positions on the lanes: ``x`` leaves the
-  convolution once more with the positions last, ``y`` enters the gate as
-  the scan leaves it, the cotangents likewise, so no transposing copy
-  stands between a kernel and the scan. Float32 as the definitions; only
-  the order of the sums over rows differs. ``ssm_fused_passes`` counts the
-  positions that ran them.
-* **An expert layer that holds a share.** The layer is told ``experts_held``
-  and ``first_expert``: it scores and selects over ALL routed experts and
-  computes, droplessly, exactly the assignments of real tokens that fall on
-  the experts it holds, and the shared expert for every token; what the
-  absent experts would have added is left out. On one chip there is no
-  exchange. The assignments are sorted held-first (``olmoe``'s
-  ``sorted_assignments``) and the first ``rows`` of them go through
-  ``olmoe.grouped_matmul``. A buffer has a static size, the worst case is
-  every assignment and the mean is ``held / routed`` of them: so the buffer
-  is one BLOCK of rows at 8/3 of the mean (``held_block_rows``) and a
-  loop runs as many blocks as this step's held assignments fill, its trips
-  read from the groups' sizes: one on nearly every step, all of them if
-  every token chose only experts held here. Exact whatever the skew, and
-  the worst case costs only when it happens. What the room costs is the
-  body's to say: the tiled grouped kernels (a TPU at the published widths,
-  ``olmoe.grouped_matmul_applies``) visit no tile past the last group, so
-  the two products take time by the filled rows and the empty ones cost
-  the dispatch's gathers and scatter-adds alone; the TPU's
-  ``lax.ragged_dot`` takes time by the buffer's rows, filled or not (7 ms
-  a layer and step for 4,096 rows more at those widths, PERF.md section 6,
-  PR 32). The statistics count ``rows_computed`` against
-  ``assignments_held``. A loop of that kind has no
-  transpose, so the function has its own differentiation rule
-  (``held_experts``): the backward pass runs the same trips and
-  differentiates each block inside its trip, adding up the weights'
-  gradients (one pass over them a block: the price of the static buffer).
+* **The mixer's two float32 passes** (the convolution under its SiLU; the
+  ``D`` skip, the gate and the grouped norm) have two bodies each and the
+  rule between them in ``fedtpu.ops.ssm_passes``. Where the tiled bodies run
+  they meet the scan in XLA's own form of the scan's arrays (``x`` once more
+  with the positions last, ``y`` as the scan leaves it).
+  ``ssm_fused_passes`` counts the positions that ran them.
+* **An expert layer that holds a share**: ``fedtpu.models.layers.
+  experts_mixer`` (the held-first sort, the blocks of the static buffer and
+  the differentiation rule are described there), here with ``relu^2``
+  experts.
 
 Parameters are float32; ``compute_dtype`` is the dtype of every large
-matmul's inputs, as in ``olmoe``. The head and its loss (``_head_loss``),
-the attention core with its two bodies, ``grouped_matmul`` with its two and
-the norms are ``olmoe``'s own functions.
+matmul's inputs. The head and its loss (``fedtpu.ops.lm_head``), the
+attention core with its two bodies (``fedtpu.ops.packed_attention``),
+``grouped_matmul`` with its two and the norm are shared with the other
+language models.
 """
 
 from __future__ import annotations
@@ -118,20 +82,22 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from fedtpu.models import olmoe
-from fedtpu.models.olmoe import (ATTENTION, EMBED, EXPERT_DISPATCH, EXPERTS,
-                                 INIT_STD, LM_HEAD_LOSS, RECOMPUTE, ROUTER,
-                                 SHARED_EXPERT, SSM, SSM_CONV, SSM_GATE_NORM,
-                                 SSM_IN_PROJ, SSM_OUT_PROJ, SSM_SCAN,
-                                 _head_loss, attention_core, gather_rows,
-                                 grouped_matmul, next_token_targets, rms_norm,
-                                 sorted_assignments)
+from fedtpu.models.layers import (INIT_STD, _experts_init, bodies_at,
+                                  experts_mixer, experts_share, held_matmuls,
+                                  rms_norm)
 from fedtpu.ops import ssm_passes
+from fedtpu.ops.lm_head import _head_loss, next_token_targets
+from fedtpu.ops.packed_attention import attention_blocks, attention_core
+from fedtpu.ops.scopes import (ATTENTION, EMBED, LM_HEAD_LOSS, SSM, SSM_CONV,
+                               SSM_GATE_NORM, SSM_IN_PROJ, SSM_OUT_PROJ,
+                               SSM_SCAN)
 
 KINDS = {"M": "mamba", "E": "experts", "*": "attention"}
-# The held assignments are computed in blocks of whole tiles of this many
-# rows (olmoe.GROUPED_ROW_TILE, what the tiled grouped kernels need).
-HELD_ROW_TILE = 256
+
+# what counts a row, not its tokens: a padded row's is left out (rows_stats)
+PER_ROW = ("padding", "fused_attention", "grouped_experts",
+           "attention_blocks_computed", "attention_blocks_causal",
+           "ssm_fused_passes", "ssm_positions", "rows_computed")
 
 _mm = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
 
@@ -152,27 +118,18 @@ def layer_kinds(cfg) -> tuple:
     return tuple(KINDS[letter] for letter in pattern)
 
 
-def experts_share(cfg) -> tuple:
-    """``(experts held, first expert)`` of this chip; 0 held = all."""
-    held = cfg.experts_held or cfg.n_routed_experts
-    if not 0 <= cfg.first_expert <= cfg.n_routed_experts - held:
+def check(cfg) -> None:
+    """What the pattern, the share and the widths must satisfy."""
+    layer_kinds(cfg)            # the pattern's letters and its length
+    experts_share(cfg)
+    if cfg.num_attention_heads % cfg.num_key_value_heads:
         raise ValueError(
-            f"experts [{cfg.first_expert}, {cfg.first_expert + held}) are "
-            f"not among the {cfg.n_routed_experts} the router scores")
-    return held, cfg.first_expert
-
-
-def held_block_rows(assignments: int, share: float) -> int:
-    """The rows of one block of the held-assignments buffer, for
-    ``assignments`` in all of which ``share`` are held on average: whole
-    tiles, 8/3 of the mean. A layer's share moves with the draw of the
-    router and the step's tokens (at the published widths a layer held 0.5
-    to 1.8 of the mean over a seed's steps, which of the four by the seed):
-    at a third over the mean most steps of some seeds took a second block
-    and none of others', and a round's time moved by 4% with the seed."""
-    tile = HELD_ROW_TILE
-    return min(-(-assignments // tile) * tile,
-               max(tile, -(-int(assignments * share * 8 / 3) // tile) * tile))
+            f"{cfg.num_attention_heads} query heads do not divide over "
+            f"{cfg.num_key_value_heads} key-value heads")
+    if cfg.mamba_num_heads % cfg.n_groups:
+        raise ValueError(
+            f"{cfg.mamba_num_heads} state-space heads do not divide "
+            f"into {cfg.n_groups} groups")
 
 
 # ------------------------------------------------------------------ init
@@ -211,21 +168,11 @@ def _attention_init(cfg, normal, ones, key, dtype):
             "v": normal(h, kv), "o": normal(q, h)}
 
 
-def _experts_init(cfg, normal, ones, key, dtype):
-    h, i, s = (cfg.hidden_size, cfg.moe_intermediate_size,
-               cfg.moe_shared_expert_intermediate_size)
-    held, _ = experts_share(cfg)
-    return {"norm": ones(h), "router": normal(h, cfg.n_routed_experts),
-            "router_bias": normal(cfg.n_routed_experts),
-            "up": normal(held, h, i), "down": normal(held, i, h),
-            "shared_up": normal(h, s), "shared_down": normal(s, h)}
-
-
 _INITS = {"mamba": _mamba_init, "attention": _attention_init,
           "experts": _experts_init}
 
 
-def nemotron_h_init(key: jax.Array, cfg, param_dtype=jnp.float32):
+def init(key: jax.Array, cfg, param_dtype=jnp.float32):
     """N(0, 0.02) weights (``initializer_range``) and selection biases, unit
     norm gains, the state-space leaves as ``_mamba_init`` says. Each kind's
     layers are a tuple under the kind's name, in the pattern's order."""
@@ -248,27 +195,6 @@ def nemotron_h_init(key: jax.Array, cfg, param_dtype=jnp.float32):
 
 
 # --------------------------------------------------------------- mamba-2
-def document_runs(segs):
-    """``(run (T,) int32, starts (T,) bool)``: the index of the run of equal
-    segment ids each position lies in (1, 2, ...), and where a run starts.
-    The state-space layer restarts at every start, padding's run included."""
-    starts = jnp.concatenate([jnp.ones((1,), bool), segs[1:] != segs[:-1]])
-    return jnp.cumsum(starts.astype(jnp.int32)), starts
-
-
-def causal_conv(x, w, b, run):
-    """Depthwise causal convolution of ``x (T, C)`` with ``w (K, C)``, ``w[j]``
-    weighing the position ``K - 1 - j`` back, over the positions of the same
-    run only: a document's first tokens see zeros before them."""
-    taps, t = w.shape[0], x.shape[0]
-    out = x * w[taps - 1] + b
-    for back in range(1, taps):
-        earlier = jnp.pad(x, ((back, 0), (0, 0)))[:t]
-        same = jnp.pad(run, (back, 0))[:t] == run       # run ids start at 1
-        out = out + jnp.where(same[:, None], earlier, 0.0) * w[taps - 1 - back]
-    return out
-
-
 def ssd_scan(x, dt, a, b, c, run, chunk: int, compute_dtype):
     """``y (T, heads, P)`` float32, ``y_t = S_t C_t`` of the recurrence
     ``S_t = exp(dt_t a) S_{t-1} [t-1 in t's run] + dt_t x_t (x) B_t``, in
@@ -330,31 +256,6 @@ def ssd_scan(x, dt, a, b, c, run, chunk: int, compute_dtype):
     return y.reshape(t, heads, p)
 
 
-def gated_group_norm(y, z, gain, groups: int, eps):
-    """``gain * RMSNorm(y * silu(z))``, the norm over each of ``groups``
-    equal parts of the last axis; float32."""
-    y = y * jax.nn.silu(z)
-    parts = y.reshape(y.shape[0], groups, -1)
-    parts = parts * lax.rsqrt(jnp.mean(parts * parts, axis=-1, keepdims=True)
-                              + eps)
-    return parts.reshape(y.shape) * gain
-
-
-def fused_passes_apply(cfg, t: int) -> bool:
-    """Whether the tiled bodies of the mixer's two float32 passes
-    (``fedtpu.ops.ssm_passes``: the convolution under its SiLU; the skip,
-    the gate and the grouped norm) exist for a sequence of ``t`` positions
-    where the program is being built: a TPU (the PROCESS's backend, as
-    ``olmoe.fused_attention_applies`` reads it), ``t`` whole row tiles, and
-    the inner width, ``xBC``'s width and a group of the norm whole lane
-    tiles. ``causal_conv`` and ``gated_group_norm`` are the definitions and
-    the body everywhere else."""
-    width = cfg.mamba_num_heads * cfg.mamba_head_dim
-    return jax.default_backend() == "tpu" and ssm_passes.tiles_apply(
-        t, min(cfg.chunk_size, t), cfg.conv_kernel, width,
-        width + 2 * cfg.n_groups * cfg.ssm_state_size, width, cfg.n_groups)
-
-
 def mamba_mixer(cfg, compute_dtype, h, layer, segs):
     """``(mixer(RMSNorm(h)), statistics)`` of one ``M`` layer."""
     t = h.shape[0]
@@ -362,8 +263,8 @@ def mamba_mixer(cfg, compute_dtype, h, layer, segs):
     groups, n = cfg.n_groups, cfg.ssm_state_size
     width, state = heads * p, groups * n
     cast = lambda arr: arr.astype(compute_dtype)
-    run, starts = document_runs(segs)
-    fused = fused_passes_apply(cfg, t)
+    run, starts = ssm_passes.document_runs(segs)
+    fused = ssm_passes.fused_passes_apply(cfg, t)
     with jax.named_scope(SSM):
         with jax.named_scope(SSM_IN_PROJ):
             x = cast(rms_norm(h, layer["norm"], cfg.layer_norm_epsilon))
@@ -379,8 +280,8 @@ def mamba_mixer(cfg, compute_dtype, h, layer, segs):
                 _, b, c = jnp.split(xbc, [width, width + state], axis=-1)
                 xs = xs.T
             else:
-                xbc = jax.nn.silu(causal_conv(xbc, layer["conv_w"],
-                                              layer["conv_b"], run))
+                xbc = jax.nn.silu(ssm_passes.causal_conv(
+                    xbc, layer["conv_w"], layer["conv_b"], run))
                 xs, b, c = jnp.split(xbc, [width, width + state], axis=-1)
             xs = xs.reshape(t, heads, p)
             dt = jax.nn.softplus(dt + layer["dt_bias"])
@@ -398,8 +299,8 @@ def mamba_mixer(cfg, compute_dtype, h, layer, segs):
                     cfg.layer_norm_epsilon, compute_dtype)
             else:
                 y = (y + layer["D"][:, None] * xs).reshape(t, width)
-                y = gated_group_norm(y, z, layer["gate_norm"], groups,
-                                     cfg.layer_norm_epsilon)
+                y = ssm_passes.gated_group_norm(
+                    y, z, layer["gate_norm"], groups, cfg.layer_norm_epsilon)
         with jax.named_scope(SSM_OUT_PROJ):
             out = _mm(cast(y), cast(layer["out_proj"]))
     real = segs > 0
@@ -427,170 +328,6 @@ def attention_mixer(cfg, compute_dtype, h, layer, segs):
     return out, {}
 
 
-# --------------------------------------------------------------- experts
-def route(x, router_w, bias, top_k: int, norm_topk_prob: bool, scale: float):
-    """``(gates (T, k) float32, experts (T, k) int32)``: sigmoid scores over
-    every expert in float32; the top k of ``score + bias`` are chosen and
-    weigh by their SCORE, renormalised and scaled. ``bias`` only picks."""
-    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    _, experts = lax.top_k(scores + lax.stop_gradient(
-        bias.astype(jnp.float32)), top_k)
-    gates = jnp.take_along_axis(scores, experts, axis=-1)
-    if norm_topk_prob:
-        gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-20)
-    return gates * scale, experts.astype(jnp.int32)
-
-
-def _activation(products):
-    """An expert's activation from its first matmuls' products: ``relu(up)^2``
-    of one (this tower's), ``silu(gate) * up`` of two (the gated form)."""
-    if len(products) == 1:
-        return jnp.square(jax.nn.relu(products[0]))
-    gate, up = products
-    return jax.nn.silu(gate) * up
-
-
-def _held_block(x, weights, gates, order, sizes, block, rows: int,
-                per_token: int, compute_dtype):
-    """What rows ``[block * rows, (block + 1) * rows)`` of the sorted
-    assignments add to the layer's output, ``(T, H)`` float32: the held
-    assignments among them, each its expert's output times its gate."""
-    cast = lambda arr: arr.astype(compute_dtype)
-    start = block * rows
-    with jax.named_scope(EXPERT_DISPATCH):
-        taken = lax.dynamic_slice_in_dim(order, start, rows)
-        # the groups' rows that fall inside this block
-        ends = jnp.cumsum(sizes)
-        inside = (jnp.clip(ends, start, start + rows)
-                  - jnp.clip(ends - sizes, start, start + rows))
-        # rows past the block's last group: ``lax.ragged_dot`` defines their
-        # output as zero, but the TPU's kernel (and the tiled one) visits no
-        # row past the last group and leaves there what memory held, forward
-        # and in both gradients. Each product's rows are cut to the filled
-        # ones by a select (which a stray infinity cannot pass, as a product
-        # with zero would), going in and coming out, so that the transposes
-        # cut them too.
-        filled = (jnp.arange(rows) < inside.sum())[:, None]
-        only_filled = lambda rows_: jnp.where(filled, rows_, 0)
-        xs = only_filled(gather_rows(cast(x), taken, per_token))
-        weigh = jnp.take(gates, taken)
-    with jax.named_scope(EXPERTS):
-        *into, down = weights
-        act = _activation([only_filled(grouped_matmul(xs, cast(w), inside))
-                           for w in into])
-        ys = only_filled(grouped_matmul(cast(act), cast(down), inside))
-    with jax.named_scope(EXPERT_DISPATCH):
-        return jnp.zeros(x.shape, jnp.float32).at[taken // per_token].add(
-            ys * weigh[:, None])
-
-
-def held_blocks(sizes, rows: int):
-    """How many blocks of ``rows`` the held assignments fill."""
-    return (sizes.sum() + rows - 1) // rows
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def held_experts(x, weights, gates, order, sizes, rows: int, per_token: int,
-                 compute_dtype):
-    """``sum over held assignments of gate * expert_e(x)``, ``(T, H)``
-    float32. ``x (T, H)`` float32; ``weights`` the held experts' matrices,
-    ``(up (held, H, I), down (held, I, H))`` of ``down relu(up x)^2`` or
-    ``(gate, up, down)`` of the gated ``down (silu(gate x) * up x)``;
-    ``gates (T * per_token,)`` every assignment's gate,
-    token-major; ``order`` (padded to whole blocks), ``sizes (held,)`` from
-    ``sorted_assignments`` with the held assignments first. A loop over as
-    many blocks of ``rows`` as hold them, its trips read from ``sizes``:
-    reverse mode only, under a rule of its own, because a loop of that kind
-    has no transpose."""
-    block = functools.partial(_held_block, x, weights, gates, order, sizes,
-                              rows=rows, per_token=per_token,
-                              compute_dtype=compute_dtype)
-    return lax.fori_loop(0, held_blocks(sizes, rows),
-                         lambda i, out: out + block(i),
-                         jnp.zeros(x.shape, jnp.float32))
-
-
-def _held_experts_fwd(x, weights, gates, order, sizes, rows, per_token,
-                      compute_dtype):
-    out = held_experts(x, weights, gates, order, sizes, rows, per_token,
-                       compute_dtype)
-    return out, (x, weights, gates, order, sizes)
-
-
-def _held_experts_bwd(rows, per_token, compute_dtype, residuals, g):
-    x, weights, gates, order, sizes = residuals
-
-    def step(i, grads):
-        # a block is differentiated inside its own trip: what it keeps for
-        # its backward pass lives and dies there
-        with jax.named_scope(RECOMPUTE):
-            _, pull = jax.vjp(
-                lambda *primals: _held_block(
-                    *primals, order, sizes, i, rows=rows, per_token=per_token,
-                    compute_dtype=compute_dtype), x, weights, gates)
-        return jax.tree.map(jnp.add, grads, pull(g))
-
-    grads = lax.fori_loop(0, held_blocks(sizes, rows), step,
-                          jax.tree.map(jnp.zeros_like, (x, weights, gates)))
-    return (*grads, None, None)
-
-
-held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
-
-
-def experts_mixer(cfg, compute_dtype, h, layer, segs, eps=None):
-    """``(mixer(RMSNorm(h)), statistics)`` of one ``E`` layer: this chip's
-    share of the routed sum, and the shared expert. A layer that has
-    ``gate`` and ``shared_gate`` beside ``up`` and ``down`` holds gated
-    experts (``fedtpu.models.xing4``'s), one without them this tower's;
-    ``eps`` is the pre-norm's where it is not this tower's."""
-    t = h.shape[0]
-    top_k, routed = cfg.num_experts_per_tok, cfg.n_routed_experts
-    held, first_expert = experts_share(cfg)
-    cast = lambda arr: arr.astype(compute_dtype)
-    gated = "gate" in layer
-    with jax.named_scope(ROUTER):
-        x = rms_norm(h, layer["norm"],
-                     cfg.layer_norm_epsilon if eps is None else eps)
-        gates, experts = route(x, layer["router"], layer["router_bias"], top_k,
-                               cfg.norm_topk_prob, cfg.routed_scaling_factor)
-    with jax.named_scope(EXPERT_DISPATCH):
-        # an assignment's group: the held expert's own index, or one past
-        # them for an expert that lives elsewhere and for padding, which is
-        # routed nowhere; sorted, the held ones come first
-        flat = experts.reshape(-1)
-        real = jnp.repeat(segs > 0, top_k)
-        local = flat - first_expert
-        here = real & (local >= 0) & (local < held)
-        order, sizes = sorted_assignments(jnp.where(here, local, held),
-                                          held + 1)
-        sizes = sizes[:held]
-        load = jnp.zeros((routed,), jnp.int32).at[flat].add(
-            real.astype(jnp.int32))
-        rows = held_block_rows(t * top_k, held / routed)
-        total, computed = sizes.sum(), held_blocks(sizes, rows) * rows
-        # counted, not derived: the held assignments the sort put inside
-        # the blocks that are computed (all of them, or something is broken)
-        covered = (jnp.take(here, order)
-                   & (jnp.arange(order.shape[0]) < computed)).sum()
-        order = jnp.pad(order, (0, -order.shape[0] % rows))
-    weights = ((layer["gate"], layer["up"], layer["down"]) if gated
-               else (layer["up"], layer["down"]))
-    out = held_experts(x, weights, gates.reshape(-1), order, sizes, rows,
-                       top_k, compute_dtype)
-    with jax.named_scope(SHARED_EXPERT):
-        xc = cast(x)
-        into = ("shared_gate", "shared_up") if gated else ("shared_up",)
-        act = _activation([_mm(xc, cast(layer[name])) for name in into])
-        out = out + _mm(cast(act), cast(layer["shared_down"]))
-    return out, {"expert_load": load,
-                 "assignments_held": total.astype(jnp.float32),
-                 "rows_computed": computed.astype(jnp.float32),
-                 "rows_held_computed": covered.astype(jnp.float32)}
-
-
 _MIXERS = {"mamba": mamba_mixer, "attention": attention_mixer,
            "experts": experts_mixer}
 
@@ -604,8 +341,8 @@ def _zero_stats(cfg):
             "rows_held_computed": zero}
 
 
-def nemotron_h_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
-    """One packed row ``(2, T)`` through the model: ``olmoe_sequence_stats``'s
+def sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
+    """One packed row ``(2, T)`` through the model: every language model's
     sums over tokens (``loss_sum``, ``correct``, ``count``, ``tokens``,
     ``padding``, ``expert_load`` over ALL routed experts and summed over
     layers, ``fused_attention``, ``grouped_experts``), ``ssm_fused_passes``
@@ -619,20 +356,11 @@ def nemotron_h_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
     tokens, segs = row[0], row[1]
     kinds = layer_kinds(cfg)
     t = tokens.shape[0]
-    core = jax.ShapeDtypeStruct((t, cfg.num_attention_heads, cfg.head_dim),
-                                compute_dtype)
-    # the rules between the bodies, read as olmoe's own callers read them
-    fused = ("attention" in kinds
-             and olmoe.fused_attention_applies(core, core, core))
-    held, _ = experts_share(cfg)
-    rows = held_block_rows(t * cfg.num_experts_per_tok,
-                           held / cfg.n_routed_experts)
-    wide, narrow = cfg.hidden_size, cfg.moe_intermediate_size
-    grouped = "experts" in kinds and all(olmoe.grouped_matmul_applies(
-        jax.ShapeDtypeStruct((rows, k), compute_dtype),
-        jax.ShapeDtypeStruct((held, k, n), compute_dtype))
-        for k, n in ((wide, narrow), (narrow, wide)))
-    tiled = "mamba" in kinds and fused_passes_apply(cfg, t)
+    _, fused, grouped = bodies_at(
+        t, cfg.num_attention_heads, cfg.head_dim, cfg.head_dim, compute_dtype,
+        experts=held_matmuls(cfg, t) if "experts" in kinds else None)
+    fused = "attention" in kinds and fused
+    tiled = "mamba" in kinds and ssm_passes.fused_passes_apply(cfg, t)
     with jax.named_scope(EMBED):
         h = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
 
@@ -659,23 +387,5 @@ def nemotron_h_sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
             "fused_attention": jnp.float32(t if fused else 0),
             "grouped_experts": jnp.float32(t if grouped else 0),
             "ssm_fused_passes": jnp.float32(t if tiled else 0),
-            **olmoe.attention_blocks(segs, fused, kinds.count("attention")),
+            **attention_blocks(segs, fused, kinds.count("attention")),
             **stats}
-
-
-def nemotron_h_stats(params, x, mask, cfg, compute_dtype=jnp.float32):
-    """``nemotron_h_sequence_stats`` summed over the rows ``x (N, 2, T)``
-    whose ``mask`` is 1, one row at a time."""
-    def one(row_and_mask):
-        row, m = row_and_mask
-        stats = nemotron_h_sequence_stats(
-            params, row * m.astype(row.dtype), cfg, compute_dtype)
-        return {**stats, **{k: stats[k] * m for k in (
-            "padding", "fused_attention", "grouped_experts",
-            "attention_blocks_computed", "attention_blocks_causal",
-            "ssm_fused_passes", "ssm_positions", "rows_computed")}}
-
-    if x.shape[0] == 1:
-        return one((x[0], mask[0]))
-    stats = lax.map(one, (x, mask))
-    return jax.tree.map(lambda a: a.sum(axis=0), stats)

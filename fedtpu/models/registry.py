@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+import importlib
 
 import jax.numpy as jnp
 
@@ -12,16 +13,23 @@ from fedtpu.models.convnet import convnet_init, convnet_apply
 
 _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16,
            "float16": jnp.float16}
+# The language models: kind -> the module that is the model. A module has
+# one interface: ``check(cfg)`` (raises where the configuration cannot be
+# built), ``init(key, cfg, param_dtype)``, ``sequence_stats(params, row, cfg,
+# compute_dtype)`` and ``PER_ROW`` (the statistics a padded row must not
+# count). Imported when asked for: a job of another kind never pays for it.
+LANGUAGE_MODELS = {"olmoe": "fedtpu.models.olmoe",
+                   "nemotron_h": "fedtpu.models.nemotron_h",
+                   "xing4": "fedtpu.models.xing4",
+                   "kimi_linear": "fedtpu.models.kimi_linear"}
 
 
 def build_model(cfg: ModelConfig):
     """Return ``(init_fn(key) -> params, apply_fn(params, x) -> logits)``.
-    For the language models (``kind='olmoe'``, ``'nemotron_h'``, ``'xing4'``,
-    ``'kimi_linear'``) the second is ``stats_fn(params, x, mask) ->
-    statistics`` (fedtpu.models.olmoe.olmoe_stats,
-    nemotron_h.nemotron_h_stats, xing4.xing4_stats,
-    kimi_linear.kimi_linear_stats): a
-    vocabulary-sized model hands out sums over tokens, never its logits."""
+    For the language models (``LANGUAGE_MODELS``) the second is
+    ``stats_fn(params, x, mask) -> statistics``, the module's
+    ``sequence_stats`` summed over rows (``fedtpu.models.layers.rows_stats``):
+    a vocabulary-sized model hands out sums over tokens, never its logits."""
     param_dtype = _DTYPES[cfg.param_dtype]
     compute_dtype = (None if cfg.compute_dtype == cfg.param_dtype
                      else _DTYPES[cfg.compute_dtype])
@@ -40,53 +48,13 @@ def build_model(cfg: ModelConfig):
                                  param_dtype=param_dtype)
         apply = functools.partial(convnet_apply, compute_dtype=compute_dtype)
         return init, apply
-    if cfg.kind == "olmoe":
-        # imported here: a job of another kind never pays for it
-        from fedtpu.models.olmoe import olmoe_init, olmoe_stats
-        if cfg.hidden_size % cfg.num_attention_heads:
-            raise ValueError(f"hidden_size {cfg.hidden_size} does not divide "
-                             f"into {cfg.num_attention_heads} heads")
-        init = functools.partial(olmoe_init, cfg=cfg, param_dtype=param_dtype)
+    if cfg.kind in LANGUAGE_MODELS:
+        from fedtpu.models.layers import rows_stats
+        model = importlib.import_module(LANGUAGE_MODELS[cfg.kind])
+        model.check(cfg)
+        init = functools.partial(model.init, cfg=cfg, param_dtype=param_dtype)
         stats = functools.partial(
-            olmoe_stats, cfg=cfg,
-            compute_dtype=compute_dtype or param_dtype)
-        return init, stats
-    if cfg.kind == "nemotron_h":
-        from fedtpu.models import nemotron_h as nh
-        nh.layer_kinds(cfg)         # the pattern's letters and its length
-        nh.experts_share(cfg)
-        if cfg.num_attention_heads % cfg.num_key_value_heads:
-            raise ValueError(
-                f"{cfg.num_attention_heads} query heads do not divide over "
-                f"{cfg.num_key_value_heads} key-value heads")
-        if cfg.mamba_num_heads % cfg.n_groups:
-            raise ValueError(
-                f"{cfg.mamba_num_heads} state-space heads do not divide "
-                f"into {cfg.n_groups} groups")
-        init = functools.partial(nh.nemotron_h_init, cfg=cfg,
-                                 param_dtype=param_dtype)
-        stats = functools.partial(
-            nh.nemotron_h_stats, cfg=cfg,
-            compute_dtype=compute_dtype or param_dtype)
-        return init, stats
-    if cfg.kind == "xing4":
-        from fedtpu.models import xing4
-        xing4.layer_kinds(cfg)      # the depth, the leading dense layers
-        xing4.experts_share(cfg)
-        init = functools.partial(xing4.xing4_init, cfg=cfg,
-                                 param_dtype=param_dtype)
-        stats = functools.partial(
-            xing4.xing4_stats, cfg=cfg,
-            compute_dtype=compute_dtype or param_dtype)
-        return init, stats
-    if cfg.kind == "kimi_linear":
-        from fedtpu.models import kimi_linear
-        kimi_linear.layer_kinds(cfg)    # the two lists, no prediction module
-        kimi_linear.experts_share(cfg)
-        init = functools.partial(kimi_linear.kimi_linear_init, cfg=cfg,
-                                 param_dtype=param_dtype)
-        stats = functools.partial(
-            kimi_linear.kimi_linear_stats, cfg=cfg,
+            rows_stats(model.sequence_stats, model.PER_ROW), cfg=cfg,
             compute_dtype=compute_dtype or param_dtype)
         return init, stats
     raise ValueError(f"unknown model kind {cfg.kind!r}")

@@ -1,8 +1,30 @@
-"""The four-stream residual module's passes over the streams, two a direction.
+"""The four-stream residual module (mHC, arXiv:2512.24880): its passes over
+the streams as XLA's, and as tiled kernels, two a direction.
 
-``fedtpu.models.xing4`` holds the definitions (``hyper_mix``, ``hyper_read``,
-``hyper_write``; its module's docstring has the algebra) and the rule that
-says where these bodies exist. A module touches the ``(n, T, C)`` float32
+A token's state is ``n`` streams of ``C``, ``X (n, C)``. Around a sublayer
+``F`` three maps are made from the token's own state: with ``x' =
+flatten(X) / sqrt(mean(flatten(X)^2) + eps)`` (no gain), ``H_pre =
+sigmoid(a_pre x' phi_pre + b_pre)`` (n), ``H_post = 2 sigmoid(a_post x'
+phi_post + b_post)`` (n) and ``H_res = SK(a_res mat(x' phi_res) + b_res)``
+(n x n), where ``SK`` exponentiates the clipped logits and normalises
+columns, then rows, ``hc_sinkhorn_iters`` times (each sum plus ``hc_eps``),
+which makes the matrix doubly stochastic. The sublayer reads ``u = H_pre X``,
+computes ``y = F(RMSNorm(u))`` and the state becomes ``H_res X + H_post^T
+y``. All of it float32. The streams lie ``(n, T, C)``, a stream a plane, and
+the maps ``(n, T)`` / ``(n, n, T)``, positions on the lanes: a ``(T, 4, 4)``
+array would fill a thirty-second of its tiles.
+
+**Which body runs where.** ``hyper_mix``, ``hyper_read`` and ``hyper_write``,
+at the end of this file, are the definition: XLA's passes (JAX differentiates
+through the Sinkhorn loop), the body on a CPU and at shapes without tiles,
+and the oracle of the kernels' tests. Where ``hyper_passes_apply`` (a TPU,
+float32 streams, ``C`` whole lane tiles, ``T`` whole row tiles, a tile within
+the chip's own memory at this ``n``) the same algebra at the same precision
+runs as the Mosaic kernels below under differentiation rules of their own,
+``mix_read`` and ``write``; the scale and bias, ``H_post``, the clip and the
+Sinkhorn turns stay in XLA (``_hyper_maps``, both bodies' own).
+
+**The kernels.** A module touches the ``(n, T, C)`` float32
 streams for four things (the norm's mean square, the logits' product with
 ``phi``, the read ``u = H_pre X`` and the write ``H_res X + H_post^T y``) and
 XLA runs each as fusions of its own, forward, recomputed and transposed, the
@@ -14,7 +36,7 @@ passes:
   logits ``z = flatten(X) phi / rms(X)`` (``(n (n + 2), T)``, positions on the
   lanes, as the definition lays them) and the streams themselves, untouched,
   for ``write`` to consume. The scale, the bias, ``H_post``, the clip, the
-  exponential and the Sinkhorn turns stay in XLA (``xing4._hyper_maps``); only
+  exponential and the Sinkhorn turns stay in XLA (``_hyper_maps``); only
   ``H_pre``'s own ``sigmoid(scale z + bias)`` is made in the tile, because
   ``u`` needs it.
 * ``write`` (B): streams, ``y`` and the maps in, the new streams out.
@@ -51,9 +73,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from fedtpu.ops.scopes import HC_SINKHORN, HYPER_CONN
 
 LANES, STRIP = 128, 8
 # Positions a grid step holds, for the cell's shapes (``n`` 4, ``T`` 4,096,
@@ -276,7 +301,7 @@ def _columns_of(a, width: int):
 def mix_read(x, phi, scale, bias, eps: float):
     """``(u, z, x)`` of the streams ``x (n, T, C)`` float32: ``z (n (n + 2),
     T) = flatten(X) phi / sqrt(mean(flatten(X)^2) + eps)`` (``phi (n (n + 2),
-    n * C)`` as ``xing4._hyper_init`` lays it, the product at ``HIGHEST``),
+    n * C)``, a row a logit, the product at ``HIGHEST``),
     ``u (T, C) = sum_i sigmoid(scale_i z_i + bias_i) x_i`` over the first ``n``
     rows of ``z`` (``scale``, ``bias (n,)``), and ``x`` itself: the copy
     ``write`` takes, so that its cotangent comes back to this rule alone."""
@@ -386,7 +411,7 @@ def _write_backward(g, x, y, maps):
 
 @jax.custom_vjp
 def write(x, y, post, res):
-    """``H_res X + H_post^T y``, ``xing4.hyper_write`` the definition: ``x (n,
+    """``H_res X + H_post^T y``, ``hyper_write`` the definition: ``x (n,
     T, C)`` and ``y (T, C)`` float32, ``post (n, T)``, ``res (n, n, T)``."""
     return _write_forward(x, y, _maps_columns(post, res, columns(x.shape[0])))
 
@@ -405,3 +430,83 @@ def _write_bwd(residuals, g):
 
 
 write.defvjp(_write_fwd, _write_bwd)
+
+
+# --------------------------------------- the definitions, and the rule
+def sinkhorn(logits, cfg):
+    """``(n, n, T)`` logits to doubly stochastic matrices, a position a
+    matrix: ``exp`` of the clipped logits, then columns and rows in turn,
+    ``hc_sinkhorn_iters`` times: a loop of that many trips (its backward pass
+    keeps the iterates, 256 KB each at 4,096 positions), because unrolled the
+    twelve modules' forty passes each, forward, recomputed and backward, were
+    a fifth of the round program's compile."""
+    def turn(_, m):
+        m = m / (m.sum(axis=0, keepdims=True) + cfg.hc_eps)
+        return m / (m.sum(axis=1, keepdims=True) + cfg.hc_eps)
+
+    with jax.named_scope(HC_SINKHORN):
+        return lax.fori_loop(
+            0, cfg.hc_sinkhorn_iters, turn,
+            jnp.exp(jnp.clip(logits, cfg.mhc_h_res_clamp_min,
+                             cfg.mhc_h_res_clamp_max)))
+
+
+def _hyper_maps(z, module, cfg):
+    """``(H_pre, H_post, H_res)`` from the normed raw logits ``z (n (n + 2),
+    T)``: the scale and the bias, then the two sigmoids and the Sinkhorn
+    turns (under ``hyper_conn`` and ``hc_sinkhorn``: the caller's scope)."""
+    n = cfg.hc_mult
+    scale = jnp.repeat(module["alpha"], np.array([n, n, n * n]),
+                       total_repeat_length=n * (n + 2))
+    logits = z * scale[:, None] + module["bias"][:, None]
+    pre = jax.nn.sigmoid(logits[:n])
+    post = 2.0 * jax.nn.sigmoid(logits[n:2 * n])
+    res = sinkhorn(logits[2 * n:].reshape(n, n, -1), cfg)
+    return pre, post, res
+
+
+def hyper_mix(x, module, cfg):
+    """The three maps of one residual module from the streams ``x (n, T,
+    C)`` float32: ``(H_pre (n, T), H_post (n, T), H_res (n, n, T))``, where
+    ``H_res[i, j]`` weighs stream ``j`` into stream ``i``."""
+    n = x.shape[0]
+    with jax.named_scope(HYPER_CONN):
+        inv = lax.rsqrt(jnp.mean(x * x, axis=(0, 2)) + cfg.rms_norm_eps)
+        phi = module["phi"].reshape(-1, n, x.shape[2])
+        # flatten(X) phi, a stream at a time: positions come out on the lanes
+        logits = sum(lax.dot_general(
+            phi[:, i], x[i], (((1,), (1,)), ((), ())),
+            precision=lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32) for i in range(n))
+        return _hyper_maps(logits * inv, module, cfg)
+
+
+def hyper_read(x, pre):
+    """``u (T, C) = H_pre X``: what a sublayer reads of the streams."""
+    with jax.named_scope(HYPER_CONN):
+        return (pre[:, :, None] * x).sum(axis=0)
+
+
+def hyper_write(x, y, post, res):
+    """``H_res X + H_post^T y``: the streams after a sublayer gave ``y``.
+    A broadcast product under a sum over the source streams, here and (by
+    autodiff) in every gradient: the form the compiler keeps as ONE
+    multiply-and-reduce pass a result. (Sixteen products written as a Python
+    sum came out of the backward pass as sixteen ``(T, C)`` arrays a module,
+    0.9 GB at the published widths; an ``einsum`` as bfloat16 convolutions.)"""
+    with jax.named_scope(HYPER_CONN):
+        return ((res[:, :, :, None] * x[None]).sum(axis=1)
+                + post[:, :, None] * y[None])
+
+
+def hyper_passes_apply(x) -> bool:
+    """Whether the tiled bodies of a residual module's passes over the
+    streams (``mix_read`` and ``write``, a differentiation rule each) exist
+    for the streams ``x (n, T, C)`` where the program is being built: a TPU
+    (the PROCESS's backend, as ``packed_attention.fused_attention_applies``
+    reads it), float32 streams, ``C`` whole lane tiles, ``T`` whole row
+    tiles, the tile within the chip's own memory at this ``n``.
+    ``hyper_mix``, ``hyper_read`` and ``hyper_write`` are the definitions
+    and the body everywhere else."""
+    return (jax.default_backend() == "tpu" and x.dtype == jnp.float32
+            and tiles_apply(*x.shape))

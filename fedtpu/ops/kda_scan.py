@@ -1,9 +1,38 @@
-"""The KDA delta-rule recurrence as two tiled kernels, one a direction.
+"""The KDA delta-rule recurrence: its chunked form, and two tiled kernels.
 
-``fedtpu.models.kimi_linear.kda_scan`` is the definition (its module's
-docstring has the algebra) and the rule that says where these bodies exist.
-The definition's chunked form is a dozen XLA passes over ``(chunks, heads,
-64, 128)`` float32 arrays with heads-first transposes between them; here a
+**The chunked form** (``kda_scan``, at the end of this file), this repo's
+own: the definition, XLA's passes over ``(chunks, heads, C, d)`` arrays, the
+body on a CPU and at shapes without tiles, and the oracle of the kernels'
+tests. With ``G_r`` the cumulative log-decay inside a chunk and ``S_0`` the
+state entering it, the corrections ``u_r = beta_r (v_r - (Diag(exp g_r)
+S_{r-1})^T k_r)`` solve ``(I + Diag(beta) A) U = Diag(beta) (V - K~ S_0)``,
+``A_ri = sum_c k_rc k_ic exp(G_rc - G_ic)`` for ``i < r``, ``K~_r = exp(G_r)
+k_r``: one inverse of a unit lower triangular matrix a chunk and head
+(``unit_lower_inverse``, by forward substitution). Then ``O = Q~ S_0 + B U``
+with ``B_ri`` the same sum with ``q_r`` for ``i <= r``, and ``S_C = Diag(exp
+G_C) S_0 + K^^T U``, ``K^_i = exp(G_C - G_i) k_i``, carried from chunk to
+chunk by a ``lax.scan``. **No exponential of a positive number is ever
+taken**: ``exp(-G)`` overflows float32 inside a chunk of 64 at the decays the
+model starts with (a token's ``g`` reaches -1.6), so ``A`` and ``B`` are made
+of sub-chunks of ``KDA_SUB`` positions: a diagonal block pairwise (``exp(G_r
+- G_i)`` per pair and channel, masked before the exponential), a block below
+the diagonal as a product of two factors taken from the row block's first
+position, ``exp(G_r - G_ref)`` and ``exp(G_ref - G_i)``, both at most 1
+because ``g <= 0``. A document's first token may fall anywhere: pairs across
+two documents are masked out of ``A`` and ``B``, only the positions of the
+entering document read ``S_0``, only the chunk's last document reaches
+``S_C``. The state, the decays, the norms and the triangular inverse
+(``HIGHEST`` precision) are float32; the chunk's large products take
+``compute_dtype`` inputs and sum in float32. Autodiff differentiates all of
+it but the inverse, which has the rule ``-T^T dT T^T``.
+
+**Which body runs where.** Where ``fused_scan_applies`` (a TPU, keys and
+values of whole lane tiles, whole chunks of whole sub-chunks) the same
+algebra at the same precision runs as the two Mosaic kernels below under a
+rule of their own (``fused_kda_scan``); ``kda_scan`` asks and calls them.
+
+**The kernels.** The definition's chunked form is a dozen XLA passes over
+``(chunks, heads, 64, 128)`` float32 arrays with heads-first transposes between them; here a
 grid step holds a block of whole chunks of ONE head, read in place out of the
 ``(T, heads * d)`` arrays the convolutions leave (column block ``h``), walks
 its chunks in order with the head's state in the chip's own memory, and
@@ -56,6 +85,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES, SUBLANES = 128, 8
+# Positions of a chunk of the recurrence, and of a sub-chunk of the decay-
+# weighted scores inside it (pairwise on the diagonal, two factors below).
+KDA_CHUNK, KDA_SUB = 64, 16
 # Chunks of one head a grid step walks (512 rows at chunks of 64): a grid
 # step costs a third of a microsecond and a chunk about one.
 BLOCK_CHUNKS = 8
@@ -428,25 +460,26 @@ def _operands(q, k, v, g, beta, run, chunk):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
-def kda_scan(q, k, v, g, beta, run, chunk: int, sub: int, compute_dtype):
-    """``kimi_linear.kda_scan`` (the definition) of ``q``, ``k``, ``g (T,
-    heads, d_k)``, ``v (T, heads, d_v)``, ``beta (T, heads)``, all float32,
-    and ``run (T,)``: ``o (T, heads, d_v)`` float32. ``tiles_apply`` says at
-    which shapes."""
+def fused_kda_scan(q, k, v, g, beta, run, chunk: int, sub: int,
+                   compute_dtype):
+    """``kda_scan`` (the definition) of ``q``, ``k``, ``g (T, heads, d_k)``,
+    ``v (T, heads, d_v)``, ``beta (T, heads)``, all float32, and ``run
+    (T,)``, in the kernels: ``o (T, heads, d_v)`` float32. ``tiles_apply``
+    says at which shapes."""
     t, heads, _ = q.shape
     o, = _forward(*_operands(q, k, v, g, beta, run, chunk), heads, chunk,
                   sub, compute_dtype, False)
     return o.reshape(t, heads, -1)
 
 
-def _kda_scan_fwd(q, k, v, g, beta, run, chunk, sub, compute_dtype):
+def _fused_kda_scan_fwd(q, k, v, g, beta, run, chunk, sub, compute_dtype):
     t, heads, _ = q.shape
     o, entering = _forward(*_operands(q, k, v, g, beta, run, chunk), heads,
                            chunk, sub, compute_dtype, True)
     return o.reshape(t, heads, -1), (q, k, v, g, beta, run, entering)
 
 
-def _kda_scan_bwd(chunk, sub, compute_dtype, residuals, do):
+def _fused_kda_scan_bwd(chunk, sub, compute_dtype, residuals, do):
     q, k, v, g, beta, run, entering = residuals
     t, heads, _ = q.shape
     dq, dk, dv, dg, dbeta = _backward(
@@ -456,4 +489,181 @@ def _kda_scan_bwd(chunk, sub, compute_dtype, residuals, do):
             dg.reshape(g.shape), dbeta[:, :, 0].T, None)
 
 
-kda_scan.defvjp(_kda_scan_fwd, _kda_scan_bwd)
+fused_kda_scan.defvjp(_fused_kda_scan_fwd, _fused_kda_scan_bwd)
+
+
+# ---------------------------- the definition (the chunked form), and the rule
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def unit_lower_inverse(low, block: int = KDA_SUB):
+    """``T = (I + L)^-1`` of strictly lower triangular ``L (..., C, C)``
+    float32 by forward substitution, products at ``HIGHEST`` precision: the
+    diagonal blocks of ``block`` rows a row a trip, all of them at once
+    (``T_r = e_r - sum_{i<r} L_ri T_i``), then block row by block row,
+    ``T_i: = -T_ii (sum_{j<i} L_ij T_j:)``. (A row a trip over the whole of
+    ``C`` reads the whole of ``T`` every trip: 64 x 33 MB a call at 4,096
+    positions, a tenth of the cell's round. And ``L`` is nilpotent, so ``(I -
+    L)(I + L^2)(I + L^4)...`` is the same matrix in ``log2 C`` products, but
+    its terms grow as ``|L|^n C(C, n)`` before they cancel: with the keys a
+    SiLU leaves, most of them on one side of the origin, ``L`` has entries
+    near a half and that product read 1e28 where the inverse's entries are
+    under 1.) Reverse mode only, under the inverse's own rule."""
+    c = low.shape[-1]
+    s = block if c % block == 0 else c
+    a = c // s
+    mm = functools.partial(jnp.matmul, precision=lax.Precision.HIGHEST)
+    corner = jnp.moveaxis(jnp.diagonal(
+        low.reshape(*low.shape[:-2], a, s, a, s), axis1=-4, axis2=-2),
+        -1, -3)                                             # (..., a, s, s)
+
+    def row(r, inv):            # rows under ``r`` are done, the rest zero
+        new = (jnp.arange(s) == r).astype(low.dtype) - mm(
+            lax.dynamic_slice_in_dim(corner, r, 1, axis=-2), inv)
+        return lax.dynamic_update_slice_in_dim(inv, new, r, axis=-2)
+
+    own = lax.fori_loop(0, s, row, jnp.zeros_like(corner))
+    inv = own[..., 0, :, :]                                 # (..., s, s)
+    for i in range(1, a):       # the ``i`` block rows above are done
+        under = low[..., i * s:(i + 1) * s, :i * s]
+        new = jnp.concatenate([-mm(own[..., i, :, :], mm(under, inv)),
+                               own[..., i, :, :]], axis=-1)
+        inv = jnp.concatenate(
+            [jnp.pad(inv, [(0, 0)] * (low.ndim - 1) + [(0, s)]), new], axis=-2)
+    return inv
+
+
+def _unit_lower_inverse_fwd(low, block):
+    inv = unit_lower_inverse(low, block)
+    return inv, inv
+
+
+def _unit_lower_inverse_bwd(block, inv, g):
+    mm = functools.partial(jnp.matmul, precision=lax.Precision.HIGHEST)
+    turned = jnp.swapaxes(inv, -1, -2)
+    return (-mm(mm(turned, g), turned),)
+
+
+unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def _decayed_scores(lefts, k, cum, sub: int, compute_dtype):
+    """``[P (n, h, C, C)]``, one for each ``left (n, h, C, d)`` of ``lefts``:
+    ``P_ri = sum_c left_rc k_ic exp(cum_rc - cum_ic)`` for ``i <= r``, zero
+    above the diagonal; ``cum (n, h, C, d)`` the cumulative log-decay inside
+    the chunk, never rising. In sub-chunks of ``sub``: a diagonal block pair
+    by pair, a block below it from the two factors either side of the row
+    block's first position."""
+    n, h, c, d = k.shape
+    a = c // sub
+    f32 = dict(preferred_element_type=jnp.float32)
+    cast = lambda arr: arr.astype(compute_dtype)
+    blocks = lambda arr: arr.reshape(n, h, a, sub, d)
+    ks, cs = blocks(k), blocks(cum)
+    idx = jnp.arange(sub)
+    inside = (idx[:, None] >= idx[None, :])[:, :, None]           # (r, i, 1)
+    pair = jnp.exp(jnp.where(inside, cs[:, :, :, :, None] - cs[:, :, :, None],
+                             -jnp.inf)) * ks[:, :, :, None]       # (.., r, i, d)
+    # below the diagonal blocks: row blocks 1.., columns before the last
+    # block (none lies under block 0, and the last block's columns lie under
+    # no other): (a - 1) x (C - sub) of the a x C pairs of blocks and columns
+    cols = c - sub
+    ref = cs[:, :, 1:, :1]                                     # (.., a - 1, 1, d)
+    earlier = (jnp.arange(cols)[None, :]
+               < (jnp.arange(1, a) * sub)[:, None])[:, :, None]
+    right = cast(k[:, :, None, :cols] * jnp.exp(jnp.where(
+        earlier, ref - cum[:, :, None, :cols], -jnp.inf)))     # (.., a - 1, cols, d)
+    eye = jnp.eye(a, dtype=jnp.float32)[:, None, :, None]       # block place
+    out = []
+    for left in lefts:
+        ls = blocks(left)
+        diag = (ls[:, :, :, :, None] * pair).sum(axis=-1)       # (.., a, r, i)
+        below = jnp.einsum(
+            "nhard,nhaid->nhari",
+            cast(ls[:, :, 1:] * jnp.exp(cs[:, :, 1:] - ref)), right, **f32)
+        below = jnp.pad(below, ((0, 0), (0, 0), (1, 0), (0, 0), (0, sub)))
+        out.append((below + (diag[:, :, :, :, None] * eye).reshape(
+            n, h, a, sub, c)).reshape(n, h, c, c))
+    return out
+
+
+def fused_scan_applies(t: int, d_k: int, d_v: int, chunk: int,
+                       sub: int) -> bool:
+    """Whether the recurrence's tiled kernels (``fused_kda_scan``: one
+    forward, one backward, a head's state in the chip's own memory) exist for
+    a row of ``t`` positions, keys ``d_k`` and values ``d_v`` wide, where the
+    program is being built: a TPU (the PROCESS's backend, as
+    ``packed_attention.fused_attention_applies`` reads it), keys and values
+    of whole lane tiles, ``t`` whole chunks and a chunk whole sub-chunks. The
+    chunked form below is the definition and the body everywhere else."""
+    return jax.default_backend() == "tpu" and tiles_apply(
+        t, d_k, d_v, chunk, sub)
+
+
+def kda_scan(q, k, v, g, beta, run, chunk: int, compute_dtype,
+             sub: int = KDA_SUB):
+    """``o (T, heads, d_v)`` float32 of the recurrence ``S_t = (I - beta_t k_t
+    k_t^T) Diag(exp g_t) S_{t-1} [t-1 in t's run] + beta_t k_t v_t^T``, ``o_t
+    = S_t^T q_t``, in chunks (the module's docstring has the algebra). ``q``,
+    ``k (T, heads, d_k)``, ``v (T, heads, d_v)``, ``g (T, heads, d_k)`` the
+    log-decay, NEVER positive, ``beta (T, heads)``, all float32; ``run (T,)``
+    from ``document_runs``; ``T`` is whole chunks (or one shorter chunk) and
+    a chunk whole sub-chunks. Where ``fused_scan_applies`` the kernels run,
+    named for their direction so that their ``op_name`` keeps it."""
+    t, heads, _ = k.shape
+    if fused_scan_applies(t, k.shape[-1], v.shape[-1], chunk, sub):
+        return fused_kda_scan(q, k, v, g, beta, run, chunk, sub,
+                              compute_dtype)
+    c = min(chunk, t)
+    sub = min(sub, c)
+    if t % c or c % sub:
+        raise ValueError(f"a sequence of {t} positions is not whole chunks of "
+                         f"{c}, or a chunk not whole sub-chunks of {sub}")
+    n = t // c
+    cast = lambda arr: arr.astype(compute_dtype)
+    f32 = dict(preferred_element_type=jnp.float32)
+    # chunks, heads, positions, width: a head's (C, C) planes have whole lanes
+    fold = lambda arr: arr.reshape(n, c, heads, -1).transpose(0, 2, 1, 3)
+    qc, kc, vc, gc = map(fold, (q, k, v, g))
+    bc = fold(beta)                                                 # (n, h, C, 1)
+    cum = jnp.cumsum(gc, axis=2)
+    runs = run.reshape(n, c)
+    last = runs[:, -1]
+    before = jnp.concatenate([jnp.zeros((1,), run.dtype), last[:-1]])
+
+    # inside a chunk: position r reads i <= r of its own run
+    idx = jnp.arange(c)
+    same = (runs[:, :, None] == runs[:, None, :])[:, None]          # (n, 1, r, i)
+    # recomputed in the backward pass: the pairwise exponentials are
+    # C * sub * d numbers a chunk and head, a gigabyte at 4,096 positions
+    kk, qk = jax.checkpoint(functools.partial(
+        _decayed_scores, sub=sub, compute_dtype=compute_dtype))(
+            (kc, qc), kc, cum)
+    a_mat = jnp.where(same & (idx[:, None] > idx[None, :]), kk, 0.0)
+    b_mat = jnp.where(same & (idx[:, None] >= idx[None, :]), qk, 0.0)
+    solve = unit_lower_inverse(bc * a_mat, sub)                     # (n, h, C, C)
+
+    # the entering state is read by the positions of the run it belongs to,
+    # and the chunk's last run is what reaches its end
+    from_start = (runs == before[:, None])[:, None, :, None]         # (n, 1, C, 1)
+    to_end = (runs == last[:, None])[:, None, :, None]
+    grown = jnp.exp(cum)
+    total = cum[:, :, -1:]                                          # (n, h, 1, d)
+    k_in = jnp.where(from_start, kc * grown, 0.0)                   # K~
+    q_in = jnp.where(from_start, qc * grown, 0.0)                   # Q~
+    k_out = jnp.where(to_end, kc * jnp.exp(total - cum), 0.0)       # K^
+    keep = jnp.where((last == before)[:, None, None],
+                     jnp.exp(total[:, :, 0]), 0.0)                  # (n, h, d)
+    w_v = jnp.einsum("nhri,nhiv->nhrv", cast(solve), cast(bc * vc), **f32)
+    w_k = jnp.einsum("nhri,nhid->nhrd", cast(solve), cast(bc * k_in), **f32)
+
+    def carry(state, step):
+        w_v, w_k, k_out, keep = step
+        u = w_v - jnp.einsum("hrd,hdv->hrv", cast(w_k), cast(state), **f32)
+        new = keep[:, :, None] * state + jnp.einsum(
+            "hrd,hrv->hdv", cast(k_out), cast(u), **f32)
+        return new, (state, u)
+
+    zero = jnp.zeros((heads, kc.shape[-1], vc.shape[-1]), jnp.float32)
+    _, (entering, u) = lax.scan(carry, zero, (w_v, w_k, k_out, keep))
+    o = (jnp.einsum("nhrd,nhdv->nhrv", cast(q_in), cast(entering), **f32)
+         + jnp.einsum("nhri,nhiv->nhrv", cast(b_mat), cast(u), **f32))
+    return o.transpose(0, 2, 1, 3).reshape(t, heads, -1)

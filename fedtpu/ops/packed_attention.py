@@ -1,5 +1,27 @@
-"""Tiled causal attention within the documents of one packed row, computing
-only the block pairs that can hold an allowed (query, key) pair.
+"""The attention core of one packed row: one function, two bodies.
+
+``attention_core(q, k, v, segs)`` is ``softmax(mask(q k^T scale)) v``, causal
+within a document. Its XLA body (``_xla_attention``) is the definition: it
+writes the ``[heads, T, T]`` float32 scores, the masked scores and the
+probabilities to memory and keeps them for the backward pass. Its fused body
+(``_fused_attention``) is the three tiled kernels below with an online
+softmax, which never hold a ``[heads, T, T]`` array: the same mask, bf16
+matmul inputs, float32 accumulation, maximum, sum and exponentials. Which
+body runs is read off what the code can see and is nobody's to set
+(``fused_attention_applies``): the fused body when the program is built for a
+TPU, the head width is a multiple of 128 lanes, ``T`` a multiple of the
+kernel's block and q, k and v share one width (a head whose query-key and
+value widths differ is padded with zeros to one, ``padded_head_width``, and
+its context cut back); the XLA body everywhere else. The fused backward takes
+its row term ``sum(o * do)`` from the bf16 ``ctx`` and feeds bf16 ``dS`` to
+its matmuls, where the XLA body's softmax backward is float32 throughout:
+within "bf16 matmul inputs", and measured inside the benchmark's limits
+(PERF.md section 6, PR 26). A model's statistics say how many positions ran
+fused and how many block pairs of those on or under the diagonal
+(``attention_blocks``).
+
+**The kernels: tiled causal attention within the documents of one packed
+row, computing only the block pairs that can hold an allowed pair.**
 
 The three kernels (forward with an online softmax, ``dk``/``dv``, ``dq``) are
 the bodies of the library's ``jax.experimental.pallas.ops.tpu.flash_attention``
@@ -46,6 +68,8 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from fedtpu.ops.scopes import ATTN_CORE
 
 LANES, SUBLANES = 128, 8
 # The library's: far below any score, and finite so that a row's maximum is.
@@ -339,3 +363,103 @@ def attention(q, k, v, segs, sm_scale: float, block: int):
     ``q``, ``k``, ``v`` ``(heads, T, d)`` with ``d`` whole lane tiles and
     ``T`` whole ``block``s of whole lanes; reverse mode only."""
     return _attention(q, k, v, segs, sm_scale, block)
+
+
+# ----------------------------------------- the core and its two bodies
+# Rows and columns of a tile of the fused attention kernel, forward and both
+# backward kernels. Chosen on the chip (PERF.md section 6, PR 26), forward +
+# backward of one (4096, 16, 128) sequence: the library's default 128s take
+# 17.0 ms (the XLA body 16.3), 256s 7.1, 512s 3.8, 1024s 3.7 with 134 MB
+# more temporaries; no mixed shape beat 512s.
+ATTENTION_BLOCK = 512
+
+
+def padded_head_width(q, v) -> int:
+    """The head width the tiled kernel would run ``q (T, heads, dq)`` and
+    ``v (T, heads, dv)`` at: the wider of the two, up to whole lane tiles.
+    The kernel takes one width for q, k and v; zero columns of q and k add
+    nothing to a score and zero columns of v give zero columns of the
+    context, which are cut, so the padded form is exact."""
+    return -(-max(q.shape[-1], v.shape[-1]) // 128) * 128
+
+
+def fused_attention_applies(q, k, v) -> bool:
+    """Whether the tiled kernel exists for these ``(T, heads, d)`` operands
+    where the program is being built: a TPU, lane-wide heads, whole blocks
+    and one head width for q, k and v (``attention_core`` pads a head whose
+    query-key and value widths differ to one before it asks).
+
+    The platform read is the PROCESS's default backend, not the one a
+    program is lowered for: a compile for a described TPU from a CPU host
+    gets the XLA body (``tests/test_aot_tpu_compile.py`` steers this rule
+    for that reason), and a CPU mesh on a TPU host at these widths would
+    get a kernel it cannot lower."""
+    t, _, d = q.shape
+    return (jax.default_backend() == "tpu" and q.shape == k.shape == v.shape
+            and d % 128 == 0 and t % ATTENTION_BLOCK == 0)
+
+
+def _xla_attention(q, k, v, segs, scale=None):
+    t, _, d = q.shape
+    scores = jnp.einsum("qhd,khd->hqk", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores / (d ** 0.5) if scale is None else scores * scale
+    idx = jnp.arange(t)
+    # causal, and within one segment; padding (segment 0) sees padding,
+    # which keeps its rows finite and is masked out of the loss
+    allowed = (idx[:, None] >= idx[None, :]) & (segs[:, None] == segs[None, :])
+    probs = jax.nn.softmax(jnp.where(allowed[None], scores, -1e30), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", probs.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+
+
+def _fused_attention(q, k, v, segs, scale=None):
+    # the kernels' layout is (heads, T, d); their mask is the XLA body's:
+    # causal, and equal segment ids (padding's 0 among them)
+    heads_first = lambda a: a.transpose(1, 0, 2)
+    ctx = attention(
+        heads_first(q), heads_first(k), heads_first(v), segs,
+        q.shape[-1] ** -0.5 if scale is None else scale, ATTENTION_BLOCK)
+    return ctx.transpose(1, 0, 2).astype(jnp.float32)
+
+
+def attention_blocks(segs, fused: bool, layers: int) -> dict:
+    """A sequence's two block counters: the (query block, key block) pairs
+    the fused body's forward kernel runs on the row ``segs (T,)``, from the
+    table it reads, and the pairs on or under the diagonal, a head's worth
+    for each of ``layers`` attention layers; both 0 where the XLA body ran
+    (it has no blocks)."""
+    if not fused:
+        return {"attention_blocks_computed": jnp.float32(0.0),
+                "attention_blocks_causal": jnp.float32(0.0)}
+    kept = pairs_kept(segs, ATTENTION_BLOCK)
+    blocks = kept.shape[0]
+    return {"attention_blocks_computed":
+            layers * kept.sum().astype(jnp.float32),
+            "attention_blocks_causal":
+            jnp.float32(layers * blocks * (blocks + 1) // 2)}
+
+
+def attention_core(q, k, v, segs, compute_dtype, scale=None):
+    """``ctx (T, heads, dv)`` float32: the attention of one packed sequence
+    after RoPE and before the output projection, ``q``, ``k`` ``(T, heads,
+    dq)`` and ``v (T, heads, dv)`` float32 and cast to ``compute_dtype`` for
+    both matmuls; the scores are scaled by ``scale`` (``dq ** -0.5`` where
+    none is given). A head whose two widths differ (latent attention: 192
+    beside 128) reaches the tiled kernel padded with zeros to one width
+    (``padded_head_width``) and its context is cut back: exact, at the
+    padded width's cost. The XLA body takes the widths as they are."""
+    q, k, v = (a.astype(compute_dtype) for a in (q, k, v))
+    if scale is None and q.shape == v.shape:
+        padded = q, k, v
+    else:
+        if scale is None:
+            scale = q.shape[-1] ** -0.5
+        wide = padded_head_width(q, v)
+        padded = tuple(jnp.pad(a, ((0, 0), (0, 0), (0, wide - a.shape[-1])))
+                       for a in (q, k, v))
+    with jax.named_scope(ATTN_CORE):
+        if not fused_attention_applies(*padded):
+            return _xla_attention(q, k, v, segs, scale)
+        ctx = _fused_attention(*padded, segs, scale)
+        return ctx if padded[2] is v else ctx[..., :v.shape[-1]]

@@ -1,11 +1,13 @@
 """The Mamba-2 mixer's two float32 passes, each ONE row-tiled kernel a direction.
 
-``fedtpu.models.nemotron_h`` holds the definitions (``causal_conv`` under a
-SiLU; ``gated_group_norm`` after the ``D`` skip) and the rule that says where
-these bodies exist. Both passes are elementwise along the width but for a
-window of ``taps`` rows (the convolution) and a mean over a group of lanes (the
-norm), so a tile of rows of some columns is all a step needs: every operand is
-read once and every result written once, forward and backward, where XLA's
+``causal_conv`` under a SiLU and ``gated_group_norm`` after the ``D`` skip,
+at the end of this file, are the definitions: the CPU's path and tier-1's,
+and autodiff's to differentiate. ``fused_passes_apply`` beside them says
+where the tiled bodies below exist. Both passes are elementwise along the
+width but for a window of ``taps`` rows (the convolution) and a mean over a
+group of lanes (the norm), so a tile of rows of some columns is all a step
+needs: every operand is read once and every result written once, forward
+and backward, where XLA's
 fusions of the definitions and of their transposes pass over the arrays three
 to seven times (PERF.md section 6, PR 36). Float32 throughout; the sums over
 rows that make the weights' gradients are the only sums whose order differs
@@ -40,6 +42,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -315,10 +318,10 @@ def _conv_backward(src, w, b, run, g, g_t, first, width):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def conv_silu(src, w, b, run, first: int, width: int, width_t: int):
     """``(out, out[:, :width_t].T)``, ``out = silu(causal_conv(src[:,
-    first:first + width], w, b, run))`` ``(T, width)`` float32,
-    ``nemotron_h.causal_conv`` the definition; its first ``width_t`` columns
-    (``x``) go out once more with the positions last, the form the scan
-    takes them in. ``src (T, >= first + width)`` float32 is read in place,
+    first:first + width], w, b, run))`` ``(T, width)`` float32, ``causal_conv``
+    the definition; its first ``width_t`` columns (``x``) go out once more
+    with the positions last, the form the scan takes them in. ``src (T, >=
+    first + width)`` float32 is read in place,
     ``w (taps, width)``, ``b (width,)``, ``run (T,)`` the run ids from 1."""
     return tuple(_conv_forward(src, w, b, run, first, width, width_t))
 
@@ -427,8 +430,8 @@ def _gate_backward(g, yt, xs, zs, skip, gain, groups, eps):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def skip_gate_norm(yt, xs, zs, skip, gain, groups: int, eps, dtype):
     """``gated_group_norm(y + skip * x, z, gain, groups, eps)`` in ``dtype``,
-    ``(T, width)``, ``nemotron_h.gated_group_norm`` the definition: ``yt
-    (T / chunk, width, chunk)`` float32 is ``chunk_transposed(y)``, the form
+    ``(T, width)``, ``gated_group_norm`` the definition: ``yt (T / chunk,
+    width, chunk)`` float32 is ``chunk_transposed(y)``, the form
     the scan leaves its result in; ``x`` and ``z`` the first ``width``
     columns of ``xs`` and of ``zs`` (float32, read in place); ``skip
     (heads,)`` a head's ``D``, a head ``width / heads`` columns; ``gain
@@ -458,3 +461,50 @@ def _skip_gate_norm_bwd(groups, eps, dtype, residuals, g):
 
 
 skip_gate_norm.defvjp(_skip_gate_norm_fwd, _skip_gate_norm_bwd)
+
+
+# --------------------------------------- the definitions, and the rule
+def document_runs(segs):
+    """``(run (T,) int32, starts (T,) bool)``: the index of the run of equal
+    segment ids each position lies in (1, 2, ...), and where a run starts.
+    The state-space layer restarts at every start, padding's run included."""
+    starts = jnp.concatenate([jnp.ones((1,), bool), segs[1:] != segs[:-1]])
+    return jnp.cumsum(starts.astype(jnp.int32)), starts
+
+
+def causal_conv(x, w, b, run):
+    """Depthwise causal convolution of ``x (T, C)`` with ``w (K, C)``, ``w[j]``
+    weighing the position ``K - 1 - j`` back, over the positions of the same
+    run only: a document's first tokens see zeros before them."""
+    taps, t = w.shape[0], x.shape[0]
+    out = x * w[taps - 1] + b
+    for back in range(1, taps):
+        earlier = jnp.pad(x, ((back, 0), (0, 0)))[:t]
+        same = jnp.pad(run, (back, 0))[:t] == run       # run ids start at 1
+        out = out + jnp.where(same[:, None], earlier, 0.0) * w[taps - 1 - back]
+    return out
+
+
+def gated_group_norm(y, z, gain, groups: int, eps):
+    """``gain * RMSNorm(y * silu(z))``, the norm over each of ``groups``
+    equal parts of the last axis; float32."""
+    y = y * jax.nn.silu(z)
+    parts = y.reshape(y.shape[0], groups, -1)
+    parts = parts * lax.rsqrt(jnp.mean(parts * parts, axis=-1, keepdims=True)
+                              + eps)
+    return parts.reshape(y.shape) * gain
+
+
+def fused_passes_apply(cfg, t: int) -> bool:
+    """Whether the tiled bodies of the mixer's two float32 passes
+    (``conv_silu``: the convolution under its SiLU; ``skip_gate_norm``: the
+    skip, the gate and the grouped norm) exist for a sequence of ``t``
+    positions where the program is being built: a TPU (the PROCESS's backend,
+    as ``packed_attention.fused_attention_applies`` reads it), ``t`` whole row
+    tiles, and the inner width, ``xBC``'s width and a group of the norm whole
+    lane tiles. ``causal_conv`` and ``gated_group_norm`` are the definitions and
+    the body everywhere else."""
+    width = cfg.mamba_num_heads * cfg.mamba_head_dim
+    return jax.default_backend() == "tpu" and tiles_apply(
+        t, min(cfg.chunk_size, t), cfg.conv_kernel, width,
+        width + 2 * cfg.n_groups * cfg.ssm_state_size, width, cfg.n_groups)

@@ -75,7 +75,8 @@ from fedtpu.parallel.round import (LAYER_KERNELS, LAYERS, MODULES, PIECES,
                                    build_round_fn,
                                    build_eval_fn, check_resident_fits,
                                    init_federated_state, global_params)
-from fedtpu.training.task import LANGUAGE_MODELS, Task, build_task
+from fedtpu.models.registry import LANGUAGE_MODELS
+from fedtpu.training.task import Task, build_task
 from fedtpu.utils.timing import Timer, force_fetch
 from fedtpu.utils.trees import to_numpy
 

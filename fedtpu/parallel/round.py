@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import PartitionSpec as P
 
+from fedtpu.ops import scopes
 from fedtpu.ops.losses import masked_cross_entropy
 from fedtpu.ops.metrics import metrics_from_confusion
 from fedtpu.ops.server_opt import (ServerOptimizer, clip_by_global_norm,
@@ -64,46 +65,28 @@ AUDIT_SPEC = {
 # down to. Metadata only: no instruction and no cache key changes with them.
 CLIENT_TRAIN, CLIENT_EVAL, AGGREGATE, METRICS = STAGES = (
     "client_train", "client_eval", "aggregate", "metrics")
-# The second level of scopes, under the stages: the server's own step inside
-# ``aggregate`` (fedtpu.parallel.stateless) and the parts of a model that
-# names its own (fedtpu.models.olmoe.LAYER_SCOPES). The ``program_scopes``
-# event maps operations to them under ``layers``.
+# The second level of scopes, under the stages: the parts of a model, each a
+# name of ``fedtpu.ops.scopes`` (every model's and piece's scope is written
+# there, once), and the server's own step inside ``aggregate``
+# (fedtpu.parallel.stateless). The ``program_scopes`` event maps operations
+# to them under ``layers``.
 SERVER_UPDATE = "server_update"
-LAYERS = ("embed", "attention", "router", "expert_dispatch", "experts",
-          "lm_head_loss", "ssm", "ssm_scan", "shared_expert", "hyper_conn",
-          "dense_mlp", "mtp_proj", "kda", "kda_scan", SERVER_UPDATE)
+LAYERS = (*scopes.LAYERS, SERVER_UPDATE)
 # The third level, inside a layer or outside every one, set where the work
-# happens: the four parts of a state-space mixer around its scan
-# (fedtpu.models.nemotron_h.mamba_mixer), the attention core alone (whichever
-# body of olmoe.attention_core runs; the rest of ``attention`` is the
-# projections'), the Sinkhorn iterations alone inside ``hyper_conn`` and the
-# low-rank projections of latent attention beside its core
-# (fedtpu.models.xing4), the four parts of a KDA mixer around its scan
-# (fedtpu.models.kimi_linear.kda_mixer: the input projections, the three
-# short convolutions, the decay / step / norms / output gate, the output
-# projection), and the one fused pass a step that applies a gradient and
-# adds the step's share to the accumulator (fedtpu.parallel.stateless). The
-# event maps operations to them under ``pieces``.
+# happens: the models' pieces (``scopes.PIECES``), and the one fused pass a
+# step that applies a gradient and adds the step's share to the accumulator
+# (fedtpu.parallel.stateless). The event maps operations to them under
+# ``pieces``.
 SGD_PASS = "sgd_pass"
-PIECES = ("ssm_in_proj", "ssm_conv", "ssm_gate_norm", "ssm_out_proj",
-          "attn_core", "hc_sinkhorn", "attn_latent", "kda_in_proj",
-          "kda_conv", "kda_gates", "kda_out_proj", SGD_PASS)
-# An outer scope AROUND layers: a whole multi-token-prediction module
-# (fedtpu.models.xing4), whose attention, experts and head keep their own
-# layers' names inside it. The event maps operations to it under
-# ``modules``, beside ``layers``, so a metric can read the module whole.
-MODULES = ("mtp",)
-# Not a piece but a direction: a forward pass run again by hand inside a
-# backward rule (nemotron_h._held_experts_bwd) names itself so, as remat's
-# lowering names its own; ``program_scopes`` reads both under ``passes``.
-RECOMPUTE = "recompute"
-# Kernels the TPU's compiler puts in an instruction's place under a name of
-# its own, which replaces the ``op_name`` and with it every scope: whose they
-# are, by the prefix of the instruction's name. ``lax.ragged_dot`` becomes
-# ``ragged-dot-none*`` (and one ``ragged-dot-metadata`` a call), and the only
-# grouped matmuls of a program are its experts'. (The Pallas body of
-# ``olmoe.grouped_matmul`` needs no entry: a Mosaic call keeps its op_name.)
-LAYER_KERNELS = {"ragged-dot": "experts"}
+PIECES = (*scopes.PIECES, SGD_PASS)
+# An outer scope AROUND layers (a multi-token-prediction module): the event
+# maps operations to it under ``modules``, beside ``layers``, so a metric can
+# read the module whole. A direction, not a piece: a forward pass run again
+# by hand inside a backward rule, which ``program_scopes`` reads under
+# ``passes`` as it does remat's own. And the kernels the TPU's compiler
+# names itself, by whose they are. All three are ``scopes``' to say.
+MODULES, RECOMPUTE, LAYER_KERNELS = (scopes.MODULES, scopes.RECOMPUTE,
+                                     scopes.LAYER_KERNELS)
 
 
 # PRNG domain-separation tag for the DP noise stream (vs the participation

@@ -24,6 +24,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from fedtpu.models.registry import LANGUAGE_MODELS
 from fedtpu.ops.losses import masked_cross_entropy
 from fedtpu.ops.metrics import (METRIC_NAMES, confusion_matrix,
                                 metrics_from_confusion)
@@ -69,17 +70,16 @@ def classification_task(apply_fn: Callable, num_classes: int) -> Task:
                 metrics=metrics_from_confusion)
 
 
-LANGUAGE_MODELS = ("olmoe", "nemotron_h", "xing4", "kimi_linear")
 # The weight of a prediction module's loss in the sum that is differentiated:
 # no key of a published config (DeepSeek-V3's, arXiv:2412.19437 section 2.2).
 MTP_LOSS_WEIGHT = 0.3
 
 
 def next_token_task(stats_fn: Callable, model_cfg) -> Task:
-    """``stats_fn(params, x, mask)`` is the model's own
-    (fedtpu.models.olmoe.olmoe_stats, nemotron_h.nemotron_h_stats,
-    xing4.xing4_stats, kimi_linear.kimi_linear_stats): rows ``x (N, 2, T)`` of token and segment ids;
-    labels are the rows' own next tokens, so ``y`` is unused.
+    """``stats_fn(params, x, mask)`` is the model's own (the second of
+    ``fedtpu.models.registry.build_model``'s pair for a language model): rows
+    ``x (N, 2, T)`` of token and segment ids; labels are the rows' own next
+    tokens, so ``y`` is unused.
 
     A model with a multi-token-prediction module (``num_nextn_predict_layers``
     1, xing4's preset) hands out a second loss's sums
@@ -87,7 +87,7 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
     the main loss's mean plus ``MTP_LOSS_WEIGHT`` times the module's, each
     over its own valid positions, and ``main_loss`` / ``mtp_loss`` stand
     beside accuracy and perplexity (the main head's) among the metrics."""
-    from fedtpu.models.olmoe import next_token_targets
+    from fedtpu.ops.lm_head import next_token_targets
 
     second = model_cfg.num_nextn_predict_layers > 0
     mean = lambda total, n: total / jnp.maximum(n, 1.0)

@@ -65,6 +65,10 @@ import pytest  # noqa: E402
 # consistency guards at the bottom of pytest_collection_modifyitems
 # below.
 QUICK_TESTS = {
+    # the seam of a model: the imports' direction, by the source (no backend)
+    "test_model_seam.py::test_the_registry_is_a_table",
+    "test_model_seam.py::"
+    "test_every_scope_is_a_name_of_the_one_file_that_writes_it",
     # OLMoE against the plain reference; the shared-global engine's refusals
     "test_olmoe.py::test_top_k_sets_are_the_references_in_float32",
     # the tiled attention core's table against a numpy count (no kernel)
@@ -412,15 +416,16 @@ def _clear_jax_caches_near_map_limit():
 @pytest.fixture
 def grouped_on_the_cpu(monkeypatch):
     """The whole model through the Pallas body of the expert matmuls
-    (``fedtpu.models.olmoe.grouped_matmul``; the hybrid stack's held experts
-    call it too): the rule between the bodies is steered to it and the
+    (``fedtpu.ops.grouped_matmul``; every held-experts layer calls it too):
+    the rule between the bodies is steered to it and the
     kernels interpreted (always under jit: the interpreter is not for eager
     use)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    from fedtpu.models import olmoe
+    from fedtpu.ops import grouped_matmul
 
-    monkeypatch.setattr(olmoe, "grouped_matmul_applies", lambda xs, w: True)
+    monkeypatch.setattr(grouped_matmul, "grouped_matmul_applies",
+                        lambda xs, w: True)
     with pltpu.force_tpu_interpret_mode():
         yield
 
@@ -429,16 +434,16 @@ def grouped_on_the_cpu(monkeypatch):
 def tiled_passes_interpreted(patch):
     """The hybrid stack's state-space mixers through the tiled bodies of
     their two float32 passes (``fedtpu.ops.ssm_passes``) on the CPU: the
-    rule between the bodies (``nemotron_h.fused_passes_apply``) is steered
+    rule between the bodies (``ssm_passes.fused_passes_apply``) is steered
     to them through ``patch`` (a ``MonkeyPatch``) and the kernels
     interpreted (always under jit, as above). The interpreter works through
     ordered callbacks, which ``jax.checkpoint`` cannot hold, so the stack's
     layers are not recomputed here: that moves no value."""
     from jax.experimental.pallas import tpu as pltpu
 
-    from fedtpu.models import nemotron_h
+    from fedtpu.ops import ssm_passes
 
-    patch.setattr(nemotron_h, "fused_passes_apply", lambda cfg, t: True)
+    patch.setattr(ssm_passes, "fused_passes_apply", lambda cfg, t: True)
     patch.setattr(jax, "checkpoint", lambda fn, **policy: fn)
     with pltpu.force_tpu_interpret_mode():
         yield
@@ -456,13 +461,13 @@ def hyper_passes_on_the_cpu(monkeypatch):
     """The four-stream stack's residual modules through the tiled bodies of
     their passes over the streams (``fedtpu.ops.hyper_conn``) on the CPU, as
     ``tiled_passes_interpreted`` drives the hybrid stack's: the rule between
-    the bodies (``xing4.hyper_passes_apply``) steered to them, the kernels
+    the bodies (``hyper_conn.hyper_passes_apply``) steered to them, the kernels
     interpreted (always under jit), no layer recomputed."""
     from jax.experimental.pallas import tpu as pltpu
 
-    from fedtpu.models import xing4
+    from fedtpu.ops import hyper_conn
 
-    monkeypatch.setattr(xing4, "hyper_passes_apply", lambda x: True)
+    monkeypatch.setattr(hyper_conn, "hyper_passes_apply", lambda x: True)
     monkeypatch.setattr(jax, "checkpoint", lambda fn, **policy: fn)
     with pltpu.force_tpu_interpret_mode():
         yield

@@ -145,8 +145,8 @@ def olmoe_round(topo):
     the CPU here and would pick the XLA bodies, so both cases steer the
     rules themselves."""
     from fedtpu.config import get_preset
-    from fedtpu.models import olmoe
     from fedtpu.models.registry import build_model
+    from fedtpu.ops import grouped_matmul, packed_attention
     from fedtpu.ops.server_opt import make_server_optimizer
     from fedtpu.parallel.stateless import build_stateless_round_fn
     from fedtpu.training.task import build_task
@@ -173,9 +173,9 @@ def olmoe_round(topo):
     def compiled(fused: bool):
         if fused not in done:
             with pytest.MonkeyPatch.context() as patch:
-                patch.setattr(olmoe, "fused_attention_applies",
+                patch.setattr(packed_attention, "fused_attention_applies",
                               lambda q, k, v: fused)
-                patch.setattr(olmoe, "grouped_matmul_applies",
+                patch.setattr(grouped_matmul, "grouped_matmul_applies",
                               lambda xs, w: fused)
                 step = build_stateless_round_fn(
                     mesh, build_task(cfg.model, stats_fn, cfg.model.vocab_size),
@@ -316,7 +316,7 @@ def test_the_grouped_matmul_picks_its_body_by_shape_on_a_tpu(
     gradients compile to the three kernels and no ``ragged-dot``; at rows
     that are no whole tile, or a width there is no tile for, to
     ``ragged-dot`` and no such kernel."""
-    from fedtpu.models import olmoe
+    from fedtpu.ops.grouped_matmul import grouped_matmul
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     one = SingleDeviceSharding(topo.devices[0])
@@ -324,7 +324,7 @@ def test_the_grouped_matmul_picks_its_body_by_shape_on_a_tpu(
 
     def with_gradients(xs, w, c, sizes):
         return jax.value_and_grad(
-            lambda xs, w: (olmoe.grouped_matmul(xs, w, sizes) * c).sum(),
+            lambda xs, w: (grouped_matmul(xs, w, sizes) * c).sum(),
             argnums=(0, 1))(xs, w)
 
     compiled = jax.jit(with_gradients).lower(  # fedtpu: noqa[FTP006] one-shot AOT compile
@@ -376,7 +376,7 @@ def test_fused_attention_core_compiles_for_v5e_without_the_scores(topo):
     kernels and under 300 MB of temporaries (53 MB when this was written)
     where the XLA body keeps over 2,000 (2,182: the scores, the masked
     scores, the probabilities)."""
-    from fedtpu.models import olmoe
+    from fedtpu.ops.packed_attention import _fused_attention, _xla_attention
 
     one = SingleDeviceSharding(topo.devices[0])
     qkv = jax.ShapeDtypeStruct((4096, 16, 128), jnp.bfloat16, sharding=one)
@@ -390,7 +390,7 @@ def test_fused_attention_core_compiles_for_v5e_without_the_scores(topo):
         return jax.jit(with_gradients).lower(  # fedtpu: noqa[FTP006] one-shot AOT compile
             qkv, qkv, qkv, qkv, segs).compile()
 
-    fused, xla = compiled(olmoe._fused_attention), compiled(olmoe._xla_attention)
+    fused, xla = compiled(_fused_attention), compiled(_xla_attention)
     assert _mosaic_calls(fused) == 3 and _mosaic_calls(xla) == 0
     assert fused.memory_analysis().temp_size_in_bytes < 300e6
     assert xla.memory_analysis().temp_size_in_bytes > 2000e6

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
-from fedtpu.models import xing4
+from fedtpu.models import layers, xing4
 from fedtpu.ops import hyper_conn as kernels
 from tests.test_xing4 import TINY, packed_row, seeded
 
@@ -72,7 +72,7 @@ def test_the_kernels_are_the_definitions(scales, res_bias, padding,
     padding: ``1 / rms`` is ``eps^-1/2`` there) stays finite."""
     x, y0, module = _case(scales, res_bias, padding)
     want, want_off, want_d = _sublayer_with_gradients(x, y0, module)
-    monkeypatch.setattr(xing4, "hyper_passes_apply", lambda x: True)
+    monkeypatch.setattr(kernels, "hyper_passes_apply", lambda x: True)
     with pltpu.force_tpu_interpret_mode():
         ours, off, ours_d = _sublayer_with_gradients(x, y0, module)
     np.testing.assert_allclose(np.asarray(ours), np.asarray(want), rtol=0,
@@ -97,16 +97,16 @@ def test_the_rule_between_the_bodies(monkeypatch):
     streams = lambda n, t, c, dtype=jnp.float32: jax.ShapeDtypeStruct(
         (n, t, c), dtype)
     cell = streams(4, 4096, 3584)
-    assert not xing4.hyper_passes_apply(cell)           # this is a CPU
+    assert not kernels.hyper_passes_apply(cell)           # this is a CPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert xing4.hyper_passes_apply(cell)
-    assert xing4.hyper_passes_apply(streams(4, 2048, 3584))
-    assert xing4.hyper_passes_apply(streams(2, 64, 128))
-    assert not xing4.hyper_passes_apply(streams(4, 4096, 3584, jnp.bfloat16))
-    assert not xing4.hyper_passes_apply(streams(4, 4096 + 32, 3584))
-    assert not xing4.hyper_passes_apply(streams(4, 4096, 3584 + 64))
-    assert not xing4.hyper_passes_apply(streams(4, 4096, 48))
-    assert not xing4.hyper_passes_apply(streams(16, 4096, 3584))    # memory
+    assert kernels.hyper_passes_apply(cell)
+    assert kernels.hyper_passes_apply(streams(4, 2048, 3584))
+    assert kernels.hyper_passes_apply(streams(2, 64, 128))
+    assert not kernels.hyper_passes_apply(streams(4, 4096, 3584, jnp.bfloat16))
+    assert not kernels.hyper_passes_apply(streams(4, 4096 + 32, 3584))
+    assert not kernels.hyper_passes_apply(streams(4, 4096, 3584 + 64))
+    assert not kernels.hyper_passes_apply(streams(4, 4096, 48))
+    assert not kernels.hyper_passes_apply(streams(16, 4096, 3584))    # memory
     assert kernels.columns(4) == 32 and kernels.columns(2) == 16
 
 
@@ -119,7 +119,7 @@ def test_a_block_on_the_kernels_is_the_plain_block(hyper_passes_on_the_cpu,
     params = seeded(TINY)["experts"][0]
     row = jnp.asarray(packed_row(np.random.default_rng(0), (30, 20, 9)))
     segs = row[1]
-    pos = xing4.segment_positions(segs)
+    pos = layers.segment_positions(segs)
     x = jax.random.normal(jax.random.key(5), (TINY.hc_mult, row.shape[1],
                                               TINY.hidden_size))
 
@@ -131,7 +131,7 @@ def test_a_block_on_the_kernels_is_the_plain_block(hyper_passes_on_the_cpu,
     both = lambda: jax.jit(jax.value_and_grad(total, argnums=(0, 1),
                                               has_aux=True))
     (_, (ours, stats)), ours_d = both()(x, params)
-    monkeypatch.setattr(xing4, "hyper_passes_apply", lambda x: False)
+    monkeypatch.setattr(kernels, "hyper_passes_apply", lambda x: False)
     (_, (want, want_stats)), want_d = both()(x, params)
     np.testing.assert_allclose(np.asarray(ours), np.asarray(want), rtol=0,
                                atol=1e-5)

@@ -1,6 +1,6 @@
 """The delta-rule recurrence's tiled kernels (fedtpu.ops.kda_scan), interpreted
 on the CPU, against the two things that say what they compute: the chunked XLA
-form ``kimi_linear.kda_scan`` (the definition, and the body wherever the
+form ``kda_scan.kda_scan`` (the definition, and the body wherever the
 kernels do not exist) and the reference's token-by-token recurrence. Values
 and the gradient of every input in float32 at the tolerances the definition's
 own test holds, over its hard cases; what bfloat16 products move and what a
@@ -13,8 +13,8 @@ import pytest
 from jax.experimental.pallas import tpu as pltpu
 
 from fedtpu.models import kimi_linear as kl
-from fedtpu.models import nemotron_h as nh
 from fedtpu.ops import kda_scan as kernels
+from fedtpu.ops import ssm_passes
 from perfbench import reference_kimi_linear as ref
 from tests.test_kimi_linear import (ONE, SEVERAL, T, TINY, _scan_inputs,
                                     seeded)
@@ -27,7 +27,7 @@ PADDING = [0] * T
 def _kernel(run, chunk, sub, dtype):
     """The kernels' ``kda_scan`` of one row, jitted (the interpreter is
     driven under ``jax.jit`` alone: SKILL.md)."""
-    return jax.jit(lambda *a: kernels.kda_scan(*a, run, chunk, sub, dtype))
+    return jax.jit(lambda *a: kernels.fused_kda_scan(*a, run, chunk, sub, dtype))
 
 
 def _with_gradients(fn, inputs, weigh):
@@ -66,12 +66,12 @@ def test_the_kernels_are_the_definition_and_the_token_by_token_recurrence(
     one sub-chunk (no level) and of eight (three levels)."""
     segs = jnp.asarray(segs, jnp.int32)
     *inputs, weigh = _scan_inputs(segs, strength, bias=bias)
-    run, starts = nh.document_runs(segs)
+    run, starts = ssm_passes.document_runs(segs)
     with pltpu.force_tpu_interpret_mode():
         ours, ours_d = _with_gradients(
             _kernel(run, chunk, sub, jnp.float32), inputs, weigh)
     for name, other in (
-            ("definition", lambda *a: kl.kda_scan(*a, run, chunk, jnp.float32,
+            ("definition", lambda *a: kernels.kda_scan(*a, run, chunk, jnp.float32,
                                                   sub)),
             ("recurrence", lambda *a: ref.kda_recurrence(*a, starts))):
         theirs, theirs_d = _with_gradients(other, inputs, weigh)
@@ -91,7 +91,7 @@ def test_bfloat16_products_stay_near_the_float32_ones_in_the_kernels():
     move the result, by under 3% of its size, and the gradients likewise."""
     segs = jnp.asarray(SEVERAL, jnp.int32)
     *inputs, weigh = _scan_inputs(segs, 0.3, seed=2)
-    run = nh.document_runs(segs)[0]
+    run = ssm_passes.document_runs(segs)[0]
     with pltpu.force_tpu_interpret_mode():
         exact, exact_d = _with_gradients(
             _kernel(run, 64, 16, jnp.float32), inputs, weigh)
@@ -109,7 +109,7 @@ def test_a_bfloat16_state_is_told_apart_at_ten_tolerances(monkeypatch):
     rounded to bfloat16 the float32 comparison fails ten times over."""
     segs = jnp.asarray(ONE, jnp.int32)
     *inputs, _ = _scan_inputs(segs, 0.1)
-    run, starts = nh.document_runs(segs)
+    run, starts = ssm_passes.document_runs(segs)
     chunk = kernels._forward_chunk
 
     def rounded_state(*args):
@@ -128,7 +128,7 @@ def test_the_rule_between_the_bodies(monkeypatch):
     TPU yes at the cell's shapes and no at a head 64 wide, at a row that is
     not whole chunks and at a chunk that is not whole sub-chunks doubling up
     to it; and where it says no the XLA form runs, where yes the kernels."""
-    applies = lambda t, d, chunk=64, sub=16: kl.fused_scan_applies(
+    applies = lambda t, d, chunk=64, sub=16: kernels.fused_scan_applies(
         t, d, d, chunk, sub)
     assert jax.default_backend() == "cpu" and not applies(4096, 128)
 
@@ -137,20 +137,20 @@ def test_the_rule_between_the_bodies(monkeypatch):
 
     segs = jnp.asarray(SEVERAL, jnp.int32)
     *inputs, _ = _scan_inputs(segs, 0.1)
-    run = nh.document_runs(segs)[0]
+    run = ssm_passes.document_runs(segs)[0]
     with monkeypatch.context() as patch:
-        patch.setattr(kernels, "kda_scan", never)
-        theirs = kl.kda_scan(*inputs, run, 64, jnp.float32)     # the XLA form
+        patch.setattr(kernels, "fused_kda_scan", never)
+        theirs = kernels.kda_scan(*inputs, run, 64, jnp.float32)     # the XLA form
         patch.setattr(jax, "default_backend", lambda: "tpu")
         assert applies(4096, 128) and applies(8192, 256)
         assert not applies(4096, 64)
         assert not applies(4096 + 32, 128)
         assert not applies(4096, 128, chunk=48) and not applies(4096, 128, sub=12)
         assert bool(jnp.array_equal(
-            kl.kda_scan(*inputs, run, 64, jnp.float32), theirs))   # d = 8
+            kernels.kda_scan(*inputs, run, 64, jnp.float32), theirs))   # d = 8
     # told the shapes have tiles, the same call goes through the kernels
-    monkeypatch.setattr(kl, "fused_scan_applies", lambda *a: True)
-    through = jax.jit(lambda *a: kl.kda_scan(*a, run, 64, jnp.float32))
+    monkeypatch.setattr(kernels, "fused_scan_applies", lambda *a: True)
+    through = jax.jit(lambda *a: kernels.kda_scan(*a, run, 64, jnp.float32))
     with pltpu.force_tpu_interpret_mode():
         ours = through(*inputs)
     np.testing.assert_allclose(np.asarray(ours), np.asarray(theirs), rtol=0,
@@ -180,7 +180,7 @@ def test_a_mixer_on_the_kernels_and_its_gates_on_their_tiles_is_the_plain_one(
         return program(h, layer)
 
     (_, (plain, plain_stats)), plain_d = both()
-    monkeypatch.setattr(kl, "fused_scan_applies", lambda *sizes: True)
+    monkeypatch.setattr(kernels, "fused_scan_applies", lambda *sizes: True)
     with pltpu.force_tpu_interpret_mode():
         (_, (tiled, tiled_stats)), tiled_d = both()
     assert float(plain_stats["kda_fused_scan"]) == 0.0
@@ -198,7 +198,7 @@ def test_a_mixer_on_the_kernels_and_its_gates_on_their_tiles_is_the_plain_one(
 
 
 def test_how_far_back_a_positions_run_reaches():
-    run = nh.document_runs(jnp.asarray([3, 3, 3, 5, 5, 0, 0, 2], jnp.int32))[0]
+    run = ssm_passes.document_runs(jnp.asarray([3, 3, 3, 5, 5, 0, 0, 2], jnp.int32))[0]
     assert kernels.positions_back(run, 4).T.tolist() == [
         [0, 1, 2, 0, 1, 0, 1, 0], [0, 0, 0, 0, 0, 0, 0, 0]]
     assert kernels.positions_back(run, 8)[:, 1].tolist() == [0] * 8

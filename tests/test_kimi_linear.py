@@ -21,9 +21,9 @@ import pytest
 
 from fedtpu.config import ModelConfig, TelemetryConfig, get_preset
 from fedtpu.models import kimi_linear as kl
-from fedtpu.models import nemotron_h as nh
-from fedtpu.models import xing4
+from fedtpu.models import layers
 from fedtpu.models.registry import build_model
+from fedtpu.ops import kda_scan, ssm_passes
 from fedtpu.orchestration.loop import build_experiment, run_experiment
 from fedtpu.training.task import build_task
 from perfbench import flops_kimi_linear, reference_kimi_linear as ref
@@ -144,12 +144,12 @@ def test_the_chunked_recurrence_is_the_token_by_token_one(segs, chunk, sub,
     1e28 (the chip's first run of the cell was not a number)."""
     segs = jnp.asarray(segs, jnp.int32)
     *inputs, weigh = _scan_inputs(segs, strength, bias=bias)
-    run, starts = nh.document_runs(segs)
+    run, starts = ssm_passes.document_runs(segs)
     if strength > 1:
         deepest = np.asarray(inputs[3]).reshape(-1, chunk, 2, 8).sum(1).min()
         with np.errstate(over="ignore"):
             assert deepest < -100 and np.isinf(np.exp(np.float32(-deepest)))
-    chunked = lambda *a: kl.kda_scan(*a, run, chunk, jnp.float32, sub)
+    chunked = lambda *a: kda_scan.kda_scan(*a, run, chunk, jnp.float32, sub)
     plain = lambda *a: ref.kda_recurrence(*a, starts)
     total = lambda fn: lambda *a: (fn(*a) * weigh).sum()
     np.testing.assert_allclose(np.asarray(chunked(*inputs)),
@@ -170,10 +170,11 @@ def test_a_document_packed_behind_another_scans_as_it_does_alone():
     same tokens in a row of their own."""
     segs = jnp.asarray([1] * 37 + [2] * 91, jnp.int32)
     *inputs, _ = _scan_inputs(segs, 0.5, seed=1)
-    both = kl.kda_scan(*inputs, nh.document_runs(segs)[0], 64, jnp.float32)
-    alone = kl.kda_scan(*(jnp.pad(a[37:], ((0, 37),) + ((0, 0),) * (a.ndim - 1))
+    both = kda_scan.kda_scan(*inputs, ssm_passes.document_runs(segs)[0], 64,
+                             jnp.float32)
+    alone = kda_scan.kda_scan(*(jnp.pad(a[37:], ((0, 37),) + ((0, 0),) * (a.ndim - 1))
                           for a in inputs),
-                        nh.document_runs(jnp.asarray([1] * 91 + [0] * 37))[0],
+                        ssm_passes.document_runs(jnp.asarray([1] * 91 + [0] * 37))[0],
                         64, jnp.float32)
     np.testing.assert_allclose(np.asarray(both[37:]), np.asarray(alone[:91]),
                                rtol=0, atol=2e-6)
@@ -182,9 +183,9 @@ def test_a_document_packed_behind_another_scans_as_it_does_alone():
 def test_bfloat16_products_stay_near_the_float32_ones():
     segs = jnp.asarray(SEVERAL, jnp.int32)
     *inputs, _ = _scan_inputs(segs, 0.3, seed=2)
-    run = nh.document_runs(segs)[0]
-    exact = kl.kda_scan(*inputs, run, 64, jnp.float32)
-    rounded = kl.kda_scan(*inputs, run, 64, jnp.bfloat16)
+    run = ssm_passes.document_runs(segs)[0]
+    exact = kda_scan.kda_scan(*inputs, run, 64, jnp.float32)
+    rounded = kda_scan.kda_scan(*inputs, run, 64, jnp.bfloat16)
     assert rounded.dtype == jnp.float32
     assert 1e-6 < float(jnp.abs(exact - rounded).max()) < 0.03 * float(
         jnp.abs(exact).max())
@@ -197,12 +198,12 @@ def test_the_inverse_of_a_unit_lower_triangular_matrix_and_its_gradient():
         # before they cancel, and the substitution does not form them
         low = jnp.asarray(np.tril(0.5 + rng.normal(size=(3, c, c)) * 0.1, -1),
                           jnp.float32)
-        inv = kl.unit_lower_inverse(low)
+        inv = kda_scan.unit_lower_inverse(low)
         np.testing.assert_allclose(
             np.asarray(inv @ (jnp.eye(c) + low)),
             np.broadcast_to(np.eye(c), (3, c, c)), rtol=0, atol=2e-5)
         weigh = jnp.asarray(rng.normal(size=(3, c, c)), jnp.float32)
-        ours = jax.grad(lambda m: (kl.unit_lower_inverse(m) * weigh).sum())(low)
+        ours = jax.grad(lambda m: (kda_scan.unit_lower_inverse(m) * weigh).sum())(low)
         theirs = jax.grad(lambda m: (jnp.linalg.inv(jnp.eye(c) + m)
                                      * weigh).sum())(low)
         scale = max(float(jnp.abs(theirs).max()), 1.0)
@@ -359,13 +360,13 @@ def test_latent_attention_without_bottleneck_or_positions_is_the_references():
     assert set(layer) == {"norm", "q", "kv_a", "kv_a_norm", "kv_b", "o"}
     h = jax.random.normal(jax.random.key(4), (T, 48))
     segs = jnp.asarray([1] * 50 + [2] * 60 + [0] * 18, jnp.int32)
-    ours = xing4.latent_attention(TINY, jnp.float32, h, layer, segs, None)
+    ours = layers.latent_attention(TINY, jnp.float32, h, layer, segs, None)
     with jax.default_matmul_precision("highest"):
         theirs = ref.attention(layer, ref._rms(h, layer["norm"], 1e-5), segs,
                                ref_cfg(TINY))
     np.testing.assert_allclose(np.asarray(ours), np.asarray(theirs), rtol=0,
                                atol=1e-5)
-    alone = xing4.latent_attention(
+    alone = layers.latent_attention(
         TINY, jnp.float32, jnp.pad(h[50:110], ((0, 68), (0, 0))), layer,
         jnp.asarray([1] * 60 + [0] * 68, jnp.int32), None)
     np.testing.assert_allclose(np.asarray(ours[50:110]),
@@ -373,7 +374,7 @@ def test_latent_attention_without_bottleneck_or_positions_is_the_references():
     # with positions the same layer gives another result (at scores large
     # enough to tell): nothing rotates here
     loud = {**layer, "q": 8 * layer["q"], "kv_a": 8 * layer["kv_a"]}
-    plain, rotated = (xing4.latent_attention(
+    plain, rotated = (layers.latent_attention(
         dataclasses.replace(TINY, mla_use_nope=nope), jnp.float32, h, loud,
         segs, jnp.arange(T)) for nope in (True, False))
     assert float(jnp.abs(plain - rotated).max()) > 1e-3
@@ -388,7 +389,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     whole = dataclasses.replace(TINY, experts_held=0, first_expert=0)
     key = jax.random.key(7)
     count = iter(range(100))
-    layer = xing4._ffn_init(
+    layer = layers._ffn_init(
         "experts", whole, lambda *s: 0.3 * jax.random.normal(
             jax.random.fold_in(key, next(count)), s),
         lambda *s: jnp.ones(s))
@@ -405,7 +406,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
         share = dataclasses.replace(TINY, experts_held=4, first_expert=first)
         part = {**layer, **{name: layer[name][first:first + 4]
                             for name in ("gate", "up", "down")}}
-        out, stats = nh.experts_mixer(share, jnp.float32, h, part, segs,
+        out, stats = layers.experts_mixer(share, jnp.float32, h, part, segs,
                                       eps=1e-5)
         total, held_sum = total + out, held_sum + stats["assignments_held"]
         with jax.default_matmul_precision("highest"):
@@ -510,7 +511,7 @@ def test_the_recurrences_kernels_keep_the_scans_scope_in_a_tiny_round(
     from fedtpu.analysis.program import BACKWARD, _pass_of, _stage_of
     from fedtpu.parallel.round import LAYERS
 
-    monkeypatch.setattr(kl, "fused_scan_applies", lambda *shapes: True)
+    monkeypatch.setattr(kda_scan, "fused_scan_applies", lambda *shapes: True)
     monkeypatch.setattr(jax, "checkpoint", lambda fn, **policy: fn)
     with pltpu.force_tpu_interpret_mode():
         exp = build_experiment(tiny_kimi_linear())
@@ -542,5 +543,5 @@ def test_what_the_registry_refuses():
     with pytest.raises(ValueError, match="not among the 16"):
         build_model(dataclasses.replace(TINY, first_expert=14))
     with pytest.raises(ValueError, match="not whole chunks"):
-        kl.kda_scan(*_scan_inputs([1] * 72, 0.1)[:5],
+        kda_scan.kda_scan(*_scan_inputs([1] * 72, 0.1)[:5],
                     jnp.ones((72,), jnp.int32), 64, jnp.float32)

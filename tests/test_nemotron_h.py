@@ -18,6 +18,7 @@ import pytest
 from jax.experimental.pallas import tpu as pltpu
 
 from fedtpu.config import ModelConfig, get_preset
+from fedtpu.models import layers
 from fedtpu.models import nemotron_h as nh
 from fedtpu.models.registry import build_model
 from fedtpu.ops import ssm_passes
@@ -232,7 +233,7 @@ def test_the_references_router_is_transformers_deepseek_v3_router():
                            3, True, 2.5)
     np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=1e-6)
     # and the program's: the same experts, the same weights
-    gates, experts = nh.route(jnp.asarray(x.numpy()),
+    gates, experts = layers.route(jnp.asarray(x.numpy()),
                               jnp.asarray(router.weight.detach().numpy()).T,
                               jnp.asarray(router.e_score_correction_bias.numpy()),
                               3, True, 2.5)
@@ -258,7 +259,7 @@ def test_two_packed_documents_scan_as_the_two_alone(chunk):
     divide both, chunks of 16 and 32 straddle the edge."""
     x, dt, a, b, c = _scan_inputs(64)
     segs = jnp.asarray([1] * 24 + [2] * 40, jnp.int32)
-    run, _ = nh.document_runs(segs)
+    run, _ = ssm_passes.document_runs(segs)
     packed = nh.ssd_scan(x, dt, a, b, c, run, chunk, jnp.float32)
     ones = lambda n: jnp.ones((n,), jnp.int32)
     first = nh.ssd_scan(x[:24], dt[:24], a, b[:24], c[:24], ones(24),
@@ -288,7 +289,7 @@ def test_the_convolution_does_not_read_across_a_documents_edge(
     w = jax.random.normal(jax.random.key(4), (4, 6))
     bias = jax.random.normal(jax.random.key(5), (6,))
     segs = jnp.asarray([1] * 24 + [2] * 37 + [0] * 3, jnp.int32)
-    run, starts = nh.document_runs(segs)
+    run, starts = ssm_passes.document_runs(segs)
     if body == "tiled":
         after = jax.nn.silu
         monkeypatch.setattr(ssm_passes, "CONV_TILE", (16, 6))
@@ -298,9 +299,9 @@ def test_the_convolution_does_not_read_across_a_documents_edge(
             packed = tiled(x, run)
     else:
         after = lambda pre: pre
-        packed = nh.causal_conv(x, w, bias, run)
+        packed = ssm_passes.causal_conv(x, w, bias, run)
     ones = lambda n: jnp.ones((n,), jnp.int32)
-    alone = [after(nh.causal_conv(x[lo:hi], w, bias, ones(hi - lo)))
+    alone = [after(ssm_passes.causal_conv(x[lo:hi], w, bias, ones(hi - lo)))
              for lo, hi in ((0, 24), (24, 61), (61, 64))]
     np.testing.assert_allclose(np.asarray(packed), np.concatenate(alone),
                                rtol=0, atol=1e-6)
@@ -364,10 +365,10 @@ def test_the_tiled_convolution_is_its_definition(where, first, monkeypatch):
     w = jax.random.normal(keys[1], (4, width))
     bias = jax.random.normal(keys[2], (width,))
     weigh = jax.random.normal(keys[3], (T, width + width_t))
-    run, _ = nh.document_runs(_segments(T, **RUN_EDGES[where]))
+    run, _ = ssm_passes.document_runs(_segments(T, **RUN_EDGES[where]))
 
     def definition(src, w, bias):
-        out = jax.nn.silu(nh.causal_conv(src[:, first:first + width], w,
+        out = jax.nn.silu(ssm_passes.causal_conv(src[:, first:first + width], w,
                                          bias, run))
         return jnp.concatenate([out, out[:, :width_t]], axis=1)
 
@@ -410,7 +411,7 @@ def test_the_tiled_gate_and_norm_is_its_definition(tile, dtype, monkeypatch):
     def definition(y, xs, zs, skip, gain):
         x = xs[:, :width].reshape(T, heads, -1)
         v = (y.reshape(T, heads, -1) + skip[:, None] * x).reshape(T, width)
-        return nh.gated_group_norm(v, zs[:, :width], gain, groups,
+        return ssm_passes.gated_group_norm(v, zs[:, :width], gain, groups,
                                    eps).astype(dtype)
 
     monkeypatch.setattr(ssm_passes, "GATE_TILE", tile)
@@ -461,7 +462,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     the uncut reference layer's."""
     whole = dataclasses.replace(TINY, experts_held=0, first_expert=0)
     key = jax.random.key(7)
-    layer = nh._experts_init(
+    layer = layers._experts_init(
         whole, lambda *s: 0.3 * jax.random.normal(
             jax.random.fold_in(key, sum(s) + len(s)), s),
         lambda *s: jnp.ones(s), key, jnp.float32)
@@ -477,7 +478,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
         share = dataclasses.replace(TINY, experts_held=4, first_expert=first)
         part = {**layer, "up": layer["up"][first:first + 4],
                 "down": layer["down"][first:first + 4]}
-        out, stats = nh.experts_mixer(share, jnp.float32, h, part, segs)
+        out, stats = layers.experts_mixer(share, jnp.float32, h, part, segs)
         total, held_sum = total + out, held_sum + stats["assignments_held"]
         # the reference given the same share gives the same part
         with jax.default_matmul_precision("highest"):
@@ -508,10 +509,10 @@ def test_the_held_buffer_is_exact_in_any_number_of_blocks(
         request.getfixturevalue("grouped_on_the_cpu")
     # the tiled kernels take whole row tiles, as ``held_block_rows`` gives
     rows = rows if body == "grouped" else min(rows, 3 * tokens)
-    monkeypatch.setattr(nh, "held_block_rows", lambda a, share: rows)
+    monkeypatch.setattr(layers, "held_block_rows", lambda a, share: rows)
     cfg = dataclasses.replace(TINY, experts_held=8, first_expert=2)
     key = jax.random.key(11)
-    layer = nh._experts_init(
+    layer = layers._experts_init(
         cfg, lambda *s: 0.3 * jax.random.normal(
             jax.random.fold_in(key, sum(s) + len(s)), s),
         lambda *s: jnp.ones(s), key, jnp.float32)
@@ -520,7 +521,7 @@ def test_the_held_buffer_is_exact_in_any_number_of_blocks(
     segs = jnp.asarray([1] * real + [0] * (tokens - real), jnp.int32)
 
     def mine(layer, h):
-        out, stats = nh.experts_mixer(cfg, jnp.float32, h, layer, segs)
+        out, stats = layers.experts_mixer(cfg, jnp.float32, h, layer, segs)
         return (out[:real] ** 2).sum(), stats
 
     def theirs(layer, h):
@@ -553,7 +554,7 @@ def test_the_counter_says_which_body_the_held_experts_ran(body, request):
     ran, none where ``lax.ragged_dot`` did (a CPU, by itself)."""
     if body == "grouped":
         request.getfixturevalue("grouped_on_the_cpu")
-    sequence_stats = jax.jit(lambda p, r: nh.nemotron_h_sequence_stats(
+    sequence_stats = jax.jit(lambda p, r: nh.sequence_stats(
         p, r, TINY, jnp.float32))
     stats = sequence_stats(seeded(TINY), rows_of()[0])
     assert int(stats["grouped_experts"]) == (T if body == "grouped" else 0)
@@ -568,7 +569,7 @@ def test_the_counter_says_which_body_the_two_passes_ran(body, request):
     ``ssm_fused_pass_positions``."""
     if body == "tiled":
         request.getfixturevalue("tiled_passes_on_the_cpu")
-    sequence_stats = jax.jit(lambda p, r: nh.nemotron_h_sequence_stats(
+    sequence_stats = jax.jit(lambda p, r: nh.sequence_stats(
         p, r, TINY, jnp.float32))
     stats = sequence_stats(seeded(TINY), rows_of()[0])
     assert int(stats["ssm_fused_passes"]) == (T if body == "tiled" else 0)
@@ -585,18 +586,18 @@ def test_the_rule_between_the_passes_bodies_reads_shapes_and_the_backend(
     width, ``xBC``'s and a group of the norm whole lane tiles; nowhere on
     a CPU."""
     cell = get_preset("nemotron-h-30b-a3b-l9").model
-    assert not nh.fused_passes_apply(cell, 8192)         # this is a CPU
+    assert not ssm_passes.fused_passes_apply(cell, 8192)         # this is a CPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert nh.fused_passes_apply(cell, 8192)
-    assert nh.fused_passes_apply(cell, 1024)
-    assert not nh.fused_passes_apply(cell, 8192 + 256)   # no whole row tile
-    assert not nh.fused_passes_apply(TINY, 1024)         # widths of 64, 128
+    assert ssm_passes.fused_passes_apply(cell, 8192)
+    assert ssm_passes.fused_passes_apply(cell, 1024)
+    assert not ssm_passes.fused_passes_apply(cell, 8192 + 256)   # no whole row tile
+    assert not ssm_passes.fused_passes_apply(TINY, 1024)         # widths of 64, 128
     narrow = dataclasses.replace(cell, n_groups=64)      # a group of 64
-    assert not nh.fused_passes_apply(narrow, 8192)
+    assert not ssm_passes.fused_passes_apply(narrow, 8192)
     odd = dataclasses.replace(cell, ssm_state_size=100)  # xBC 5,696 wide
-    assert not nh.fused_passes_apply(odd, 8192)
+    assert not ssm_passes.fused_passes_apply(odd, 8192)
     long = dataclasses.replace(cell, conv_kernel=12)     # past a halo block
-    assert not nh.fused_passes_apply(long, 8192)
+    assert not ssm_passes.fused_passes_apply(long, 8192)
 
 
 # ------------------------------------------------ (e) the vocabulary slice
@@ -691,7 +692,7 @@ def test_the_registry_refuses_what_the_stack_cannot_run(change, message):
 
 def test_the_held_block_is_whole_tiles_at_eight_thirds_of_the_mean():
     # the benchmark's step: 8,192 tokens x 6 choices, 8 of 128 experts held
-    assert nh.held_block_rows(8192 * 6, 8 / 128) == 8192
-    assert nh.held_block_rows(4096 * 6, 8 / 128) == 4096
-    assert nh.held_block_rows(64 * 3, 4 / 16) == 256        # one tile at least
-    assert nh.held_block_rows(8192 * 6, 1.0) == 8192 * 6    # never past all
+    assert layers.held_block_rows(8192 * 6, 8 / 128) == 8192
+    assert layers.held_block_rows(4096 * 6, 8 / 128) == 4096
+    assert layers.held_block_rows(64 * 3, 4 / 16) == 256        # one tile at least
+    assert layers.held_block_rows(8192 * 6, 1.0) == 8192 * 6    # never past all

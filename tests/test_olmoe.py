@@ -11,8 +11,11 @@ import pytest
 from jax.experimental.pallas import tpu as pltpu
 
 from fedtpu.config import ModelConfig
-from fedtpu.models import olmoe
+from fedtpu.models import layers, olmoe
 from fedtpu.models.registry import build_model
+from fedtpu.ops import grouped_matmul as grouped
+from fedtpu.ops import lm_head
+from fedtpu.ops import packed_attention as attn
 from perfbench import reference_lm
 
 TINY = ModelConfig(kind="olmoe", hidden_size=32, num_attention_heads=4,
@@ -21,14 +24,14 @@ TINY = ModelConfig(kind="olmoe", hidden_size=32, num_attention_heads=4,
 T = 32
 # The size at which the fused attention body exists (lane-wide heads, whole
 # blocks), two of its blocks long; the rest as tiny as TINY.
-FUSED_T, FUSED_HEADS, FUSED_D = 2 * olmoe.ATTENTION_BLOCK, 2, 128
+FUSED_T, FUSED_HEADS, FUSED_D = 2 * attn.ATTENTION_BLOCK, 2, 128
 FUSED = ModelConfig(kind="olmoe", hidden_size=FUSED_HEADS * FUSED_D,
                     num_attention_heads=FUSED_HEADS, num_hidden_layers=1,
                     num_experts=8, num_experts_per_tok=2,
                     intermediate_size=16, vocab_size=64)
 # The size at which the grouped expert kernels exist (lane-wide widths, whole
 # row tiles): top-2 of 8 experts over four row tiles of assignments.
-GROUPED_T = 2 * olmoe.GROUPED_ROW_TILE
+GROUPED_T = 2 * grouped.GROUPED_ROW_TILE
 GROUPED = ModelConfig(kind="olmoe", hidden_size=128, num_attention_heads=4,
                       num_hidden_layers=1, num_experts=8,
                       num_experts_per_tok=2, intermediate_size=128,
@@ -41,7 +44,7 @@ REF_CFG = {k: getattr(TINY, k) for k in
 @pytest.fixture(autouse=True)
 def _small_loss_chunks(monkeypatch):
     # four chunks of the 32-token rows, so the chunked loss is what runs
-    monkeypatch.setattr(olmoe, "LOSS_CHUNK", 8)
+    monkeypatch.setattr(lm_head, "LOSS_CHUNK", 8)
 
 
 def _row(seed, docs=(12, 14), t=T, vocab=TINY.vocab_size):
@@ -72,7 +75,7 @@ def _params(cfg=TINY, seed=0):
 
 def _sys_loss(cfg, dtype):
     def f(p, row):
-        s = olmoe.olmoe_sequence_stats(p, row, cfg, dtype)
+        s = olmoe.sequence_stats(p, row, cfg, dtype)
         return s["loss_sum"] / jnp.maximum(s["count"], 1.0), s
     return f
 
@@ -186,7 +189,7 @@ def test_dropless_under_a_skew_over_four_times_the_mean(experts, request):
 
 def _summed_loss_and_gradients(cfg):
     def total(p, rows):
-        s = [olmoe.olmoe_sequence_stats(p, r, cfg) for r in rows]
+        s = [olmoe.sequence_stats(p, r, cfg) for r in rows]
         return sum(x["loss_sum"] for x in s), s
     return jax.value_and_grad(total, has_aux=True)
 
@@ -196,7 +199,7 @@ def fused_on_the_cpu(monkeypatch):
     """The whole model through the fused attention body: the rule between
     the bodies is steered to it and the kernel interpreted (always under
     jit: the interpreter is not for eager use)."""
-    monkeypatch.setattr(olmoe, "fused_attention_applies", lambda q, k, v: True)
+    monkeypatch.setattr(attn, "fused_attention_applies", lambda q, k, v: True)
     with pltpu.force_tpu_interpret_mode():
         yield
 
@@ -245,21 +248,21 @@ def test_depth_two_scanned_is_two_blocks_by_hand(experts, request):
         ref, _ = reference(p, row)
     if experts == "grouped":
         request.getfixturevalue("grouped_on_the_cpu")
-    scanned = jax.jit(lambda p, row: olmoe.olmoe_sequence_stats(p, row, cfg))
+    scanned = jax.jit(lambda p, row: olmoe.sequence_stats(p, row, cfg))
     got = scanned(p, row)
 
     @jax.jit
     def by_hand(p, row):
         tokens, segs = row
-        pos = olmoe.segment_positions(segs)
+        pos = layers.segment_positions(segs)
         h = p["embed"][tokens]
         for i in range(2):
             h, _ = olmoe._block(cfg, jnp.float32, h,
                                 jax.tree.map(lambda a: a[i], p["layers"]),
                                 segs, pos)
-        labels, valid = olmoe.next_token_targets(tokens, segs)
-        return olmoe._head_loss(
-            olmoe.rms_norm(h, p["final_norm"], cfg.rms_norm_eps), p["head"],
+        labels, valid = lm_head.next_token_targets(tokens, segs)
+        return lm_head._head_loss(
+            layers.rms_norm(h, p["final_norm"], cfg.rms_norm_eps), p["head"],
             labels, valid, jnp.float32)[0]
 
     want = by_hand(p, row)
@@ -268,7 +271,7 @@ def test_depth_two_scanned_is_two_blocks_by_hand(experts, request):
     assert abs(float(got["loss_sum"]) - float(ref)) <= 1e-4 * t / T
 
 
-# The head's own differentiation rule (olmoe._head_loss) against plain
+# The head's own differentiation rule (lm_head._head_loss) against plain
 # autodiff of the head and loss written here whole: no chunks, no checkpoint,
 # no rule.
 def _plain_head_loss(h, head, labels, valid, dtype):
@@ -288,7 +291,7 @@ def _head_inputs(t, seed=21):
     h = 2.0 * jax.random.normal(kh, (t, TINY.hidden_size))
     head = 0.5 * jax.random.normal(kw, (TINY.hidden_size, TINY.vocab_size))
     row = _row(seed, docs=(t // 3, t // 2), t=t)
-    labels, valid = olmoe.next_token_targets(jnp.asarray(row[0]),
+    labels, valid = lm_head.next_token_targets(jnp.asarray(row[0]),
                                              jnp.asarray(row[1]))
     assert float(valid.sum()) == t // 3 + t // 2 - 2 < t - 4
     return h, head, labels, valid
@@ -306,7 +309,7 @@ def _head_inputs(t, seed=21):
     (jnp.float32, 1e-5, 1e-5), (jnp.bfloat16, 3e-2, 6e-2)])
 def test_the_heads_rule_is_plain_autodiff(dtype, loss_tol, grad_tol, t, chunks):
     h, head, labels, valid = _head_inputs(t)
-    assert olmoe._loss_chunks(h, labels, valid)[0].shape[0] == chunks
+    assert lm_head._loss_chunks(h, labels, valid)[0].shape[0] == chunks
 
     def mean_loss(body):
         def f(h, head):
@@ -314,7 +317,7 @@ def test_the_heads_rule_is_plain_autodiff(dtype, loss_tol, grad_tol, t, chunks):
             return loss / valid.sum(), correct
         return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)
 
-    (loss, correct), (dh, dw) = mean_loss(olmoe._head_loss)(h, head)
+    (loss, correct), (dh, dw) = mean_loss(lm_head._head_loss)(h, head)
     (want, want_correct), (want_dh, want_dw) = mean_loss(_plain_head_loss)(h, head)
     assert abs(float(loss) - float(want)) <= loss_tol * max(1.0, float(want))
     assert float(correct) == float(want_correct)
@@ -324,7 +327,7 @@ def test_the_heads_rule_is_plain_autodiff(dtype, loss_tol, grad_tol, t, chunks):
     # rows outside the loss move nothing
     assert bool(jnp.all(dh[valid == 0] == 0.0)) and float(valid.min()) == 0.0
     # the undifferentiated call is the same forward pass
-    plain_call = olmoe._head_loss(h, head, labels, valid, dtype)
+    plain_call = lm_head._head_loss(h, head, labels, valid, dtype)
     assert float(plain_call[0]) == pytest.approx(float(loss * valid.sum()), rel=1e-6)
     assert float(plain_call[1]) == float(correct)
 
@@ -369,7 +372,7 @@ def test_the_head_multiplies_over_the_vocabulary_three_times_a_chunk_not_four():
     holds four in two), and the undifferentiated call holds the logits
     matmul alone."""
     h, head, labels, valid = _head_inputs(T)
-    f = lambda h, head: olmoe._head_loss(h, head, labels, valid, jnp.bfloat16)
+    f = lambda h, head: lm_head._head_loss(h, head, labels, valid, jnp.bfloat16)
     grad = jax.make_jaxpr(jax.grad(lambda h, head: f(h, head)[0], argnums=(0, 1)))
     dots = _vocab_dots(grad(h, head).jaxpr, TINY.vocab_size)
     assert len(dots) == 3 and len(set(dots)) == 1 and len(dots[0]) == 1, dots
@@ -384,7 +387,7 @@ def test_the_head_multiplies_over_the_vocabulary_three_times_a_chunk_not_four():
 
 def test_forward_mode_through_the_head_is_refused():
     h, head, labels, valid = _head_inputs(T)
-    f = lambda h: olmoe._head_loss(h, head, labels, valid, jnp.float32)[0]
+    f = lambda h: lm_head._head_loss(h, head, labels, valid, jnp.float32)[0]
     with pytest.raises(TypeError, match="custom_vjp"):
         jax.jvp(f, (h,), (h,))
 
@@ -430,8 +433,8 @@ def test_the_fused_attention_body_is_the_xla_body(dtype, ctx_tol, grad_tol):
     args = [jax.random.normal(k, (FUSED_T, FUSED_HEADS, FUSED_D))
             for k in jax.random.split(jax.random.key(4), 4)]
     with pltpu.force_tpu_interpret_mode():
-        ctx, grads = _core_and_gradients(olmoe._fused_attention, dtype, segs)(*args)
-    want, want_grads = _core_and_gradients(olmoe._xla_attention, dtype, segs)(*args)
+        ctx, grads = _core_and_gradients(attn._fused_attention, dtype, segs)(*args)
+    want, want_grads = _core_and_gradients(attn._xla_attention, dtype, segs)(*args)
     assert ctx.dtype == want.dtype == jnp.float32
     assert float(jnp.max(jnp.abs(ctx - want))) <= ctx_tol
     assert _gap(grads, want_grads) <= grad_tol
@@ -448,7 +451,7 @@ def test_a_row_of_padding_alone_is_finite_and_moves_nothing_when_fused(
     assert all(bool(jnp.all(a == 0.0)) for a in jax.tree.leaves(g))
     args = [jax.random.normal(k, (FUSED_T, FUSED_HEADS, FUSED_D))
             for k in jax.random.split(jax.random.key(5), 4)]
-    ctx, grads = _core_and_gradients(olmoe._fused_attention, jnp.bfloat16,
+    ctx, grads = _core_and_gradients(attn._fused_attention, jnp.bfloat16,
                                      row[1])(*args)
     assert all(bool(jnp.all(jnp.isfinite(a))) for a in (ctx, *grads))
 
@@ -466,15 +469,15 @@ def test_the_rule_between_the_attention_bodies(monkeypatch, backend, q, v,
                                                fused, core):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32)
-    assert olmoe.fused_attention_applies(sds(q), sds(q), sds(v)) is fused
+    assert attn.fused_attention_applies(sds(q), sds(q), sds(v)) is fused
     ran = []
     for name in ("_fused_attention", "_xla_attention"):
-        body = getattr(olmoe, name)
-        monkeypatch.setattr(olmoe, name, lambda *a, _name=name, _body=body: (
+        body = getattr(attn, name)
+        monkeypatch.setattr(attn, name, lambda *a, _name=name, _body=body: (
             ran.append((_name, a[0].shape[-1])), _body(*a))[1])
     # either body traces at these shapes, and gives (T, heads, v's width)
     ctx = jax.eval_shape(
-        lambda *a: olmoe.attention_core(*a, jnp.bfloat16), sds(q), sds(q),
+        lambda *a: attn.attention_core(*a, jnp.bfloat16), sds(q), sds(q),
         sds(v), jax.ShapeDtypeStruct(q[:1], jnp.int32))
     # the tiled body at one width for q, k and v, the XLA body as they are
     assert ran == [("_fused_attention", -(-q[2] // 128) * 128) if core
@@ -486,7 +489,7 @@ def test_the_rule_between_the_attention_bodies(monkeypatch, backend, q, v,
 # interpreted on the CPU, always under jit) against ``lax.ragged_dot``, which
 # defines what is computed: values and both gradients, over four row tiles
 # and eight groups.
-ROWS = 4 * olmoe.GROUPED_ROW_TILE
+ROWS = 4 * grouped.GROUPED_ROW_TILE
 GROUP_SIZES = {
     # an empty first, middle and last group; one under a row tile; one that
     # starts 40 rows into the first tile, spans three and straddles two of
@@ -540,22 +543,22 @@ CUT_TILES = {
     (jnp.float32, 1e-5, 1e-5), (jnp.bfloat16, 1e-5, 2.0 ** -7)])
 def test_the_pallas_grouped_matmul_is_ragged_dot(monkeypatch, dtype, out_tol,
                                                  grad_tol, groups, k, n):
-    sizes = np.asarray(GROUP_SIZES[groups](olmoe.GROUPED_ROW_TILE), np.int32)
+    sizes = np.asarray(GROUP_SIZES[groups](grouped.GROUPED_ROW_TILE), np.int32)
     total = int(sizes.sum())
     assert (total == ROWS) == (groups != "uneven") and total <= ROWS
     for key, tiles in CUT_TILES.items():
-        monkeypatch.setitem(olmoe._MEASURED_TILES, key, tiles)
+        monkeypatch.setitem(grouped._MEASURED_TILES, key, tiles)
     cut = ("forward", k, n) in CUT_TILES
-    assert cut == bool(n % olmoe._grouped_tiles("forward", k, n)[2]
-                       or k % olmoe._grouped_tiles("input_gradient", n, k)[2])
+    assert cut == bool(n % grouped._grouped_tiles("forward", k, n)[2]
+                       or k % grouped._grouped_tiles("input_gradient", n, k)[2])
     keys = jax.random.split(jax.random.key(8), 3)
     xs = jax.random.normal(keys[0], (ROWS, k)).astype(dtype)
     w = jax.random.normal(keys[1], (len(sizes), k, n)).astype(dtype)
     c = jax.random.normal(keys[2], (ROWS, n))
     with pltpu.force_tpu_interpret_mode():
-        got = _matmul_and_gradients(olmoe._pallas_grouped_matmul,
+        got = _matmul_and_gradients(grouped._pallas_grouped_matmul,
                                     jnp.asarray(sizes))(xs, w, c)
-    want = _matmul_and_gradients(olmoe._xla_grouped_matmul,
+    want = _matmul_and_gradients(grouped._xla_grouped_matmul,
                                  jnp.asarray(sizes))(xs, w, c)
     for a, b, tol in zip(got, want, (out_tol, grad_tol, grad_tol)):
         assert a.dtype == b.dtype and a.shape == b.shape
@@ -590,14 +593,14 @@ def test_the_rule_between_the_grouped_matmul_bodies(monkeypatch, backend, xs,
                                                     w, dtype, pallas):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     sds = lambda shape, dt=dtype: jax.ShapeDtypeStruct(shape, dt)
-    assert olmoe.grouped_matmul_applies(sds(xs), sds(w)) is pallas
+    assert grouped.grouped_matmul_applies(sds(xs), sds(w)) is pallas
     ran = []
     for name in ("_pallas_grouped_matmul", "_xla_grouped_matmul"):
-        body = getattr(olmoe, name)
-        monkeypatch.setattr(olmoe, name, lambda *a, _name=name, _body=body: (
+        body = getattr(grouped, name)
+        monkeypatch.setattr(grouped, name, lambda *a, _name=name, _body=body: (
             ran.append(_name), _body(*a))[1])
     # either body traces at these shapes, and gives (rows, N) float32
-    out = jax.eval_shape(lambda *a: olmoe.grouped_matmul(*a), sds(xs), sds(w),
+    out = jax.eval_shape(lambda *a: grouped.grouped_matmul(*a), sds(xs), sds(w),
                          sds(w[:1], jnp.int32))
     assert ran == ["_pallas_grouped_matmul" if pallas else "_xla_grouped_matmul"]
     assert out.shape == (xs[0], w[2]) and out.dtype == jnp.float32
@@ -615,10 +618,10 @@ def test_the_rule_between_the_grouped_matmul_bodies(monkeypatch, backend, xs,
     ("input_gradient", 2048, 1024, (256, 2048, 1024)),
     ("weight_gradient", 2048, 1024, (256, 1024, 1024)),
     ("weight_gradient", 1024, 2048, (256, 1024, 1024)),
-    *((*key, tiles) for key, tiles in sorted(olmoe._MEASURED_TILES.items()))])
+    *((*key, tiles) for key, tiles in sorted(grouped._MEASURED_TILES.items()))])
 def test_the_tiles_of_the_grouped_kernels(kernel, k, n, tiles):
-    assert olmoe._grouped_tiles(kernel, k, n) == tiles
+    assert grouped._grouped_tiles(kernel, k, n) == tiles
     tm, tk, tn = tiles
-    assert olmoe.GROUPED_ROW_TILE % tm == 0
+    assert grouped.GROUPED_ROW_TILE % tm == 0
     assert all(tile % 128 == 0 or tile == width
                for tile, width in ((tk, k), (tn, n)))

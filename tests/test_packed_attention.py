@@ -1,5 +1,5 @@
 """The tiled attention core that leaves out the blocks across two documents
-(fedtpu.ops.packed_attention, through ``olmoe._fused_attention``): its
+(fedtpu.ops.packed_attention, through ``packed_attention._fused_attention``): its
 kernels interpreted on the CPU against the XLA body, which defines what is
 computed; the table it decides from against a numpy count of the allowed
 pairs; the two block counters of all three language models."""
@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
-from fedtpu.models import olmoe
 from fedtpu.models.registry import build_model
 from fedtpu.ops import packed_attention
 from fedtpu.training.task import build_task
@@ -59,7 +58,7 @@ def _kept(segs, block):
 @pytest.mark.parametrize("layout", sorted(LAYOUTS))
 def test_the_tiled_core_is_the_xla_body_on_packed_rows(
         monkeypatch, layout, width, dtype, ctx_tol, grad_tol):
-    monkeypatch.setattr(olmoe, "ATTENTION_BLOCK", BLOCK)
+    monkeypatch.setattr(packed_attention, "ATTENTION_BLOCK", BLOCK)
     segs = _segs(LAYOUTS[layout])
     kept, causal = _kept(segs, BLOCK).sum(), 8 * 9 // 2
     # the rows exercise what they are named for: 36, 15 and 21 of 36
@@ -69,9 +68,9 @@ def test_the_tiled_core_is_the_xla_body_on_packed_rows(
             for k in jax.random.split(jax.random.key(7), 4)]
     segs = jnp.asarray(segs)
     with pltpu.force_tpu_interpret_mode():
-        ctx, grads = _core_and_gradients(olmoe._fused_attention, dtype,
+        ctx, grads = _core_and_gradients(packed_attention._fused_attention, dtype,
                                          segs)(*args)
-    want, want_grads = _core_and_gradients(olmoe._xla_attention, dtype,
+    want, want_grads = _core_and_gradients(packed_attention._xla_attention, dtype,
                                            segs)(*args)
     assert ctx.dtype == want.dtype == jnp.float32
     assert float(jnp.max(jnp.abs(ctx - want))) <= ctx_tol
@@ -143,9 +142,11 @@ def test_the_block_counters_are_the_numpy_count(monkeypatch, kind, layers):
     assert on_the_cpu["lm_attention_blocks_computed"] == 0
     assert on_the_cpu["lm_attention_blocks_causal"] == 0
 
-    monkeypatch.setattr(olmoe, "ATTENTION_BLOCK", block)
-    monkeypatch.setattr(olmoe, "fused_attention_applies", lambda q, k, v: True)
-    monkeypatch.setattr(olmoe, "_fused_attention", olmoe._xla_attention)
+    monkeypatch.setattr(packed_attention, "ATTENTION_BLOCK", block)
+    monkeypatch.setattr(packed_attention, "fused_attention_applies",
+                        lambda q, k, v: True)
+    monkeypatch.setattr(packed_attention, "_fused_attention",
+                        packed_attention._xla_attention)
     causal = (t // block) * (t // block + 1) // 2
     want = [int(np.tril(allowed_blocks(s, block)).sum()) for s in rows]
     assert want[1] == causal and want[0] < causal and want[2] < causal

@@ -465,9 +465,9 @@ def test_classification_through_the_task_is_bit_for_bit_what_it_was(monkeypatch)
 
 def test_the_layers_of_the_compiled_round_are_named():
     from fedtpu.analysis.program import program_scopes
-    from fedtpu.models.olmoe import LAYER_SCOPES
+    from fedtpu.ops import scopes
     from fedtpu.parallel.round import STAGES
-    assert LAYERS == LAYER_SCOPES + ("server_update",)
+    assert LAYERS == scopes.LAYERS + ("server_update",)
     exp = build_experiment(tiny_olmoe())
     text = exp.make_step(1).lower(exp.state, exp.batch).compile().as_text()
     layers = set(program_scopes(text, LAYERS, strict=True)["scopes"].values())
@@ -530,9 +530,9 @@ def _named(text, marker, listed):
                                   "recompute"])
 def test_the_pieces_and_passes_of_a_tiny_hybrid_round(what):
     from fedtpu.analysis.program import PASSES
-    from fedtpu.models.olmoe import PIECE_SCOPES, RECOMPUTE
-    assert round_mod.PIECES == PIECE_SCOPES + ("sgd_pass",)
-    assert round_mod.RECOMPUTE == RECOMPUTE
+    from fedtpu.ops import scopes
+    assert round_mod.PIECES == scopes.PIECES + ("sgd_pass",)
+    assert round_mod.RECOMPUTE == scopes.RECOMPUTE
     text, walk = _compiled_round("hybrid")
     layers, pieces, passes = walk["layers"], walk["pieces"], walk["passes"]
     heavy = lambda key: key.startswith(("fusion", "dot", "convolution"))

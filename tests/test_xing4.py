@@ -24,9 +24,10 @@ import pytest
 from jax.experimental.pallas import tpu as pltpu
 
 from fedtpu.config import ModelConfig, TelemetryConfig, get_preset
-from fedtpu.models import nemotron_h as nh
-from fedtpu.models import olmoe, xing4
+from fedtpu.models import layers, xing4
 from fedtpu.models.registry import build_model
+from fedtpu.ops import hyper_conn, lm_head
+from fedtpu.ops import packed_attention as attn
 from fedtpu.orchestration.loop import build_experiment, run_experiment
 from fedtpu.training import task as task_mod
 from fedtpu.training.task import build_task
@@ -215,7 +216,7 @@ def test_the_shares_of_a_gated_expert_layer_add_up_to_the_uncut_layer():
     whole = dataclasses.replace(TINY, experts_held=0, first_expert=0)
     key = jax.random.key(7)
     count = iter(range(100))
-    layer = xing4._ffn_init(
+    layer = layers._ffn_init(
         "experts", whole, lambda *s: 0.3 * jax.random.normal(
             jax.random.fold_in(key, next(count)), s),
         lambda *s: jnp.ones(s))
@@ -232,7 +233,7 @@ def test_the_shares_of_a_gated_expert_layer_add_up_to_the_uncut_layer():
         share = dataclasses.replace(TINY, experts_held=4, first_expert=first)
         part = {**layer, **{name: layer[name][first:first + 4]
                             for name in ("gate", "up", "down")}}
-        out, stats = nh.experts_mixer(share, jnp.float32, h, part, segs,
+        out, stats = layers.experts_mixer(share, jnp.float32, h, part, segs,
                                       eps=1e-6)
         total, held_sum = total + out, held_sum + stats["assignments_held"]
         with jax.default_matmul_precision("highest"):
@@ -258,8 +259,8 @@ def _attention_layer(cfg, seed=3):
 
 
 def _program_attention(cfg, layer, u, segs):
-    pos = olmoe.segment_positions(segs)
-    return xing4.latent_attention(cfg, jnp.float32, u, layer, segs, pos)
+    pos = layers.segment_positions(segs)
+    return layers.latent_attention(cfg, jnp.float32, u, layer, segs, pos)
 
 
 def test_latent_attention_is_transformers_deepseek_v3_attention():
@@ -296,10 +297,10 @@ def test_latent_attention_is_transformers_deepseek_v3_attention():
             getattr(module, theirs).weight.copy_(to_torch(layer[ours]).T)
         module.q_a_layernorm.weight.copy_(to_torch(layer["q_a_norm"]))
         module.kv_a_layernorm.weight.copy_(to_torch(layer["kv_a_norm"]))
-    assert abs(module.scaling - xing4.attention_scale(TINY)) < 1e-9
+    assert abs(module.scaling - layers.attention_scale(TINY)) < 1e-9
     assert abs(module.scaling - 24 ** -0.5 * (0.1 * np.log(64) + 1) ** 2) < 1e-9
     np.testing.assert_allclose(rotary.inv_freq.numpy(),
-                               xing4.yarn_inv_freq(TINY), rtol=1e-6)
+                               layers.yarn_inv_freq(TINY), rtol=1e-6)
 
     def published(x):
         """``transformers`` on one document ``x (n, 48)`` alone."""
@@ -349,14 +350,14 @@ def test_rope_positions_restart_and_the_scale_carries_mscale_squared():
     np.testing.assert_allclose(np.asarray(packed[20:60]), np.asarray(alone),
                                rtol=0, atol=1e-5)
     rc = ref_cfg(TINY)
-    assert abs(xing4.attention_scale(TINY) - ref.softmax_scale(rc)) < 1e-12
+    assert abs(layers.attention_scale(TINY) - ref.softmax_scale(rc)) < 1e-12
     assert abs(ref.softmax_scale(rc)
                - 24 ** -0.5 * (0.1 * np.log(64) + 1) ** 2) < 1e-9
-    np.testing.assert_array_equal(xing4.yarn_inv_freq(TINY),
+    np.testing.assert_array_equal(layers.yarn_inv_freq(TINY),
                                   ref.yarn_inv_freq(rc))
     # YaRN at the published head: the fast columns keep their frequency,
     # the slow ones are divided by the factor
-    inv = xing4.yarn_inv_freq(get_preset("xing4-29b-a4b-l5-mtp1").model)
+    inv = layers.yarn_inv_freq(get_preset("xing4-29b-a4b-l5-mtp1").model)
     plain = 1.0 / 10000.0 ** (np.arange(0, 64, 2) / 64)
     np.testing.assert_allclose(inv[:8], plain[:8], rtol=1e-6)
     np.testing.assert_allclose(inv[-4:], plain[-4:] / 64, rtol=1e-6)
@@ -367,7 +368,7 @@ def test_rope_positions_restart_and_the_scale_carries_mscale_squared():
 # 1e-5; the zero columns add nothing to a score and the cut columns of the
 # context carry no cotangent back.
 def test_the_padded_tiled_core_is_the_unpadded_xla_body(monkeypatch):
-    t, heads, dq, dv = olmoe.ATTENTION_BLOCK, 2, 192, 128
+    t, heads, dq, dv = attn.ATTENTION_BLOCK, 2, 192, 128
     keys = jax.random.split(jax.random.key(4), 4)
     q, k = (jax.random.normal(key, (t, heads, dq)) for key in keys[:2])
     v, w = (jax.random.normal(key, (t, heads, dv)) for key in keys[2:])
@@ -376,7 +377,7 @@ def test_the_padded_tiled_core_is_the_unpadded_xla_body(monkeypatch):
     scale = 0.11
 
     def core_and_gradients(q, k, v):
-        core = lambda q, k, v: olmoe.attention_core(q, k, v, segs,
+        core = lambda q, k, v: attn.attention_core(q, k, v, segs,
                                                     jnp.float32, scale=scale)
         grads = jax.grad(lambda *a: (core(*a) * w).sum(), argnums=(0, 1, 2))(
             q, k, v)
@@ -385,7 +386,7 @@ def test_the_padded_tiled_core_is_the_unpadded_xla_body(monkeypatch):
     jitted = jax.jit(core_and_gradients)
     want, want_grads = jitted(q, k, v)
     ran = []
-    monkeypatch.setattr(olmoe, "fused_attention_applies", lambda *a: (
+    monkeypatch.setattr(attn, "fused_attention_applies", lambda *a: (
         ran.append(tuple(x.shape for x in a)), True)[1])
     steered = jax.jit(core_and_gradients)    # traced anew: the rule is read again
     with pltpu.force_tpu_interpret_mode():
@@ -396,7 +397,7 @@ def test_the_padded_tiled_core_is_the_unpadded_xla_body(monkeypatch):
     assert _gap(grads, want_grads) <= 1e-5
     assert all(g.shape == a.shape for g, a in zip(grads, (q, k, v)))
     # and the scale is the caller's: the default would read otherwise
-    plain = olmoe._xla_attention(q, k, v, segs)
+    plain = attn._xla_attention(q, k, v, segs)
     assert float(jnp.max(jnp.abs(plain - want))) > 1e-2
 
 
@@ -418,14 +419,14 @@ def test_h_res_is_doubly_stochastic_to_the_iterations_own_residual():
     the columns to what 20 iterations leave (under 1e-2 from this start),
     falling with the iterations; and the maps are the reference's."""
     module, x = _module_and_streams(TINY)
-    pre, post, res = xing4.hyper_mix(x, module, TINY)
+    pre, post, res = hyper_conn.hyper_mix(x, module, TINY)
     assert pre.shape == (4, T) and post.shape == (4, T) and res.shape == (4, 4, T)
     assert float(jnp.abs(res.sum(axis=1) - 1.0).max()) <= 1e-5
     off = float(xing4.sinkhorn_residual(res))
     assert off <= 1e-2
     fewer = dataclasses.replace(TINY, hc_sinkhorn_iters=3)
     assert float(xing4.sinkhorn_residual(
-        xing4.hyper_mix(x, module, fewer)[2])) > 2 * off
+        hyper_conn.hyper_mix(x, module, fewer)[2])) > 2 * off
     assert bool(jnp.all(res > 0)) and bool(jnp.all((pre > 0) & (pre < 1)))
     assert bool(jnp.all((post > 0) & (post < 2)))
     want = _reference_maps(TINY, module, x)
@@ -493,11 +494,11 @@ def test_one_stream_with_the_mix_switched_off_is_a_pre_norm_block():
     layer = {**layer, "attn_hc": off, "ffn_hc": off}
     h = jax.random.normal(jax.random.key(3), (T, 48))
     segs = jnp.asarray([1] * 40 + [2] * 24, jnp.int32)
-    pos = olmoe.segment_positions(segs)
+    pos = layers.segment_positions(segs)
     out, _ = xing4.block("dense", cfg, jnp.float32, h[None], layer, segs, pos)
-    want = h + xing4.latent_attention(cfg, jnp.float32, h, layer["attn"], segs,
+    want = h + layers.latent_attention(cfg, jnp.float32, h, layer["attn"], segs,
                                       pos)
-    want = want + xing4.dense_mlp(cfg, jnp.float32, want, layer["ffn"])
+    want = want + layers.dense_mlp(cfg, jnp.float32, want, layer["ffn"])
     assert out.shape == (1, T, 48)
     np.testing.assert_allclose(np.asarray(out[0]), np.asarray(want), rtol=0,
                                atol=1e-5)
@@ -512,7 +513,7 @@ def test_the_modules_targets_and_validity_at_document_edges():
     # a document of four has two targets, of two none, of three one
     np.testing.assert_array_equal(
         valid, [1, 1, 0, 0, 0, 0, 1, 0, 0, 0, 0, 0])
-    _, main = olmoe.next_token_targets(tokens, segs)
+    _, main = lm_head.next_token_targets(tokens, segs)
     docs = 3
     assert float(main.sum()) - float(valid.sum()) == docs   # one more a document
 
