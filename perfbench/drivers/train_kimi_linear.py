@@ -20,8 +20,6 @@ the benchmark's generator.
 from __future__ import annotations
 
 import gc
-import glob
-import os
 import time
 
 import jax
@@ -182,18 +180,6 @@ def limits_of(conf: dict) -> dict:
                                      PARAMS_SHARE_TOLERANCE)}
 
 
-def table(ev) -> dict:
-    """Every ``layer_metrics/kl_*.json`` reading of the traced run. The
-    contract holds ``per_layer`` to 128 entries and the accepted benchmark has
-    122, so six of this cell's are declared in ``BENCHMARK.json`` and reach
-    the result line; the whole table, those six among it, goes on a line of
-    its own, ``kl_table``, read by the same readers from the same files."""
-    names = sorted(os.path.basename(path)[:-len(".json")] for path in
-                   glob.glob(ev.manifest.file("layer_metrics", "kl_*")))
-    found = {name: ev.metric(name) for name in names}
-    return {name: value for name, value in found.items() if value is not None}
-
-
 def run(ctx) -> dict:
     cell, conf, traffic = ctx.cell, ctx.config, ctx.traffic
     if ctx.rehearsal:
@@ -279,7 +265,6 @@ def run(ctx) -> dict:
         # a row passes every KDA layer once a round
         ctx.evidence.facts["kda_rows"] = (traced["rounds"] * counts["sequences"]
                                           * len(model["kda_layers"]))
-        lines.append({"kl_table": table(ctx.evidence)})
     out["correct"] = bool(correct and out["correct"])
     out["lines"] = lines
     return out

@@ -19,7 +19,7 @@ only. Per real token:
   ``W_o``), and per (query, key) pair causal attention within a document
   allows, over every head, the score over the ``nope + rope`` columns and
   the weighted sum over the ``v`` columns (``core_flops``: what
-  ``kl_attn_core_mfu`` is read against);
+  ``attn_core_mfu`` is read against);
 * feed-forward: a leading layer's three matmuls; an expert layer's router
   over ALL routed experts, its shared expert's three matmuls and the routed
   experts' three for the assignments this chip holds: ``experts per token *

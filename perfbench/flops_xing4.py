@@ -14,7 +14,7 @@ only. Per real token:
   ``W_kvb``, ``W_o``), and per (query, key) pair causal attention within a
   document allows, over every head, the score over the ``nope + rope``
   columns and the weighted sum over the ``v`` columns (``core``: what
-  ``x4_attn_core_mfu`` is read against; the program's tiled body computes
+  ``attn_core_mfu`` is read against; the program's tiled body computes
   both at the padded width, ``2 * 256`` columns a pair and head where
   ``192 + 128`` are needed, and its backward pass the scores once more);
 * a residual module, two a block: the projection of the flattened streams

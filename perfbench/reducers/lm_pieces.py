@@ -7,35 +7,39 @@ of a state-space mixer around its scan, ``ssm_in_proj`` / ``ssm_conv`` /
 and its ``program_scopes`` event maps each operation to the innermost of
 them under ``pieces`` and to the direction it runs in (``forward``,
 ``recompute``, ``backward``, ``update``) under ``passes``, beside ``layers``
-and ``scopes``. Same rule as ``lm_layers`` / ``hybrid_layers``: an
+and ``scopes``. Same rule as ``lm_layers``: an
 operation's self time (a ``while`` less what its body covers), averaged over
 the devices, per traced round, in milliseconds; operations whose middle lies
 inside the loop's check annotations are the state check's and are left out.
 
-A layer's pieces split what its reducer gives the layer, the same
-operations by the same test, so each group adds up to the layer's metric:
-the mixer's four and ``ssm_rest_ms`` (scope ``ssm`` under none of the four:
-an instruction of the compiler's whose neighbours disagree) to
-``ssm_proj_ms``; ``attn_core_ms`` and ``attn_proj_ms`` (``attention``
-outside the core: norms, projections, RoPE, the key-value repeat) to
-``attention_ms`` / ``nh_attention_ms``; ``embed_ms``, ``sgd_pass_ms`` and
-``outside_rest_ms`` (``client_train`` or ``aggregate`` under no layer the
-layer reducers read, and neither) to ``layers_unscoped_ms`` /
-``nh_layers_unscoped_ms``; the four passes to ``client_train_ms +
+A layer's pieces split what ``lm_layers`` gives the layer, the same
+operations by the same test, so each group adds up to the layer's metric
+in every cell: the mixer's four and ``ssm_rest_ms`` (scope ``ssm`` under
+none of the four: an instruction of the compiler's whose neighbours
+disagree) to ``ssm_proj_ms``; ``attn_core_ms`` and ``attn_proj_ms``
+(``attention`` outside the core: norms, projections, RoPE, the key-value
+repeat, the latent bottlenecks) to ``attention_ms``; ``embed_ms``,
+``sgd_pass_ms`` and ``outside_rest_ms`` (``client_train`` or ``aggregate``
+under no layer of ``lm_layers.FIELDS``, and neither) to
+``layers_unscoped_ms``; the four passes to ``client_train_ms +
 aggregate_ms``. The whole table, a row a layer (``layer/piece`` where a
 piece applies, ``outside`` for none) and a column a pass, goes to the run's
 notes under ``layer_pass_ms``. A program that emits no ``pieces`` (a parent
 of the PR that brought them) gives nothing.
 """
 
-# the layers with a metric of their own (OLMoE's six are among the hybrid
-# stack's nine): what lies outside them is ``*layers_unscoped_ms``
-from perfbench.reducers.hybrid_layers import CHECKS, FIELDS as LAYERED, STAGES
+# the layers with a metric of their own, whichever model names them: what
+# lies outside them is ``layers_unscoped_ms``
+from perfbench.reducers.lm_layers import CHECKS, FIELDS as LAYERED, STAGES
 
 INSIDE = {"ssm": ("ssm_in_proj", "ssm_conv", "ssm_gate_norm", "ssm_out_proj"),
           "attention": ("attn_core",)}
 REST = {"ssm": "ssm_rest_ms", "attention": "attn_proj_ms"}
 PASSES = ("forward", "recompute", "backward", "update")
+# every field ``reduce`` gives: what a metric's file may name
+EMITS = (*(f"{piece}_ms" for inside in INSIDE.values() for piece in inside),
+         *REST.values(), "embed_ms", "sgd_pass_ms", "outside_rest_ms",
+         *(f"{p}_ms" for p in PASSES))
 
 
 def _piece_of(layer, piece, staged):
@@ -67,9 +71,7 @@ def reduce(ev):
                              (pieces, "pieces"), (passes, "passes")):
             merged.update(payload.get(name) or {})
     checks = [(h.start, h.end) for h in view.host if h.name in CHECKS]
-    fields = [f"{piece}_ms" for inside in INSIDE.values() for piece in inside]
-    fields += [*REST.values(), "embed_ms", "sgd_pass_ms", "outside_rest_ms"]
-    acc = dict.fromkeys(fields + [f"{p}_ms" for p in PASSES], 0.0)
+    acc = dict.fromkeys(EMITS, 0.0)
     table = {}
     for ops in view.devices.values():
         for o in ops:

@@ -16,20 +16,19 @@ import pytest
 
 from perfbench import datasets_lm, flops_kimi_linear, manifest, xplane
 from perfbench.evidence import Evidence
+from perfbench.tests.entries import check_cell
 
 ROOT = os.path.dirname(manifest.HERE)
 CELL, CONFIG = "kimi-linear-l5-fed8-packed", "kimi-linear-48b-a3b-l5-fed8"
-# per_layer holds 128 entries and the accepted benchmark 122: these six are
-# declared, and the cell's whole table of 34 goes on the traced run's
-# ``kl_table`` line (train_kimi_linear.table)
-DECLARED = ["kl_kda_proj_ms", "kl_kda_scan_ms", "kl_kda_conv_ms",
-            "kl_kda_gates_ms", "kl_kda_scan_roofline",
-            "kl_kda_restarts_per_row"]
-TABLE = 34
-ADDING_UP = ("kl_kda_proj_ms", "kl_kda_scan_ms", "kl_attention_ms",
-             "kl_dense_mlp_ms", "kl_shared_expert_ms", "kl_router_ms",
-             "kl_expert_dispatch_ms", "kl_experts_ms", "kl_lm_head_ms",
-             "kl_server_update_ms", "kl_layers_unscoped_ms")
+# the delta-rule mixer's own readings; every other is a shared name that
+# lists the cell (PR 43: all of them are declared and reach the result line)
+OWN = ["kl_kda_proj_ms", "kl_kda_scan_ms", "kl_kda_conv_ms",
+       "kl_kda_gates_ms", "kl_kda_scan_roofline", "kl_kda_restarts_per_row",
+       "kl_kda_scan_fused_pct", "kl_kda_in_proj_ms", "kl_kda_out_proj_ms"]
+ADDING_UP = ("kl_kda_proj_ms", "kl_kda_scan_ms", "attention_ms",
+             "dense_mlp_ms", "shared_expert_ms", "router_ms",
+             "expert_dispatch_ms", "experts_ms", "lm_head_ms",
+             "server_update_ms", "layers_unscoped_ms")
 TINY = {"hidden_size": 8, "num_hidden_layers": 5, "first_k_dense_replace": 1,
         "kda_layers": (1, 2, 3, 5), "full_attn_layers": (4,),
         "kda_num_heads": 2, "kda_head_dim": 4, "short_conv_kernel_size": 4,
@@ -46,7 +45,7 @@ REF_CFG = {"num_attention_heads": 2, "kv_lora_rank": 4, "qk_nope_head_dim": 4,
                                   "short_conv_kernel_size": 4}}
 
 
-def test_the_new_entries_resolve_and_touch_no_other_cell():
+def test_the_entries_that_list_the_cell_resolve_and_no_other_models_do():
     m = manifest.load(ROOT)
     cell = m.cell(CELL)
     assert cell["config"] == CONFIG and cell["chips"] == 1
@@ -62,25 +61,17 @@ def test_the_new_entries_resolve_and_touch_no_other_cell():
         k: v for k, v in xing4.items() if k not in own}
     assert traffic["trace_chunks"] == 1 and traffic["fixed_job_chunks"] == 2
     assert traffic["check_rounds"] == traffic["warmup_rounds"] == 1
-    own = [p for p in m.doc["per_layer"] if p.get("workloads") == [CELL]]
-    assert [p["name"] for p in own] == DECLARED
-    assert all(p["moves"] == "round_ms" for p in own)
+    listed = check_cell(m, CELL)
+    names = {p["name"] for p in listed}
+    assert set(OWN) | set(ADDING_UP) <= names
+    assert {p["name"] for p in listed if p["name"].startswith("kl_")} == set(OWN)
     assert len(m.doc["per_layer"]) <= 128
-    files = sorted(f[:-len(".json")] for f in os.listdir(
-        os.path.join(manifest.HERE, "layer_metrics")) if f.startswith("kl_"))
-    assert len(files) == TABLE and set(DECLARED) | set(ADDING_UP) <= set(files)
-    for name in files:
-        spec = m.layer_metric(name)
-        assert spec["name"] == name and spec["moves"] == "round_ms"
-        assert spec["read"]["kind"] in ("trace", "registry", "registry_ratio")
-    # appended: the new entries are the last of their lists, and no accepted
-    # metric's list gained or lost a cell
-    assert m.doc["per_layer"][-len(own):] == own
-    assert m.doc["workloads"][-1]["name"] == CELL
-    assert m.doc["configs"][-1]["name"] == CONFIG
-    for p in m.doc["per_layer"]:
-        if p not in own:
-            assert CELL not in p.get("workloads", [])
+    for p in listed:
+        if "workloads" in p:
+            spec = m.layer_metric(p["name"])
+            assert spec["moves"] == "round_ms"
+            assert spec["read"]["kind"] in ("trace", "registry",
+                                            "registry_ratio")
     assert {e["name"] for e in m.metrics_of("end_to_end", CELL)} >= {
         "setup_s", "round_ms", "peak_hbm_mb"}
     assert len(open(os.path.join(ROOT, "BENCHMARK.json")).read()) < 64 * 1024
@@ -182,7 +173,7 @@ def _view(ops, host=()):
                             start=0.0, end=max(o.end for o in ops))
 
 
-def test_kimi_linear_layers_sums_self_times_by_innermost_scope():
+def test_lm_layers_sums_the_delta_rule_stack_by_innermost_scope():
     op = xplane.Op
     ev = Evidence(manifest=manifest.load(ROOT))
     ev.trace = _view(
@@ -197,6 +188,8 @@ def test_kimi_linear_layers_sums_self_times_by_innermost_scope():
         kda_rows=16, peaks={"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e6},
         cost={"core_flops": 60.0, "scan": {"flops": 10.0, "bytes": 0.05}})
     ev.sinks["job"] = [
+        {"kind": "manifest", "payload": {"config": {"model": {
+            "kind": "kimi_linear"}}}},
         {"kind": "program_scopes", "payload": {
             "program": "round_step",
             "scopes": {"while.1": "client_train", "fusion.1 bf16[8]": "client_train",
@@ -220,42 +213,44 @@ def test_kimi_linear_layers_sums_self_times_by_innermost_scope():
             "lm_attention_blocks_computed": 30.0,
             "lm_attention_blocks_causal": 40.0},
             "gauges": {"moe_expert_load_max_over_mean": 1.5}}}]
-    assert ev.metric("kl_attention_ms") == pytest.approx(300e-6 / 2)
+    assert ev.metric("attention_ms") == pytest.approx(300e-6 / 2)
     assert ev.metric("kl_kda_scan_ms") == pytest.approx(200e-6 / 2)
     assert ev.metric("kl_kda_proj_ms") == pytest.approx(200e-6 / 2)
-    assert ev.metric("kl_experts_ms") == pytest.approx(200e-6 / 2)
-    assert ev.metric("kl_server_update_ms") == pytest.approx(200e-6 / 2)
-    assert ev.metric("kl_layers_unscoped_ms") == pytest.approx(100e-6 / 2)
-    assert ev.metric("kl_dense_mlp_ms") == 0.0
+    assert ev.metric("experts_ms") == pytest.approx(200e-6 / 2)
+    assert ev.metric("server_update_ms") == pytest.approx(200e-6 / 2)
+    assert ev.metric("layers_unscoped_ms") == pytest.approx(100e-6 / 2)
+    assert ev.metric("dense_mlp_ms") == 0.0
     # the eleven add up to what the two stages took, and the four passes too
     assert sum(ev.metric(n) for n in ADDING_UP) == pytest.approx(
         (1000 + 200) * 1e-6 / 2)
-    assert sum(ev.metric(f"kl_{p}_ms") for p in (
+    assert sum(ev.metric(f"{p}_ms") for p in (
         "forward", "recompute", "backward", "update")) == pytest.approx(
             (1000 + 200) * 1e-6 / 2)
-    assert ev.metric("kl_backward_ms") == pytest.approx(200e-6 / 2)
+    assert ev.metric("backward_ms") == pytest.approx(200e-6 / 2)
     # the pieces
     assert ev.metric("kl_kda_gates_ms") == pytest.approx(200e-6 / 2)
     assert ev.metric("kl_kda_in_proj_ms") == 0.0
-    assert ev.metric("kl_attn_core_ms") == pytest.approx(300e-6 / 2)   # lm_pieces
+    assert ev.metric("attn_core_ms") == pytest.approx(300e-6 / 2)   # lm_pieces
     # 0.05 bytes at 1e6 a second: 5e-8 s; 10 operations: 1e-8 s; bytes bound
     assert ev.notes["kl_kda_scan_roofline_bound"] == "bytes"
     assert ev.metric("kl_kda_scan_roofline") == pytest.approx(100 * 5e-8 / 0.1e-6)
     # 60 operations a round in 0.15 us at 1e9 a second
-    assert ev.metric("kl_attn_core_mfu") == pytest.approx(100 * 60 / 0.15e-6 / 1e9)
+    assert ev.metric("attn_core_mfu") == pytest.approx(100 * 60 / 0.15e-6 / 1e9)
     flops = flops_kimi_linear.held_experts_flops(TINY, 10)
-    assert ev.metric("kl_experts_mfu") == pytest.approx(100 * flops / 0.1e-6 / 1e9)
-    assert ev.metric("kl_experts_held_share_pct") == pytest.approx(3.125)
-    assert ev.metric("kl_expert_rows_computed_over_routed") == pytest.approx(1.6)
+    assert ev.metric("experts_mfu") == pytest.approx(100 * flops / 0.1e-6 / 1e9)
+    assert ev.metric("experts_held_share_pct") == pytest.approx(3.125)
+    assert ev.metric("expert_rows_computed_over_routed") == pytest.approx(1.6)
     assert ev.metric("kl_kda_restarts_per_row") == pytest.approx(3.5)
-    assert ev.metric("kl_attention_fused_pct") == pytest.approx(100.0)
-    assert ev.metric("kl_attn_blocks_computed_over_causal") == pytest.approx(0.75)
-    assert ev.metric("kl_expert_load_max_over_mean") == 1.5
-    # the line the traced run prints beside the six declared metrics
-    from perfbench.drivers.train_kimi_linear import table
-    whole = table(ev)
-    assert set(DECLARED) | set(ADDING_UP) <= set(whole)
-    assert all(whole[name] == ev.metric(name) for name in whole)
+    assert ev.metric("attention_fused_pct") == pytest.approx(100.0)
+    assert ev.metric("attn_blocks_computed_over_causal") == pytest.approx(0.75)
+    assert ev.metric("expert_load_max_over_mean") == 1.5
+    # every reading the cell lists that this made-up run can give is one
+    # the result line would hold: nothing waits on a line of its own
+    listed = {p["name"] for p in ev.manifest.metrics_of("per_layer", CELL)}
+    for name in (*OWN, *ADDING_UP):
+        assert name in listed, name
+        # (this made-up run counts no position in the kernels)
+        assert (ev.metric(name) is None) == (name == "kl_kda_scan_fused_pct")
 
 
 def test_a_program_without_the_scopes_or_counters_gives_nothing():
@@ -266,9 +261,10 @@ def test_a_program_without_the_scopes_or_counters_gives_nothing():
         "program": "round_step", "scopes": {"fusion.1 f32[8]": "client_train"},
         "unscoped": []}},
         {"kind": "counters", "payload": {"counters": {"rounds": 3}, "gauges": {}}}]
-    from perfbench.drivers.train_kimi_linear import table
-    assert table(ev) == {}
-    assert all(ev.metric(name) is None for name in DECLARED)
+    m = manifest.load(ROOT)
+    for p in m.doc["per_layer"]:
+        if CELL in p.get("workloads", ()):
+            assert ev.metric(p["name"]) is None, p["name"]
 
 
 def _tiny_params(rng):
@@ -414,12 +410,10 @@ def test_the_traced_walk_through_would_report_the_new_metrics():
     last = lines[-1]
     assert last["rehearsal_passed"] is True and last["correct"] is False
     # the registry's (a CPU trace has no device plane: the device-trace
-    # metrics need the chip): the declared one in the result line, the others
-    # on the table's line
-    assert "kl_kda_restarts_per_row" in last["would_report"]
-    whole = next(l["kl_table"] for l in lines if "kl_table" in l)
-    assert {"kl_kda_restarts_per_row", "kl_experts_held_share_pct",
-            "kl_expert_rows_computed_over_routed", "kl_moe_tokens_dropped",
-            "kl_padding_pct", "kl_attention_fused_pct",
-            "kl_experts_grouped_pct", "kl_expert_load_max_over_mean"} <= set(
-                whole)
+    # metrics need the chip), every one in the result line
+    assert not any("kl_table" in l for l in lines)
+    assert {"kl_kda_restarts_per_row", "kl_kda_scan_fused_pct",
+            "experts_held_share_pct", "expert_rows_computed_over_routed",
+            "moe_tokens_dropped", "lm_padding_pct", "attention_fused_pct",
+            "experts_grouped_pct", "expert_load_max_over_mean"} <= set(
+                last["would_report"])

@@ -1,6 +1,6 @@
 """PR 35's per-layer metrics: the ``lm_pieces`` reducer on a hand-built trace
-beside the two layer reducers it splits, and the event's cost through the
-span reader."""
+beside the layer reducer it splits, a stack with a state-space mixer and one
+without, and the event's cost through the span reader."""
 
 import os
 
@@ -8,6 +8,7 @@ import pytest
 
 from perfbench import manifest, xplane
 from perfbench.evidence import Evidence
+from perfbench.tests.entries import check_cell
 from perfbench.xplane import Op, TraceView
 
 ROOT = os.path.dirname(manifest.HERE)
@@ -39,9 +40,16 @@ OPS = [
 ]
 
 
-def _evidence(with_pieces=True):
+def _mixers(row) -> bool:
+    return (row[1] or "").startswith("ssm")
+
+
+def _evidence(with_pieces=True, mixer=True):
+    """``mixer`` off: the rows of ``OPS`` a program without a state-space
+    mixer has (OLMoE's names no ``ssm``)."""
+    rows = [row for row in OPS if mixer or not _mixers(row)]
     ops, at = [], 10.0
-    for name, _, _, _, us in OPS:
+    for name, _, _, _, us in rows:
         ops.append(Op(name, at * US, (at + us) * US))
         at += us
     # the state check's program lists a key of the round program's too
@@ -56,11 +64,11 @@ def _evidence(with_pieces=True):
     payload = {
         "program": "round_step", "width": 1, "unscoped": [],
         "scopes": {name: "aggregate" if layer == "server_update"
-                   else "client_train" for name, layer, *_ in OPS},
-        "layers": {name: layer for name, layer, *_ in OPS if layer}}
+                   else "client_train" for name, layer, *_ in rows},
+        "layers": {name: layer for name, layer, *_ in rows if layer}}
     if with_pieces:
-        payload["pieces"] = {name: piece for name, _, piece, *_ in OPS if piece}
-        payload["passes"] = {name: way for name, _, _, way, _ in OPS}
+        payload["pieces"] = {name: piece for name, _, piece, *_ in rows if piece}
+        payload["passes"] = {name: way for name, _, _, way, _ in rows}
     ev.sinks["job"] = [
         {"kind": "program_scopes", "dur_s": 0.25, "payload": {
             "program": "state_check", "width": None, "unscoped": [],
@@ -69,53 +77,51 @@ def _evidence(with_pieces=True):
     return ev
 
 
-def _total(*tests):
+def _total(mixer, *tests):
     """Microseconds of ``OPS`` a round of two, in milliseconds."""
-    return sum(us for *row, us in OPS if all(t(*row) for t in tests)) / 2 / 1000
+    return sum(us for *row, us in OPS if all(t(*row) for t in tests)
+               and (mixer or not _mixers(row))) / 2 / 1000
 
 
-@pytest.mark.parametrize("prefix,layers_reducer", [
-    ("", "lm_layers"), ("nh_", "hybrid_layers")])
-def test_each_group_of_pieces_adds_up_to_its_layer(prefix, layers_reducer):
-    ev = _evidence()
-    mixer = [ev.metric(name) for name in (
+@pytest.mark.parametrize("mixer", [False, True], ids=["olmoe", "hybrid"])
+def test_each_group_of_pieces_adds_up_to_its_layer(mixer):
+    ev = _evidence(mixer=mixer)
+    parts = [ev.metric(name) for name in (
         "ssm_in_proj_ms", "ssm_conv_ms", "ssm_gate_norm_ms",
         "ssm_out_proj_ms", "ssm_rest_ms")]
-    assert mixer == pytest.approx([0.005, 0.012, 0.002, 0.004, 0.002])
-    assert sum(mixer) == pytest.approx(ev.metric("ssm_proj_ms"))
-    core, proj = (ev.metric(prefix + "attn_core_ms"),
-                  ev.metric(prefix + "attn_proj_ms"))
+    assert parts == pytest.approx(
+        [0.005, 0.012, 0.002, 0.004, 0.002] if mixer else [0.0] * 5)
+    assert sum(parts) == pytest.approx(ev.metric("ssm_proj_ms"))
+    core, proj = ev.metric("attn_core_ms"), ev.metric("attn_proj_ms")
     assert (core, proj) == pytest.approx((0.009, 0.0025))
-    assert core + proj == pytest.approx(
-        ev.metric("nh_attention_ms" if prefix else "attention_ms"))
-    outside = [ev.metric(prefix + name) for name in (
+    assert core + proj == pytest.approx(ev.metric("attention_ms"))
+    outside = [ev.metric(name) for name in (
         "embed_ms", "sgd_pass_ms", "outside_rest_ms")]
     assert outside == pytest.approx([0.001, 0.010, 0.002])
-    unscoped = ev.reduced(layers_reducer)[prefix + "layers_unscoped_ms"]
-    if layers_reducer == "lm_layers":
-        # OLMoE's reducer knows no mixer: on its own cell there is none
-        unscoped -= ev.metric("ssm_proj_ms") + ev.metric("ssm_scan_ms")
-    assert sum(outside) == pytest.approx(unscoped)
-    passes = {way: ev.metric(f"{prefix}{way}_ms") for way in (
+    assert sum(outside) == pytest.approx(ev.metric("layers_unscoped_ms"))
+    passes = {way: ev.metric(f"{way}_ms") for way in (
         "forward", "recompute", "backward", "update")}
     assert passes == pytest.approx({
-        way: _total(lambda *row, way=way: row[3] == way) for way in passes})
+        way: _total(mixer, lambda *row, way=way: row[3] == way)
+        for way in passes})
     assert sum(passes.values()) == pytest.approx(
         ev.metric("client_train_ms") + ev.metric("aggregate_ms"))
     # the table in the notes: a row a layer and piece, a column a pass
     table = ev.notes["layer_pass_ms"]
-    assert table["ssm/ssm_conv"] == {"forward": 0.003, "recompute": 0.003,
-                                     "backward": 0.006, "update": 0.0}
-    assert table["ssm"]["update"] == 0.001 and table["outside/sgd_pass"][
-        "update"] == 0.010
+    if mixer:
+        assert table["ssm/ssm_conv"] == {"forward": 0.003, "recompute": 0.003,
+                                         "backward": 0.006, "update": 0.0}
+        assert table["ssm"]["update"] == 0.001
+    assert table["outside/sgd_pass"]["update"] == 0.010
+    # (each of its numbers rounded to a microsecond)
     assert sum(map(sum, (row.values() for row in table.values()))) == (
-        pytest.approx(sum(passes.values())))
+        pytest.approx(sum(passes.values()), abs=1e-3 * len(table)))
 
 
 def test_a_program_without_pieces_gives_nothing():
     ev = _evidence(with_pieces=False)
-    for name in ("ssm_conv_ms", "attn_core_ms", "nh_outside_rest_ms",
-                 "recompute_ms", "nh_update_ms"):
+    for name in ("ssm_conv_ms", "attn_core_ms", "outside_rest_ms",
+                 "recompute_ms", "update_ms"):
         assert ev.metric(name) is None
     assert ev.metric("ssm_proj_ms") == pytest.approx(0.025)   # the parent's
     assert "layer_pass_ms" not in ev.notes
@@ -137,15 +143,23 @@ def test_the_events_cost_is_read_by_the_span_reader():
     assert ev.metric("scopes_emit_s") is None
 
 
-def test_every_new_metric_resolves_and_lists_its_cell():
+def test_every_piece_and_pass_resolves_and_lists_the_cells_that_have_it():
     m = manifest.load(ROOT)
-    new = [e for e in m.doc["per_layer"] if m.layer_metric(e["name"])[
+    lm = ["olmoe-l1-fed8-4k", "nemotron-l9-fed8-packed",
+          "xing4-l5-mtp1-fed8-4k", "kimi-linear-l5-fed8-packed"]
+    for cell in lm:
+        check_cell(m, cell)
+    from perfbench.reducers import lm_pieces
+    pieces = [e for e in m.doc["per_layer"] if m.layer_metric(e["name"])[
         "read"].get("reducer") == "lm_pieces"]
-    assert len(new) == 23
-    for entry in new:
-        hybrid = entry["name"].startswith(("nh_", "ssm_"))
-        assert entry["workloads"] == [
-            "nemotron-l9-fed8-packed" if hybrid else "olmoe-l1-fed8-4k"]
+    # every field the reducer gives is declared, once
+    assert sorted(m.layer_metric(e["name"])["read"]["field"]
+                  for e in pieces) == sorted(lm_pieces.EMITS)
+    for entry in pieces:
+        # the mixer's pieces are the hybrid stack's; the rest every
+        # language model's
+        assert entry["workloads"] == (
+            lm[1:2] if entry["name"].startswith("ssm_") else lm)
         assert entry["moves"] == "round_ms" and entry["layer"] == "round program"
     assert "workloads" not in next(
         e for e in m.doc["per_layer"] if e["name"] == "scopes_emit_s")

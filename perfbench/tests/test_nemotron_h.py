@@ -15,6 +15,7 @@ import pytest
 
 from perfbench import datasets_lm, flops_nemotron_h, manifest, xplane
 from perfbench.evidence import Evidence
+from perfbench.tests.entries import check_cell
 
 ROOT = os.path.dirname(manifest.HERE)
 CELL, CONFIG = "nemotron-l9-fed8-packed", "nemotron-twotower-30b-a3b-l9-fed8"
@@ -27,22 +28,21 @@ TINY = {"hidden_size": 8, "hybrid_override_pattern": "ME*E",
         "vocab_size": 32}
 
 
-def test_the_new_entries_resolve_and_touch_no_other_cell():
+def test_the_entries_that_list_the_cell_resolve_and_no_other_models_do():
     m = manifest.load(ROOT)
     cell = m.cell(CELL)
     assert cell["config"] == CONFIG and cell["chips"] == 1
     assert m.traffic(cell["traffic"])["driver"] == "train_nemotron_h"
     assert os.path.exists(os.path.join(
         manifest.HERE, "drivers", m.traffic(cell["traffic"])["driver"] + ".py"))
-    own = [p for p in m.doc["per_layer"] if p.get("workloads") == [CELL]]
-    assert len(own) == 19 and all(p["moves"] == "round_ms" for p in own)
-    for p in own:
-        assert m.layer_metric(p["name"])["read"]["kind"] in (
-            "trace", "registry", "registry_ratio")
-    # no accepted metric's list gained or lost a cell
-    for p in m.doc["per_layer"]:
-        if p not in own:
-            assert CELL not in p.get("workloads", [])
+    listed = check_cell(m, CELL)
+    names = {p["name"] for p in listed}
+    # the mixer's own, and the layers it shares with the other stacks
+    assert {"ssm_proj_ms", "ssm_scan_ms", "ssm_scan_roofline",
+            "shared_expert_ms", "attention_ms", "experts_ms", "experts_mfu",
+            "lm_head_ms", "layers_unscoped_ms"} <= names
+    assert all(p["moves"] == "round_ms" for p in listed
+               if "workloads" in p)
     # the cell reports setup_s, one other end-to-end metric, per-layer ones
     assert {e["name"] for e in m.metrics_of("end_to_end", CELL)} >= {
         "setup_s", "round_ms", "peak_hbm_mb"}
@@ -114,7 +114,7 @@ def _view(ops, host=()):
                             start=0.0, end=max(o.end for o in ops))
 
 
-def test_hybrid_layers_sums_self_times_by_innermost_scope():
+def test_lm_layers_sums_the_hybrid_stack_by_innermost_scope():
     op = xplane.Op
     ev = Evidence(manifest=manifest.load(ROOT))
     ev.trace = _view(
@@ -128,6 +128,8 @@ def test_hybrid_layers_sums_self_times_by_innermost_scope():
         peaks={"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e6},
         cost={"scan": {"flops": 100.0, "bytes": 0.05}})
     ev.sinks["job"] = [
+        {"kind": "manifest", "payload": {"config": {"model": {
+            "kind": "nemotron_h"}}}},
         {"kind": "program_scopes", "payload": {
             "program": "round_step",
             "scopes": {"while.1": "client_train", "fusion.1 bf16[8]": "client_train",
@@ -142,15 +144,15 @@ def test_hybrid_layers_sums_self_times_by_innermost_scope():
             "moe_rows_computed": 64.0}, "gauges": {}}}]
     assert ev.metric("ssm_scan_ms") == pytest.approx(400e-6 / 2)
     assert ev.metric("ssm_proj_ms") == pytest.approx(300e-6 / 2)
-    assert ev.metric("nh_experts_ms") == pytest.approx(200e-6 / 2)
-    assert ev.metric("nh_server_update_ms") == pytest.approx(200e-6 / 2)
-    assert ev.metric("nh_layers_unscoped_ms") == pytest.approx(100e-6 / 2)
+    assert ev.metric("experts_ms") == pytest.approx(200e-6 / 2)
+    assert ev.metric("server_update_ms") == pytest.approx(200e-6 / 2)
+    assert ev.metric("layers_unscoped_ms") == pytest.approx(100e-6 / 2)
     assert ev.metric("shared_expert_ms") == 0.0
     # the ten add up to what the two stages took
     total = sum(ev.metric(n) for n in (
-        "ssm_proj_ms", "ssm_scan_ms", "shared_expert_ms", "nh_attention_ms",
-        "nh_router_ms", "nh_expert_dispatch_ms", "nh_experts_ms",
-        "nh_lm_head_ms", "nh_server_update_ms", "nh_layers_unscoped_ms"))
+        "ssm_proj_ms", "ssm_scan_ms", "shared_expert_ms", "attention_ms",
+        "router_ms", "expert_dispatch_ms", "experts_ms",
+        "lm_head_ms", "server_update_ms", "layers_unscoped_ms"))
     assert total == pytest.approx((1000 + 200) * 1e-6 / 2)
     # 0.05 bytes at 1e6 a second bound (5e-8 s; 100 operations take 1e-7...
     # no: 1e-7 s is longer): the operations bound, over 0.2 us of scan
@@ -158,7 +160,7 @@ def test_hybrid_layers_sums_self_times_by_innermost_scope():
     assert ev.metric("ssm_scan_roofline") == pytest.approx(100 * 1e-7 / 0.2e-6)
     # 10 held assignments a round: 3 * 10 * 4 * 8 * 6 operations in 0.1 us
     flops = flops_nemotron_h.held_experts_flops(TINY, 10)
-    assert ev.metric("nh_experts_mfu") == pytest.approx(100 * flops / 0.1e-6 / 1e9)
+    assert ev.metric("experts_mfu") == pytest.approx(100 * flops / 0.1e-6 / 1e9)
     assert ev.metric("experts_held_share_pct") == pytest.approx(10.0)
     assert ev.metric("expert_rows_computed_over_routed") == pytest.approx(1.6)
 
@@ -173,7 +175,7 @@ def test_a_program_without_the_scopes_or_counters_gives_nothing():
         {"kind": "counters", "payload": {"counters": {"rounds": 3}, "gauges": {}}}]
     m = manifest.load(ROOT)
     for p in m.doc["per_layer"]:
-        if p.get("workloads") == [CELL]:
+        if CELL in p.get("workloads", ()):
             assert ev.metric(p["name"]) is None, p["name"]
 
 

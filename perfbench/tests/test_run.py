@@ -37,13 +37,20 @@ import json
 import pytest
 
 
-@pytest.mark.parametrize("cell,trace,would_report", [
-    ("income2560-chunk100", "0", ["peak_hbm_mb", "round_ms", "setup_s"]),
-    ("income2560-default", "1", ["backend_init_s", "build_span_s", "data_build_s",
-                                 "job_compiles", "job_fixed_s", "loop_round_ms", "setup_compile_s",
-                                 "setup_compiles"]),
-])
-def test_rehearsal_walks_the_flow_and_reports_nothing(cell, trace, would_report):
+def _host_side(cell, trace):
+    """What a walk-through on the CPU can read: every end-to-end metric, and
+    of the per-layer ones those the host's clock, the program's spans and its
+    counters give (a CPU trace has no device plane). From the manifest, so a
+    PR that declares one more breaks nothing here."""
+    m = manifest.load(ROOT)
+    group = "per_layer" if trace == "1" else "end_to_end"
+    return sorted(e["name"] for e in m.metrics_of(group, cell)
+                  if e["source"] != "device_trace")
+
+
+@pytest.mark.parametrize("cell,trace", [
+    ("income2560-chunk100", "0"), ("income2560-default", "1")])
+def test_rehearsal_walks_the_flow_and_reports_nothing(cell, trace):
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     done = subprocess.run(
         [sys.executable, "-m", "perfbench.run", "--workload", cell, "--seed", "5",
@@ -53,4 +60,14 @@ def test_rehearsal_walks_the_flow_and_reports_nothing(cell, trace, would_report)
     last = json.loads(done.stdout.strip().splitlines()[-1])
     assert last["rehearsal_passed"] is True and last["correct"] is False
     assert "metrics" not in last and "device" not in last
-    assert last["would_report"] == would_report      # no device metric on the CPU
+    would = _host_side(cell, trace)
+    # no device metric on the CPU, and nothing the manifest does not list
+    assert set(last["would_report"]) <= set(would)
+    if trace == "0":
+        assert last["would_report"] == would == [
+            "peak_hbm_mb", "round_ms", "setup_s"]
+    else:
+        assert {"backend_init_s", "build_span_s", "data_build_s",
+                "job_compiles", "job_fixed_s", "loop_round_ms",
+                "setup_compile_s", "setup_compiles"} <= set(
+                    last["would_report"])

@@ -15,13 +15,14 @@ import pytest
 
 from perfbench import datasets_lm, flops_xing4, manifest, xplane
 from perfbench.evidence import Evidence
+from perfbench.tests.entries import check_cell
 
 ROOT = os.path.dirname(manifest.HERE)
 CELL, CONFIG = "xing4-l5-mtp1-fed8-4k", "xing4-29b-a4b-l5-mtp1-fed8"
-ADDING_UP = ("x4_attention_ms", "x4_hyper_conn_ms", "x4_dense_mlp_ms",
-             "x4_shared_expert_ms", "x4_router_ms", "x4_expert_dispatch_ms",
-             "x4_experts_ms", "x4_mtp_proj_ms", "x4_lm_head_ms",
-             "x4_server_update_ms", "x4_layers_unscoped_ms")
+ADDING_UP = ("attention_ms", "x4_hyper_conn_ms", "dense_mlp_ms",
+             "shared_expert_ms", "router_ms", "expert_dispatch_ms",
+             "experts_ms", "x4_mtp_proj_ms", "lm_head_ms",
+             "server_update_ms", "layers_unscoped_ms")
 TINY = {"hidden_size": 8, "num_hidden_layers": 3, "first_k_dense_replace": 1,
         "num_nextn_predict_layers": 1, "num_attention_heads": 2,
         "q_lora_rank": 6, "kv_lora_rank": 4, "qk_nope_head_dim": 4,
@@ -31,7 +32,7 @@ TINY = {"hidden_size": 8, "num_hidden_layers": 3, "first_k_dense_replace": 1,
         "moe_intermediate_size": 6, "vocab_size": 32}
 
 
-def test_the_new_entries_resolve_and_touch_no_other_cell():
+def test_the_entries_that_list_the_cell_resolve_and_no_other_models_do():
     m = manifest.load(ROOT)
     cell = m.cell(CELL)
     assert cell["config"] == CONFIG and cell["chips"] == 1
@@ -49,21 +50,14 @@ def test_the_new_entries_resolve_and_touch_no_other_cell():
     # ONE round is compared, and the warm-up job ends there
     assert traffic["trace_chunks"] == 1
     assert traffic["check_rounds"] == traffic["warmup_rounds"] == 1
-    own = [p for p in m.doc["per_layer"] if p.get("workloads") == [CELL]]
-    assert len(own) == 33 and all(p["moves"] == "round_ms" for p in own)
-    assert all(p["name"].startswith("x4_") for p in own)
-    assert set(ADDING_UP) <= {p["name"] for p in own}
+    listed = check_cell(m, CELL)
+    names = {p["name"] for p in listed}
+    assert set(ADDING_UP) <= names
+    own = [p for p in listed if p["name"].startswith("x4_")]
+    assert own and all(p["moves"] == "round_ms" for p in own)
     for p in own:
         assert m.layer_metric(p["name"])["read"]["kind"] in (
             "trace", "registry", "registry_ratio")
-    # appended: the new entries are the last of their lists, and no accepted
-    # metric's list gained or lost a cell
-    assert m.doc["per_layer"][-33:] == own
-    assert m.doc["workloads"][-1]["name"] == CELL
-    assert m.doc["configs"][-1]["name"] == CONFIG
-    for p in m.doc["per_layer"]:
-        if p not in own:
-            assert CELL not in p.get("workloads", [])
     assert {e["name"] for e in m.metrics_of("end_to_end", CELL)} >= {
         "setup_s", "round_ms", "peak_hbm_mb"}
     assert len(json.dumps(m.doc)) < 64 * 1024
@@ -151,7 +145,7 @@ def _view(ops, host=()):
                             start=0.0, end=max(o.end for o in ops))
 
 
-def test_xing4_layers_sums_self_times_by_innermost_scope():
+def test_lm_layers_sums_the_four_stream_stack_by_innermost_scope():
     op = xplane.Op
     ev = Evidence(manifest=manifest.load(ROOT))
     ev.trace = _view(
@@ -166,6 +160,7 @@ def test_xing4_layers_sums_self_times_by_innermost_scope():
         peaks={"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e6},
         cost={"core_flops": 60.0, "hyper": {"flops": 10.0, "bytes": 0.05}})
     ev.sinks["job"] = [
+        {"kind": "manifest", "payload": {"config": {"model": {"kind": "xing4"}}}},
         {"kind": "program_scopes", "payload": {
             "program": "round_step",
             "scopes": {"while.1": "client_train", "fusion.1 bf16[8]": "client_train",
@@ -189,31 +184,31 @@ def test_xing4_layers_sums_self_times_by_innermost_scope():
             "moe_rows_computed": 64.0, "mtp_positions": 150.0,
             "lm_fused_attention_positions": 200.0},
             "gauges": {"attention_padded_width": 256.0}}}]
-    assert ev.metric("x4_attention_ms") == pytest.approx(500e-6 / 2)
+    assert ev.metric("attention_ms") == pytest.approx(500e-6 / 2)
     assert ev.metric("x4_hyper_conn_ms") == pytest.approx(200e-6 / 2)
-    assert ev.metric("x4_experts_ms") == pytest.approx(200e-6 / 2)
-    assert ev.metric("x4_server_update_ms") == pytest.approx(200e-6 / 2)
-    assert ev.metric("x4_layers_unscoped_ms") == pytest.approx(100e-6 / 2)
-    assert ev.metric("x4_dense_mlp_ms") == 0.0
+    assert ev.metric("experts_ms") == pytest.approx(200e-6 / 2)
+    assert ev.metric("server_update_ms") == pytest.approx(200e-6 / 2)
+    assert ev.metric("layers_unscoped_ms") == pytest.approx(100e-6 / 2)
+    assert ev.metric("dense_mlp_ms") == 0.0
     # the eleven add up to what the two stages took
     assert sum(ev.metric(n) for n in ADDING_UP) == pytest.approx(
         (1000 + 200) * 1e-6 / 2)
     # the pieces, and the module's overlapping sum
     assert ev.metric("x4_attn_latent_ms") == pytest.approx(200e-6 / 2)
     assert ev.metric("x4_hc_sinkhorn_ms") == pytest.approx(200e-6 / 2)
-    assert ev.metric("x4_attn_core_ms") == pytest.approx(300e-6 / 2)   # lm_pieces
+    assert ev.metric("attn_core_ms") == pytest.approx(300e-6 / 2)   # lm_pieces
     assert ev.metric("x4_mtp_ms") == pytest.approx(400e-6 / 2)
     # 60 operations a round in 0.15 us at 1e9 a second
-    assert ev.metric("x4_attn_core_mfu") == pytest.approx(100 * 60 / 0.15e-6 / 1e9)
+    assert ev.metric("attn_core_mfu") == pytest.approx(100 * 60 / 0.15e-6 / 1e9)
     # 0.05 bytes at 1e6 a second: 5e-8 s; 10 operations: 1e-8 s; bytes bound
     assert ev.notes["x4_hyper_conn_roofline_bound"] == "bytes"
     assert ev.metric("x4_hyper_conn_roofline") == pytest.approx(100 * 5e-8 / 0.1e-6)
     flops = flops_xing4.held_experts_flops(TINY, 10)
-    assert ev.metric("x4_experts_mfu") == pytest.approx(100 * flops / 0.1e-6 / 1e9)
-    assert ev.metric("x4_experts_held_share_pct") == pytest.approx(12.5)
-    assert ev.metric("x4_expert_rows_computed_over_routed") == pytest.approx(1.6)
+    assert ev.metric("experts_mfu") == pytest.approx(100 * flops / 0.1e-6 / 1e9)
+    assert ev.metric("experts_held_share_pct") == pytest.approx(12.5)
+    assert ev.metric("expert_rows_computed_over_routed") == pytest.approx(1.6)
     assert ev.metric("x4_mtp_positions_pct") == pytest.approx(75.0)
-    assert ev.metric("x4_attention_fused_pct") == pytest.approx(100.0)
+    assert ev.metric("attention_fused_pct") == pytest.approx(100.0)
     assert ev.metric("x4_attention_padded_width") == 256.0
 
 
@@ -227,7 +222,7 @@ def test_a_program_without_the_scopes_or_counters_gives_nothing():
         {"kind": "counters", "payload": {"counters": {"rounds": 3}, "gauges": {}}}]
     m = manifest.load(ROOT)
     for p in m.doc["per_layer"]:
-        if p.get("workloads") == [CELL]:
+        if CELL in p.get("workloads", ()):
             assert ev.metric(p["name"]) is None, p["name"]
 
 
@@ -399,8 +394,8 @@ def test_the_traced_walk_through_would_report_the_new_metrics():
     would = set(last["would_report"])
     # the registry's and the scopes' (a CPU trace has no device plane: the
     # device-trace metrics need the chip)
-    assert {"x4_mtp_positions_pct", "x4_experts_held_share_pct",
-            "x4_expert_rows_computed_over_routed", "x4_moe_tokens_dropped",
-            "x4_padding_pct", "x4_attention_fused_pct",
-            "x4_experts_grouped_pct", "x4_attention_padded_width",
-            "x4_expert_load_max_over_mean"} <= would
+    assert {"x4_mtp_positions_pct", "experts_held_share_pct",
+            "expert_rows_computed_over_routed", "moe_tokens_dropped",
+            "lm_padding_pct", "attention_fused_pct",
+            "experts_grouped_pct", "x4_attention_padded_width",
+            "expert_load_max_over_mean"} <= would
