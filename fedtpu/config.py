@@ -96,7 +96,7 @@ class ModelConfig:
     """Model family + shape. MLP is FL_CustomMLP...:12-25; ConvNet is the
     BASELINE.json config-5 CIFAR-10 stress model (new, no reference analogue)."""
 
-    kind: str = "mlp"                    # 'mlp' | 'convnet' | 'olmoe' | 'nemotron_h' | 'xing4' | 'kimi_linear'
+    kind: str = "mlp"                    # 'mlp' | 'convnet' | 'olmoe' | 'nemotron_h' | 'xing4' | 'kimi_linear' | 'phi4_flash'
     # () degenerates the MLP to a single Linear — multinomial logistic
     # regression (pinned by tests/test_round_smoke.py).
     hidden_sizes: Tuple[int, ...] = (50, 200)  # FL_CustomMLP...:40
@@ -205,6 +205,30 @@ class ModelConfig:
     kda_head_dim: int = 128
     short_conv_kernel_size: int = 4
     mla_use_nope: bool = False
+    # kind='phi4_flash' (fedtpu.models.phi4_flash): the keys of the published
+    # config.json of microsoft/Phi-4-mini-flash-reasoning (``model_type:
+    # phi4flash``) under their own names; it also reads hidden_size,
+    # num_attention_heads, num_key_value_heads, num_hidden_layers (the
+    # PUBLISHED depth, 32: a layer's kind follows from its published index),
+    # intermediate_size and vocab_size above, which its preset sets. Even
+    # layers a Mamba-1 mixer (mamba_* are the family's names for the inner
+    # expansion, the state, the taps and the step's rank; 0 = ceil(hidden /
+    # 16)), odd layers of the first half differential attention under
+    # sliding_window; layer depth / 2 keeps its scan's output as the memory,
+    # the next is full attention whose keys and values are kept, and after
+    # them even layers are Gated Memory Units and odd ones cross-attention.
+    # LayerNorm with a bias, a gated SiLU feed-forward every layer, no
+    # positions, the head tied to the embedding. layers_held: the published
+    # indices of the layers this chip holds, in order (() = all of them).
+    sliding_window: int = 512
+    mb_per_layer: int = 2
+    layer_norm_eps: float = 1e-5
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    tie_word_embeddings: bool = False
+    layers_held: Tuple[int, ...] = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -823,6 +847,34 @@ PRESETS["kimi-linear-48b-a3b-l5"] = ExperimentConfig(
                       norm_topk_prob=True, routed_scaling_factor=2.446,
                       rms_norm_eps=1e-5, vocab_size=20480, experts_held=8,
                       first_expert=0, compute_dtype="bfloat16"),
+    optim=OptimConfig(name="sgd", learning_rate=0.005, momentum=0.0,
+                      steplr_gamma=1.0),
+    fed=FedConfig(rounds=20, client_state="stateless", local_batch_rows=1,
+                  one_step_kind=True, server_opt="fedavgm",
+                  server_momentum=0.9, same_init=True),
+)
+
+
+# microsoft/Phi-4-mini-flash-reasoning (SambaY) at its published widths, as
+# one 16 GB chip of a four-stage pipeline holds it: eight of its 32 layers
+# (two periods of the self-decoder, Mamba-1 and window-512 differential
+# attention; the Mamba-1 layer that keeps the memory and the full attention
+# that keeps keys and values; one period of the cross-decoder, a Gated Memory
+# Unit and cross-attention) and a quarter of the vocabulary, the head tied to
+# the embedding: 979.4M parameters. Federated as the other language models'
+# presets are, on 16 packed 4,096-token sequences, with one kind of step
+# (PERF.md section 6, PR 44).
+PRESETS["phi4-mini-flash-l8"] = ExperimentConfig(
+    data=DataConfig(dataset_name="tokens", synthetic_rows=16,
+                    synthetic_features=4096),
+    shard=ShardConfig(num_clients=8, shuffle=False),
+    model=ModelConfig(kind="phi4_flash", hidden_size=2560,
+                      num_attention_heads=40, num_key_value_heads=20,
+                      num_hidden_layers=32,
+                      layers_held=(0, 1, 2, 3, 16, 17, 18, 19),
+                      intermediate_size=10240, sliding_window=512,
+                      tie_word_embeddings=True, vocab_size=50016,
+                      compute_dtype="bfloat16"),
     optim=OptimConfig(name="sgd", learning_rate=0.005, momentum=0.0,
                       steplr_gamma=1.0),
     fed=FedConfig(rounds=20, client_state="stateless", local_batch_rows=1,

@@ -4,8 +4,8 @@ A model file imports ``fedtpu.ops`` and this module and never another model;
 what only one model uses stays in that model. Everything here works on one
 packed sequence (``(2, T)`` int32 token and segment ids, 0 marking padding).
 
-* **Norm, positions, the initializer.** ``rms_norm``, ``_rope``,
-  ``segment_positions``, ``INIT_STD``, ``cut_from_one_draw``.
+* **Norm, positions, the initializer.** ``rms_norm``, ``layer_norm``,
+  ``_rope``, ``segment_positions``, ``INIT_STD``, ``cut_from_one_draw``.
 * **An expert layer that holds a share** (``experts_mixer``: the hybrid
   stack's ``relu^2`` experts, and the gated ones of the four-stream and the
   delta-rule stack). The layer is told ``experts_held`` and
@@ -77,6 +77,15 @@ INIT_STD = 0.02
 def rms_norm(x, gain, eps):
     x = x.astype(jnp.float32)
     return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
+
+
+def layer_norm(x, gain, bias, eps):
+    """LayerNorm over the last axis, float32: mean and variance taken, a
+    gain and a bias."""
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    return (x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+            * gain + bias)
 
 
 def segment_positions(segs):
@@ -426,11 +435,23 @@ def latent_attention(cfg, compute_dtype, u, layer, segs, pos):
 
 
 def dense_mlp(cfg, compute_dtype, u, layer):
-    """``W_down(silu(W_gate x) * W_up x)`` of ``x = RMSNorm(u)``."""
+    """``W_down(silu(W_gate x) * W_up x)`` of ``x = RMSNorm(u)``. A layer
+    that has ``gate_up`` holds the two first matrices as one, ``[gate | up]``
+    (one product, then cut in two), and one that has ``norm_bias`` stands
+    behind a LayerNorm (``cfg.layer_norm_eps``)."""
     cast = lambda arr: arr.astype(compute_dtype)
     with jax.named_scope(DENSE_MLP):
-        x = cast(rms_norm(u, layer["norm"], cfg.rms_norm_eps))
-        act = jax.nn.silu(_mm(x, cast(layer["gate"]))) * _mm(x, cast(layer["up"]))
+        if "norm_bias" in layer:
+            x = cast(layer_norm(u, layer["norm"], layer["norm_bias"],
+                                cfg.layer_norm_eps))
+        else:
+            x = cast(rms_norm(u, layer["norm"], cfg.rms_norm_eps))
+        if "gate_up" in layer:
+            gate, up = jnp.split(_mm(x, cast(layer["gate_up"])), 2, axis=-1)
+            act = jax.nn.silu(gate) * up
+        else:
+            act = (jax.nn.silu(_mm(x, cast(layer["gate"])))
+                   * _mm(x, cast(layer["up"])))
         return _mm(cast(act), cast(layer["down"]))
 
 

@@ -109,8 +109,9 @@ def layer_kinds(cfg) -> tuple:
     if unknown:
         raise ValueError(
             f"hybrid_override_pattern {pattern!r} has letters {unknown}: "
-            f"the stack knows {sorted(KINDS)} (a plain MLP layer, '-', is "
-            "not built)")
+            f"the stack knows {sorted(KINDS)}: one mixer a layer. (A plain "
+            "MLP layer, '-', is not built in THIS stack; a model whose every "
+            "layer is a mixer and a feed-forward is kind='phi4_flash'.)")
     if len(pattern) != cfg.num_hidden_layers:
         raise ValueError(
             f"hybrid_override_pattern {pattern!r} has {len(pattern)} layers "

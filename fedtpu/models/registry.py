@@ -21,7 +21,8 @@ _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16,
 LANGUAGE_MODELS = {"olmoe": "fedtpu.models.olmoe",
                    "nemotron_h": "fedtpu.models.nemotron_h",
                    "xing4": "fedtpu.models.xing4",
-                   "kimi_linear": "fedtpu.models.kimi_linear"}
+                   "kimi_linear": "fedtpu.models.kimi_linear",
+                   "phi4_flash": "fedtpu.models.phi4_flash"}
 
 
 def build_model(cfg: ModelConfig):

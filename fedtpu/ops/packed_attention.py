@@ -1,7 +1,10 @@
 """The attention core of one packed row: one function, two bodies.
 
 ``attention_core(q, k, v, segs)`` is ``softmax(mask(q k^T scale)) v``, causal
-within a document. Its XLA body (``_xla_attention``) is the definition: it
+within a document, and under a window where one is asked for (a query sees
+the ``window`` positions that end at its own: the table of kept block pairs,
+the mask inside a block and the XLA body each take it; with none, each is
+what it was). Its XLA body (``_xla_attention``) is the definition: it
 writes the ``[heads, T, T]`` float32 scores, the masked scores and the
 probabilities to memory and keeps them for the backward pass. Its fused body
 (``_fused_attention``) is the three tiled kernels below with an online
@@ -85,15 +88,21 @@ def block_ranges(segs, block: int):
     return jnp.stack([ids.min(axis=1), ids.max(axis=1)], axis=1)
 
 
-def pairs_kept(segs, block: int):
+def pairs_kept(segs, block: int, window: int | None = None):
     """``(blocks, blocks)`` bool, ``[query block, key block]``, of the row
     ``segs (T,)``: on or under the diagonal, and the two blocks' ranges of
-    ids (``block_ranges``) overlap."""
+    ids (``block_ranges``) overlap. Under a ``window`` (a query sees the
+    ``window`` positions that end at its own) also: the key block's last
+    position is within the window of the query block's first."""
     ranges = block_ranges(segs, block)
     lo, hi = ranges[:, 0], ranges[:, 1]
     at = jnp.arange(ranges.shape[0])
-    return ((at[:, None] >= at[None, :]) & (lo[:, None] <= hi[None, :])
+    kept = ((at[:, None] >= at[None, :]) & (lo[:, None] <= hi[None, :])
             & (lo[None, :] <= hi[:, None]))
+    if window is None:
+        return kept
+    # q0 - (k0 + block - 1) < window, q0 and k0 the blocks' first positions
+    return kept & ((at[:, None] - at[None, :]) * block - (block - 1) < window)
 
 
 def _held(kept):
@@ -111,13 +120,16 @@ def _held(kept):
     return following % kept.shape[1]
 
 
-def _mask(q_ids_ref, k_ids_ref, qi, ki, block):
+def _mask(q_ids_ref, k_ids_ref, qi, ki, block, window=None):
     """Causal, and equal segment ids: ``(block, block)`` bool of the pair of
-    blocks ``(qi, ki)``."""
+    blocks ``(qi, ki)``; under a ``window``, the key within it too."""
     q_ids = jnp.tile(q_ids_ref[...], (1, block // LANES))
     rows = lax.broadcasted_iota(jnp.int32, (block, block), 0) + qi * block
     cols = lax.broadcasted_iota(jnp.int32, (block, block), 1) + ki * block
-    return jnp.logical_and(q_ids == k_ids_ref[:1, :], cols <= rows)
+    mask = jnp.logical_and(q_ids == k_ids_ref[:1, :], cols <= rows)
+    if window is None:
+        return mask
+    return jnp.logical_and(mask, rows - cols < window)
 
 
 def _wide(a, width):
@@ -125,7 +137,7 @@ def _wide(a, width):
 
 
 def _forward_kernel(runs_ref, held_ref, q_ref, k_ref, v_ref, q_ids_ref,
-                    k_ids_ref, o_ref, *rest, sm_scale):
+                    k_ids_ref, o_ref, *rest, sm_scale, window=None):
     # the rows' sums and maxima are written where a backward pass will read
     # them, and not by a forward pass alone
     *statistics, m_scratch, l_scratch, acc_scratch = rest
@@ -146,8 +158,8 @@ def _forward_kernel(runs_ref, held_ref, q_ref, k_ref, v_ref, q_ids_ref,
                             preferred_element_type=jnp.float32)
         if sm_scale != 1.0:
             s *= sm_scale
-        s += jnp.where(_mask(q_ids_ref, k_ids_ref, qi, ki, block), 0.0,
-                       MASK_VALUE)
+        s += jnp.where(_mask(q_ids_ref, k_ids_ref, qi, ki, block, window),
+                       0.0, MASK_VALUE)
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
         p = jnp.exp(s - _wide(m_next, block))
         l_corr = jnp.exp(m_prev - m_next) * l_prev
@@ -185,7 +197,7 @@ def _probabilities_and_ds(q, k, v, l, m, do, di, mask, sm_scale):
 
 def _dkv_kernel(runs_ref, held_ref, q_ref, k_ref, v_ref, q_ids_ref, k_ids_ref,
                 l_ref, m_ref, do_ref, di_ref, dk_ref, dv_ref, dk_scratch,
-                dv_scratch, *, sm_scale):
+                dv_scratch, *, sm_scale, window=None):
     del held_ref
     ki, qi, blocks = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
     block = q_ref.shape[1]
@@ -200,7 +212,7 @@ def _dkv_kernel(runs_ref, held_ref, q_ref, k_ref, v_ref, q_ids_ref, k_ids_ref,
         q, do = q_ref[0], do_ref[0]
         p, ds = _probabilities_and_ds(
             q, k_ref[0], v_ref[0], l_ref[0], m_ref[0], do, di_ref[0],
-            _mask(q_ids_ref, k_ids_ref, qi, ki, block), sm_scale)
+            _mask(q_ids_ref, k_ids_ref, qi, ki, block, window), sm_scale)
         dv_scratch[...] += lax.dot(p.T.astype(do.dtype), do,
                                    preferred_element_type=jnp.float32)
         dk_scratch[...] += lax.dot(ds.T.astype(do.dtype), q,
@@ -213,7 +225,8 @@ def _dkv_kernel(runs_ref, held_ref, q_ref, k_ref, v_ref, q_ids_ref, k_ids_ref,
 
 
 def _dq_kernel(runs_ref, held_ref, q_ref, k_ref, v_ref, q_ids_ref, k_ids_ref,
-               l_ref, m_ref, do_ref, di_ref, dq_ref, dq_scratch, *, sm_scale):
+               l_ref, m_ref, do_ref, di_ref, dq_ref, dq_scratch, *, sm_scale,
+               window=None):
     del held_ref
     qi, ki, blocks = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
     block = q_ref.shape[1]
@@ -227,7 +240,7 @@ def _dq_kernel(runs_ref, held_ref, q_ref, k_ref, v_ref, q_ids_ref, k_ids_ref,
         k = k_ref[0]
         _, ds = _probabilities_and_ds(
             q_ref[0], k, v_ref[0], l_ref[0], m_ref[0], do_ref[0], di_ref[0],
-            _mask(q_ids_ref, k_ids_ref, qi, ki, block), sm_scale)
+            _mask(q_ids_ref, k_ids_ref, qi, ki, block, window), sm_scale)
         dq_scratch[...] += lax.dot(ds.astype(k.dtype), k,
                                    preferred_element_type=jnp.float32)
 
@@ -284,7 +297,16 @@ def _ids(segs):
             lax.broadcast_in_dim(segs, (SUBLANES, t), (1,)))
 
 
-def _forward(q, k, v, segs, kept, sm_scale, block, statistics: bool):
+def _bound(kernel, sm_scale, window):
+    """The kernel with its constants; the window only where there is one, so
+    that a call without is the call it always was."""
+    if window is None:
+        return functools.partial(kernel, sm_scale=sm_scale)
+    return functools.partial(kernel, sm_scale=sm_scale, window=window)
+
+
+def _forward(q, k, v, segs, kept, sm_scale, block, statistics: bool,
+             window=None):
     """``(ctx, l, m)``; ``l`` and ``m (heads, T)``, the rows' sums and
     maxima, only where ``statistics`` (a backward pass follows)."""
     heads, t, width = q.shape
@@ -293,7 +315,7 @@ def _forward(q, k, v, segs, kept, sm_scale, block, statistics: bool):
                                                     False)
     stat = jax.ShapeDtypeStruct((heads, t, LANES), jnp.float32)
     o, *lm = _call(
-        functools.partial(_forward_kernel, sm_scale=sm_scale),
+        _bound(_forward_kernel, sm_scale, window),
         "packed_attention_forward", kept, False, (q, k, v, *_ids(segs)),
         [rows, keys, keys, row_ids, key_ids],
         [rows] + [row_sums] * 2 * statistics,
@@ -306,7 +328,8 @@ def _forward(q, k, v, segs, kept, sm_scale, block, statistics: bool):
     return (o, *(a[..., 0] for a in lm))
 
 
-def _backward(q, k, v, segs, kept, l, m, do, di, sm_scale, block):
+def _backward(q, k, v, segs, kept, l, m, do, di, sm_scale, block,
+              window=None):
     _, t, width = q.shape
     lanes = lambda a: jnp.broadcast_to(a[..., None], (*a.shape, LANES))
     operands = (q, k, v, *_ids(segs), lanes(l), lanes(m), do, lanes(di))
@@ -317,7 +340,7 @@ def _backward(q, k, v, segs, kept, l, m, do, di, sm_scale, block):
         rows, keys, row_ids, key_ids, row_sums = _specs(
             block, width, t // block, queries_inner)
         return _call(
-            functools.partial(kernel, sm_scale=sm_scale), name, kept,
+            _bound(kernel, sm_scale, window), name, kept,
             queries_inner, operands,
             [rows, keys, keys, row_ids, key_ids, row_sums, row_sums, rows,
              row_sums], [keys if queries_inner else rows] * len(of),
@@ -330,22 +353,23 @@ def _backward(q, k, v, segs, kept, l, m, do, di, sm_scale, block):
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _attention(q, k, v, segs, sm_scale, block):
-    kept = pairs_kept(segs, block)
-    return _forward(q, k, v, segs, kept, sm_scale, block, False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _attention(q, k, v, segs, sm_scale, block, window=None):
+    kept = pairs_kept(segs, block, window)
+    return _forward(q, k, v, segs, kept, sm_scale, block, False, window)[0]
 
 
-def _attention_fwd(q, k, v, segs, sm_scale, block):
-    kept = pairs_kept(segs, block)
-    o, l, m = _forward(q, k, v, segs, kept, sm_scale, block, True)
+def _attention_fwd(q, k, v, segs, sm_scale, block, window=None):
+    kept = pairs_kept(segs, block, window)
+    o, l, m = _forward(q, k, v, segs, kept, sm_scale, block, True, window)
     return o, (q, k, v, segs, kept, o, l, m)
 
 
-def _attention_bwd(sm_scale, block, residuals, do):
+def _attention_bwd(sm_scale, block, window, residuals, do):
     q, k, v, segs, kept, o, l, m = residuals
     di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
-    dq, dk, dv = _backward(q, k, v, segs, kept, l, m, do, di, sm_scale, block)
+    dq, dk, dv = _backward(q, k, v, segs, kept, l, m, do, di, sm_scale, block,
+                           window)
     return dq, dk, dv, None
 
 
@@ -356,13 +380,15 @@ _attention.defvjp(_attention_fwd, _attention_bwd)
 # shape share one trace and one lowering of each kernel, where every call
 # site would bring its own (24 Mosaic payloads in the four-stream round for
 # 4, and 7 to 9 s of its compile: PERF.md section 6, PR 38).
-@functools.partial(jax.jit, static_argnames=("sm_scale", "block"))
-def attention(q, k, v, segs, sm_scale: float, block: int):
+@functools.partial(jax.jit, static_argnames=("sm_scale", "block", "window"))
+def attention(q, k, v, segs, sm_scale: float, block: int,
+              window: int | None = None):
     """``ctx (heads, T, d)`` in the operands' dtype: causal attention within
     the segments of ``segs (T,)`` int32 (equal ids, padding's 0 among them),
     ``q``, ``k``, ``v`` ``(heads, T, d)`` with ``d`` whole lane tiles and
-    ``T`` whole ``block``s of whole lanes; reverse mode only."""
-    return _attention(q, k, v, segs, sm_scale, block)
+    ``T`` whole ``block``s of whole lanes; under a ``window`` a query sees
+    only the ``window`` positions that end at its own; reverse mode only."""
+    return _attention(q, k, v, segs, sm_scale, block, window)
 
 
 # ----------------------------------------- the core and its two bodies
@@ -399,7 +425,7 @@ def fused_attention_applies(q, k, v) -> bool:
             and d % 128 == 0 and t % ATTENTION_BLOCK == 0)
 
 
-def _xla_attention(q, k, v, segs, scale=None):
+def _xla_attention(q, k, v, segs, scale=None, window=None):
     t, _, d = q.shape
     scores = jnp.einsum("qhd,khd->hqk", q, k,
                         preferred_element_type=jnp.float32)
@@ -408,31 +434,38 @@ def _xla_attention(q, k, v, segs, scale=None):
     # causal, and within one segment; padding (segment 0) sees padding,
     # which keeps its rows finite and is masked out of the loss
     allowed = (idx[:, None] >= idx[None, :]) & (segs[:, None] == segs[None, :])
+    if window is not None:
+        allowed = allowed & (idx[:, None] - idx[None, :] < window)
     probs = jax.nn.softmax(jnp.where(allowed[None], scores, -1e30), axis=-1)
     return jnp.einsum("hqk,khd->qhd", probs.astype(v.dtype), v,
                       preferred_element_type=jnp.float32)
 
 
-def _fused_attention(q, k, v, segs, scale=None):
+def _fused_attention(q, k, v, segs, scale=None, window=None):
     # the kernels' layout is (heads, T, d); their mask is the XLA body's:
     # causal, and equal segment ids (padding's 0 among them)
     heads_first = lambda a: a.transpose(1, 0, 2)
+    # the window only where there is one: a call without is the call it was
+    under = {} if window is None else {"window": window}
     ctx = attention(
         heads_first(q), heads_first(k), heads_first(v), segs,
-        q.shape[-1] ** -0.5 if scale is None else scale, ATTENTION_BLOCK)
+        q.shape[-1] ** -0.5 if scale is None else scale, ATTENTION_BLOCK,
+        **under)
     return ctx.transpose(1, 0, 2).astype(jnp.float32)
 
 
-def attention_blocks(segs, fused: bool, layers: int) -> dict:
+def attention_blocks(segs, fused: bool, layers: int,
+                     window: int | None = None) -> dict:
     """A sequence's two block counters: the (query block, key block) pairs
     the fused body's forward kernel runs on the row ``segs (T,)``, from the
-    table it reads, and the pairs on or under the diagonal, a head's worth
+    table it reads (a ``window``'s, where the layers have one), and the
+    pairs on or under the diagonal, a head's worth
     for each of ``layers`` attention layers; both 0 where the XLA body ran
     (it has no blocks)."""
     if not fused:
         return {"attention_blocks_computed": jnp.float32(0.0),
                 "attention_blocks_causal": jnp.float32(0.0)}
-    kept = pairs_kept(segs, ATTENTION_BLOCK)
+    kept = pairs_kept(segs, ATTENTION_BLOCK, window)
     blocks = kept.shape[0]
     return {"attention_blocks_computed":
             layers * kept.sum().astype(jnp.float32),
@@ -440,7 +473,7 @@ def attention_blocks(segs, fused: bool, layers: int) -> dict:
             jnp.float32(layers * blocks * (blocks + 1) // 2)}
 
 
-def attention_core(q, k, v, segs, compute_dtype, scale=None):
+def attention_core(q, k, v, segs, compute_dtype, scale=None, window=None):
     """``ctx (T, heads, dv)`` float32: the attention of one packed sequence
     after RoPE and before the output projection, ``q``, ``k`` ``(T, heads,
     dq)`` and ``v (T, heads, dv)`` float32 and cast to ``compute_dtype`` for
@@ -448,7 +481,9 @@ def attention_core(q, k, v, segs, compute_dtype, scale=None):
     none is given). A head whose two widths differ (latent attention: 192
     beside 128) reaches the tiled kernel padded with zeros to one width
     (``padded_head_width``) and its context is cut back: exact, at the
-    padded width's cost. The XLA body takes the widths as they are."""
+    padded width's cost. The XLA body takes the widths as they are. Under a
+    ``window`` a query sees the ``window`` positions of its document that end
+    at its own (``0 <= t - s < window``); without one, all before it."""
     q, k, v = (a.astype(compute_dtype) for a in (q, k, v))
     if scale is None and q.shape == v.shape:
         padded = q, k, v
@@ -460,6 +495,6 @@ def attention_core(q, k, v, segs, compute_dtype, scale=None):
                        for a in (q, k, v))
     with jax.named_scope(ATTN_CORE):
         if not fused_attention_applies(*padded):
-            return _xla_attention(q, k, v, segs, scale)
-        ctx = _fused_attention(*padded, segs, scale)
+            return _xla_attention(q, k, v, segs, scale, window)
+        ctx = _fused_attention(*padded, segs, scale, window)
         return ctx if padded[2] is v else ctx[..., :v.shape[-1]]

@@ -508,3 +508,15 @@ def fused_passes_apply(cfg, t: int) -> bool:
     return jax.default_backend() == "tpu" and tiles_apply(
         t, min(cfg.chunk_size, t), cfg.conv_kernel, width,
         width + 2 * cfg.n_groups * cfg.ssm_state_size, width, cfg.n_groups)
+
+
+def fused_conv_applies(t: int, taps: int, width: int) -> bool:
+    """Whether ``conv_silu`` alone exists for a sequence of ``t`` positions
+    whose convolution is over the first ``width`` columns of its operand,
+    where the program is being built (a mixer with no grouped norm, the
+    Mamba-1 one: the same rule as ``fused_passes_apply`` without the gate's
+    part): a TPU, whole row tiles, a window within a halo, whole lane tiles.
+    ``causal_conv`` under a SiLU is the body everywhere else."""
+    return (jax.default_backend() == "tpu" and t % CONV_TILE[0] == 0
+            and CONV_TILE[0] % LANES == 0 and taps - 1 <= HALO
+            and width % LANES == 0)

@@ -122,7 +122,8 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
     # what a model that holds a share of its experts counts besides, and
     # what its other layers do (nemotron_h's state-space layers; xing4's
     # residual modules and prediction module; kimi_linear's delta-rule
-    # recurrences): read off the statistics it hands out
+    # recurrences; phi4_flash's selective scans and its window): read off
+    # the statistics it hands out
     share = {"moe_assignments_held": "assignments_held",
              "moe_rows_computed": "rows_computed",
              "ssm_positions": "ssm_positions",
@@ -133,18 +134,32 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
              "mtp_positions": "mtp_count",
              "kda_positions": "kda_positions",
              "kda_document_restarts": "kda_restarts",
-             "kda_fused_scan_positions": "kda_fused_scan"}
+             "kda_fused_scan_positions": "kda_fused_scan",
+             "s6_positions": "s6_positions",
+             "s6_chunked_scan_positions": "s6_chunked_scan",
+             "s6_fused_conv_positions": "s6_fused_conv",
+             "s6_document_restarts": "s6_restarts",
+             "lm_attention_pairs": "attention_pairs",
+             "lm_window_pairs": "window_pairs"}
 
     def counters(s):
+        own = {name: s[key] for name, key in share.items() if key in s}
+        lm = {"lm_tokens": s["count"],
+              "lm_padding_tokens": s["padding"],
+              "lm_fused_attention_positions": s["fused_attention"],
+              "lm_attention_blocks_computed": s["attention_blocks_computed"],
+              "lm_attention_blocks_causal": s["attention_blocks_causal"]}
+        if "expert_load" not in s:
+            # a model without an expert layer (phi4_flash) routes nothing
+            # and counts none of the experts' counters
+            return {**lm, **own}
         load = s["expert_load"].astype(jnp.float32)
         routed = load.sum()
         if "assignments_held" in s:
             # its own experts' assignments, all of them inside the blocks
             # it computed; the rest belong to other chips
             dropped = s["assignments_held"] - s["rows_held_computed"]
-            extra = {"moe_assignments_total": routed,
-                     **{name: s[key] for name, key in share.items()
-                        if key in s}}
+            extra = {"moe_assignments_total": routed, **own}
         else:
             # every assignment of a real token is computed
             dropped, extra = assignments * s["tokens"] - routed, {}
@@ -171,11 +186,7 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
             "moe_tokens_dropped": dropped,      # 0 by construction
             "moe_expert_load_max_over_mean": load.max() / jnp.maximum(
                 load.mean(), 1.0),
-            "lm_tokens": s["count"],
-            "lm_padding_tokens": s["padding"],
-            "lm_fused_attention_positions": s["fused_attention"],
-            "lm_attention_blocks_computed": s["attention_blocks_computed"],
-            "lm_attention_blocks_causal": s["attention_blocks_causal"],
+            **lm,
             "moe_grouped_kernel_positions": s["grouped_experts"],
             "moe_expert_load": s["expert_load"],
             **extra,

@@ -745,3 +745,70 @@ def test_the_delta_rule_round_runs_its_recurrences_in_the_tiled_kernels(
     assert directions == dict.fromkeys((FORWARD, RECOMPUTE, BACKWARD), KDA_LAYERS)
     # no (chunks, heads, C, C) plane of scores is an array of the program
     assert "f32[64,32,64,64]" not in kimi_linear_round.as_text()
+
+
+# The decoder-hybrid-decoder round's account (the compiler's own peak, this
+# file's compile for a described v5e).
+PHI4_ROUND_ACCOUNT = 13_804_177_920
+
+
+@pytest.fixture(scope="module")
+def phi4_flash_round(topo):
+    """The round of the decoder-hybrid-decoder preset (``phi4_flash``: eight
+    layers of Phi-4-mini-flash-reasoning at published widths, three Mamba-1
+    mixers, two window and one full differential attention, a Gated Memory
+    Unit and cross-attention, a quarter of the vocabulary, the head tied to
+    the embedding, 979.3M parameters)."""
+    return _one_step_kind_round(topo, "phi4-mini-flash-l8", 979_332_096)
+
+
+def test_the_decoder_hybrid_decoder_round_at_published_widths_fits_one_v5e_chip(
+        phi4_flash_round):
+    """The round's account (the compiler's own peak) lies between the 11.75
+    GB the engine's 12 bytes a parameter come to and the bound the
+    configuration file states (15.7 GB: ISSUE 44), with a QUARTER of the
+    vocabulary held, and is what the file's ``memory`` states to a
+    thousandth of a percent; global and momentum in place. The four
+    attention layers ran the tiled core, two calls a layer (the forward
+    kernel, once more in the layer's recomputation, and the two backward),
+    at the padded head (64 | 128 to 128); the three Mamba-1 convolutions ran
+    the hybrid stack's tiled kernel; every scope the reducers read is in the
+    program. And no array of the program is as large as one row's scan
+    states, ``(4096, 5120, 16)``, forward or backward: a chunk's are the
+    largest of the scan's."""
+    import json
+    import math
+    import os
+
+    from perfbench.drivers.train_xing4 import program_account
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "perfbench", "configs",
+                           "phi4-mini-flash-l8-fed8.json")) as fh:
+        memory = json.load(fh)["memory"]
+    account = program_account(phi4_flash_round.memory_analysis())
+    assert account["peak"] > 0 and account["total"] == account["peak"]
+    assert memory["engine_bytes"] == 12 * 979_332_096
+    assert memory["engine_bytes"] <= account["total"] <= memory[
+        "round_account_bound_bytes"] == 15.7e9, account
+    assert memory["round_account_bytes"] == PHI4_ROUND_ACCOUNT
+    assert abs(account["total"] - PHI4_ROUND_ACCOUNT) <= (
+        1e-5 * PHI4_ROUND_ACCOUNT), account
+    assert account["aliased"] >= 7.8e9
+    text = phi4_flash_round.as_text()
+    assert _attention_kernels(phi4_flash_round) == _attention_calls(
+        forward=2 * 2 * 4, backward=2 * 4)
+    assert re.search(r"bf16\[20,4096,128\]", text)     # q, k, v at one width
+    convs = collections.Counter(
+        name for name, _ in _named_kernels(phi4_flash_round, "s6_conv"))
+    assert convs == {"ssm_conv_forward": 6, "ssm_conv_backward": 3}
+    for scope in ("ssm", "s6_proj", "s6_conv", "s6_scan", "s6_gate", "gmu",
+                  "attention", "attn_core", "attn_window", "attn_full",
+                  "attn_cross", "diff_combine", "dense_mlp", "lm_head_loss",
+                  "embed", "tied_embed_grad", "sgd_pass", "server_update"):
+        assert f"/{scope}/" in text or f"({scope})" in text, scope
+    states = 4096 * 5120 * 16
+    largest = max(math.prod(map(int, shape.split(",")))
+                  for shape in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
+    assert largest == 50_016 * 2560 < states, largest   # the embedding
+    assert "f32[16,4,16,5120]" in text      # a chunk's: 64 positions
