@@ -11,9 +11,10 @@ bf16 operands and float32 sums, gradients rounded to bf16 once, where
 autodiff rounded the XLA body's float32 ones. ``grouped_matmul_applies``
 picks, and it is nobody's to set: the kernels on a TPU at bf16 operands,
 whole row tiles and the widths that have tiles from a sweep on the chip (1024
-and 2048; the hybrid stack's 2688 x 1856); ``lax.ragged_dot`` everywhere
-else. A model's ``grouped_experts`` statistic says which ran. The dispatch of
-every expert layer starts here too (``sorted_assignments``, ``gather_rows``).
+and 2048; the hybrid stack's 2688 x 1856, the Xing4.0 stack's 3584 x 1024,
+the Kimi-Linear stack's 2304 x 1024); ``lax.ragged_dot`` everywhere else. A
+model's ``grouped_experts`` statistic says which ran. The dispatch of every
+expert layer starts here too (``sorted_assignments``, ``gather_rows``).
 """
 
 from __future__ import annotations
@@ -96,6 +97,82 @@ GROUPED_WEIGHT_TILE = 2048 * 1024
 # (256, 1856, 1024) and (256, 1024, 1024) here: 2.99 for the six against
 # 2.51. Neither kernel visits a tile past the last group: the XLA body's
 # 1.7-2.8 ms are mostly the buffer's empty rows.
+# The same three kernels at the Xing4.0 stack's widths (8 held experts of
+# 3,584 x 1,024, a block of 5,632 rows) and at the Kimi-Linear stack's
+# (2,304 x 1,024, a block of 2,816 rows). Chosen on the chip (PERF.md section
+# 6, PR 45) by PR 33's recipe but for the clock: the candidates that fit the
+# kernel's 16 MB (264 of 306, compiled for a described v5e first), one block
+# in 8 groups as each cell's own corpus and initial router fill it (twelve
+# layer-steps: 634 to 3,077 rows filled in groups of 0 to 1,314; 320 to 999
+# in groups of 3 to 402), beside ``lax.ragged_dot`` on the same operands. A
+# call here is shorter than the host's dispatch of it: timed a call a dispatch
+# (20 a filling), every candidate read 0.20-0.26 ms and the ranking was
+# noise. So a ``lax.scan`` runs 120 calls a dispatch (ten times the twelve
+# fillings, the sizes scanned, one element of each result kept), three
+# dispatches timed, mean ms a call with the kernels' own group metadata:
+#   gmm, [5632,3584].[8,3584,1024], float32 out: XLA 0.252; (256, 3584, 512)
+#     0.157, (128, 3584, 512) 0.159, (256, 3584, 256) 0.173, (256, 896, 1024)
+#     0.175, (256, 1792, 1024) 0.176, (128, 3584, 256) 0.178, (256, 1280,
+#     1024) 0.181; best of 512 rows (512, 896, 1024) 0.224; worst (512, 1024,
+#     512) 0.269 of 28.
+#   gmm, [5632,1024].[8,1024,3584], float32 out: XLA 0.263; (256, 1024, 1792)
+#     0.155, (128, 1024, 1792) 0.158, (256, 1024, 896) 0.161, (128, 1024,
+#     896) 0.165, (256, 1024, 1280) 0.166, (128, 1024, 1280) 0.167, (256,
+#     256, 3584) 0.167; best of 512 rows (512, 1024, 896) 0.221; worst (512,
+#     512, 1024) 0.276 of 26.
+#   gmm on the weight in place (transpose_rhs), bf16 out, [5632,1024] to
+#     [5632,3584]: XLA 0.271; (256, 1024, 1792) 0.153, (128, 1024, 1792)
+#     0.156, (256, 1024, 896) 0.159, (128, 1024, 896) 0.163, (256, 1024,
+#     1280) 0.164, (256, 512, 3584) 0.165, (128, 1024, 1280) 0.165; best of
+#     512 rows (512, 1024, 1792) 0.220; worst (512, 512, 1024) 0.274 of 28.
+#   gmm on the weight in place (transpose_rhs), bf16 out, [5632,3584] to
+#     [5632,1024]: XLA 0.308; (256, 3584, 512) 0.156, (128, 3584, 512) 0.158,
+#     (256, 3584, 256) 0.168, (128, 3584, 256) 0.170, (256, 1792, 1024)
+#     0.176, (256, 896, 1024) 0.176, (256, 1280, 1024) 0.182; best of 512
+#     rows (512, 896, 1024) 0.223; worst (512, 1024, 512) 0.270 of 28.
+#   tgmm, to [8,3584,1024]: XLA 0.383; (128, 1792, 1024) 0.147, (128, 896,
+#     1024) 0.158, (128, 3584, 512) 0.163, (128, 1792, 512) 0.170, (128,
+#     1280, 1024) 0.173, (128, 3584, 256) 0.176, (128, 512, 1024) 0.176; best
+#     of 512 rows (512, 896, 1024) 0.253; worst (512, 1024, 512) 0.311 of 25.
+#   tgmm, to [8,1024,3584]: XLA 0.365; (128, 1024, 1792) 0.158, (128, 256,
+#     3584) 0.162, (128, 512, 1792) 0.169, (128, 1024, 896) 0.172, (128,
+#     1024, 1280) 0.172, (256, 256, 3584) 0.187, (128, 1024, 512) 0.188; best
+#     of 512 rows (512, 256, 3584) 0.256; worst (512, 512, 1024) 0.315 of 25.
+#   gmm, [2816,2304].[8,2304,1024], float32 out: XLA 0.141; (128, 2304, 1024)
+#     0.085, (256, 2304, 1024) 0.087, (128, 2304, 512) 0.088, (256, 2304,
+#     512) 0.088, (256, 1152, 1024) 0.095, (256, 768, 1024) 0.097, (128,
+#     2304, 256) 0.099; worst (256, 1024, 512) 0.141 of 18.
+#   gmm, [2816,1024].[8,1024,2304], float32 out: XLA 0.160; (128, 1024, 2304)
+#     0.084, (256, 1024, 2304) 0.085, (128, 1024, 1152) 0.087, (256, 1024,
+#     1152) 0.087, (128, 1024, 768) 0.089, (256, 1024, 768) 0.090, (256, 512,
+#     2304) 0.093; worst (256, 512, 1024) 0.129 of 18.
+#   gmm on the weight in place (transpose_rhs), bf16 out, [2816,1024] to
+#     [2816,2304]: XLA 0.128; (128, 1024, 2304) 0.085, (256, 1024, 2304)
+#     0.086, (128, 1024, 1152) 0.087, (256, 1024, 1152) 0.087, (128, 1024,
+#     768) 0.089, (256, 1024, 768) 0.090, (256, 512, 2304) 0.094; worst (256,
+#     512, 1024) 0.129 of 18.
+#   gmm on the weight in place (transpose_rhs), bf16 out, [2816,2304] to
+#     [2816,1024]: XLA 0.122; (128, 2304, 1024) 0.085, (256, 2304, 1024)
+#     0.087, (128, 2304, 512) 0.087, (256, 2304, 512) 0.088, (128, 2304, 256)
+#     0.094, (256, 1152, 1024) 0.096, (256, 2304, 256) 0.098; worst (256,
+#     1024, 512) 0.141 of 18.
+#   tgmm, to [8,2304,1024]: XLA 0.183; (128, 1152, 1024) 0.080, (128, 768,
+#     1024) 0.084, (128, 2304, 512) 0.090, (128, 1152, 512) 0.096, (128,
+#     2304, 256) 0.098, (256, 1152, 1024) 0.102, (256, 768, 1024) 0.105;
+#     worst (256, 1024, 512) 0.150 of 16.
+#   tgmm, to [8,1024,2304]: XLA 0.184; (128, 512, 2304) 0.081, (128, 1024,
+#     1152) 0.088, (128, 256, 2304) 0.089, (128, 1024, 768) 0.091, (128, 512,
+#     1152) 0.097, (256, 512, 2304) 0.102, (128, 1024, 512) 0.107; worst
+#     (256, 512, 1024) 0.151 of 16.
+# So: both ``gmm`` take the contracted width whole, with half the output
+# width at 3,584 x 1,024 (the whole of either weight does not fit the 16 MB)
+# and the whole of it at 2,304 x 1,024, so a group's weight is fetched once: a
+# call is bound by the eight weights' 59 or 38 MB (0.072 or 0.046 ms at the
+# memory's peak), not by its 0.3 to 3 thousand rows; ``tgmm`` takes half a
+# weight's gradient at a time; 256 rows in Xing4.0's ``gmm`` (by 1-2%: its
+# groups reach 1,314 rows), 128 everywhere else (by 1-28%). In the round
+# (the cells' traced runs, same section) the calls read 0.130-0.163 ms
+# (``tgmm`` to [8,1024,3584] 0.213) and 0.048-0.088.
 _MEASURED_TILES = {
     ("forward", 2688, 1856): (128, 2688, 640),
     ("forward", 1856, 2688): (128, 1856, 896),
@@ -103,6 +180,18 @@ _MEASURED_TILES = {
     ("input_gradient", 1856, 2688): (128, 1856, 896),
     ("weight_gradient", 2688, 1856): (128, 896, 1856),
     ("weight_gradient", 1856, 2688): (128, 1856, 896),
+    ("forward", 3584, 1024): (256, 3584, 512),
+    ("forward", 1024, 3584): (256, 1024, 1792),
+    ("input_gradient", 1024, 3584): (256, 1024, 1792),
+    ("input_gradient", 3584, 1024): (256, 3584, 512),
+    ("weight_gradient", 3584, 1024): (128, 1792, 1024),
+    ("weight_gradient", 1024, 3584): (128, 1024, 1792),
+    ("forward", 2304, 1024): (128, 2304, 1024),
+    ("forward", 1024, 2304): (128, 1024, 2304),
+    ("input_gradient", 1024, 2304): (128, 1024, 2304),
+    ("input_gradient", 2304, 1024): (128, 2304, 1024),
+    ("weight_gradient", 2304, 1024): (128, 1152, 1024),
+    ("weight_gradient", 1024, 2304): (128, 512, 2304),
 }
 
 
@@ -127,8 +216,9 @@ def grouped_matmul_applies(xs, w) -> bool:
     a pair of widths that has tiles from a sweep on the chip: each width
     whole width tiles and, as the contracted width of a kernel, leaving a
     width tile's room in one weight tile (1024 or 2048), or the pair in
-    ``_MEASURED_TILES`` (2688 and 1856). Any other width (1408, 4096) runs
-    ``lax.ragged_dot`` until it has a sweep of its own."""
+    ``_MEASURED_TILES`` (2688 and 1856, 3584 and 1024, 2304 and 1024). Any
+    other width (1408, 4096), or a measured width beside one it was not
+    measured with, runs ``lax.ragged_dot`` until it has a sweep of its own."""
     (rows, k), n = xs.shape, w.shape[2]
     return (jax.default_backend() == "tpu"
             and xs.dtype == w.dtype == jnp.bfloat16

@@ -307,15 +307,19 @@ def test_the_olmoe_round_passes_over_the_parameters_once_a_step(olmoe_round):
 
 @pytest.mark.parametrize("rows,groups,k,width,kernels", [
     (32768, 64, 2048, 1024, True), (32768 + 128, 64, 2048, 1024, False),
-    (32768, 64, 2048, 1408, False), (8192, 8, 2688, 1856, True)])
+    (32768, 64, 2048, 1408, False), (8192, 8, 2688, 1856, True),
+    (5632, 8, 3584, 1024, True), (5632, 8, 1024, 3584, True),
+    (2816, 8, 2304, 1024, True), (2816, 8, 1024, 2304, True)])
 def test_the_grouped_matmul_picks_its_body_by_shape_on_a_tpu(
         topo, monkeypatch, rows, groups, k, width, kernels):
     """The rule itself, told only that the backend is a TPU: at the
-    benchmark's shapes (OLMoE's, and a block of the hybrid stack's held
-    experts, whose widths its tiles do not divide) a grouped matmul and its
-    gradients compile to the three kernels and no ``ragged-dot``; at rows
-    that are no whole tile, or a width there is no tile for, to
-    ``ragged-dot`` and no such kernel."""
+    benchmark's shapes (OLMoE's, a block of the hybrid stack's held
+    experts, whose widths its tiles do not divide, and a block of the
+    Xing4.0 and of the Kimi-Linear stack's, both products: the compile
+    also holds each tile of the table to the kernel's 16 MB) a grouped
+    matmul and its gradients compile to the three kernels and no
+    ``ragged-dot``; at rows that are no whole tile, or a width there is no
+    tile for, to ``ragged-dot`` and no such kernel."""
     from fedtpu.ops.grouped_matmul import grouped_matmul
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -569,10 +573,12 @@ def xing4_round(topo):
 
 
 X4_BLOCKS = 6               # five layers and the prediction module's block
+X4_EXPERT_BLOCKS = 5        # four of the layers and the module's block
 X4_STEP_KINDS = 1           # every step from the working copy: one trace
 # The four-stream round's account with the residual modules' passes in their
-# kernels (the compiler's own peak, this file's compile for a described v5e).
-XING4_ROUND_ACCOUNT = 14_917_739_520
+# kernels and the held experts in the grouped ones (the compiler's own peak,
+# this file's compile for a described v5e).
+XING4_ROUND_ACCOUNT = 14_793_590_784
 
 
 def test_the_four_stream_round_at_published_widths_fits_one_v5e_chip(xing4_round):
@@ -584,12 +590,19 @@ def test_the_four_stream_round_at_published_widths_fits_one_v5e_chip(xing4_round
     (the configuration file is the benchmark's and states PR 37's
     15,146,139,136; with the residual modules' passes in the kernels of PR 41
     the streams' layout copies and the cotangents' sums are no arrays and
-    the compile reads 228 MB less; the chip's compiler allows 15.75 GiB,
+    the compile reads 228 MB less, with the held experts in the grouped
+    kernels of PR 45 no transposed copy of an expert weight is one and it
+    reads 124 MB less again; the chip's compiler allows 15.75 GiB,
     16.9 GB); global and momentum in place. Every block's
     attention ran the tiled core at the padded head, a kind of step: the
     forward kernel, once more in the block's recomputation, and the two
-    backward; the held experts at widths without tiles ran the compiler's
-    own grouped kernel; every scope the reducers read is in the program."""
+    backward; the held experts ran in the grouped kernels at the tiles
+    measured for 3,584 x 1,024 (PR 45), an expert block and kind of step:
+    three products forward, the same in the block's recomputation (the
+    residual module's write reads their sum) and once more in the experts'
+    own backward pass, their three input gradients and their three weight
+    gradients, and the compiler's own grouped kernel is nowhere; every scope
+    the reducers read is in the program."""
     import json
     import os
 
@@ -612,7 +625,10 @@ def test_the_four_stream_round_at_published_widths_fits_one_v5e_chip(xing4_round
     assert _attention_kernels(xing4_round) == _attention_calls(
         forward=2 * each, backward=each)
     assert re.search(r"bf16\[32,4096,256\]", text)      # q, k, v at one width
-    assert "ragged-dot" in text and _pallas_calls(xing4_round, "experts") == []
+    experts = X4_EXPERT_BLOCKS * X4_STEP_KINDS
+    assert sorted(_pallas_calls(xing4_round, "experts")) == (
+        ["gmm"] * 12 * experts + ["tgmm"] * 3 * experts)
+    assert "ragged-dot" not in text
     for scope in ("attention", "attn_core", "attn_latent", "hyper_conn",
                   "hc_sinkhorn", "dense_mlp", "shared_expert", "router",
                   "expert_dispatch", "experts", "mtp", "mtp_proj",
@@ -658,9 +674,11 @@ def test_the_four_stream_round_runs_its_residual_modules_in_the_tiled_kernels(
 
 
 KDA_LAYERS = 4              # of the preset's five, K K K F K
-# The delta-rule round's account with the recurrence in its kernels (the
-# compiler's own peak, this file's compile for a described v5e).
-KIMI_ROUND_ACCOUNT = 9_388_397_056
+KIMI_EXPERT_LAYERS = 4      # every layer after the leading dense one
+# The delta-rule round's account with the recurrence in its kernels and the
+# held experts in the grouped ones (the compiler's own peak, this file's
+# compile for a described v5e).
+KIMI_ROUND_ACCOUNT = 9_230_271_488
 
 
 @pytest.fixture(scope="module")
@@ -680,9 +698,13 @@ def test_the_delta_rule_round_at_published_widths_fits_one_v5e_chip(
     ISSUE 39), and is what the file's ``memory`` states to a thousandth of a
     percent; global and momentum in place. The one attention layer ran the
     tiled core at the padded head (the forward kernel, once more in the
-    layer's recomputation, and the two backward); the held experts at widths
-    without tiles ran the compiler's own grouped kernel; every scope the
-    reducers read is in the program."""
+    layer's recomputation, and the two backward); the held experts ran in
+    the grouped kernels at the tiles measured for 2,304 x 1,024 (PR 45), an
+    expert layer: three products forward and once more in the experts' own
+    backward pass (the layer's recomputation needs no sum of theirs and the
+    compiler drops them there), their three input gradients and their three
+    weight gradients, and the compiler's own grouped kernel is nowhere; every
+    scope the reducers read is in the program."""
     import json
     import os
 
@@ -699,7 +721,8 @@ def test_the_delta_rule_round_at_published_widths_fits_one_v5e_chip(
     # the file is the benchmark's and states PR 39's account, the recurrence
     # in its XLA form; in the kernels of PR 40 its scores, triangular
     # inverses and chunk products are no arrays, and the compile reads
-    # 2.06 GB less
+    # 2.06 GB less; with the held experts in the grouped kernels of PR 45
+    # 158 MB less again
     assert memory["round_account_bytes"] == 11_445_461_504
     assert abs(account["total"] - KIMI_ROUND_ACCOUNT) <= (
         1e-5 * KIMI_ROUND_ACCOUNT), account
@@ -708,8 +731,9 @@ def test_the_delta_rule_round_at_published_widths_fits_one_v5e_chip(
     assert _attention_kernels(kimi_linear_round) == _attention_calls(
         forward=2, backward=1)
     assert re.search(r"bf16\[32,4096,256\]", text)      # q, k, v at one width
-    assert "ragged-dot" in text and _pallas_calls(kimi_linear_round,
-                                                  "experts") == []
+    assert sorted(_pallas_calls(kimi_linear_round, "experts")) == (
+        ["gmm"] * 9 * KIMI_EXPERT_LAYERS + ["tgmm"] * 3 * KIMI_EXPERT_LAYERS)
+    assert "ragged-dot" not in text
     for scope in ("kda", "kda_scan", "kda_in_proj", "kda_conv", "kda_gates",
                   "kda_out_proj", "attention", "attn_core", "attn_latent",
                   "dense_mlp", "shared_expert", "router", "expert_dispatch",

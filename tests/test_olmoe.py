@@ -500,6 +500,9 @@ GROUP_SIZES = {
                            tile - 128],
     # every row in one group
     "one_group": lambda tile: [0, 0, 0, 4 * tile, 0, 0, 0, 0],
+    # a held block as a layer-step fills it: a third of the rows, groups of
+    # under a row tile, three of them empty
+    "third": lambda tile: [0, 90, tile // 2 + 2, 0, 41, 60, 0, 20],
 }
 
 
@@ -535,22 +538,29 @@ CUT_TILES = {
 # their own size. The last two cases run at widths that are no whole number
 # of their tiles (``CUT_TILES``), with groups of no rows and rows past the
 # last group: what the kernel cuts off a tile is in no sum, and no column
-# past a width is written.
+# past a width is written. The four after them run at the Xing4.0 and
+# Kimi-Linear stacks' own widths and the table's own tiles (PR 45), both
+# products of a held expert, on a block filled to a third.
 @pytest.mark.parametrize("groups,k,n", [
     *((name, 256, 128) for name in sorted(GROUP_SIZES)),
-    ("uneven", 384, 192), ("uneven", 192, 384)])
+    ("uneven", 384, 192), ("uneven", 192, 384),
+    ("third", 3584, 1024), ("third", 1024, 3584),
+    ("third", 2304, 1024), ("third", 1024, 2304)])
 @pytest.mark.parametrize("dtype,out_tol,grad_tol", [
     (jnp.float32, 1e-5, 1e-5), (jnp.bfloat16, 1e-5, 2.0 ** -7)])
 def test_the_pallas_grouped_matmul_is_ragged_dot(monkeypatch, dtype, out_tol,
                                                  grad_tol, groups, k, n):
     sizes = np.asarray(GROUP_SIZES[groups](grouped.GROUPED_ROW_TILE), np.int32)
     total = int(sizes.sum())
-    assert (total == ROWS) == (groups != "uneven") and total <= ROWS
+    assert (total == ROWS) == (groups in ("exact", "one_group"))
+    assert total <= ROWS and (groups != "third" or total == ROWS // 3)
+    at_its_own_widths = ("forward", k, n) in grouped._MEASURED_TILES
     for key, tiles in CUT_TILES.items():
         monkeypatch.setitem(grouped._MEASURED_TILES, key, tiles)
     cut = ("forward", k, n) in CUT_TILES
-    assert cut == bool(n % grouped._grouped_tiles("forward", k, n)[2]
-                       or k % grouped._grouped_tiles("input_gradient", n, k)[2])
+    assert at_its_own_widths or cut == bool(
+        n % grouped._grouped_tiles("forward", k, n)[2]
+        or k % grouped._grouped_tiles("input_gradient", n, k)[2])
     keys = jax.random.split(jax.random.key(8), 3)
     xs = jax.random.normal(keys[0], (ROWS, k)).astype(dtype)
     w = jax.random.normal(keys[1], (len(sizes), k, n)).astype(dtype)
@@ -588,7 +598,18 @@ def test_the_pallas_grouped_matmul_is_ragged_dot(monkeypatch, dtype, out_tol,
     ("tpu", (8192, 2688), (8, 2688, 1856), jnp.float32, False),
     ("cpu", (8192, 1856), (8, 1856, 2688), jnp.bfloat16, False),
     # a measured width beside one it was not measured with
-    ("tpu", (8192, 2688), (8, 2688, 1024), jnp.bfloat16, False)])
+    ("tpu", (8192, 2688), (8, 2688, 1024), jnp.bfloat16, False),
+    # the Xing4.0 and Kimi-Linear stacks' held experts (PR 45): a block of
+    # each cell's own rows, the products into and out of the experts
+    ("tpu", (5632, 3584), (8, 3584, 1024), jnp.bfloat16, True),
+    ("tpu", (5632, 1024), (8, 1024, 3584), jnp.bfloat16, True),
+    ("tpu", (2816, 2304), (8, 2304, 1024), jnp.bfloat16, True),
+    ("tpu", (2816, 1024), (8, 1024, 2304), jnp.bfloat16, True),
+    ("tpu", (5632, 3584), (8, 3584, 1024), jnp.float32, False),
+    ("cpu", (2816, 1024), (8, 1024, 2304), jnp.bfloat16, False),
+    ("tpu", (2816 + 128, 2304), (8, 2304, 1024), jnp.bfloat16, False),
+    # each measured with 1,024, not with the other
+    ("tpu", (5632, 3584), (8, 3584, 2304), jnp.bfloat16, False)])
 def test_the_rule_between_the_grouped_matmul_bodies(monkeypatch, backend, xs,
                                                     w, dtype, pallas):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
@@ -608,9 +629,10 @@ def test_the_rule_between_the_grouped_matmul_bodies(monkeypatch, backend, xs,
 
 # OLMoE's tiles are PR 29's to the number (its round program is compared
 # with the parent's whenever this rule changes); the hybrid stack's are the
-# table's, from the sweep of PR 33. Every tile is whole lanes or the whole
-# width (what a block of a Mosaic kernel may be), and its rows divide the
-# row tile the callers' buffers are whole numbers of.
+# table's, from the sweep of PR 33, the Xing4.0 and Kimi-Linear stacks' from
+# the sweep of PR 45. Every tile is whole lanes or the whole width (what a
+# block of a Mosaic kernel may be), and its rows divide the row tile the
+# callers' buffers are whole numbers of.
 @pytest.mark.parametrize("kernel,k,n,tiles", [
     ("forward", 2048, 1024, (256, 2048, 1024)),
     ("forward", 1024, 2048, (256, 1024, 2048)),
