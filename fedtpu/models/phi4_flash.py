@@ -30,7 +30,8 @@ bias). No positions anywhere.
   x_t) (x) B_t``, ``h = 0`` at a document's first token; ``y_t = h_t C_t + D
   * x_t``; ``Mixer = W_out (y * SiLU(z))``. The layer that produces the
   memory hands on ``m = y`` (with the skip, before the gate). The recurrence
-  is ``fedtpu.ops.selective_scan``, a chunk of the row at a time.
+  is ``fedtpu.ops.selective_scan``: two tiled kernels on a TPU, a chunk of
+  the row at a time in XLA everywhere else.
 * **Gated Memory Unit**: ``Mixer = W_2 (m * SiLU(W_1 u))``.
 * **Attention**, self: ``[q | k | v] = W_qkv u + b``, heads of ``d = hidden /
   heads``, scale ``d^-1/2``; key ``s`` is allowed for query ``t`` iff same
@@ -96,7 +97,7 @@ LAMBDA_STD = 0.1
 # what counts a row, not its tokens: a padded row's is left out (rows_stats)
 PER_ROW = ("padding", "fused_attention", "attention_blocks_computed",
            "attention_blocks_causal", "s6_positions", "s6_chunked_scan",
-           "s6_fused_conv")
+           "s6_fused_scan", "s6_fused_conv")
 
 _mm = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
 ATTENTIONS = ("window", "full", "cross")
@@ -298,6 +299,7 @@ def s6_mixer(cfg, compute_dtype, u, layer, segs):
     return out, y, {
         "s6_positions": jnp.float32(t),
         "s6_chunked_scan": jnp.float32(scan.chunked_scan_positions(t)),
+        "s6_fused_scan": jnp.float32(scan.fused_scan_positions(t, inner, n)),
         "s6_fused_conv": jnp.float32(t if fused else 0),
         "s6_restarts": (starts & (segs > 0)).sum().astype(jnp.float32)}
 
@@ -400,8 +402,8 @@ def decoder(layers, h, segs, cfg, compute_dtype):
     """The held layers on the embedded rows ``h (T, C)`` float32: ``(h
     after the last, the Mamba-1 layers' statistics)``."""
     kinds = layer_kinds(cfg)
-    stats = dict.fromkeys(("s6_positions", "s6_chunked_scan", "s6_fused_conv",
-                           "s6_restarts"), jnp.float32(0.0))
+    stats = dict.fromkeys(("s6_positions", "s6_chunked_scan", "s6_fused_scan",
+                           "s6_fused_conv", "s6_restarts"), jnp.float32(0.0))
     shared = {}
     for (index, kind), layer in zip(kinds, layers):
         # recomputed from its inputs in the backward pass: one (T, C) array a
@@ -413,7 +415,8 @@ def decoder(layers, h, segs, cfg, compute_dtype):
     # the mean over the Mamba-1 layers: the row's positions, or 0
     mixers = max(sum(kind.startswith("s6") for _, kind in kinds), 1)
     stats.update({k: stats[k] / mixers
-                  for k in ("s6_chunked_scan", "s6_fused_conv")})
+                  for k in ("s6_chunked_scan", "s6_fused_scan",
+                            "s6_fused_conv")})
     return h, stats
 
 
@@ -421,9 +424,11 @@ def sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
     """One packed row ``(2, T)`` through the model: every language model's
     sums over tokens, and this stack's own, summed over its Mamba-1 layers:
     ``s6_positions`` (positions the scan ran over), ``s6_chunked_scan``
-    (those whose states were never held beyond a chunk) and ``s6_fused_conv``
-    (those whose convolution ran in the tiled kernel; of both the mean over
-    the layers, so the row's positions or 0), ``s6_restarts``
+    (those whose states were never held beyond a chunk or a kernel's block),
+    ``s6_fused_scan`` (those whose scan ran in the two kernels) and
+    ``s6_fused_conv`` (those whose convolution ran in the tiled kernel; of
+    the three the mean over the layers, so the row's positions or 0),
+    ``s6_restarts``
     (documents whose state started at zero); and of the row, ``window_pairs``
     over ``attention_pairs`` (allowed pairs under the window and without
     it). The model has no experts and hands out none of their statistics."""

@@ -137,6 +137,7 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
              "kda_fused_scan_positions": "kda_fused_scan",
              "s6_positions": "s6_positions",
              "s6_chunked_scan_positions": "s6_chunked_scan",
+             "s6_fused_scan_positions": "s6_fused_scan",
              "s6_fused_conv_positions": "s6_fused_conv",
              "s6_document_restarts": "s6_restarts",
              "lm_attention_pairs": "attention_pairs",

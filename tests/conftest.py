@@ -86,6 +86,8 @@ QUICK_TESTS = {
     "test_kda_scan_kernels.py::test_how_far_back_a_positions_run_reaches",
     # the residual modules' kernels: the rule between the two bodies
     "test_hyper_conn_kernels.py::test_the_rule_between_the_bodies",
+    # the selective scan's kernels: the rule between the two bodies
+    "test_selective_scan_kernels.py::test_the_rule_between_the_bodies",
     "test_stateless_round.py::"
     "test_minibatches_need_the_stateless_engine_and_a_known_client_state",
     # the stage of each operation from a compiled program's text (pure text)
@@ -470,6 +472,27 @@ def hyper_passes_on_the_cpu(monkeypatch):
     from fedtpu.ops import hyper_conn
 
     monkeypatch.setattr(hyper_conn, "hyper_passes_apply", lambda x: True)
+    monkeypatch.setattr(jax, "checkpoint", lambda fn, **policy: fn)
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+@pytest.fixture
+def fused_scan_on_the_cpu(monkeypatch):
+    """The decoder-hybrid-decoder stack's Mamba-1 mixers through the two
+    kernels of their selective scan (``fedtpu.ops.selective_scan``) on the
+    CPU, as ``tiled_passes_interpreted`` drives the hybrid stack's passes:
+    the rule between the bodies (``selective_scan.fused_scan_applies``)
+    steered to them at blocks of 128 positions and tiles of 128 channels
+    (what a tiny model's rows and inner width are), the kernels interpreted
+    (always under jit), no layer recomputed."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from fedtpu.ops import selective_scan
+
+    monkeypatch.setattr(selective_scan, "fused_scan_applies",
+                        lambda t, d, n: True)
+    monkeypatch.setattr(selective_scan, "scan_tiles", lambda d: (128, 128))
     monkeypatch.setattr(jax, "checkpoint", lambda fn, **policy: fn)
     with pltpu.force_tpu_interpret_mode():
         yield

@@ -771,9 +771,10 @@ def test_the_delta_rule_round_runs_its_recurrences_in_the_tiled_kernels(
     assert "f32[64,32,64,64]" not in kimi_linear_round.as_text()
 
 
-# The decoder-hybrid-decoder round's account (the compiler's own peak, this
-# file's compile for a described v5e).
-PHI4_ROUND_ACCOUNT = 13_804_177_920
+S6_LAYERS = 3               # of the preset's eight: layers 0, 2 and 16
+# The decoder-hybrid-decoder round's account with the selective scan in its
+# kernels (the compiler's own peak, this file's compile for a described v5e).
+PHI4_ROUND_ACCOUNT = 13_704_988_160
 
 
 @pytest.fixture(scope="module")
@@ -798,8 +799,10 @@ def test_the_decoder_hybrid_decoder_round_at_published_widths_fits_one_v5e_chip(
     at the padded head (64 | 128 to 128); the three Mamba-1 convolutions ran
     the hybrid stack's tiled kernel; every scope the reducers read is in the
     program. And no array of the program is as large as one row's scan
-    states, ``(4096, 5120, 16)``, forward or backward: a chunk's are the
-    largest of the scan's."""
+    states, ``(4096, 5120, 16)``, forward or backward, nor as a block's
+    states of all channels (256 positions), nor is the XLA body's chunk of 64
+    there: in the kernels a state never leaves the chip's own memory (PR
+    46)."""
     import json
     import math
     import os
@@ -815,7 +818,10 @@ def test_the_decoder_hybrid_decoder_round_at_published_widths_fits_one_v5e_chip(
     assert memory["engine_bytes"] == 12 * 979_332_096
     assert memory["engine_bytes"] <= account["total"] <= memory[
         "round_account_bound_bytes"] == 15.7e9, account
-    assert memory["round_account_bytes"] == PHI4_ROUND_ACCOUNT
+    # the file is the benchmark's and states PR 44's account, the scan in its
+    # XLA form; in the kernels of PR 46 a chunk's state-sized arrays and the
+    # chunks' entering states are no arrays, and the compile reads 99 MB less
+    assert memory["round_account_bytes"] == 13_804_177_920
     assert abs(account["total"] - PHI4_ROUND_ACCOUNT) <= (
         1e-5 * PHI4_ROUND_ACCOUNT), account
     assert account["aliased"] >= 7.8e9
@@ -832,7 +838,43 @@ def test_the_decoder_hybrid_decoder_round_at_published_widths_fits_one_v5e_chip(
                   "embed", "tied_embed_grad", "sgd_pass", "server_update"):
         assert f"/{scope}/" in text or f"({scope})" in text, scope
     states = 4096 * 5120 * 16
-    largest = max(math.prod(map(int, shape.split(",")))
-                  for shape in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
+    shapes = {tuple(map(int, shape.split(",")))
+              for shape in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text)}
+    largest = max(map(math.prod, shapes))
     assert largest == 50_016 * 2560 < states, largest   # the embedding
-    assert "f32[16,4,16,5120]" in text      # a chunk's: 64 positions
+    # what is shaped as states (an axis of 16 beside all 5,120 channels):
+    # the blocks' entering states, one a block of 256 positions, and nothing
+    # as large as the XLA body's chunk of 64 positions
+    stately = {shape for shape in shapes
+               if len(shape) > 2 and 16 in shape and 5120 in shape}
+    assert (16, 16, 5120) in stately, stately
+    assert max(map(math.prod, stately)) < 64 * 16 * 5120, stately
+    assert "f32[16,4,16,5120]" not in text  # the XLA body's chunk: 64 positions
+
+
+def test_the_decoder_hybrid_decoder_round_runs_its_scans_in_the_tiled_kernels(
+        phi4_flash_round):
+    """PR 46, the rule told the backend is a TPU: a Mamba-1 layer's selective
+    scan is one Mosaic call forward, the same once more in the layer's
+    recomputation (there it writes the blocks' entering states too), and one
+    backward; each call stands under ``ssm/s6_scan`` (what ``p4_s6_scan_ms``
+    reads: the piece ``s6_scan`` of the layer ``ssm``) and its ``op_name``
+    tells the direction as ``analysis.program`` reads it."""
+    from fedtpu.analysis.program import (BACKWARD, FORWARD, RECOMPUTE,
+                                         _pass_of, _stage_of)
+    from fedtpu.parallel.round import LAYERS, PIECES
+
+    calls = _named_kernels(phi4_flash_round, "s6_scan")
+    assert collections.Counter(name for name, _ in calls) == {
+        "s6_scan_forward": 2 * S6_LAYERS, "s6_scan_backward": S6_LAYERS}
+    directions = {FORWARD: 0, RECOMPUTE: 0, BACKWARD: 0}
+    for name, before in calls:
+        op_name = f"{before}/{name}/pallas_call"
+        assert "ssm/s6_scan/" in op_name or "ssm)/s6_scan/" in op_name, op_name
+        assert _stage_of(op_name, LAYERS) == "ssm"
+        assert _stage_of(op_name, PIECES) == "s6_scan"
+        direction = _pass_of(op_name, (), ())
+        assert (direction == BACKWARD) == (name == "s6_scan_backward"), op_name
+        directions[direction] += 1
+    assert directions == dict.fromkeys((FORWARD, RECOMPUTE, BACKWARD),
+                                       S6_LAYERS)

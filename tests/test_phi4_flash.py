@@ -313,6 +313,7 @@ def test_two_rounds_through_run_experiment_match_the_references_fedavgm(
     assert counted["stateless_client_steps"] == 2 * 10
     assert counted["s6_positions"] == 2 * 10 * T * 3    # three Mamba-1 layers
     assert counted["s6_chunked_scan_positions"] == 2 * 10 * T
+    assert counted["s6_fused_scan_positions"] == 0      # a CPU: the XLA body
     assert counted["s6_fused_conv_positions"] == 0      # a CPU
     assert counted["s6_document_restarts"] == 2 * 3 * starts
     assert starts > 20                                  # several a row
@@ -535,6 +536,55 @@ def test_the_scopes_of_a_tiny_round_name_this_stacks_pieces():
     passes = {walk["passes"].get(k, "forward") for k, piece in pieces.items()
               if piece == "s6_scan"}
     assert {"forward", "recompute", "backward"} <= passes
+
+
+def test_the_scans_kernels_keep_the_scans_scope_and_count_their_positions(
+        fused_scan_on_the_cpu, tmp_path):
+    """The rule between the scan's bodies told yes and the kernels
+    interpreted (``jax.checkpoint`` a pass-through: the interpreter's
+    callbacks cannot stand under it), at an inner width of one lane tile and
+    a state of one sublane tile: a tiny round LOWERED names
+    ``s6_scan_forward`` and ``s6_scan_backward`` on its operations' name
+    stacks, each under ``ssm/s6_scan`` and so the piece ``s6_scan`` (what
+    ``p4_s6_scan_ms`` reads), the second in the backward pass; and RUN, it
+    counts every scan position as one of the kernels', ``s6_fused_scan_
+    positions`` = the rows' positions = ``s6_chunked_scan_positions``. (The
+    compiled round at published widths holds the same of the Mosaic calls
+    themselves: ``tests/test_aot_tpu_compile.py``.)"""
+    import re
+
+    from fedtpu.analysis.program import BACKWARD, _pass_of, _stage_of
+    from fedtpu.parallel.round import LAYERS, PIECES
+
+    sink = str(tmp_path / "ev.jsonl")
+    cfg = tiny_phi4_flash(rounds=1,
+                          telemetry=TelemetryConfig(events_path=sink))
+    cfg = cfg.replace(
+        model=dataclasses.replace(cfg.model, hidden_size=64, mamba_d_state=8),
+        data=dataclasses.replace(cfg.data, synthetic_rows=2),
+        shard=dataclasses.replace(cfg.shard, num_clients=2))
+    exp = build_experiment(cfg)
+    text = exp.make_step(1).lower(exp.state, exp.batch).as_text(
+        debug_info=True)
+    names = set(re.findall(
+        r'"([^"]*/s6_scan_(?:forward|backward)/[^"]*)"', text))
+    kernels = {re.search(r"s6_scan_(?:forward|backward)", n).group(0)
+               for n in names}
+    assert kernels == {"s6_scan_forward", "s6_scan_backward"}
+    for name in names:
+        assert re.search(r"ssm\)*/s6_scan/s6_scan_(forward|backward)/", name)
+        assert _stage_of(name, LAYERS) == "ssm", name
+        assert _stage_of(name, PIECES) == "s6_scan", name
+        assert (_pass_of(name, (), ()) == BACKWARD) == (
+            "s6_scan_backward" in name), name
+    run_experiment(cfg, verbose=False)
+    counted = [json.loads(line) for line in open(sink)]
+    counted = [e for e in counted
+               if e["kind"] == "counters"][-1]["payload"]["counters"]
+    assert counted["s6_positions"] == 2 * T * 3         # three Mamba-1 layers
+    assert counted["s6_fused_scan_positions"] == 2 * T
+    assert counted["s6_chunked_scan_positions"] == 2 * T
+    assert counted["s6_fused_conv_positions"] == 0      # that rule: a CPU
 
 
 def test_what_the_registry_refuses():
