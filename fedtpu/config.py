@@ -96,7 +96,7 @@ class ModelConfig:
     """Model family + shape. MLP is FL_CustomMLP...:12-25; ConvNet is the
     BASELINE.json config-5 CIFAR-10 stress model (new, no reference analogue)."""
 
-    kind: str = "mlp"                    # 'mlp' | 'convnet' | 'olmoe' | 'nemotron_h' | 'xing4' | 'kimi_linear' | 'phi4_flash'
+    kind: str = "mlp"                    # 'mlp' | 'convnet' | 'olmoe' | 'nemotron_h' | 'xing4' | 'kimi_linear' | 'solar_open2' | 'phi4_flash'
     # () degenerates the MLP to a single Linear — multinomial logistic
     # regression (pinned by tests/test_round_smoke.py).
     hidden_sizes: Tuple[int, ...] = (50, 200)  # FL_CustomMLP...:40
@@ -205,6 +205,27 @@ class ModelConfig:
     kda_head_dim: int = 128
     short_conv_kernel_size: int = 4
     mla_use_nope: bool = False
+    # kind='solar_open2' (the same module, fedtpu.models.kimi_linear): the
+    # keys of the published config.json of upstage/Solar-Open2-250B. It
+    # reads kda_num_heads, kda_head_dim and short_conv_kernel_size above (its
+    # ``linear_attn_config`` group flat), hidden_size, num_attention_heads,
+    # num_key_value_heads, head_dim, num_hidden_layers, first_k_dense_replace
+    # (0), n_routed_experts, n_shared_experts, num_experts_per_tok,
+    # norm_topk_prob, routed_scaling_factor, moe_intermediate_size,
+    # rms_norm_eps, vocab_size, experts_held and first_expert, which its
+    # preset sets. gqa_layers: the layers, 0-BASED as published, whose mixer
+    # is grouped-query softmax attention without positions
+    # (fedtpu.models.layers.attention_mixer); every other layer is a KDA
+    # mixer. The stack reads this list and not the model's name: where it is
+    # given the "full" layer is the grouped-query one, where it is empty the
+    # two 1-based lists above name the layers and the full layer is latent
+    # attention. use_gqa_gate: that layer's context times sigmoid(W_g x), a
+    # number a head and channel, before W_o. kda_allow_neg_eigval: the delta
+    # rule's step is 2 sigmoid(W_b x), so I - beta k k^T has eigenvalues in
+    # (-1, 1].
+    gqa_layers: Tuple[int, ...] = ()
+    use_gqa_gate: bool = False
+    kda_allow_neg_eigval: bool = False
     # kind='phi4_flash' (fedtpu.models.phi4_flash): the keys of the published
     # config.json of microsoft/Phi-4-mini-flash-reasoning (``model_type:
     # phi4flash``) under their own names; it also reads hidden_size,
@@ -847,6 +868,39 @@ PRESETS["kimi-linear-48b-a3b-l5"] = ExperimentConfig(
                       norm_topk_prob=True, routed_scaling_factor=2.446,
                       rms_norm_eps=1e-5, vocab_size=20480, experts_held=8,
                       first_expert=0, compute_dtype="bfloat16"),
+    optim=OptimConfig(name="sgd", learning_rate=0.005, momentum=0.0,
+                      steplr_gamma=1.0),
+    fed=FedConfig(rounds=20, client_state="stateless", local_batch_rows=1,
+                  one_step_kind=True, server_opt="fedavgm",
+                  server_momentum=0.9, same_init=True),
+)
+
+
+# upstage/Solar-Open2-250B at its published widths, as one 16 GB chip holds
+# it where 40 chips share each expert layer by experts and the four chips of
+# a host each mixer by heads: the model's first four layers of 48 (one whole
+# period G K K K: the gated grouped-query softmax layer without positions,
+# then three KDA mixers whose step runs to 2), 8 of each layer's 320 routed
+# experts (the router stays 320 wide, top-8) beside the shared one, 16 of 64
+# query and KDA heads and 2 of 8 key-value heads (every head keeps its 128),
+# an eighth of the vocabulary: 905.8M parameters. Federated as the other
+# language models' presets are, on 16 packed 4,096-token sequences, with one
+# kind of step (PERF.md section 6, PR 47).
+PRESETS["solar-open2-250b-l4"] = ExperimentConfig(
+    data=DataConfig(dataset_name="tokens", synthetic_rows=16,
+                    synthetic_features=4096),
+    shard=ShardConfig(num_clients=8, shuffle=False),
+    model=ModelConfig(kind="solar_open2", hidden_size=4096,
+                      num_attention_heads=16, num_key_value_heads=2,
+                      head_dim=128, num_hidden_layers=4, gqa_layers=(0,),
+                      use_gqa_gate=True, kda_allow_neg_eigval=True,
+                      kda_num_heads=16, kda_head_dim=128,
+                      first_k_dense_replace=0, intermediate_size=10240,
+                      n_routed_experts=320, moe_intermediate_size=1280,
+                      num_experts_per_tok=8, norm_topk_prob=True,
+                      routed_scaling_factor=1.0, rms_norm_eps=1e-5,
+                      vocab_size=24576, experts_held=8, first_expert=0,
+                      compute_dtype="bfloat16"),
     optim=OptimConfig(name="sgd", learning_rate=0.005, momentum=0.0,
                       steplr_gamma=1.0),
     fed=FedConfig(rounds=20, client_state="stateless", local_batch_rows=1,

@@ -1,22 +1,32 @@
-"""Kimi-Linear: a gated delta-rule recurrence (KDA) three layers in four,
-latent attention without positions in the fourth, a leading dense layer and
+"""The delta-rule stack of two models: a gated delta-rule recurrence (KDA)
+three layers in four and softmax attention without positions in the fourth,
 a held share of sparse experts, on packed sequences.
 
-The stack is the one ``config.json`` of moonshotai/Kimi-Linear-48B-A3B-
-Instruct (``model_type: kimi_linear``) defines; the KDA layer is
-``fla.layers.kda.KimiDeltaAttention`` and its recurrence
-``fla.ops.kda.naive.naive_recurrent_kda``, which the model's own
-``modeling_kimi.py`` follows. What the config leaves to the code is listed in
-the benchmark's configuration file under ``assumed``. Every layer is
+* **Kimi-Linear** (``kind="kimi_linear"``; ``config.json`` of moonshotai/
+  Kimi-Linear-48B-A3B-Instruct, ``model_type: kimi_linear``): the fourth
+  layer is latent attention, the lists ``kda_layers`` / ``full_attn_layers``
+  are 1-based as published, a leading dense layer, ``beta = sigmoid``.
+* **Solar-Open2** (``kind="solar_open2"``; ``config.json`` of upstage/
+  Solar-Open2-250B, ``model_type: solar_open2``): the fourth layer is gated
+  grouped-query attention and LEADS the period, the list ``gqa_layers`` is
+  0-based as published and every other layer is KDA, no dense layer, ``beta =
+  2 sigmoid`` (``kda_allow_neg_eigval``).
+
+The KDA layer is ``fla.layers.kda.KimiDeltaAttention`` and its recurrence
+``fla.ops.kda.naive.naive_recurrent_kda``, which Kimi-Linear's own
+``modeling_kimi.py`` follows. What a config leaves to the code is listed in
+the benchmark's configuration files under ``assumed``. Every layer is
 ``h + Mixer(RMSNorm(h))``, then ``h + FFN(RMSNorm(h))``.
 
-* **The KDA mixer** (layers ``kda_layers``; ``kda_num_heads`` heads, keys and
+* **The KDA mixer** (``kda_num_heads`` heads, keys and
   values ``kda_head_dim`` wide). ``q, k, v = SiLU(conv(W x))``, the
   convolution depthwise, causal, ``short_conv_kernel_size`` taps, no bias,
   zeros before a document's first token (``ssm_passes.causal_conv``); a head's
   ``q <- q / |q| d^-1/2`` and ``k <- k / |k|``. The log-decay of head ``h``
   and key channel ``i`` is ``g = -exp(A_log[h]) softplus(W_f2 W_f1 x +
-  dt_bias)``, the step ``beta = sigmoid(W_b x)``. A head's state ``S (d_k,
+  dt_bias)``, the step ``beta = sigmoid(W_b x)``, or twice that where
+  ``kda_allow_neg_eigval``: ``I - beta k k^T`` then has eigenvalues in (-1,
+  1] and the state can flip sign along ``k``. A head's state ``S (d_k,
   d_v)`` is zero at a document's first token and
   ``S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T``,
   ``o_t = S_t^T q_t``; then ``W_o [RMSNorm_head(o) * sigmoid(W_g2 W_g1 x +
@@ -27,9 +37,13 @@ the benchmark's configuration file under ``assumed``. Every layer is
   the two Mosaic kernels that run it on a TPU with a head's state in the
   chip's memory, and the rule between them (``fused_scan_applies``).
   ``kda_fused_scan`` among the statistics says which ran.
-* **Latent attention** (layers ``full_attn_layers``):
-  ``fedtpu.models.layers.latent_attention`` with no query bottleneck and
-  nothing rotated (``q_lora_rank`` None, ``mla_use_nope``).
+* **The "full" layer**, by the list that names it (``_gqa``; nothing in this
+  module reads ``kind``): by the two 1-based lists latent attention
+  (``fedtpu.models.layers.latent_attention`` with no query bottleneck and
+  nothing rotated: ``q_lora_rank`` None, ``mla_use_nope``), by ``gqa_layers``
+  grouped-query attention without positions (``layers.attention_mixer``, the
+  hybrid stack's) whose context is multiplied by ``sigmoid(W_g x)`` before
+  ``W_o`` (``use_gqa_gate``).
 * **Feed-forward**: the first ``first_k_dense_replace`` layers
   ``layers.dense_mlp``, every other ``layers.experts_mixer`` with the gated
   activation: sigmoid scores over all routed experts, the top
@@ -52,9 +66,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from fedtpu.models.layers import (_ffn_init, bodies_at, cut_from_one_draw,
-                                  dense_mlp, experts_mixer, experts_share,
-                                  held_matmuls, latent_attention, rms_norm)
+from fedtpu.models.layers import (_ffn_init, attention_mixer, bodies_at,
+                                  cut_from_one_draw, dense_mlp, experts_mixer,
+                                  experts_share, held_matmuls,
+                                  latent_attention, rms_norm)
 from fedtpu.ops import kda_scan as scan
 from fedtpu.ops.kda_scan import KDA_CHUNK, KDA_SUB
 from fedtpu.ops.lm_head import _head_loss, next_token_targets
@@ -71,23 +86,45 @@ L2_EPS = 1e-6
 PER_ROW = ("padding", "fused_attention", "grouped_experts",
            "attention_blocks_computed", "attention_blocks_causal",
            "rows_computed", "kda_positions", "kda_fused_scan",
-           "kda_log_decay_min", "sequences")
+           "kda_log_decay_min", "kda_step_max", "sequences")
 
 _mm = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
 
 
+def _gqa(cfg) -> bool:
+    """Whether the stack's "full" layer is the grouped-query one: where the
+    configuration names its layers by ``gqa_layers`` (0-based, every other
+    layer KDA); the two 1-based lists name them where it is empty. The
+    configuration decides, whatever the model is called."""
+    return bool(cfg.gqa_layers)
+
+
 def layer_kinds(cfg) -> tuple:
     """``(mixer, feed-forward)`` of every layer, in order: ``"kda"`` or
-    ``"full"``, ``"dense"`` or ``"experts"``. The two published lists are
-    1-based and must name every layer once; the stack builds no multi-token-
+    ``"full"``, ``"dense"`` or ``"experts"``. Kimi-Linear publishes two
+    1-based lists, ``kda_layers`` and ``full_attn_layers``, which must name
+    every layer once; Solar-Open2 publishes ``gqa_layers``, 0-based, and
+    every layer it does not name is KDA. The stack builds no multi-token-
     prediction module."""
     layers = cfg.num_hidden_layers
     kda, full = tuple(cfg.kda_layers), tuple(cfg.full_attn_layers)
-    if sorted(kda + full) != list(range(1, layers + 1)):
+    if _gqa(cfg):       # the two 1-based lists are not read
+        full = tuple(i + 1 for i in cfg.gqa_layers)
+        if len(set(full)) != len(full) or not all(
+                1 <= i <= layers for i in full):
+            raise ValueError(
+                f"gqa_layers {tuple(cfg.gqa_layers)} does not name layers "
+                f"among the {layers} (0-based, as Solar-Open2 publishes "
+                "them), each once")
+        kda = tuple(i for i in range(1, layers + 1) if i not in full)
+    elif sorted(kda + full) != list(range(1, layers + 1)):
         raise ValueError(
-            f"kda_layers {kda} and full_attn_layers {full} do not name each "
-            f"of the {layers} layers once: a layer is a KDA mixer or latent "
-            "attention, and no other kind is built")
+            f"kda_layers {kda} and full_attn_layers {full} do not name "
+            f"each of the {layers} layers once (1-based, as Kimi-Linear "
+            "publishes them), and gqa_layers (0-based, as Solar-Open2 "
+            "publishes it) is empty: a layer is a KDA mixer or the full "
+            "layer, latent attention by the two lists and grouped-query "
+            "attention by gqa_layers, and no other kind is built")
     if not 0 <= cfg.first_k_dense_replace <= layers:
         raise ValueError(f"first_k_dense_replace {cfg.first_k_dense_replace} "
                          f"is not within the {layers} layers")
@@ -101,9 +138,13 @@ def layer_kinds(cfg) -> tuple:
 
 
 def check(cfg) -> None:
-    """What the two lists and the share must satisfy."""
-    layer_kinds(cfg)            # the two lists, no prediction module
+    """What the lists, the share and the heads must satisfy."""
+    layer_kinds(cfg)            # the lists, no prediction module
     experts_share(cfg)
+    if _gqa(cfg) and cfg.num_attention_heads % cfg.num_key_value_heads:
+        raise ValueError(
+            f"{cfg.num_attention_heads} query heads do not divide over "
+            f"{cfg.num_key_value_heads} key-value heads")
 
 
 # ------------------------------------------------------------------ init
@@ -140,6 +181,15 @@ def _kda_own_init(cfg, key, dtype):
             "g_bias": jnp.zeros((width,), dtype)}
 
 
+def _gqa_init(cfg, normal, ones):
+    h = cfg.hidden_size
+    q, kv = (cfg.num_attention_heads * cfg.head_dim,
+             cfg.num_key_value_heads * cfg.head_dim)
+    gate = {"gate": normal(h, q)} if cfg.use_gqa_gate else {}
+    return {"norm": ones(h), "q": normal(h, q), "k": normal(h, kv),
+            "v": normal(h, kv), **gate, "o": normal(q, h)}
+
+
 def _full_init(cfg, normal, ones):
     h, heads = cfg.hidden_size, cfg.num_attention_heads
     nope, rope, v = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -165,7 +215,8 @@ def init(key: jax.Array, cfg, param_dtype=jnp.float32):
             own = {**weights(functools.partial(_kda_init, cfg)),
                    **_kda_own_init(cfg, fresh(), param_dtype)}
         else:
-            own = weights(functools.partial(_full_init, cfg))
+            own = weights(functools.partial(
+                _gqa_init if _gqa(cfg) else _full_init, cfg))
         return {"mixer": own,
                 "ffn": weights(functools.partial(_ffn_init, ffn, cfg))}
 
@@ -228,6 +279,8 @@ def kda_mixer(cfg, compute_dtype, h, layer, segs):
             fall = (-jnp.exp(layer["A_log"].astype(jnp.float32))[:, None, None]
                     * jax.nn.softplus(tiles(decay + layer["dt_bias"])))
             g, beta = _rows(fall), jax.nn.sigmoid(step)
+            if cfg.kda_allow_neg_eigval:
+                beta = 2.0 * beta
         with jax.named_scope(KDA_SCAN):
             o = scan.kda_scan(by_head(q), by_head(k), by_head(v), by_head(g),
                               beta, run, KDA_CHUNK, compute_dtype)
@@ -242,10 +295,15 @@ def kda_mixer(cfg, compute_dtype, h, layer, segs):
                       * jax.nn.sigmoid(tiles(gate + layer["g_bias"])))
         with jax.named_scope(KDA_OUT_PROJ):
             out = _mm(cast(y), cast(layer["o_proj"]))
+    real = segs > 0
+    steps = jnp.where(real[:, None], lax.stop_gradient(beta), 0.0)
+    count = lambda mask: mask.sum().astype(jnp.float32)
     return out, {"kda_positions": jnp.float32(t),
                  "kda_fused_scan": jnp.float32(t if fused else 0),
-                 "kda_restarts": (starts & (segs > 0)).sum().astype(
-                     jnp.float32),
+                 "kda_restarts": count(starts & real),
+                 "kda_head_steps": heads * count(real),
+                 "kda_steps_over_one": count(steps > 1.0),
+                 "kda_step_max": steps.max(),
                  "kda_log_decay_min": deepest}
 
 
@@ -257,6 +315,9 @@ def block(kinds, cfg, compute_dtype, h, layer, segs):
     mixer, ffn = kinds
     if mixer == "kda":
         out, stats = kda_mixer(cfg, compute_dtype, h, layer["mixer"], segs)
+    elif _gqa(cfg):
+        out, stats = attention_mixer(cfg, compute_dtype, h, layer["mixer"],
+                                     segs, eps=cfg.rms_norm_eps)
     else:       # without positions: ``pos`` is not read
         out, stats = latent_attention(cfg, compute_dtype, h, layer["mixer"],
                                       segs, None), {}
@@ -273,7 +334,8 @@ def _zero_stats(cfg):
     return {"expert_load": jnp.zeros((cfg.n_routed_experts,), jnp.int32),
             "assignments_held": zero, "rows_computed": zero,
             "rows_held_computed": zero, "kda_positions": zero,
-            "kda_fused_scan": zero, "kda_restarts": zero}
+            "kda_fused_scan": zero, "kda_restarts": zero,
+            "kda_head_steps": zero, "kda_steps_over_one": zero}
 
 
 def sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
@@ -286,26 +348,36 @@ def sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
     started at zero),
     ``kda_log_decay_min`` (the most negative cumulative log-decay of any
     chunk, head and channel of the sequence: at most 0, and under -88 where
-    ``exp(-G)`` would have overflowed float32) and ``sequences`` (1)."""
+    ``exp(-G)`` would have overflowed float32), ``kda_head_steps`` (real
+    positions times heads: the delta rule's steps), ``kda_steps_over_one``
+    (those of them whose ``beta`` is over 1, where ``I - beta k k^T`` has a
+    negative eigenvalue: none unless ``kda_allow_neg_eigval``),
+    ``kda_step_max`` (the largest ``beta`` at a real position) and
+    ``sequences`` (1)."""
     tokens, segs = row[0], row[1]
     kinds = layer_kinds(cfg)
     t, heads = tokens.shape[0], cfg.num_attention_heads
     full = sum(mixer == "full" for mixer, _ in kinds)
+    # the core's head as the full layer of this kind hands it over
+    core = (dict(qk_width=cfg.head_dim, v_width=cfg.head_dim) if _gqa(cfg)
+            else dict(qk_width=cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+                      v_width=cfg.v_head_dim, scaled=True))
     _, fused, grouped = bodies_at(
-        t, heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim,
-        compute_dtype, scaled=True,
+        t, heads, compute_dtype=compute_dtype, **core,
         experts=(held_matmuls(cfg, t)
                  if any(ffn == "experts" for _, ffn in kinds) else None))
     fused = full > 0 and fused
     with jax.named_scope(EMBED):
         h = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
-    stats, deepest = _zero_stats(cfg), jnp.float32(0.0)
+    stats, deepest, largest = (_zero_stats(cfg), jnp.float32(0.0),
+                               jnp.float32(0.0))
     for kind, layer in zip(kinds, params["layers"]):
         # recomputed from its input in the backward pass: one (T, C) array a
         # layer is kept
         h, own = jax.checkpoint(functools.partial(
             block, kind, cfg, compute_dtype, segs=segs))(h, layer)
         deepest = jnp.minimum(deepest, own.pop("kda_log_decay_min", 0.0))
+        largest = jnp.maximum(largest, own.pop("kda_step_max", 0.0))
         stats = {**stats, **{k: stats[k] + v for k, v in own.items()}}
     stats["kda_fused_scan"] = stats["kda_fused_scan"] / max(
         sum(mixer == "kda" for mixer, _ in kinds), 1)
@@ -320,4 +392,5 @@ def sequence_stats(params, row, cfg, compute_dtype=jnp.float32):
             "fused_attention": jnp.float32(t if fused else 0),
             "grouped_experts": jnp.float32(t if grouped else 0),
             "sequences": jnp.float32(1.0), "kda_log_decay_min": deepest,
+            "kda_step_max": largest,
             **attention_blocks(segs, fused, full), **stats}

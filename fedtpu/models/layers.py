@@ -32,6 +32,9 @@ packed sequence (``(2, T)`` int32 token and segment ids, 0 marking padding).
   rule (``held_experts``): the backward pass runs the same trips and
   differentiates each block inside its trip, adding up the weights'
   gradients (one pass over them a block: the price of the static buffer).
+* **Grouped-query attention without positions** (``attention_mixer``: the
+  hybrid stack's ``*`` layer, and with the sigmoid gate on its context the
+  delta-rule stack's softmax layer where the config asks for one).
 * **Latent attention** (``latent_attention``; ``transformers``'
   ``DeepseekV3Attention``): the query through a bottleneck of
   ``q_lora_rank`` behind an RMSNorm, keys and values through one of
@@ -64,7 +67,7 @@ from fedtpu.ops import packed_attention
 from fedtpu.ops.grouped_matmul import (gather_rows, grouped_matmul,
                                        sorted_assignments)
 from fedtpu.ops.packed_attention import attention_core
-from fedtpu.ops.scopes import (ATTENTION, ATTN_LATENT, DENSE_MLP,
+from fedtpu.ops.scopes import (ATTENTION, ATTN_GATE, ATTN_LATENT, DENSE_MLP,
                                EXPERT_DISPATCH, EXPERTS, RECOMPUTE, ROUTER,
                                SHARED_EXPERT)
 
@@ -342,6 +345,35 @@ def experts_mixer(cfg, compute_dtype, h, layer, segs, eps=None):
                  "assignments_held": total.astype(jnp.float32),
                  "rows_computed": computed.astype(jnp.float32),
                  "rows_held_computed": covered.astype(jnp.float32)}
+
+
+# ------------------------------------------------ grouped-query attention
+def attention_mixer(cfg, compute_dtype, h, layer, segs, eps=None):
+    """``(mixer(RMSNorm(h)), {})`` of one grouped-query softmax layer
+    without positions: query head ``i`` attends key-value head ``i // (heads
+    / kv heads)``. A layer that has ``gate`` (``use_gqa_gate``) multiplies
+    the context by ``sigmoid(W_g x)``, a number a head and channel, float32,
+    before ``W_o``; ``eps`` is the pre-norm's where it is not the config's
+    ``layer_norm_epsilon``."""
+    t = h.shape[0]
+    heads, kv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                     cfg.head_dim)
+    cast = lambda arr: arr.astype(compute_dtype)
+    with jax.named_scope(ATTENTION):
+        x = cast(rms_norm(h, layer["norm"],
+                          cfg.layer_norm_epsilon if eps is None else eps))
+        q = _mm(x, cast(layer["q"])).reshape(t, heads, hd)
+        # the core's bodies take one head count: each key-value head is
+        # repeated for the query heads that share it
+        k, v = (jnp.repeat(_mm(x, cast(layer[name])).reshape(t, kv, hd),
+                           heads // kv, axis=1) for name in ("k", "v"))
+        ctx = attention_core(q, k, v, segs, compute_dtype).reshape(
+            t, heads * hd)
+        if "gate" in layer:
+            with jax.named_scope(ATTN_GATE):
+                ctx = ctx * jax.nn.sigmoid(_mm(x, cast(layer["gate"])))
+        out = _mm(cast(ctx), cast(layer["o"]))
+    return out, {}
 
 
 # ------------------------------------------------------- latent attention

@@ -14,7 +14,8 @@ behind one pre-norm, ``h <- h + mixer(RMSNorm(h))``, and the letter of
   ``y_t = S_t C_t + D x_t``; then ``w * RMSNorm(y * silu(z))`` over each of
   the ``n_groups`` groups of the inner width (gate first, then the norm) and
   ``W_out``. No bias but the convolution's.
-* ``*``, grouped-query attention: ``num_attention_heads`` query heads of
+* ``*``, grouped-query attention (``fedtpu.models.layers.attention_mixer``,
+  shared with the delta-rule stack): ``num_attention_heads`` query heads of
   ``head_dim`` (NOT ``hidden_size / heads``) over ``num_key_value_heads``
   key-value heads, causal, no positional encoding of any kind (positions
   reach the model through the state-space layers).
@@ -82,13 +83,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from fedtpu.models.layers import (INIT_STD, _experts_init, bodies_at,
-                                  experts_mixer, experts_share, held_matmuls,
-                                  rms_norm)
+from fedtpu.models.layers import (INIT_STD, _experts_init, attention_mixer,
+                                  bodies_at, experts_mixer, experts_share,
+                                  held_matmuls, rms_norm)
 from fedtpu.ops import ssm_passes
 from fedtpu.ops.lm_head import _head_loss, next_token_targets
-from fedtpu.ops.packed_attention import attention_blocks, attention_core
-from fedtpu.ops.scopes import (ATTENTION, EMBED, LM_HEAD_LOSS, SSM, SSM_CONV,
+from fedtpu.ops.packed_attention import attention_blocks
+from fedtpu.ops.scopes import (EMBED, LM_HEAD_LOSS, SSM, SSM_CONV,
                                SSM_GATE_NORM, SSM_IN_PROJ, SSM_OUT_PROJ,
                                SSM_SCAN)
 
@@ -307,26 +308,6 @@ def mamba_mixer(cfg, compute_dtype, h, layer, segs):
     real = segs > 0
     return out, {"ssm_positions": jnp.float32(t),
                  "ssm_restarts": (starts & real).sum().astype(jnp.float32)}
-
-
-# ------------------------------------------------------------- attention
-def attention_mixer(cfg, compute_dtype, h, layer, segs):
-    """``(mixer(RMSNorm(h)), {})`` of one ``*`` layer: query head ``i``
-    attends key-value head ``i // (heads / kv heads)``; no positions."""
-    t = h.shape[0]
-    heads, kv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                     cfg.head_dim)
-    cast = lambda arr: arr.astype(compute_dtype)
-    with jax.named_scope(ATTENTION):
-        x = cast(rms_norm(h, layer["norm"], cfg.layer_norm_epsilon))
-        q = _mm(x, cast(layer["q"])).reshape(t, heads, hd)
-        # the core's bodies take one head count: each key-value head is
-        # repeated for the query heads that share it
-        k, v = (jnp.repeat(_mm(x, cast(layer[name])).reshape(t, kv, hd),
-                           heads // kv, axis=1) for name in ("k", "v"))
-        ctx = attention_core(q, k, v, segs, compute_dtype)
-        out = _mm(cast(ctx.reshape(t, heads * hd)), cast(layer["o"]))
-    return out, {}
 
 
 _MIXERS = {"mamba": mamba_mixer, "attention": attention_mixer,

@@ -22,6 +22,7 @@ LANGUAGE_MODELS = {"olmoe": "fedtpu.models.olmoe",
                    "nemotron_h": "fedtpu.models.nemotron_h",
                    "xing4": "fedtpu.models.xing4",
                    "kimi_linear": "fedtpu.models.kimi_linear",
+                   "solar_open2": "fedtpu.models.kimi_linear",
                    "phi4_flash": "fedtpu.models.phi4_flash"}
 
 

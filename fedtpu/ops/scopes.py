@@ -45,14 +45,17 @@ LAYERS = (EMBED, ATTENTION, ROUTER, EXPERT_DISPATCH, EXPERTS, LM_HEAD_LOSS,
 # mixer (its four projections, the short convolution, the selective scan,
 # the ``D`` skip and the gate) and the Gated Memory Unit inside ``ssm``, the
 # combination of differential attention's two softmaxes inside ``attention``,
-# and the meeting of a tied embedding's two gradients inside ``embed``.
+# the meeting of a tied embedding's two gradients inside ``embed``, and the
+# sigmoid gate on a grouped-query layer's context (its projection, the
+# sigmoid, the product) inside ``attention``.
 (SSM_IN_PROJ, SSM_CONV, SSM_GATE_NORM, SSM_OUT_PROJ, ATTN_CORE, HC_SINKHORN,
  ATTN_LATENT, KDA_IN_PROJ, KDA_CONV, KDA_GATES, KDA_OUT_PROJ, S6_PROJ,
- S6_CONV, S6_SCAN, S6_GATE, GMU, DIFF_COMBINE, TIED_EMBED_GRAD) = PIECES = (
+ S6_CONV, S6_SCAN, S6_GATE, GMU, DIFF_COMBINE, TIED_EMBED_GRAD,
+ ATTN_GATE) = PIECES = (
     "ssm_in_proj", "ssm_conv", "ssm_gate_norm", "ssm_out_proj", "attn_core",
     "hc_sinkhorn", "attn_latent", "kda_in_proj", "kda_conv", "kda_gates",
     "kda_out_proj", "s6_proj", "s6_conv", "s6_scan", "s6_gate", "gmu",
-    "diff_combine", "tied_embed_grad")
+    "diff_combine", "tied_embed_grad", "attn_gate")
 # An outer scope AROUND layers: a whole multi-token-prediction module, whose
 # attention, experts and head keep their own layers' names inside it; and
 # which of three an attention layer is (under a window, full, or reading

@@ -121,9 +121,9 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
     assignments = model_cfg.num_experts_per_tok * model_cfg.num_hidden_layers
     # what a model that holds a share of its experts counts besides, and
     # what its other layers do (nemotron_h's state-space layers; xing4's
-    # residual modules and prediction module; kimi_linear's delta-rule
-    # recurrences; phi4_flash's selective scans and its window): read off
-    # the statistics it hands out
+    # residual modules and prediction module; kimi_linear's and
+    # solar_open2's delta-rule recurrences; phi4_flash's selective scans and
+    # its window): read off the statistics it hands out
     share = {"moe_assignments_held": "assignments_held",
              "moe_rows_computed": "rows_computed",
              "ssm_positions": "ssm_positions",
@@ -135,6 +135,8 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
              "kda_positions": "kda_positions",
              "kda_document_restarts": "kda_restarts",
              "kda_fused_scan_positions": "kda_fused_scan",
+             "kda_head_steps": "kda_head_steps",
+             "kda_steps_over_one": "kda_steps_over_one",
              "s6_positions": "s6_positions",
              "s6_chunked_scan_positions": "s6_chunked_scan",
              "s6_fused_scan_positions": "s6_fused_scan",
@@ -179,6 +181,12 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
             # recurrences (under -88, ``exp(-G)`` would overflow float32)
             extra.update(kda_log_decay_min=s["kda_log_decay_min"]
                          / jnp.maximum(s["sequences"], 1.0))
+        if "kda_step_max" in s:
+            # and the mean over its steps of a step's largest ``beta`` at a
+            # real position (under 1, or under 2 where the model allows
+            # negative eigenvalues)
+            extra.update(kda_step_max=s["kda_step_max"]
+                         / jnp.maximum(s["sequences"], 1.0))
         if second:
             extra.update(main_loss=mean(s["loss_sum"], s["count"]),
                          mtp_loss=mean(s["mtp_loss_sum"], s["mtp_count"]))
@@ -200,7 +208,8 @@ def next_token_task(stats_fn: Callable, model_cfg) -> Task:
                 counters=counters,
                 gauges=("moe_expert_load_max_over_mean",
                         "attention_padded_width", "hc_sinkhorn_residual",
-                        "main_loss", "mtp_loss", "kda_log_decay_min"))
+                        "main_loss", "mtp_loss", "kda_log_decay_min",
+                        "kda_step_max"))
 
 
 def build_task(model_cfg, model_fn: Callable, num_classes: int) -> Task:

@@ -80,6 +80,8 @@ QUICK_TESTS = {
     "test_xing4.py::test_the_modules_targets_and_validity_at_document_edges",
     # the delta-rule stack: what its layer lists and its scan refuse
     "test_kimi_linear.py::test_what_the_registry_refuses",
+    "test_solar_open2.py::"
+    "test_layer_kinds_from_the_published_lists_and_what_the_registry_refuses",
     # the decoder-hybrid-decoder stack: what its held layers must satisfy
     "test_phi4_flash.py::test_what_the_registry_refuses",
     # the recurrence's kernels: the one operand their masks come from

@@ -131,6 +131,19 @@ def test_every_scope_is_a_name_of_the_one_file_that_writes_it():
     assert round_mod.LAYER_KERNELS == scopes.LAYER_KERNELS
 
 
+def test_only_the_registry_reads_a_models_name():
+    """A model's module and the kernels under it decide by what the
+    configuration states (a list, a flag, a shape), so a second model can
+    take a stack over by its fields alone: nothing under ``fedtpu/models/``
+    or ``fedtpu/ops/`` but the registry reads ``.kind``."""
+    for path in _files("models") + _files("ops"):
+        if path == registry.__file__:
+            continue
+        read = [node.lineno for node in ast.walk(_tree(path))
+                if isinstance(node, ast.Attribute) and node.attr == "kind"]
+        assert read == [], (path, read)
+
+
 def test_the_registry_is_a_table():
     """No branch a language model in ``build_model``: the kinds its source
     compares ``cfg.kind`` with are the two classifiers'."""
